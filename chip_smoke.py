@@ -1,0 +1,599 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+    python chip_smoke.py
+
+Drives the three normal entry points once, in this one process, on every
+TPU chip the process sees, through the same ``main(argv)`` functions the
+CLIs expose, on synthetic data made from a seed:
+
+  device    refuse anything but a TPU; say what was found
+  kernels   every Pallas entry against its jnp reference (oracle at HIGHEST
+            matmul precision), each proven Mosaic-compiled by the text of
+            the program that ran
+  ps        cli.train, ResNet-18 / Cifar10 at batch 128 per worker: the
+            default wire, then the int8 wire (on several chips also the
+            homomorphic 2-round wire), a checkpoint, cli.evaluate --once
+  lm        cli.train_lm at d512 x 6, seq 1024, bf16, flash attention, with
+            two checkpoints
+  serve     cli.serve on the older checkpoint, a handful of requests and
+            one hot rollover onto the newer
+
+While each ``main`` runs, jax's own compile log is read: no step program
+may compile twice for the same argument shapes. After each trainer leg the
+same step is built once more through the library, so the script can look at
+what ``main`` keeps to itself: the compiled program's text (Mosaic custom
+calls by kernel name), the sharding of every state and batch leaf after a
+step, and each device's memory.
+
+A leg that fails raises: the traceback is the report, the exit code is
+non-zero and no result line is printed. On success the LAST line of stdout
+is one JSON object, ``{"ok": true, "device": {...}, ...}``. It carries no
+speed: sizes, rates and utilization are the benchmark's job, not this
+script's (``"claim": null``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import importlib.metadata
+import json
+import logging
+import math
+import os
+import sys
+import tempfile
+import time
+
+# the model shapes are the repo's own: ResNet-18 / Cifar10 is the
+# reference's canonical job (run_pytorch.sh), d512 x 6 / seq 1024 / batch 8
+# is the LM shape bench.py measures. Steps are few; widths are not cut.
+PS_ARGS = [
+    "--network", "ResNet18", "--dataset", "Cifar10", "--batch-size", "128",
+    "--lr", "0.01", "--momentum", "0.9", "--max-steps", "10",
+    "--eval-freq", "0", "--log-interval", "5",
+]
+PS_WIRES = {
+    "default": [],
+    "int8": ["--compress-grad", "compress", "--bucket-bytes", "4194304",
+             "--error-feedback"],
+    # several chips only: accumulate_rescale_int8 and the int8
+    # all_to_all / all_gather have nothing to do on one
+    "2round_homomorphic": [
+        "--compress-grad", "2round", "--wire-domain", "homomorphic",
+        "--bucket-bytes", "4194304", "--error-feedback",
+    ],
+}
+# Mosaic kernels each compiled PS step must contain
+PS_KERNELS = {
+    "default": (),
+    "int8": ("ps_quantize_2d",),
+    "2round_homomorphic": ("ps_quantize_2d", "ps_accum_rescale"),
+}
+LM_ARGS = [
+    "--dim", "512", "--depth", "6", "--heads", "8", "--seq-len", "1024",
+    "--batch-size", "8", "--dtype", "bfloat16", "--attention-impl", "flash",
+    "--max-steps", "6", "--eval-freq", "3", "--log-interval", "1",
+]
+LM_KERNELS = ("ps_flash_fwd", "ps_flash_dq", "ps_flash_dkv")
+SERVE_ARGS = [
+    "--step", "3", "--slots", "8", "--requests", "16", "--rate", "20",
+    "--prompt-min", "4", "--prompt-max", "16", "--new-min", "8",
+    "--new-max", "32", "--poll-interval", "0.05", "--dtype", "bfloat16",
+]
+FLASH_SHAPE = (8, 1024, 8, 64)  # B, T, H, D: the LM leg's attention
+BUCKET_ELEMS = (4 << 20) // 4   # one 4 MiB f32 gradient bucket
+
+
+class CompileLog(logging.Handler):
+    """What jax compiled and what its persistent cache did, from jax's own
+    log records (they name the program; its monitoring events do not).
+
+    Matches three message formats of the installed jax; ``check_steps``
+    refuses to pass on an empty log, so a jax that words them differently
+    fails the smoke instead of silently passing it."""
+
+    LOGGERS = ("jax._src.interpreters.pxla", "jax._src.compiler")
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.compiled = []  # (program, argument shapes)
+        self.hits = []      # persistent cache, by module name
+        self.misses = []
+        self._saved = []
+
+    def emit(self, record):
+        msg = str(record.msg)
+        if msg.startswith("Compiling %s with global shapes"):
+            self.compiled.append((record.args[0], str(record.args[1])))
+        elif msg.startswith("Persistent compilation cache hit"):
+            self.hits.append(record.args[0])
+        elif msg.startswith("PERSISTENT COMPILATION CACHE MISS"):
+            self.misses.append(record.args[0])
+        elif record.levelno >= logging.WARNING:
+            sys.stderr.write(record.getMessage() + "\n")
+
+    def __enter__(self):
+        for name in self.LOGGERS:
+            lg = logging.getLogger(name)
+            self._saved.append((lg, lg.level, lg.propagate))
+            lg.setLevel(logging.DEBUG)
+            lg.propagate = False  # DEBUG records stop here, not on stderr
+            lg.addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        for lg, level, propagate in self._saved:
+            lg.removeHandler(self)
+            lg.setLevel(level)
+            lg.propagate = propagate
+        self._saved = []
+
+    def check_steps(self, leg, programs):
+        """Each named step program was compiled, and no (program, argument
+        shapes) pair twice — a second compile of the same shapes is a
+        sharding the first call did not have (state left off the mesh).
+        Returns {program: "hit" | "miss"}: what the persistent cache did
+        for it (a program that missed on any shape set is a miss)."""
+        outcome = {}
+        for prog in programs:
+            seen = [s for p, s in self.compiled if p == prog]
+            if not seen:
+                raise AssertionError(
+                    f"{leg}: {prog} never compiled — wrong program name, "
+                    f"or jax words its compile log differently"
+                )
+            if len(set(seen)) != len(seen):
+                raise AssertionError(
+                    f"{leg}: {prog} compiled {len(seen)} times for "
+                    f"{len(set(seen))} distinct argument shapes"
+                )
+            module = prog.replace("(", "_").replace(")", "")
+            outcome[prog] = (
+                "hit" if module in self.hits and module not in self.misses
+                else "miss"
+            )
+            print(f"[{leg}] {prog}: compiled once per shape set "
+                  f"({len(seen)}), persistent cache {outcome[prog]}",
+                  flush=True)
+        return outcome
+
+
+def check_on_all_devices(leg, what, tree, devices):
+    import jax
+
+    want = set(devices)
+    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
+        if not isinstance(leaf, jax.Array):
+            continue
+        if set(leaf.sharding.device_set) != want:
+            raise AssertionError(
+                f"{leg}: {what}{jax.tree_util.keystr(path)} lives on "
+                f"{len(leaf.sharding.device_set)} of {len(want)} devices "
+                f"({leaf.sharding})"
+            )
+
+
+def check_memory_in_use(leg, devices):
+    for d in devices:
+        in_use = (d.memory_stats() or {}).get("bytes_in_use", 0)
+        if not in_use:
+            raise AssertionError(f"{leg}: {d} reports no memory in use")
+    print(f"[{leg}] all {len(devices)} device(s) hold live buffers",
+          flush=True)
+
+
+def check_kernels(leg, hlo_text, expect):
+    """The compiled program runs each expected kernel as a Mosaic custom
+    call; an entry that took its jnp twin or interpret mode is named."""
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    census = kernel_census(hlo_text)
+    missing = [k for k in expect if not census["mosaic"].get(k)]
+    print(f"[{leg}] kernels: mosaic={census['mosaic']} "
+          f"jnp_twin={census['jnp']}", flush=True)
+    if missing:
+        raise AssertionError(
+            f"{leg}: no Mosaic custom call for {missing} in the compiled "
+            f"program (jnp twin or interpret mode took them); census "
+            f"{census}"
+        )
+
+
+def check_finite(leg, name, value):
+    if not math.isfinite(float(value)):
+        raise AssertionError(f"{leg}: {name} is {value}")
+
+
+@contextlib.contextmanager
+def jnp_twins():
+    """Trace under PS_TPU_DISABLE_PALLAS: every ops/ entry takes its jnp
+    twin — the reference side of the quantizer parities."""
+    os.environ["PS_TPU_DISABLE_PALLAS"] = "1"
+    try:
+        yield
+    finally:
+        del os.environ["PS_TPU_DISABLE_PALLAS"]
+
+
+# ------------------------------------------------------------------ legs
+
+
+def leg_kernels(devices):
+    """Each Pallas entry, once, against its jnp reference — and the text of
+    the program that produced the kernel side must hold its Mosaic call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ps_pytorch_tpu.ops import quantize as qz
+    from ps_pytorch_tpu.ops.flash_attention import flash_attention
+    from ps_pytorch_tpu.parallel.ring_attention import (
+        full_attention,
+        make_ring_attention,
+        make_seq_mesh,
+    )
+    from tools.tpu_validate import (
+        BF16_BOUND,
+        F32_DEFAULT_PRECISION_BOUND,
+    )
+
+    leg = "kernels"
+
+    def run(fn, *args, expect):
+        compiled = jax.jit(fn).lower(*args).compile()
+        check_kernels(leg, compiled.as_text(), expect)
+        return jax.device_get(compiled(*args))
+
+    def rel_err(got, want):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        return float(np.max(np.abs(got - want)) / max(1.0, np.max(np.abs(want))))
+
+    def oracle_loss(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            o = full_attention(q, k, v, causal=True)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+
+    def flash_loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2), o
+
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    def compare(label, loss_fn, q, k, v, bound):
+        """Output and all three gradients of loss_fn (Mosaic-compiled)
+        against the HIGHEST-precision oracle's."""
+        (_, o), g = run(grad(loss_fn), q, k, v, expect=LM_KERNELS)
+        (_, o_ref), g_ref = jax.device_get(
+            jax.jit(grad(oracle_loss))(q, k, v)
+        )
+        errs = {"o": rel_err(o, o_ref)}
+        errs.update((n, rel_err(a, r))
+                    for n, a, r in zip(("dq", "dk", "dv"), g, g_ref))
+        print(f"[{leg}] {label}: {errs} (bound {bound})", flush=True)
+        bad = {n: e for n, e in errs.items() if not e < bound}
+        if bad:
+            raise AssertionError(f"{leg}: {label} parity broken: {bad}")
+
+    b, t, h, d = FLASH_SHAPE
+    rng = np.random.RandomState(0)
+    qkv = lambda *shape, dtype: tuple(
+        jnp.asarray(rng.randn(*shape) * 0.5, dtype) for _ in range(3)
+    )
+    for dtype, bound in ((jnp.float32, F32_DEFAULT_PRECISION_BOUND),
+                         (jnp.bfloat16, BF16_BOUND)):
+        for t_len in (t, t - 24):  # the second is the pad-and-mask path
+            compare(f"flash {jnp.dtype(dtype).name} T={t_len}", flash_loss,
+                    *qkv(b, t_len, h, d, dtype=dtype), bound)
+
+    # the ring-hop partials, over every chip (one chip: a ring of one)
+    ring = make_ring_attention(
+        make_seq_mesh(len(devices)), causal=True, impl="flash"
+    )
+
+    def ring_loss(q, k, v):
+        o = ring(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) ** 2), o
+
+    compare(f"ring-flash over {len(devices)} device(s)", ring_loss,
+            *qkv(2, t, 4, d, dtype=jnp.float32), F32_DEFAULT_PRECISION_BOUND)
+
+    # quantizers on one 4 MiB bucket: the kernel's integers against the
+    # jnp twin's, and the round trip against the scale
+    x = jnp.asarray(rng.randn(BUCKET_ELEMS).astype(np.float32))
+    for name, bs, kernel in (("per_tensor", 0, "ps_quantize_2d"),
+                             ("rows_128", 128, "ps_quantize_rows"),
+                             ("rows_4096", 4096, "ps_quantize_rows")):
+        quant = lambda a, bs=bs: qz.quantize_int8(a, block_size=bs)
+        qk, sk = run(quant, x, expect=(kernel,))
+        with jnp_twins():
+            qr, sr = jax.device_get(jax.jit(lambda a: quant(a))(x))
+        diff = np.abs(qk.astype(np.int32) - qr.astype(np.int32))
+        back = np.asarray(qz.dequantize_int8(
+            qk, sk, block_size=bs, shape=x.shape if bs else None))
+        err = float(np.max(np.abs(back - np.asarray(x))))
+        lim = float(np.max(sk)) * 1.01 + 1e-7
+        print(f"[{leg}] quantize {name}: {int(np.count_nonzero(diff))} of "
+              f"{diff.size} ints differ from the jnp twin (max "
+              f"{int(diff.max())}), round trip {err:.3e} <= {lim:.3e}",
+              flush=True)
+        # a tie x.5 may round the other way under another fusion of the
+        # scale multiply; anything more is a kernel bug
+        if diff.max() > 1 or np.count_nonzero(diff) > diff.size * 1e-4:
+            raise AssertionError(f"{leg}: quantize {name} != jnp twin")
+        if not np.array_equal(sk, sr) or err > lim:
+            raise AssertionError(f"{leg}: quantize {name} scale/round trip")
+
+    n = max(len(devices), 4)
+    recv = jnp.asarray(
+        rng.randint(-127, 128, (n, BUCKET_ELEMS // 4)).astype(np.int8))
+    accum = lambda r: qz.accumulate_rescale_int8(r, float(n))
+    got = run(accum, recv, expect=("ps_accum_rescale",))
+    with jnp_twins():
+        want = jax.device_get(jax.jit(lambda r: accum(r))(recv))
+    if not np.array_equal(got, want):
+        raise AssertionError(
+            f"{leg}: accumulate_rescale_int8 n={n} is not bit-identical "
+            f"to its jnp twin ({int(np.count_nonzero(got != want))} differ)"
+        )
+    print(f"[{leg}] accum_rescale n={n}: bit-identical to the jnp twin",
+          flush=True)
+    return {}
+
+
+def leg_ps(wire, workdir, devices, clog):
+    """cli.train on one wire; the int8 wire is then read back by
+    cli.evaluate --once."""
+    import jax
+
+    from ps_pytorch_tpu import checkpoint as ckpt
+    from ps_pytorch_tpu.cli import _flags
+    from ps_pytorch_tpu.cli import evaluate as evaluate_cli
+    from ps_pytorch_tpu.cli import train as train_cli
+    from ps_pytorch_tpu.parallel import batch_sharding
+    from ps_pytorch_tpu.trainer import Trainer
+
+    leg = f"ps_{wire}"
+    n = len(devices)
+    train_dir = os.path.join(workdir, leg)
+    argv = PS_ARGS + PS_WIRES[wire] + ["--num-workers", str(n)]
+    steps = int(argv[argv.index("--max-steps") + 1])
+
+    out = train_cli.main(argv + ["--train-dir", train_dir])
+    # before the library pass below compiles the same step a second time
+    programs = clog.check_steps(leg, ["jit(step)"])
+    check_finite(leg, "train loss", out["train"]["loss"])
+    check_finite(leg, "val loss", out["val"]["loss"])
+    if ckpt.latest_valid_step(train_dir) != steps:
+        raise AssertionError(
+            f"{leg}: wanted a valid checkpoint at step {steps}, found "
+            f"{ckpt.available_steps(train_dir)}"
+        )
+    result = {"step_programs": programs}
+
+    if wire == "int8":
+        ev = evaluate_cli.main([
+            "--network", "ResNet18", "--dataset", "Cifar10",
+            "--model-dir", train_dir, "--once",
+        ])
+        if list(ev) != [steps]:
+            raise AssertionError(f"{leg}: evaluator read steps {list(ev)}")
+        # the checkpoint read back on one device scores the same 1000
+        # test images as the trainer's own validation on the mesh
+        a, b = ev[steps]["loss"], out["val"]["loss"]
+        check_finite(leg, "evaluator loss", a)
+        if abs(a - b) > 1e-3 * max(1.0, abs(b)):
+            raise AssertionError(
+                f"{leg}: evaluator loss {a} != trainer validation {b}"
+            )
+        print(f"[{leg}] checkpoint read back: evaluator loss {a:.6f} == "
+              f"trainer validation {b:.6f}", flush=True)
+
+    # the same step once more through the library, to look inside it
+    parser = argparse.ArgumentParser()
+    _flags.add_train_flags(parser)
+    _flags.add_ps_flags(parser)
+    args = parser.parse_args(argv + ["--no-checkpoints"])
+    pcfg = _flags.ps_config_from(args, n)
+    trainer = Trainer(_flags.train_config_from(args), pcfg)
+    global_batch = args.batch_size * n
+    batch = jax.device_put(
+        {"image": trainer.dataset.train_images[:global_batch],
+         "label": trainer.dataset.train_labels[:global_batch]},
+        batch_sharding(trainer.mesh, pcfg),
+    )
+    step = trainer._train_step.lower(
+        trainer.state, batch, trainer._key
+    ).compile()
+    check_kernels(leg, step.as_text(), PS_KERNELS[wire])
+    state, metrics = step(trainer.state, batch, trainer._key)
+    check_finite(leg, "library step loss", jax.device_get(metrics["loss"]))
+    check_on_all_devices(leg, "params", state.params, devices)
+    check_on_all_devices(leg, "opt_state", state.opt_state, devices)
+    check_on_all_devices(leg, "batch", batch, devices)
+    check_memory_in_use(leg, devices)
+    return result
+
+
+def leg_lm(train_dir, devices, clog):
+    import jax
+    import jax.numpy as jnp
+
+    from ps_pytorch_tpu import checkpoint as ckpt
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.models.transformer import TransformerConfig
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel.dp_sp import (
+        init_lm_state,
+        make_lm_train_step,
+        make_mesh_2d,
+        shard_tokens_2d,
+    )
+
+    leg = "lm"
+    out = train_lm_cli.main(LM_ARGS + ["--train-dir", train_dir])
+    check_finite(leg, "loss", out["loss"])
+    steps = ckpt.available_steps(train_dir)
+    if steps != [3, 6]:
+        raise AssertionError(f"{leg}: wanted checkpoints [3, 6], got {steps}")
+    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+
+    # the same step once more through the library (cli.train_lm's dp_sp
+    # branch, by the functions it calls), to look inside it
+    opt = dict(zip(LM_ARGS[::2], LM_ARGS[1::2]))
+    cfg = TransformerConfig(
+        dim=int(opt["--dim"]), depth=int(opt["--depth"]),
+        heads=int(opt["--heads"]), max_seq_len=int(opt["--seq-len"]),
+        attention_impl=opt["--attention-impl"],
+        compute_dtype=jnp.bfloat16,
+    )
+    tx = build_optimizer("sgd", 0.1, momentum=0.9, weight_decay=0.0)
+    mesh = make_mesh_2d(1, len(devices))
+    params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
+    tokens = shard_tokens_2d(
+        jnp.asarray(train_lm_cli.make_synthetic_tokens(
+            cfg.vocab_size, int(opt["--batch-size"]), cfg.max_seq_len, seed=2
+        )),
+        mesh,
+    )
+    step = make_lm_train_step(cfg, tx, mesh).lower(
+        params, opt_state, tokens
+    ).compile()
+    check_kernels(leg, step.as_text(), LM_KERNELS)
+    params, opt_state, loss = step(params, opt_state, tokens)
+    check_finite(leg, "library step loss", jax.device_get(loss))
+    check_on_all_devices(leg, "params", params, devices)
+    check_on_all_devices(leg, "opt_state", opt_state, devices)
+    check_on_all_devices(leg, "tokens", tokens, devices)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
+def leg_serve(lm_dir, devices, clog):
+    from ps_pytorch_tpu.cli import serve as serve_cli
+
+    leg = "serve"
+    n = len(devices)
+    argv = SERVE_ARGS + ["--model-dir", lm_dir]
+    if n > 1:
+        argv += ["--num-workers", str(n)]
+    summary = serve_cli.main(argv)
+    want = int(SERVE_ARGS[SERVE_ARGS.index("--requests") + 1])
+    if summary["requests_completed"] != want:
+        raise AssertionError(
+            f"{leg}: {summary['requests_completed']} of {want} requests "
+            f"completed"
+        )
+    if summary["weights_step"] != 6 or len(summary["rollovers"]) != 1:
+        raise AssertionError(
+            f"{leg}: wanted one rollover 3 -> 6, ended on step "
+            f"{summary['weights_step']} after {summary['rollovers']} "
+            f"(aborts {summary['rollover_aborts']})"
+        )
+    if summary["new_tokens"] <= 0:
+        raise AssertionError(f"{leg}: no tokens came out")
+    return {"step_programs": clog.check_steps(
+        leg, ["jit(prefill)", "jit(step)"]
+    )}
+
+
+# ------------------------------------------------------------------ main
+
+
+def _version(dist):
+    try:
+        return importlib.metadata.version(dist)
+    except importlib.metadata.PackageNotFoundError:
+        return None
+
+
+def main() -> int:
+    # in a directory that holds this file and nothing else of the repo,
+    # this import is where the script dies (non-zero, no result line)
+    from ps_pytorch_tpu.utils import enable_persistent_compile_cache
+
+    cache_dir = enable_persistent_compile_cache()
+
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        print(
+            f"chip_smoke: needs a TPU, but jax found platform "
+            f"{dev.platform!r} ({getattr(dev, 'device_kind', '?')}, "
+            f"{len(devices)} device(s)); JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}. A CPU run proves "
+            f"nothing about the chip — not running.",
+            file=sys.stderr,
+        )
+        return 2
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    versions = {d: _version(d) for d in ("jax", "jaxlib", "libtpu", "flax",
+                                         "optax")}
+    print(f"[device] {device} versions={versions} "
+          f"compile_cache={cache_dir}", flush=True)
+
+    events = {"hits": 0, "misses": 0, "backend_compile_s": 0.0}
+
+    def on_event(name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            events["hits"] += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            events["misses"] += 1
+
+    def on_duration(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            events["backend_compile_s"] += secs
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+    legs = {}
+
+    def run(name, leg):
+        t0 = time.perf_counter()
+        with CompileLog() as clog:
+            detail = leg(clog)
+        legs[name] = {
+            "ok": True,
+            "seconds": round(time.perf_counter() - t0, 1),
+            "cache_hits": len(clog.hits),
+            "cache_misses": len(clog.misses),
+            **detail,
+        }
+        print(f"[{name}] ok: {legs[name]}", flush=True)
+
+    t_start = time.perf_counter()
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+        lm_dir = os.path.join(workdir, "lm")
+        run("kernels", lambda clog: leg_kernels(devices))
+        wires = ["default", "int8"]
+        if len(devices) > 1:
+            wires.append("2round_homomorphic")
+        for wire in wires:
+            run(f"ps_{wire}",
+                lambda clog, w=wire: leg_ps(w, workdir, devices, clog))
+        run("lm", lambda clog: leg_lm(lm_dir, devices, clog))
+        run("serve", lambda clog: leg_serve(lm_dir, devices, clog))
+
+    print(json.dumps({
+        "ok": True,
+        "device": device,
+        "versions": versions,
+        "legs": legs,
+        "seconds": round(time.perf_counter() - t_start, 1),
+        # time inside jax's backend-compile step, cache lookups included:
+        # what a cold cache costs and a warm one saves
+        "compile_seconds": round(events["backend_compile_s"], 1),
+        "compile_cache": {"dir": cache_dir, "hits": events["hits"],
+                          "misses": events["misses"]},
+        "claim": None,
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -20,6 +20,4 @@ reference mpi4py/PyTorch parameter-server implementation (see SURVEY.md):
   and an out-of-band polling evaluator (reference: src/distributed_evaluator.py).
 """
 
-from . import _compat  # noqa: F401  (installs the jax.shard_map alias)
-
 __version__ = "0.1.0"

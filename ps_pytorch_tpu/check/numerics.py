@@ -16,7 +16,7 @@ interpretation over the same traced jaxpr tracks, per variable,
   * residual provenance (``deqs``) — the dequantization events it
     descends from (the error-feedback closure check, PSC112).
 
-Call-likes (pjit / shard_map / remat / custom_{jvp,vjp}) are entered
+Call-likes (jit / shard_map / remat / custom_{jvp,vjp}) are entered
 exactly, mirroring the walker's 1:1 invar/outvar mapping. ``cond``
 branches are joined exactly (one branch runs). ``scan``/``while`` carry
 state is ITERATED to a provenance fixpoint with bounds dropped to
@@ -41,19 +41,11 @@ from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .walker import _is_var, _open
+from .walker import EXACT_CALLS as _EXACT_CALLS, _is_var, _open
 
 # reduce-kind collectives (the walker's REDUCE_KINDS, by primitive name):
 # outputs are "downstream of the gradient reduce" for PSC114
 _REDUCE_PRIMS = {"psum", "psum_scatter", "reduce_scatter", "all_to_all"}
-
-# call-like primitives entered with the exact 1:1 invar/outvar mapping
-_EXACT_CALLS = {
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
-    "checkpoint", "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "shard_map",
-    "custom_lin",
-}
 
 _EMPTY: FrozenSet[int] = frozenset()
 

@@ -21,7 +21,7 @@ Two measurements, two tools:
   the update-path collapse on real configs.
 
 Sub-jaxpr handling mirrors walker.py: exact through the call-like
-primitives (pjit / shard_map / remat / custom_*), conservative inside
+primitives (jit / shard_map / remat / custom_*), conservative inside
 scan / while / cond (a tainted input taints every output and the WHOLE
 body counts, nested sub-jaxprs included) — an over-approximation that
 can only raise the count, never hide de-fusion.

@@ -1,6 +1,6 @@
 """Jaxpr collective walker: the measurement half of pscheck.
 
-Walks a traced step function's jaxpr (recursing through pjit/shard_map/
+Walks a traced step function's jaxpr (recursing through jit/shard_map/
 scan/while/cond/custom_* sub-jaxprs) and returns every collective
 equation with its axes, per-device payload shape/dtype, and byte count —
 the ground truth the contract rules (rules.py) check against. A reverse
@@ -9,7 +9,7 @@ parameters (as opposed to, say, the metrics pmean), which is what lets
 PSC102 say "psummed over that axis BEFORE the optimizer" instead of
 "psummed somewhere".
 
-Liveness is exact through pjit / shard_map / custom_{jvp,vjp} / remat
+Liveness is exact through jit / shard_map / custom_{jvp,vjp} / remat
 call boundaries (1:1 invar/outvar mapping) and conservative inside
 scan / while / cond bodies (any live output marks the whole body live —
 an over-approximation that can only add ancestors, never lose one).
@@ -97,6 +97,17 @@ def _payload_by_dtype(eqn) -> List[Tuple[str, Tuple[Tuple[int, ...], ...], int]]
     ]
 
 
+# call-like primitives whose invars/outvars map 1:1 onto their sub-jaxpr's,
+# by the names the installed jax gives them (jax.jit traces to `jit`, the
+# old `pjit`; jax.checkpoint to `remat2`). Shared with check/numerics.py: a
+# name missing here makes BOTH walkers treat the call as opaque, and the
+# gradient-path taint then leaks onto every psum behind it.
+EXACT_CALLS = frozenset({
+    "jit", "closed_call", "call", "remat2", "custom_jvp_call",
+    "custom_jvp_call_jaxpr", "custom_vjp_call", "shard_map", "custom_lin",
+})
+
+
 def _subjaxprs(eqn) -> List[Tuple[Any, bool]]:
     """(jaxpr-like, exact_io_mapping) pairs under one equation.
 
@@ -105,18 +116,12 @@ def _subjaxprs(eqn) -> List[Tuple[Any, bool]]:
     conservative treatment.
     """
     name = eqn.primitive.name
-    exact_names = {
-        "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
-        "checkpoint", "custom_jvp_call", "custom_vjp_call",
-        "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "shard_map",
-        "custom_lin",
-    }
     out: List[Tuple[Any, bool]] = []
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr", "cond_jaxpr",
                 "body_jaxpr"):
         sub = eqn.params.get(key)
         if sub is not None:
-            exact = name in exact_names and key in ("jaxpr", "call_jaxpr",
+            exact = name in EXACT_CALLS and key in ("jaxpr", "call_jaxpr",
                                                     "fun_jaxpr")
             out.append((sub, exact))
     for br in eqn.params.get("branches", ()) or ():

@@ -32,7 +32,11 @@ from ..data import (
 from ..models import apply_model, build_model, init_model, input_shape_for
 from ..ops.metrics import accuracy, cross_entropy_loss
 from ..trainer import average_metrics
-from ..utils import format_eval_line, get_logger
+from ..utils import (
+    enable_persistent_compile_cache,
+    format_eval_line,
+    get_logger,
+)
 
 logger = get_logger()
 
@@ -132,6 +136,7 @@ class Evaluator:
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     parser = argparse.ArgumentParser("ps_pytorch_tpu.cli.evaluate")
     parser.add_argument("--eval-batch-size", type=int, default=1000)
     parser.add_argument("--model-dir", type=str, default="output/models/")

@@ -26,7 +26,7 @@ import numpy as np
 
 from ..checkpoint import listify_raw, load_checkpoint_raw, poll_checkpoints
 from ..ops.metrics import next_token_nll
-from ..utils import get_logger
+from ..utils import enable_persistent_compile_cache, get_logger
 
 logger = get_logger()
 
@@ -133,6 +133,7 @@ def evaluate_checkpoint(model_dir: str, step: int, eval_size: int = 64,
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     p = argparse.ArgumentParser("ps_pytorch_tpu.cli.evaluate_lm")
     p.add_argument("--model-dir", required=True)
     p.add_argument("--eval-size", type=int, default=64,

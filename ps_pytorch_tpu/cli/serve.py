@@ -45,7 +45,7 @@ from ..serve import (
 )
 from ..serve.engine import checkpoint_model
 from ..serve.traffic import make_requests, run_open_loop
-from ..utils import get_logger
+from ..utils import enable_persistent_compile_cache, get_logger
 
 logger = get_logger()
 
@@ -55,6 +55,7 @@ SERVE_SEQUENCE_SEED_OFFSET = 104729
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     p = argparse.ArgumentParser("ps_pytorch_tpu.cli.serve")
     p.add_argument("--model-dir", required=True)
     p.add_argument("--step", type=int, default=None,

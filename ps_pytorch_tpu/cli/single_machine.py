@@ -11,13 +11,14 @@ import argparse
 
 from ..parallel import PSConfig
 from ..trainer import Trainer
-from ..utils import get_logger
+from ..utils import enable_persistent_compile_cache, get_logger
 from ._flags import add_train_flags, train_config_from
 
 logger = get_logger()
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     parser = argparse.ArgumentParser("ps_pytorch_tpu.cli.single_machine")
     add_train_flags(parser)
     args = parser.parse_args(argv)

@@ -22,7 +22,7 @@ import jax
 
 from ..parallel import initialize_multihost
 from ..trainer import Trainer
-from ..utils import get_logger
+from ..utils import enable_persistent_compile_cache, get_logger
 from ._flags import (
     add_ps_flags,
     add_train_flags,
@@ -35,6 +35,7 @@ logger = get_logger()
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     parser = argparse.ArgumentParser("ps_pytorch_tpu.cli.train")
     add_train_flags(parser)
     add_ps_flags(parser)

@@ -37,11 +37,21 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from ..models.transformer import TransformerConfig, init_transformer
+from ..models.transformer import TransformerConfig
 from ..optim import build_optimizer
-from ..parallel.dp_sp import make_lm_train_step, make_mesh_2d, shard_tokens_2d
+from ..parallel.dp_sp import (
+    init_lm_state,
+    make_lm_train_step,
+    make_mesh_2d,
+    shard_tokens_2d,
+)
 from ..trainer import append_metrics_line
-from ..utils import format_iter_line, get_logger, host_sync
+from ..utils import (
+    enable_persistent_compile_cache,
+    format_iter_line,
+    get_logger,
+    host_sync,
+)
 
 logger = get_logger()
 
@@ -73,6 +83,7 @@ def make_synthetic_tokens(
 
 
 def main(argv=None) -> dict:
+    enable_persistent_compile_cache()
     parser = argparse.ArgumentParser("ps_pytorch_tpu.cli.train_lm")
     parser.add_argument("--num-dp", type=int, default=1)
     parser.add_argument("--num-sp", type=int, default=0,
@@ -192,8 +203,7 @@ def main(argv=None) -> dict:
             raise ValueError(
                 f"--batch-size must be divisible by num_dp={args.num_dp}"
             )
-        params = init_transformer(cfg, key)
-        opt_state = tx.init(params)
+        params, opt_state = init_lm_state(cfg, tx, key, mesh)
         step = make_lm_train_step(cfg, tx, mesh)
         run = lambda p, o, tok: step(p, o, shard_tokens_2d(jnp.asarray(tok), mesh))
         to_plain = lambda p: p

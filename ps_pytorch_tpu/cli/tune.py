@@ -19,7 +19,11 @@ import jax
 
 from ..data import prepare_data
 from ..trainer import Trainer
-from ..utils import get_logger, parse_iter_line
+from ..utils import (
+    enable_persistent_compile_cache,
+    get_logger,
+    parse_iter_line,
+)
 from ._flags import add_ps_flags, add_train_flags, ps_config_from, train_config_from
 
 logger = get_logger()
@@ -99,6 +103,9 @@ def tune_lm(args) -> dict:
 
 
 def main(argv=None) -> dict:
+    # sweep candidates re-jit the same step; the persistent cache makes a
+    # re-run of the sweep (and any HLO-identical candidate) compile-free
+    enable_persistent_compile_cache()
     parser = argparse.ArgumentParser("ps_pytorch_tpu.cli.tune")
     add_train_flags(parser)
     add_ps_flags(parser)
@@ -115,12 +122,6 @@ def main(argv=None) -> dict:
     parser.add_argument("--lm-heads", type=int, default=4)
     parser.add_argument("--lm-vocab-size", type=int, default=64)
     args = parser.parse_args(argv)
-
-    # sweep candidates re-jit the same step; the persistent cache makes a
-    # re-run of the sweep (and any HLO-identical candidate) compile-free
-    from ..utils import enable_persistent_compile_cache
-
-    enable_persistent_compile_cache()
 
     if args.workload == "lm":
         return tune_lm(args)

@@ -40,7 +40,6 @@ def gather_rows(array: np.ndarray, indices: np.ndarray) -> np.ndarray:
     lib = _load()
     if (
         lib is None
-        or getattr(lib, "psl_gather", None) is None
         or array.nbytes == 0
         or not array.flags.c_contiguous
     ):

@@ -7,15 +7,20 @@ weights on the host wire), different engine — our own shuffle+LZ C++ library
 instead of an external c-blosc dependency. Array framing (dtype/shape) is a
 small JSON header ahead of the byte stream.
 
-The shared library is built on demand with g++ (native/Makefile has the same
-recipe); if no compiler is available the module falls back to zlib so the
-checkpoint/codec feature degrades gracefully rather than failing.
+The shared library is built on demand with g++ from native/*.cc and named
+after a hash of those sources (native/Makefile writes the same name), so a
+binary built from other sources is never loaded: a changed source is a new
+file name and a rebuild. Without the sources or a compiler the module falls
+back to zlib — and says so once — so the checkpoint/codec feature degrades
+rather than fails.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import json
+import logging
 import os
 import subprocess
 import threading
@@ -24,9 +29,10 @@ from typing import Optional
 
 import numpy as np
 
+logger = logging.getLogger("ps_pytorch_tpu")
+
 _PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NATIVE_DIR = os.path.join(_PKG_DIR, "_native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libpsnative.so")
 _SRC_DIR = os.path.join(os.path.dirname(_PKG_DIR), "native")
 _SOURCES = ("codec.cc", "loader.cc")
 
@@ -37,35 +43,41 @@ _lib_tried = False
 MAGIC = b"PSAR"  # array framing magic (codec stream has its own 'PSC1')
 
 
-def _build_library() -> Optional[ctypes.CDLL]:
-    """Compile the native sources and return a handle to the FRESH build.
-
-    The handle is dlopen'd from a unique temp path before the os.replace
-    into _LIB_PATH: dlopen caches by pathname, so re-opening _LIB_PATH
-    after replacing a stale .so would silently return the old mapping."""
-    sources = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    if not all(os.path.exists(s) for s in sources):
+def _library_path() -> Optional[str]:
+    """_native/libpsnative-<hash of the sources>.so, or None without them."""
+    digest = hashlib.sha256()
+    try:
+        for name in _SOURCES:
+            with open(os.path.join(_SRC_DIR, name), "rb") as f:
+                digest.update(f.read())
+    except OSError:
         return None
+    return os.path.join(
+        _NATIVE_DIR, f"libpsnative-{digest.hexdigest()[:16]}.so"
+    )
+
+
+def _build_library(lib_path: str) -> Optional[str]:
+    """Compile the native sources into lib_path (atomically: concurrent
+    builders replace it with identical bytes). Returns why it failed."""
     os.makedirs(_NATIVE_DIR, exist_ok=True)
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O3", "-std=c++17", "-fPIC", "-Wall",
         "-shared", "-pthread",
-        "-o", tmp, *sources,
+        "-o", tmp, *(os.path.join(_SRC_DIR, s) for s in _SOURCES),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        lib = ctypes.CDLL(tmp)
-        # publish for other processes; our mapping survives the rename
-        os.replace(tmp, _LIB_PATH)
-    except (OSError, subprocess.SubprocessError):
+        os.replace(tmp, lib_path)
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return None
-    return lib
+        return f"{type(e).__name__}: {e}"
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -75,19 +87,21 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib_tried:
             return _lib
         _lib_tried = True
-        lib = None
-        if os.path.exists(_LIB_PATH):
+        lib_path = _library_path()
+        why = None
+        if lib_path is None:
+            why = f"no sources under {_SRC_DIR}"
+        elif not os.path.exists(lib_path):
+            why = _build_library(lib_path)
+        if why is None:
             try:
-                lib = ctypes.CDLL(_LIB_PATH)
-            except OSError:
-                lib = None
-        if lib is None or getattr(lib, "psl_gather", None) is None:
-            # missing or stale (pre-loader.cc) build — compile fresh; keep
-            # a stale-but-working codec lib if no compiler is available
-            rebuilt = _build_library()
-            if rebuilt is not None:
-                lib = rebuilt
-        if lib is None:
+                lib = ctypes.CDLL(lib_path)
+            except OSError as e:
+                why = f"OSError: {e}"
+        if why is not None:
+            logger.warning(
+                "native codec unavailable (%s): falling back to zlib", why
+            )
             return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.psc_max_compressed.restype = ctypes.c_size_t
@@ -102,13 +116,12 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.psc_decompress.argtypes = [
             u8p, ctypes.c_size_t, u8p, ctypes.c_size_t, ctypes.c_int,
         ]
-        if getattr(lib, "psl_gather", None) is not None:
-            lib.psl_gather.restype = ctypes.c_int
-            lib.psl_gather.argtypes = [
-                u8p, ctypes.c_int64, ctypes.c_int64,
-                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, u8p,
-                ctypes.c_int,
-            ]
+        lib.psl_gather.restype = ctypes.c_int
+        lib.psl_gather.argtypes = [
+            u8p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, u8p,
+            ctypes.c_int,
+        ]
         _lib = lib
         return _lib
 

@@ -18,21 +18,30 @@ plain XLA.
 Causality is enforced by masking with global positions (uniform grid —
 fully-masked blocks still run; the win is memory, not skipped FLOPs).
 
-Selection follows ops/quantize.py's convention: Pallas on TPU backends,
+Selection is ops/pallas_mode.py's: Mosaic-compiled on TPU backends,
 interpret mode under PS_TPU_PALLAS_INTERPRET=1 (how CPU CI exercises the
 kernels), pure-jnp reference otherwise (PS_TPU_DISABLE_PALLAS=1 forces
 it). The jnp reference is ring_attention.full_attention — also the test
 oracle.
+
+What Mosaic needs of the layout: the per-row softmax stats (lse, delta,
+and the ring partials' m and l) cross the kernel boundary as [BH, 1, T]
+with (1, 1, block_q) blocks — a lane-major row whose second-to-last block
+dim equals the array's — and are transposed to/from the [block_q, 1]
+column the score tile broadcasts against inside the kernel. Compiled
+calls therefore need block sizes that are multiples of 128 or cover the
+whole (padded) sequence; the defaults and _plan_one's padding give that.
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from .pallas_mode import kernel_mode, pallas_mode
 
 NEG_INF = -1e30
 
@@ -65,16 +74,6 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
         pad_keep = k_local < k_len
         keep = pad_keep if keep is None else (keep & pad_keep)
     return jnp.where(keep, scores, NEG_INF)
-
-
-def _pallas_mode() -> Optional[dict]:
-    if os.environ.get("PS_TPU_DISABLE_PALLAS"):
-        return None
-    if os.environ.get("PS_TPU_PALLAS_INTERPRET"):
-        return {"interpret": True}
-    if jax.default_backend() == "tpu":
-        return {}
-    return None
 
 
 # --------------------------------------------------------------- forward
@@ -131,13 +130,13 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
                 l = l_ref[:]
                 l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0
                 o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-                lse_ref[0] = (m_ref[:] + jnp.log(l_safe))[:, 0]
+                lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).T
             else:
                 # partial triple for ring hops: UNNORMALIZED numerator plus
                 # the (m, l) stats, merged across hops by the caller
                 pv_ref[0] = acc_ref[:]
-                mo_ref[0] = m_ref[:][:, 0]
-                lo_ref[0] = l_ref[:][:, 0]
+                mo_ref[0] = m_ref[:].T
+                lo_ref[0] = l_ref[:].T
 
     return kernel
 
@@ -176,28 +175,22 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
     n_q, n_k = t // block_q, tk // block_k
     kernel = _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
                               k_len=k_len)
+    row = pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi))
+    row_shape = jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)
+    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))]
     if normalize:
-        out_specs = [
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
-        ]
+        out_specs += [row]
+        out_shape = [jax.ShapeDtypeStruct((bh, t, d), q3.dtype), row_shape]
     else:
-        out_specs = [
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-        ]
+        out_specs += [row, row]
         out_shape = [
             jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
+            row_shape,
+            row_shape,
         ]
-    return pl.pallas_call(
+    out, *rows = pl.pallas_call(
         kernel,
+        name="ps_flash_fwd",
         grid=(bh, n_q, n_k),
         in_specs=[
             _smem_spec(),
@@ -214,6 +207,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
         ],
         **mode,
     )(_offsets_arr(offsets), q3, k3, v3)
+    return (out, *(r.reshape(bh, t) for r in rows))
 
 
 # --------------------------------------------------------------- backward
@@ -234,8 +228,8 @@ def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0][:, None]  # [Bq, 1]
-        delta = delta_ref[0][:, None]  # [Bq, 1]
+        lse = lse_ref[0].T  # [1, Bq] -> [Bq, 1]
+        delta = delta_ref[0].T
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if masked:
             scores = _mask_scores(
@@ -273,8 +267,8 @@ def _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=None):
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0].T  # [1, Bq] -> [Bq, 1]
+        delta = delta_ref[0].T
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if masked:
             scores = _mask_scores(
@@ -312,12 +306,14 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     tk = k3.shape[1]
     n_q, n_k = t // block_q, tk // block_k
     off = _offsets_arr(offsets)
+    lse, delta = lse.reshape(bh, 1, t), delta.reshape(bh, 1, t)
     dq_dt = out_dtype or q3.dtype
     dk_dt = out_dtype or k3.dtype
     dv_dt = out_dtype or v3.dtype
 
     dq = pl.pallas_call(
         _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=k_len),
+        name="ps_flash_dq",
         grid=(bh, n_q, n_k),
         in_specs=[
             _smem_spec(),
@@ -325,8 +321,8 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), dq_dt),
@@ -336,6 +332,7 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
 
     dk, dv = pl.pallas_call(
         _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=k_len),
+        name="ps_flash_dkv",
         grid=(bh, n_k, n_q),
         in_specs=[
             _smem_spec(),
@@ -343,8 +340,8 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, ki, qi: (b, qi)),
-            pl.BlockSpec((1, block_q), lambda b, ki, qi: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
@@ -415,12 +412,12 @@ def _pad_t(x, tp, value=0.0):
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q3, k3, v3, scale, causal, block_q, block_k, k_len):
     o, _ = _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k,
-                      _pallas_mode() or {"interpret": True}, k_len=k_len)
+                      kernel_mode("flash_attention"), k_len=k_len)
     return o
 
 
 def _flash_vjp_fwd(q3, k3, v3, scale, causal, block_q, block_k, k_len):
-    mode = _pallas_mode() or {"interpret": True}
+    mode = kernel_mode("flash_attention")
     o, lse = _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
                         k_len=k_len)
     return o, (q3, k3, v3, o, lse)
@@ -428,7 +425,7 @@ def _flash_vjp_fwd(q3, k3, v3, scale, causal, block_q, block_k, k_len):
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, k_len, res, do3):
     q3, k3, v3, o3, lse = res
-    mode = _pallas_mode() or {"interpret": True}
+    mode = kernel_mode("flash_attention")
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1)
     return _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal,
                       block_q, block_k, mode, k_len=k_len)
@@ -454,10 +451,11 @@ def flash_attention(
     to the block grid and the padded keys masked inside the kernels, so
     tiles stay MXU-shaped (no silent degradation to tiny blocks).
     """
-    if _pallas_mode() is None:
+    if pallas_mode() is None:
         from ..parallel.ring_attention import full_attention
 
-        return full_attention(q, k, v, causal=causal, scale=scale)
+        with jax.named_scope("ps_flash_jnp"):
+            return full_attention(q, k, v, causal=causal, scale=scale)
 
     b, t, h, d = q.shape
     if scale is None:
@@ -499,7 +497,7 @@ def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
     k3, v3 = _pad_t(k3, tpk), _pad_t(v3, tpk)
     pv, m, l = _flash_fwd(
         q3, k3, v3, scale, causal, bq, bk,
-        mode if mode is not None else (_pallas_mode() or {"interpret": True}),
+        kernel_mode("flash_partial") if mode is None else mode,
         offsets=(q_off, k_off), normalize=False,
         k_len=(tk if tpk != tk else None),
     )
@@ -525,7 +523,7 @@ def flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, causal,
     k3, v3 = _pad_t(k3, tpk), _pad_t(v3, tpk)
     dq, dk, dv = _flash_bwd(
         q3, k3, v3, lse, delta, do3, scale, causal, bq, bk,
-        mode if mode is not None else (_pallas_mode() or {"interpret": True}),
+        kernel_mode("flash_grads_partial") if mode is None else mode,
         offsets=(q_off, k_off), out_dtype=jnp.float32,
         k_len=(tk if tpk != tk else None),
     )

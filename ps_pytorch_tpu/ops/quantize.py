@@ -29,7 +29,6 @@ reference's per-worker Blosc streams cannot offer).
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -37,19 +36,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .pallas_mode import COMPILED, pallas_mode
+
 _LANE = 128
 _SUBLANE = 8
 
 
 def _pallas_mode(x: jax.Array) -> Optional[dict]:
-    """None = use jnp; otherwise kwargs for pl.pallas_call."""
-    if os.environ.get("PS_TPU_DISABLE_PALLAS"):
+    """ops/pallas_mode.pallas_mode(), except that a compiled launch is not
+    worth it below one (8, 128) vreg tile of input: None = use jnp."""
+    mode = pallas_mode()
+    if mode == COMPILED and x.size < _LANE * _SUBLANE:
         return None
-    if os.environ.get("PS_TPU_PALLAS_INTERPRET"):
-        return {"interpret": True}
-    if jax.default_backend() == "tpu" and x.size >= _LANE * _SUBLANE:
-        return {}
-    return None
+    return mode
 
 
 # ------------------------------------------------------------ pallas kernels
@@ -77,6 +76,7 @@ def _pallas_quantize_2d(x2: jax.Array, inv_scale: jax.Array, mode: dict) -> jax.
     block_m = min(m, 1024)
     return pl.pallas_call(
         _quant_kernel,
+        name="ps_quantize_2d",
         out_shape=jax.ShapeDtypeStruct((m, _LANE), jnp.int8),
         grid=(pl.cdiv(m, block_m),),
         in_specs=[
@@ -100,6 +100,7 @@ def _pallas_quantize_rows(xb: jax.Array, inv: jax.Array, mode: dict) -> jax.Arra
     block_nb = -(-block_nb // _SUBLANE) * _SUBLANE  # sublane-align the tile
     return pl.pallas_call(
         _quant_rows_kernel,
+        name="ps_quantize_rows",
         out_shape=jax.ShapeDtypeStruct((nb, bs), jnp.int8),
         grid=(pl.cdiv(nb, block_nb),),
         in_specs=[
@@ -166,7 +167,12 @@ def quantize_int8(
         ):
             q = _pallas_quantize_rows(xb, inv, mode)
         else:
-            q = jnp.clip(_round(xb * inv, rounding, key), -127, 127).astype(jnp.int8)
+            # the scope names the jnp path in the jaxpr and the compiled
+            # program, where chip_smoke.py and a profile can see it
+            with jax.named_scope("ps_quantize_rows_jnp"):
+                q = jnp.clip(
+                    _round(xb * inv, rounding, key), -127, 127
+                ).astype(jnp.int8)
         return q, scale
 
     absmax = jnp.max(jnp.abs(x))
@@ -182,7 +188,10 @@ def quantize_int8(
         q2 = _pallas_quantize_2d(flat.reshape(rows_pad, _LANE), inv, mode)
         q = q2.reshape(-1)[:n].reshape(x.shape)
     else:
-        q = jnp.clip(_round(x * inv, rounding, key), -127, 127).astype(jnp.int8)
+        with jax.named_scope("ps_quantize_2d_jnp"):
+            q = jnp.clip(
+                _round(x * inv, rounding, key), -127, 127
+            ).astype(jnp.int8)
     return q, scale
 
 
@@ -423,6 +432,7 @@ def _pallas_accum_rescale(recv: jax.Array, divisor, mode: dict) -> jax.Array:
     block_s = min(s, 16384 // _LANE * _LANE)
     out = pl.pallas_call(
         _accum_rescale_kernel,
+        name="ps_accum_rescale",
         out_shape=jax.ShapeDtypeStruct((1, s), jnp.int8),
         grid=(pl.cdiv(s, block_s),),
         in_specs=[
@@ -451,9 +461,10 @@ def accumulate_rescale_int8(recv: jax.Array, divisor) -> jax.Array:
     mode = _pallas_mode(recv)
     if mode is not None and recv.shape[1] % _LANE == 0:
         return _pallas_accum_rescale(recv, divisor, mode)
-    return homomorphic_rescale(
-        jnp.sum(recv.astype(jnp.int32), axis=0), divisor
-    )
+    with jax.named_scope("ps_accum_rescale_jnp"):
+        return homomorphic_rescale(
+            jnp.sum(recv.astype(jnp.int32), axis=0), divisor
+        )
 
 
 def quantization_error(x: jax.Array, block_size: int = 0) -> jax.Array:

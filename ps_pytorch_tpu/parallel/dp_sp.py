@@ -32,8 +32,12 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.transformer import TransformerConfig, apply_transformer
-from .mesh import WORKER_AXIS
+from ..models.transformer import (
+    TransformerConfig,
+    apply_transformer,
+    init_transformer,
+)
+from .mesh import WORKER_AXIS, replicated_sharding
 from .ring_attention import SEQ_AXIS
 
 
@@ -89,6 +93,20 @@ def lm_loss_local(
     loss_sum = jnp.sum(nll * valid[None, :])
     count = jnp.float32(b_loc) * jnp.sum(valid)
     return loss_sum / lax.psum(count, sp_axis)
+
+
+def init_lm_state(
+    cfg: TransformerConfig,
+    tx: optax.GradientTransformation,
+    key: jax.Array,
+    mesh: Mesh,
+):
+    """Init (params, opt_state) replicated ON THE MESH, like every other
+    scheme's init_*_state: state left uncommitted on device 0 makes the
+    train step compile twice — once for device-0 inputs, again for its
+    own mesh-sharded outputs."""
+    params = init_transformer(cfg, key)
+    return jax.device_put((params, tx.init(params)), replicated_sharding(mesh))
 
 
 def make_lm_train_step(

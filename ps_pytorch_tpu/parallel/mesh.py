@@ -150,13 +150,11 @@ def initialize_multihost(
     run_multihost.sh relies on this).
 
     CPU backend note (the 2-process localhost jobs tests/test_multihost.py
-    spawns): jax 0.4.37 defaults ``jax_cpu_collectives_implementation`` to
-    "none", so ANY multiprocess computation — including the assert_equal
-    psum hidden inside ``device_put`` onto a non-addressable sharding —
-    dies with "Multiprocess computations aren't implemented on the CPU
-    backend". This jaxlib ships the gloo TCP collectives, so a
-    multi-process job that is explicitly pinned to CPU flips them on
-    before the backend is created. Must run before anything touches
+    spawns): cross-process CPU collectives ride jaxlib's gloo TCP
+    transport (the default ``jax_cpu_collectives_implementation``), whose
+    pairs match ops by FIFO order — a multi-process job that is
+    explicitly pinned to CPU therefore turns async dispatch off before
+    the backend is created (see below). Must run before anything touches
     ``jax.devices()`` (backend creation reads the flag once).
 
     SPMD contract: every process runs this with the same effective
@@ -179,7 +177,6 @@ def initialize_multihost(
         # (unset JAX_PLATFORMS is left alone: a TPU pod runs that way,
         # and perturbing its cpu client config for a backend it never
         # uses for collectives buys nothing)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         # gloo TCP pairs match ops by FIFO order, not tags: with async
         # dispatch two in-flight XLA computations (a train step and a
         # host-collective psum, or a prefetch device_put's assert_equal

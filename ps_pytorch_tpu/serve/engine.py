@@ -255,7 +255,7 @@ class ServingEngine:
         self._plan = plan_buckets(self._layout.total, 0, align=1)
         flat = _flat_params(self._layout, self._plan, params)
         self._params = FlatVector(
-            flat=self._place_flat(flat), layout=self._layout, plan=self._plan
+            flat=self._place(flat), layout=self._layout, plan=self._plan
         )
 
         pool = init_kv_pool(cfg, serve.slots, serve.max_len, int8=serve.kv_int8)
@@ -268,8 +268,16 @@ class ServingEngine:
         self._prefill = jax.jit(
             make_prefill_step(cfg, serve), donate_argnums=donate
         )
+        # the [slots] token/position outputs are pinned to the placement
+        # _place gives the host-built triple: a tick that threads them
+        # straight back in then hits the SAME compiled program as a tick
+        # that rebuilt the triple from host arrays. Left to the compiler,
+        # on a mesh the two differ in sharding and the second variant
+        # compiles mid-traffic, after warmup
+        small = replicated_sharding(mesh) if mesh is not None else None
         self._decode = jax.jit(
-            make_decode_step(cfg, serve), donate_argnums=donate
+            make_decode_step(cfg, serve), donate_argnums=donate,
+            out_shardings=(None, small, small),
         )
 
         s = serve.slots
@@ -340,10 +348,12 @@ class ServingEngine:
         return cls(cfg, params, serve, mesh=mesh, model_dir=model_dir,
                    step=step, tracer=tracer, **engine_kw)
 
-    def _place_flat(self, flat: np.ndarray) -> jax.Array:
+    def _place(self, host: np.ndarray) -> jax.Array:
+        """Host array -> device, replicated over the mesh when there is
+        one (the flat weights and the per-tick [slots] vectors)."""
         if self.mesh is not None:
-            return jax.device_put(flat, replicated_sharding(self.mesh))
-        return jnp.asarray(flat)
+            return jax.device_put(host, replicated_sharding(self.mesh))
+        return jnp.asarray(host)
 
     # ---------------------------------------------------------- rollover
     def poll_rollover(self) -> Optional[int]:
@@ -437,7 +447,7 @@ class ServingEngine:
             from_step=self.step, to_step=new_step,
         ):
             self._params = FlatVector(
-                flat=self._place_flat(flat),
+                flat=self._place(flat),
                 layout=self._layout,
                 plan=self._plan,
             )
@@ -598,8 +608,8 @@ class ServingEngine:
         with tr.span("decode_dispatch", cat="serve", tick=self._tick_no):
             if self._dirty or self._dev is None:
                 self._dev = (
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    jnp.asarray(self._active),
+                    self._place(self._tok), self._place(self._pos),
+                    self._place(self._active),
                 )
                 self._dirty = False
             tok_d, pos_d, act_d = self._dev

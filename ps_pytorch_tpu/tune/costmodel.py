@@ -52,11 +52,11 @@ import numpy as np
 # inherits (tools/predicted_scaling.py wrote it; tests pin the format)
 DEFAULT_SCALING_MODEL = "runs/predicted_scaling.json"
 
-# per-device single-step compute floor, seconds, by network — the
-# measured single-chip step time divided across the mesh. Sources:
-# ResNet18 b1024: runs/predicted_scaling.json model.t1_seconds (itself
-# from runs/tpu_r03/bench_resnet18.json); LeNet b8192:
-# runs/tpu_r03/bench_lenet.json (8192 images / 1156512.8 images/sec).
+# per-device single-step compute floor, seconds, by network — a
+# single-chip step time divided across the mesh. Both values are
+# unverified (no chip record in the repository backs them; ResNet18
+# b1024 matches runs/predicted_scaling.json model.t1_seconds, LeNet is
+# b8192): re-measure under ROADMAP D7.
 # Used only when the scaling-model file is absent or names no t1 for
 # the network — the profile always records which source it used.
 _T1_FALLBACK_S = {"ResNet18": 6.693e-2, "LeNet": 7.083e-3}

@@ -219,8 +219,8 @@ def spec_for(knobs: Knobs, network: str):
 
 def backend_info() -> Dict[str, Optional[str]]:
     """The live jax backend identity every probe (and bench record)
-    stamps: platform + device kind. CPU-fallback evidence must never be
-    indistinguishable from TPU evidence again (BENCH_r05)."""
+    stamps: platform + device kind — the one thing that tells a CPU run
+    from a chip run in a saved record."""
     import jax
 
     devs = jax.devices()

@@ -1,29 +1,37 @@
-"""Persistent XLA compile cache — shared by bench.py and cli/tune.py.
+"""Persistent XLA compile cache — one rule for every entry point.
 
-First compile of a big train step is ~20-40s on TPU; the disk cache makes
-every later process with the same HLO skip straight to steady state. Note
-the cache keys on the HLO hash: a sweep whose candidates differ in a baked
-constant (e.g. tune's lr grid — each lr is folded into the optimizer
-transform) still compiles each DISTINCT candidate once, but re-running the
-same sweep (the common tuning workflow) compiles nothing.
+The first compile of a big train step takes tens of seconds on a TPU; the
+disk cache lets every later process with the same program skip it. The
+cache's path is part of its key, so the directory must not move between
+runs:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself at import, and
+  this module sets no directory in code — the operator's choice stands.
+- unset: ``<checkout>/.jax_cache`` (git-ignored), next to the code that
+  produced the programs. Never ``/tmp``, a pid or a timestamp.
+
+Call first thing in ``main`` — jax decides once, at its first compile,
+whether the process has a cache. The cache keys on the HLO hash: a sweep
+whose candidates differ in a baked constant (cli/tune's lr grid) still
+compiles each DISTINCT candidate once, and re-running it compiles nothing.
 """
 
 from __future__ import annotations
 
 import os
 
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-def enable_persistent_compile_cache(
-    cache_dir: str | None = None,
-) -> None:
+
+def enable_persistent_compile_cache() -> str:
+    """Turn the persistent cache on; returns the directory in use."""
     import jax
 
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            cache_dir
-            or os.environ.get("JAX_COMPILATION_CACHE_DIR", "/tmp/ps_tpu_jax_cache"),
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # older jax without these options
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return env_dir or REPO_CACHE_DIR

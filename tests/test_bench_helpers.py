@@ -1,14 +1,13 @@
-"""Pin bench.py's record-key and evidence-attachment helpers.
+"""Pin bench.py's record-key helpers and its failure behaviour.
 
-The driver parses bench's ONE JSON line per round; metric keys must stay
-aligned between success, error, and CPU-fallback records (and between f32
-and bf16 configs), and a fallback must never attach a banked hardware
-record from a different config. These invariants went through three
-review cycles — pinned here so they can't regress silently."""
+The driver parses bench's ONE JSON line per run; metric keys must stay
+aligned between every BENCH_* knob combination (and between f32 and bf16
+configs), every record names the backend it ran on, and a run that cannot
+start is a traceback and a non-zero exit — never a record."""
 
 import importlib.util
-import json
 import os
+import subprocess
 import sys
 
 import pytest
@@ -110,7 +109,7 @@ def test_cnn_compress_override_tags_metric(bench, monkeypatch):
     base = bench._success_metric()
     assert base == "resnet18_cifar10_b1024_train_throughput"
     # canonical mode requested explicitly -> canonical key (never forks
-    # the banked evidence)
+    # the canonical record)
     monkeypatch.setenv("BENCH_COMPRESS", "int8")
     assert bench._success_metric() == base
     monkeypatch.setenv("BENCH_COMPRESS", "int8_2round")
@@ -252,38 +251,6 @@ def test_comm_contract_entry_exact_match_only(bench):
     assert bench._comm_contract_entry("resnet18", None, None) is None
 
 
-def test_last_tpu_record_matches_metric_exactly(bench, tmp_path, monkeypatch):
-    # point the repo-relative runs/ glob at a temp tree via __file__ patching
-    (tmp_path / "runs" / "tpu_r99").mkdir(parents=True)
-    rec_dir = tmp_path / "runs" / "tpu_r99"
-    (rec_dir / "bench_resnet18.json").write_text(json.dumps({
-        "metric": "resnet18_cifar10_b1024_train_throughput",
-        "value": 15298.6, "device": "TPU v5 lite",
-    }))
-    (rec_dir / "bench_resnet18_bf16.json").write_text(json.dumps({
-        "metric": "resnet18_cifar10_b1024_train_throughput_bf16",
-        "value": 30000.0, "device": "TPU v5 lite",
-    }))
-    (rec_dir / "bench_cpu.json").write_text(json.dumps({
-        "metric": "resnet18_cifar10_b1024_train_throughput",
-        "value": 10.0, "device": "cpu",
-    }))
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-
-    got = bench._last_tpu_record("resnet18_cifar10_b1024_train_throughput")
-    assert got is not None and got["value"] == 15298.6
-    assert got["source"].endswith("bench_resnet18.json")
-    assert "recorded" in got
-
-    # a bf16 run must NOT pick up the f32 record (and vice versa)
-    got_bf16 = bench._last_tpu_record(
-        "resnet18_cifar10_b1024_train_throughput_bf16"
-    )
-    assert got_bf16["value"] == 30000.0
-    # CPU-labeled files are never evidence
-    assert bench._last_tpu_record("nonexistent_metric") is None
-
-
 def test_success_metric_covers_all_workloads(bench, monkeypatch):
     monkeypatch.delenv("BENCH_DTYPE", raising=False)
     for var in list(bench._LM_DEFAULTS) + list(bench._DEC_DEFAULTS):
@@ -302,71 +269,6 @@ def test_success_metric_covers_all_workloads(bench, monkeypatch):
     for wl, want in cases.items():
         monkeypatch.setenv("BENCH_WORKLOAD", wl)
         assert bench._success_metric() == want
-
-
-def test_attach_banked_uses_parent_metric(bench, tmp_path, monkeypatch):
-    # the fallback child runs shrunken shapes; BENCH_PARENT_METRIC must
-    # win over the child env's own (mismatching) tag
-    rec_dir = tmp_path / "runs" / "tpu_r99"
-    rec_dir.mkdir(parents=True)
-    (rec_dir / "bench_lm_1k.json").write_text(json.dumps({
-        "metric": "lm_d512x6_s1024_b8_train_tokens_per_sec",
-        "value": 220555.7, "device": "TPU v5 lite",
-    }))
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    monkeypatch.setenv("BENCH_WORKLOAD", "lm")
-    monkeypatch.setenv("BENCH_LM_SEQ", "256")  # the child's liveness shape
-    monkeypatch.setenv(
-        "BENCH_PARENT_METRIC", "lm_d512x6_s1024_b8_train_tokens_per_sec"
-    )
-    rec = {}
-    bench._attach_banked(rec)
-    assert rec["last_tpu_record"]["value"] == 220555.7
-    # the quotable one-liner names the banked evidence and labels the
-    # record a liveness signal (VERDICT r04 item 7)
-    assert "not a TPU measurement" in rec["headline"]
-    assert "220555.7" in rec["headline"]
-    # without the parent key, the shrunken tag matches nothing
-    monkeypatch.delenv("BENCH_PARENT_METRIC")
-    rec2 = {}
-    bench._attach_banked(rec2)
-    assert "last_tpu_record" not in rec2
-    assert "no banked TPU record" in rec2["headline"]
-
-
-def test_last_tpu_record_timestamp_tier_and_methodology(
-    bench, tmp_path, monkeypatch
-):
-    """ADVICE r04: (a) an empty/falsy timestamp must rank in the mtime tier
-    (tier and date from the SAME truthy value); (b) the returned copy always
-    carries explicit chain depth + timing methodology so chained
-    (dispatch-amortized) and per-dispatch records can't be confused."""
-    rec_dir = tmp_path / "runs" / "tpu_r99"
-    rec_dir.mkdir(parents=True)
-    key = "lenet_mnist_b8192_train_throughput"
-    # empty timestamp — would have been promoted to the timestamped tier by
-    # the old `"timestamp" in rec` check while dating itself from mtime
-    (rec_dir / "bench_a.json").write_text(json.dumps({
-        "metric": key, "value": 1.0, "device": "TPU v5 lite",
-        "timestamp": "",
-    }))
-    # genuinely timestamped (older than any plausible mtime) must still win
-    (rec_dir / "bench_b.json").write_text(json.dumps({
-        "metric": key, "value": 2.0, "device": "TPU v5 lite",
-        "timestamp": "2020-01-01T00:00:00Z", "chain": 10,
-    }))
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    got = bench._last_tpu_record(key)
-    assert got["value"] == 2.0
-    assert got["chain"] == 10
-    assert got["timing"] == "chained_fori_loop"
-    # an un-chained record reports per-dispatch methodology explicitly
-    (rec_dir / "bench_b.json").write_text(json.dumps({
-        "metric": key, "value": 2.0, "device": "TPU v5 lite",
-        "timestamp": "2020-01-01T00:00:00Z",
-    }))
-    got = bench._last_tpu_record(key)
-    assert got["chain"] == 1 and got["timing"] == "per_dispatch"
 
 
 def test_validate_env_rejects_non_integer_knobs(bench, monkeypatch):
@@ -404,8 +306,7 @@ def test_backend_info_stamps_platform_and_device_kind(bench):
 
 
 def test_require_same_backend_refuses_mixed_ab_variants(bench):
-    """BENCH_r05 banked CPU-fallback numbers indistinguishable from TPU
-    evidence; an A/B speedup across backends must refuse, not report."""
+    """An A/B speedup across backends must refuse, not report."""
     cpu = {"backend": {"platform": "cpu", "device_kind": "cpu"}}
     tpu = {"backend": {"platform": "tpu", "device_kind": "TPU v5 lite"}}
     bench._require_same_backend(cpu, dict(cpu))  # like-for-like: fine
@@ -414,3 +315,18 @@ def test_require_same_backend_refuses_mixed_ab_variants(bench):
     # a variant missing the stamp counts as a distinct (unknown) backend
     with pytest.raises(SystemExit, match="across backends"):
         bench._require_same_backend(cpu, {})
+
+
+def test_broken_backend_is_a_traceback_not_a_record():
+    """No probe, no CPU re-run, no catch-all: when the backend cannot
+    start, bench.py exits non-zero with the traceback on stderr and prints
+    nothing a driver could parse as a record."""
+    env = dict(os.environ, JAX_PLATFORMS="no_such_backend", BENCH_STEPS="1")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "Traceback" in proc.stderr
+    assert "no_such_backend" in proc.stderr
+    assert proc.stdout.strip() == ""

@@ -673,7 +673,15 @@ def test_homomorphic_e2e_training_parity_vs_dequant(mesh, extra):
     results = {}
     for domain in ("dequant", "homomorphic"):
         cfg = PSConfig(num_workers=N, wire_domain=domain, **extra)
-        state, step, batch = _tiny_setup(mesh, cfg, seed=6)
+        # seed 2, not the original 6: at lr 0.05 / momentum 0.9 on one
+        # 16-sample batch the 6-step loss oscillates, and whether it ends
+        # below its start depends on the init draw. jax 0.9.0's default
+        # (partitionable) threefry stream draws a different init from the
+        # same key than the jax this pin was written on; under it seed 6
+        # ends ABOVE its start on every wire, the uncompressed psum
+        # included (3.14 -> 8.82), while seeds 2 and 3 descend on all
+        # three. The two wires under test agree to 3 decimals either way.
+        state, step, batch = _tiny_setup(mesh, cfg, seed=2)
         losses = []
         p1 = None
         for i in range(6):

@@ -14,14 +14,13 @@ devices each -> one 8-device job) and drives the actual product CLI:
   _stop_consensus) — the capability the reference's tag-77 kill never
   actually wired (SURVEY.md section 2 straggler row).
 
-These need cross-process CPU collectives, which jax 0.4.37 only has via
-the gloo TCP backend (initialize_multihost enables it; without it every
-multiprocess CPU computation aborts). Gloo pairs match ops by FIFO
+These need cross-process CPU collectives, which ride jaxlib's gloo TCP
+backend. Gloo pairs match ops by FIFO
 order, not tags, and XLA's CPU executor can issue independent
 collectives of one computation in thread-pool order — so under load a
 run occasionally dies with `gloo::EnforceNotMet` (op-size mismatch) or
 a peer-reset/hang as a process aborts mid-collective. That is a known
-transport flake of this pinned jax, independent of the product code
+transport flake of the gloo backend, independent of the product code
 under test, so each test retries its whole 2-process attempt ONCE when
 the failure signature is gloo's; a second strike fails the test.
 """
@@ -41,7 +40,7 @@ sys.path.insert(0, REPO)
 from tpu_env import clean_cpu_env  # noqa: E402
 from tools.mp_util import free_port as _free_port  # noqa: E402
 
-# the failure signatures of jax 0.4.37's gloo TCP transport (see module
+# the failure signatures of the gloo TCP transport (see module
 # docstring) — the ONLY errors a retry may absorb
 _GLOO_FLAKE_SIGNS = (
     "gloo::EnforceNotMet",

@@ -3,7 +3,6 @@ helpers (no compiles — the compile-level paths are smoked by the tools
 themselves and the bench workloads)."""
 
 import importlib.util
-import json
 import os
 
 import pytest
@@ -178,23 +177,3 @@ def test_chain_default_and_override(bench, monkeypatch):
     assert bench._chain() == 10
     monkeypatch.setenv("BENCH_CHAIN", "0")  # floor at 1: never a 0-iter loop
     assert bench._chain() == 1
-
-
-def test_last_tpu_record_prefers_embedded_timestamp(bench, tmp_path, monkeypatch):
-    d = tmp_path / "runs" / "tpu_r98"
-    d.mkdir(parents=True)
-    # older embedded timestamp but newer mtime (the fresh-clone hazard) vs
-    # newer embedded timestamp: the embedded field must win
-    (d / "bench_a.json").write_text(json.dumps({
-        "metric": "m", "value": 1.0, "device": "TPU v5 lite",
-        "timestamp": "2026-01-01T00:00:00Z",
-    }))
-    (d / "bench_b.json").write_text(json.dumps({
-        "metric": "m", "value": 2.0, "device": "TPU v5 lite",
-        "timestamp": "2026-06-01T00:00:00Z",
-    }))
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    rec = bench._last_tpu_record("m")
-    assert rec["value"] == 2.0
-    assert rec["recorded"] == "2026-06-01T00:00:00Z"
-    assert rec["source"].endswith("bench_b.json")

@@ -35,8 +35,8 @@ What is measured vs modeled:
             figure), --dcn-gbs 12.5 (order-of-100-Gbps per-host NIC —
             set your fabric's real figure). Compute time at n workers =
             t1 / n (fixed global batch, the reference's own
-            normalization), t1 from the banked TPU ResNet18 b=1024
-            record when present (--t1 overrides).
+            normalization), t1 an assumed single-chip ResNet18 b=1024
+            step time unless --t1 gives a measured one.
 
 Efficiency bounds: "no overlap" serializes compute + comm; "full overlap"
 takes max(compute, comm) — the XLA latency-hiding scheduler lands between
@@ -199,17 +199,10 @@ def child(args) -> None:
     }))
 
 
-def _banked_t1() -> tuple[float | None, str | None]:
-    """Per-step seconds of the banked single-chip TPU ResNet18 b=1024 f32
-    record (the t_compute anchor), or (None, None). Reuses bench.py's
-    newest-matching-record lookup so both tools agree on which banked
-    record is 'the' evidence for a metric key."""
-    import bench
-
-    rec = bench._last_tpu_record("resnet18_cifar10_b1024_train_throughput")
-    if rec is None or not rec.get("value"):
-        return None, None
-    return 1024.0 / rec["value"], rec.get("source")
+# per-step seconds of single-chip ResNet18 b=1024 f32, the t_compute
+# anchor when --t1 is not given: unverified (no chip record backs it),
+# re-measure under ROADMAP D7 and pass the result as --t1
+_T1_ASSUMED_S = 0.067
 
 
 def predict(row: dict, t1: float, bw: float, dcn_bw: float | None = None) -> dict:
@@ -282,7 +275,8 @@ def main(argv=None) -> dict:
                    help="per-host one-way DCN GB/s (default 12.5 = 100 "
                         "Gbps NIC; set your fabric's real figure)")
     p.add_argument("--t1", type=float, default=None,
-                   help="single-chip step seconds; default: banked TPU record")
+                   help="single-chip step seconds measured on a chip; "
+                        "default: an unverified assumption")
     p.add_argument("--timeout", type=int, default=900)
     p.add_argument("--out", default=None)
     p.add_argument("--contract", default=None,
@@ -300,9 +294,10 @@ def main(argv=None) -> dict:
 
     from tpu_env import clean_cpu_env
 
-    t1, t1_src = (args.t1, "--t1") if args.t1 else _banked_t1()
-    if t1 is None:
-        t1, t1_src = 0.067, "fallback (no banked record): 15.3k img/s r03"
+    t1, t1_src = (
+        (args.t1, "--t1") if args.t1
+        else (_T1_ASSUMED_S, "assumed (not measured on a chip)")
+    )
     bw = args.ici_gbs * 1e9
 
     rows, failures = [], []

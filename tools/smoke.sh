@@ -16,8 +16,9 @@
 # Chrome timeline by tools/trace_report.py), the serve-chaos leg
 # (traffic spike + decode stalls + corrupt staged rollover -> shed
 # events, full lifecycle accounting, rollover abort onto old weights),
-# and the headline benchmark in its trimmed form. Budget ~8 minutes of
-# CPU (compiles dominate).
+# and the headline benchmark in its trimmed form (an explicit CPU run).
+# Budget ~8 minutes of CPU (compiles dominate). The chip has its own
+# smoke: python chip_smoke.py.
 #
 #   bash tools/smoke.sh
 set -euo pipefail
@@ -39,8 +40,8 @@ trap 'rm -rf "$TMP"' EXIT
 # pass (PSL006-008, multihost deadlock/torn-replica hazards) runs as its
 # own leg so a divergence regression is named before the general gate;
 # lint.sh reads only source text; check.sh traces the real step
-# functions on the same scrubbed 8-device CPU environment the rest of
-# the smoke uses.
+# functions on the same 8-device CPU environment the rest of the smoke
+# uses.
 run bash tools/lint.sh --select PSL006,PSL007,PSL008
 run bash tools/lint.sh
 
@@ -340,6 +341,15 @@ print("autotune smoke: %d ranked, %d pruned (%s), best %s"
          rec["best"]["name"]))
 PYEOF
 
-run python bench.py
+# the headline benchmark, trimmed, as an EXPLICIT CPU run: run() sets
+# JAX_PLATFORMS=cpu and the record must say so itself (bench.py has no
+# fallback; a backend that cannot start is a traceback and a non-zero exit)
+run python bench.py | tee "$TMP/bench.json"
+python - "$TMP/bench.json" <<'PYEOF'
+import json, sys
+rec = json.loads(open(sys.argv[1]).read().strip().splitlines()[-1])
+assert rec["backend"]["platform"] == "cpu", rec["backend"]
+print("bench smoke: %s on %s" % (rec["metric"], rec["backend"]))
+PYEOF
 
 echo "SMOKE OK"

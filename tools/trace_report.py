@@ -24,15 +24,13 @@ monotonic clock. This tool:
 - ``--require-phases a,b,c`` exits nonzero unless every named phase is
   present (the smoke gate).
 
-The earlier one-off analysis tools fold in as subcommands:
+The earlier one-off analysis tool folds in as a subcommand:
 
   python tools/trace_report.py overlap <hlo|trace|topology|jaxpr> [...]
       -> tools/overlap_report.py (comm/compute overlap evidence;
          `jaxpr --overlap on|off` reports the pipelined wire's
          schedule-freedom numbers, `trace` knows the per-bucket
          `bucket_reduce_o<offset>` span names — §6g)
-  python tools/trace_report.py window [outdir]
-      -> tools/window_report.py (TPU bench-window rollup)
 
 Usage:
   python tools/trace_report.py runs/trace/ --metrics runs/metrics.jsonl \\
@@ -241,17 +239,13 @@ def merge(
 
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    # folded one-off tools ride as subcommands (their modules remain the
-    # implementation and keep their own CLIs working)
+    # the folded one-off tool rides as a subcommand (its module remains
+    # the implementation and keeps its own CLI working)
     if argv and argv[0] == "overlap":
         import overlap_report
 
         overlap_report.main(argv[1:])
         return 0
-    if argv and argv[0] == "window":
-        import window_report
-
-        return window_report.main(argv[1] if len(argv) > 1 else "runs/tpu_r04")
 
     p = argparse.ArgumentParser(
         "tools/trace_report.py",
