@@ -26,10 +26,14 @@ calls by kernel name), the sharding of every state and batch leaf after a
 step, and each device's memory.
 
 A leg that fails raises: the traceback is the report, the exit code is
-non-zero and no result line is printed. On success the LAST line of stdout
-is one JSON object, ``{"ok": true, "device": {...}, ...}``. It carries no
-speed: sizes, rates and utilization are the benchmark's job, not this
-script's (``"claim": null``).
+non-zero and no result line is printed. On success the last two lines of
+stdout are JSON objects. The second to last is the summary: per-leg
+ok / seconds / cache hits, compile seconds, and no speed — sizes, rates and
+utilization are the benchmark's job, not this script's (``"claim": null``).
+The LAST is the result, and holds exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with the device as jax reports it; whoever runs this script parses that
+line and refuses any other key in it.
 """
 
 from __future__ import annotations
@@ -501,6 +505,19 @@ def leg_serve(lm_dir, devices, clog):
 # ------------------------------------------------------------------ main
 
 
+def describe_devices(devices):
+    """The device as jax reports it. The result line holds exactly this
+    under "device": three keys, two texts and a whole number."""
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def result_line(device):
+    """The last line of stdout: these keys and no others (the summary line
+    before it is where everything else goes)."""
+    return json.dumps({"ok": True, "device": device})
+
+
 def _version(dist):
     try:
         return importlib.metadata.version(dist)
@@ -529,8 +546,7 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices)}
+    device = describe_devices(devices)
     versions = {d: _version(d) for d in ("jax", "jaxlib", "libtpu", "flax",
                                          "optax")}
     print(f"[device] {device} versions={versions} "
@@ -580,8 +596,6 @@ def main() -> int:
         run("serve", lambda clog: leg_serve(lm_dir, devices, clog))
 
     print(json.dumps({
-        "ok": True,
-        "device": device,
         "versions": versions,
         "legs": legs,
         "seconds": round(time.perf_counter() - t_start, 1),
@@ -591,7 +605,8 @@ def main() -> int:
         "compile_cache": {"dir": cache_dir, "hits": events["hits"],
                           "misses": events["misses"]},
         "claim": None,
-    }))
+    }), flush=True)
+    print(result_line(device), flush=True)  # nothing after it
     return 0
 
 

@@ -8,10 +8,12 @@ Three pins, all checkable without a chip:
 - the compile cache has one rule: ``JAX_COMPILATION_CACHE_DIR`` wins and
   then no directory is set in code; unset, the fixed in-checkout path;
 - ``chip_smoke.py`` refuses a CPU: non-zero exit, the reason on stderr, no
-  result line.
+  result line; and the result line it would print holds exactly the keys
+  its caller parses.
 """
 
 import inspect
+import json
 import os
 import re
 import subprocess
@@ -174,3 +176,27 @@ def test_chip_smoke_refuses_a_cpu():
     assert proc.returncode != 0
     assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
     assert proc.stdout.strip() == ""  # no result line
+
+
+def test_chip_smoke_result_line_has_exactly_the_parsed_keys():
+    """Whoever runs chip_smoke.py parses its LAST stdout line and refuses
+    any key but ok / device{platform, kind, count}: per-leg detail belongs
+    on the summary line before it (a PR was refused for merging the two)."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    line = chip_smoke.result_line(chip_smoke.describe_devices(jax.devices()))
+    assert "\n" not in line
+    got = json.loads(line)
+    assert got == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
+    }
+    assert isinstance(got["device"]["kind"], str)
+    assert type(got["device"]["count"]) is int
+    # and main() ends on it: the result line is the last thing printed
+    tail = inspect.getsource(chip_smoke.main).rstrip().splitlines()[-2:]
+    assert "print(result_line(device)" in tail[0] and "return 0" in tail[1]
