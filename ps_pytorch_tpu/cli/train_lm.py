@@ -29,6 +29,7 @@ data/datasets.make_synthetic.
 from __future__ import annotations
 
 import argparse
+import os
 import time
 from typing import Optional
 
@@ -45,7 +46,7 @@ from ..parallel.dp_sp import (
     make_mesh_2d,
     shard_tokens_2d,
 )
-from ..trainer import append_metrics_line
+from ..trainer import _shared_run_id, append_metrics_line
 from ..utils import (
     enable_persistent_compile_cache,
     format_iter_line,
@@ -139,6 +140,15 @@ def main(argv=None) -> dict:
     parser.add_argument("--profile-dir", type=str, default=None,
                         help="write a jax.profiler device trace for steps "
                              "3..12 (view with tensorboard/xprof)")
+    parser.add_argument("--trace", type=str, default=None, metavar="DIR",
+                        help="write this process's host-phase span stream "
+                             "(trace_train_lm_p<i>.jsonl) here: one `step` "
+                             "span per iteration holding `fetch` (batch "
+                             "gather), `dispatch` (the step call), and on "
+                             "log steps `sync` (the host waiting for the "
+                             "device), `log` and `metrics_write` — the "
+                             "names cli.train records (obs/trace.py; "
+                             "merge with tools/trace_report.py)")
     parser.add_argument("--train-dir", type=str, default=None,
                         help="checkpoint dir (scheme-agnostic plain layout; "
                              "consumed by cli.evaluate_lm)")
@@ -389,20 +399,30 @@ def main(argv=None) -> dict:
         "LM %dx d%d h%d (%d params), seq %d, %s",
         args.depth, args.dim, args.heads, n_params, args.seq_len, layout,
     )
-    from ..obs import run_header
+    from ..obs import NULL_TRACER, Tracer, run_header
 
+    # one id for the metrics and the span stream, on every host
+    run_id = _shared_run_id()
+    geometry = {
+        "parallelism": args.parallelism,
+        "dim": args.dim, "depth": args.depth,
+        "heads": args.heads, "seq_len": args.seq_len,
+        "params": n_params,
+    }
     append_metrics_line(
         args.metrics_file,
-        run_header(
-            "train_lm",
-            geometry={
-                "parallelism": args.parallelism,
-                "dim": args.dim, "depth": args.depth,
-                "heads": args.heads, "seq_len": args.seq_len,
-                "params": n_params,
-            },
-        ),
+        run_header("train_lm", run_id=run_id, geometry=geometry),
     )
+    tr = NULL_TRACER
+    if args.trace:
+        tr = Tracer(
+            "train_lm",
+            path=os.path.join(
+                args.trace, f"trace_train_lm_p{jax.process_index()}.jsonl"
+            ),
+            run_id=run_id, pid=jax.process_index(), annotate=True,
+            geometry=geometry,
+        )
 
     def save_lm_checkpoint(step_no):
         if args.train_dir is None:
@@ -453,49 +473,74 @@ def main(argv=None) -> dict:
             "--profile-dir set but max-steps < 3: tracing starts at step 3 "
             "(after compile + settle), so no trace will be written"
         )
-    for step_no in range(1, args.max_steps + 1):
-        if step_no == warmup + 1 and args.max_steps > warmup:
-            host_sync(params)
-            steady_t0 = time.perf_counter()
-        if args.profile_dir and step_no == 3:  # after compile + settle
-            jax.profiler.start_trace(args.profile_dir)
-            profiling = True
-        log_now = step_no % args.log_interval == 0 or step_no == 1
-        if log_now:
-            # drain the async-dispatch backlog BEFORE starting the clock so
-            # dt measures ONE step, not the queue of unlogged steps
-            # (host-read barrier — block_until_ready can lie, utils/sync.py)
-            host_sync(params)
-        t0 = time.perf_counter()
-        idx = rng.randint(0, len(corpus), args.batch_size)
-        params, opt_state, loss = run(params, opt_state, corpus[idx])
-        if log_now:
-            loss = float(loss)
-            host_sync(params)  # include the param update in dt
-            dt = time.perf_counter() - t0
-            logger.info(
-                format_iter_line(
-                    rank="mesh", step=step_no, epoch=1,
-                    seen=step_no * args.batch_size,
-                    total=args.max_steps * args.batch_size,
-                    loss=loss, time_cost=dt, forward=dt,
-                )
-            )
-            record = {"kind": "train_lm", "parallelism": args.parallelism,
-                      "step": step_no, "loss": loss, "time_cost": round(dt, 6)}
-            if args.parallelism in ("moe", "ep_sp", "pp_moe"):
-                # router balance: aux == 1 is perfectly balanced; a climb
-                # toward num_experts signals expert collapse
-                record["aux_loss"] = round(float(aux_box["aux"]), 6)
-                logger.info("MoE load-balance aux: %.4f", record["aux_loss"])
-            append_metrics_line(args.metrics_file, record)
-        if profiling and step_no >= profile_stop:
-            host_sync(params)  # trace must contain retired work
-            jax.profiler.stop_trace()
-            profiling = False
-            logger.info("profiler trace written to %s", args.profile_dir)
-        if args.eval_freq > 0 and step_no % args.eval_freq == 0:
-            save_lm_checkpoint(step_no)
+    flush_due = False  # a log step closed; flush after the next dispatch
+    try:
+        for step_no in range(1, args.max_steps + 1):
+            with tr.span("step", step=step_no):
+                if step_no == warmup + 1 and args.max_steps > warmup:
+                    host_sync(params)
+                    steady_t0 = time.perf_counter()
+                if args.profile_dir and step_no == 3:  # after compile + settle
+                    jax.profiler.start_trace(args.profile_dir)
+                    profiling = True
+                log_now = step_no % args.log_interval == 0 or step_no == 1
+                if log_now:
+                    # drain the async-dispatch backlog BEFORE starting the
+                    # clock so dt measures ONE step, not the queue of
+                    # unlogged steps (host-read barrier — block_until_ready
+                    # can lie, utils/sync.py)
+                    with tr.span("sync"):
+                        host_sync(params)
+                t0 = time.perf_counter()
+                with tr.span("fetch"):
+                    idx = rng.randint(0, len(corpus), args.batch_size)
+                    batch = corpus[idx]
+                with tr.span("dispatch"):
+                    params, opt_state, loss = run(params, opt_state, batch)
+                if flush_due:
+                    # span I/O once the device is busy again, never
+                    # between a log step's sync and the next dispatch
+                    tr.flush()
+                    flush_due = False
+                if log_now:
+                    with tr.span("sync"):
+                        loss = float(loss)
+                        host_sync(params)  # include the param update in dt
+                    dt = time.perf_counter() - t0
+                    with tr.span("log"):
+                        logger.info(
+                            format_iter_line(
+                                rank="mesh", step=step_no, epoch=1,
+                                seen=step_no * args.batch_size,
+                                total=args.max_steps * args.batch_size,
+                                loss=loss, time_cost=dt, forward=dt,
+                            )
+                        )
+                    record = {"kind": "train_lm",
+                              "parallelism": args.parallelism,
+                              "step": step_no, "loss": loss,
+                              "time_cost": round(dt, 6)}
+                    if args.parallelism in ("moe", "ep_sp", "pp_moe"):
+                        # router balance: aux == 1 is perfectly balanced; a
+                        # climb toward num_experts signals expert collapse
+                        record["aux_loss"] = round(float(aux_box["aux"]), 6)
+                        logger.info(
+                            "MoE load-balance aux: %.4f", record["aux_loss"]
+                        )
+                    with tr.span("metrics_write"):
+                        append_metrics_line(args.metrics_file, record)
+                    flush_due = True
+                if profiling and step_no >= profile_stop:
+                    host_sync(params)  # trace must contain retired work
+                    jax.profiler.stop_trace()
+                    profiling = False
+                    logger.info(
+                        "profiler trace written to %s", args.profile_dir
+                    )
+                if args.eval_freq > 0 and step_no % args.eval_freq == 0:
+                    save_lm_checkpoint(step_no)
+    finally:
+        tr.flush()  # the trailing steps' spans
     if steady_t0 is not None:
         host_sync(params)  # params chain: serializes the whole window
         steady = {

@@ -162,8 +162,9 @@ def prefetch_to_device(
     images and the [B] labels.
 
     ``tracer`` (obs/trace.py) wraps each device_put dispatch in an
-    ``h2d`` span — dispatch walltime, not transfer completion: the
-    transfer itself overlaps compute, which is the point of prefetching."""
+    ``h2d`` span carrying the ``bytes`` uploaded — dispatch walltime,
+    not transfer completion: the transfer itself overlaps compute,
+    which is the point of prefetching."""
     queue = collections.deque()
     if tracer is None:
         from ..obs import NULL_TRACER as tracer  # noqa: N811 - constant
@@ -173,7 +174,13 @@ def prefetch_to_device(
             batch = next(iterator, None)
             if batch is None:
                 return
-            with tracer.span("h2d"):
+            attrs = {}
+            if tracer.enabled:
+                attrs["bytes"] = sum(
+                    getattr(x, "nbytes", 0)
+                    for x in jax.tree_util.tree_leaves(batch)
+                )
+            with tracer.span("h2d", **attrs):
                 queue.append(jax.device_put(batch, device))
 
     enqueue(size)

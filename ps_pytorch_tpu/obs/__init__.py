@@ -4,9 +4,9 @@ Three layers (ARCHITECTURE §7g):
 
 - ``obs.schema`` — the unified JSONL event registry (kind -> required
   fields + int contract), ``run_header`` records, run ids;
-- ``obs.trace`` — the host-side span tracer (ring-buffered, flushed at
-  existing sync points, Chrome-trace exportable) and NULL_TRACER, the
-  zero-cost off switch;
+- ``obs.trace`` — the host-side span tracer (ring-buffered, flushed
+  once per window while the device is busy, Chrome-trace exportable)
+  and NULL_TRACER, the zero-cost off switch;
 - ``obs.profiler`` — bounded ``jax.profiler`` capture windows for
   ``--profile-dir``.
 

@@ -50,7 +50,15 @@ class ProfileWindow:
         if not self.active and self.start <= step < self.stop:
             import jax
 
-            jax.profiler.start_trace(self.dir)
+            # device ops and the program's own annotations, nothing
+            # more: Python call tracing and host tracer level 2 (every
+            # layout chunk of an upload, 300,000 events a block on the
+            # v5e) slow the loader tenfold — the capture would distort
+            # the loop it watches (PERF.md section 3)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
             self.active = True
             logger.info(
                 "profiler capture started: steps [%d, %d) -> %s",

@@ -138,9 +138,11 @@ EVENT_KINDS: Dict[str, EventSpec] = {
     "span": EventSpec(
         required=("name", "t", "dur"),
         int_fields=("depth", "step", "tick", "slot", "rid",
-                    "new_tokens", "weights_step", "from_step", "to_step"),
+                    "new_tokens", "weights_step", "from_step", "to_step",
+                    "bytes", "block", "wall_ns", "err_ns"),
         doc="one traced host-side phase: t/dur are seconds on the "
-            "stream header's monotonic clock",
+            "stream header's monotonic clock; a clock_sync span pairs "
+            "that clock with the wall clock (wall_ns +- err_ns at t)",
     ),
     # ---- serving request lifecycle (ARCHITECTURE §7i): every submitted
     # request terminates in EXACTLY one of request_done | request_shed |

@@ -14,17 +14,25 @@ the observed):
   reusable null context manager; instrumented call sites stay
   unconditional and pay ~a method call per phase per step.
 - Spans buffer in an in-memory ring (``deque(maxlen=ring)``) and flush
-  to the per-process trace file only at the call sites that already
-  sync (the trainer's log window, every Nth serve tick) — tracing adds
-  file I/O where the host was already stalling on the device, never a
-  new stall.
+  to the per-process trace file once per window, at a moment the
+  device is busy: the trainer flushes right after the dispatch that
+  follows a log window (never between the window's ``sync`` and that
+  dispatch — there the device is idle and the write would lengthen the
+  gap a traced run is there to measure), the serve loop every Nth tick.
 
 Each trace file is a JSONL stream: one ``run_header`` record (run id,
 schema version, wall+monotonic clock base — obs/schema.py), then one
 ``span`` record per completed span with ``t``/``dur`` in seconds on the
-header's monotonic clock. ``tools/trace_report.py`` merges any number
-of per-process files into one perfetto-loadable Chrome trace via the
-header wall clocks and summarizes p50/p99 per phase.
+header's monotonic clock. A span opened inside another records its
+``parent``'s name and inherits its ``step``, so the spans of one loop
+iteration share an identifier. ``clock_sync`` records (one taken at
+construction, one per flush) pair the wall clock with the span clock:
+``wall_ns`` is ``time.time_ns()`` read between two ``perf_counter()``
+reads, ``t`` their midpoint and ``err_ns`` half their distance, so a
+span's wall-clock time is good to microseconds however long the run
+(the header's single ``t_wall`` drifts). ``tools/trace_report.py``
+merges any number of per-process files into one perfetto-loadable
+Chrome trace on that wall clock and summarizes p50/p99 per phase.
 
 When ``annotate=True`` each span also enters a
 ``jax.profiler.TraceAnnotation`` scope of the same name, so the host
@@ -35,6 +43,7 @@ no-ops when no profiler session is active — safe to leave on.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import json
 import os
@@ -100,7 +109,16 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         self._depth = len(tr._stack)
-        tr._stack.append(self._name)
+        if tr._stack:
+            # the spans of one loop iteration share its identifier: a
+            # child records who opened it and inherits the parent's step
+            # (the loader's gather/h2d cannot know the step they feed)
+            top = tr._stack[-1]
+            inherited = {"parent": top._name}
+            if "step" in top._attrs:
+                inherited["step"] = top._attrs["step"]
+            self._attrs = {**inherited, **self._attrs}
+        tr._stack.append(self)
         if tr._ann_cls is not None:
             self._ann = tr._ann_cls(self._name)
             self._ann.__enter__()
@@ -149,7 +167,10 @@ class Tracer:
         # span t/dur are seconds on THIS clock base (the header's t_mono)
         self._base = self.header["t_mono"]
         self._buf: collections.deque = collections.deque(maxlen=max(ring, 1))
-        self._stack: List[str] = []
+        self._stack: List[_Span] = []
+        # the wall clock paired with the span clock NOW; written right
+        # after the header (a pathless tracer never writes it)
+        self._sync0 = self._clock_sync()
         self.dropped = 0  # ring overflow count (oldest spans evicted)
         self._dropped_reported = 0  # watermark already flushed as a marker
         self._header_written = False
@@ -188,6 +209,19 @@ class Tracer:
     def instant(self, name: str, cat: str = "instant", **attrs) -> None:
         self._append(name, self.now(), 0.0, cat, len(self._stack), attrs)
 
+    def _clock_sync(self) -> dict:
+        """One ``clock_sync`` record: the wall clock read between two
+        reads of the span clock. Host-pure — two clock reads, no I/O."""
+        a = time.perf_counter()
+        wall_ns = time.time_ns()
+        b = time.perf_counter()
+        return {
+            "kind": "span", "name": "clock_sync", "cat": "meta",
+            "t": round((a + b) / 2 - self._base, 9), "dur": 0.0,
+            "depth": 0, "async": True, "wall_ns": wall_ns,
+            "err_ns": int((b - a) * 5e8) + 1,
+        }
+
     def _append(self, name, t, dur, cat, depth, attrs) -> None:
         if len(self._buf) == self._buf.maxlen:
             self.dropped += 1  # deque evicts the OLDEST span silently
@@ -211,9 +245,12 @@ class Tracer:
         return out
 
     def flush(self) -> int:
-        """Append drained spans (validated) to the trace file; writes the
-        run_header first on the first flush. Call from sites that already
-        sync (log windows), never per step. Returns spans written.
+        """Append drained spans (validated) to the trace file, closed by
+        one ``clock_sync`` record; writes the run_header (and the
+        construction-time ``clock_sync``) first on the first flush. Call
+        once per window while the device is busy (right after a
+        dispatch), never per step and never where the device waits for
+        the host. Returns spans written, the ``clock_sync`` records apart.
 
         A pathless (in-memory) tracer is a no-op here — the ring keeps
         its spans for a later ``drain()``: the serve engine flushes
@@ -232,8 +269,10 @@ class Tracer:
                 "async": True, "dropped_total": self.dropped,
             })
             self._dropped_reported = self.dropped
-        if not self._header_written and not spans:
+        if not spans:
             return 0
+        n = len(spans)
+        spans.append(self._clock_sync())
         d = os.path.dirname(self.path)
         if d:
             os.makedirs(d, exist_ok=True)
@@ -241,9 +280,10 @@ class Tracer:
             if not self._header_written:
                 f.write(json.dumps(validate_event(dict(self.header))) + "\n")
                 self._header_written = True
+                spans.insert(0, self._sync0)
             for rec in spans:
                 f.write(json.dumps(validate_event(rec)) + "\n")
-        return len(spans)
+        return n
 
 
 # ------------------------------------------------------------------ reports
@@ -287,12 +327,19 @@ def chrome_trace_events(
     t0_wall: float = 0.0,
 ) -> List[dict]:
     """Convert one stream (header + span records) to Chrome trace_event
-    dicts. ``ts`` is microseconds of (header wall base + span monotonic
-    offset − ``t0_wall``) — the multihost merge rule: every process's
-    spans land on one wall-clock timeline, durations stay monotonic-
-    clock-accurate."""
+    dicts. ``ts`` is microseconds of (wall base + span monotonic offset
+    − ``t0_wall``) — the multihost merge rule: every process's spans
+    land on one wall-clock timeline, durations stay monotonic-clock-
+    accurate. The wall base of a span is the newest ``clock_sync`` at
+    or before it (the header's ``t_wall`` in a stream without one), so
+    a long run's spans do not drift off the other hosts'."""
     p = int(header.get("pid", 0)) if pid is None else pid
-    base = float(header.get("t_wall", 0.0)) - t0_wall
+    syncs = sorted(
+        (float(s["t"]), s["wall_ns"] * 1e-9 - float(s["t"]) - t0_wall)
+        for s in spans if s.get("name") == "clock_sync" and "wall_ns" in s
+    )
+    sync_ts = [t for t, _ in syncs]
+    header_base = float(header.get("t_wall", 0.0)) - t0_wall
     out: List[dict] = [
         {
             "name": "process_name",
@@ -316,6 +363,10 @@ def chrome_trace_events(
         tid = 0
         if s.get("async"):
             tid = 10 + int(s.get("slot", -1)) + 1
+        base = header_base
+        if syncs:
+            i = bisect.bisect_right(sync_ts, float(s["t"])) - 1
+            base = syncs[max(i, 0)][1]
         ev = {
             "name": s["name"],
             "cat": s.get("cat", "phase"),

@@ -199,6 +199,28 @@ class Trainer:
                 f"use a large value to effectively disable storms)"
             )
         self._stop_requested = False
+        # one run id ties this run's streams together (metrics JSONL run
+        # header + the per-process span trace file) — broadcast from
+        # process 0 so every host agrees on it. The tracer comes first
+        # so that set-up itself is timed (the `build` spans below); its
+        # header's geometry is filled in at the end of __init__, before
+        # anything can flush.
+        self.run_id = _shared_run_id()
+        self.tracer = NULL_TRACER
+        if tcfg.trace_dir:
+            self.tracer = Tracer(
+                "train",
+                path=os.path.join(
+                    tcfg.trace_dir,
+                    f"trace_train_p{jax.process_index()}.jsonl",
+                ),
+                run_id=self.run_id,
+                pid=jax.process_index(),
+                # host spans double as jax.profiler.TraceAnnotation
+                # scopes, so a --profile-dir capture shows the named
+                # phases on the profiler timeline too
+                annotate=True,
+            )
         # straggler watchdog event counter (observable --mode action)
         self.straggler_steps = 0
         # storm escalation state (straggler_storm_n consecutive slow steps)
@@ -236,9 +258,34 @@ class Trainer:
         self.faults = resolve_fault_plan(tcfg.fault_plan)
         if self.faults is not None:
             logger.warning("fault injection ACTIVE: %s", self.faults)
-        self.dataset = dataset or prepare_data(
-            tcfg.dataset, root=tcfg.data_root, allow_synthetic=tcfg.allow_synthetic
+        # set-up the program owns, by part: data, model (mesh, network,
+        # optimizer), state (initialised and sharded: the many one-op
+        # programs) and step (the jitted train and eval steps)
+        tr = self.tracer
+        with tr.span("build"):
+            with tr.span("build.data"):
+                self.dataset = dataset or prepare_data(
+                    tcfg.dataset, root=tcfg.data_root,
+                    allow_synthetic=tcfg.allow_synthetic,
+                )
+            with tr.span("build.model"):
+                self._build_model(tcfg, pcfg)
+            with tr.span("build.state"):
+                n_params = self._build_state(tcfg, pcfg)
+            with tr.span("build.step"):
+                self._build_step(tcfg, pcfg)
+        if tr.enabled:
+            tr.header["geometry"] = self._geometry()
+        logger.info(
+            "model %s (%d params), dataset %s%s, %d workers",
+            tcfg.network,
+            n_params,
+            self.dataset.name,
+            " [synthetic]" if self.dataset.synthetic else "",
+            pcfg.num_workers,
         )
+
+    def _build_model(self, tcfg: TrainConfig, pcfg: PSConfig) -> None:
         if pcfg.dcn_hosts > 1:
             from .parallel import make_hybrid_mesh
 
@@ -270,11 +317,23 @@ class Trainer:
             # variants — same math, no per-leaf tree_map
             flat=(pcfg.state_layout == "flat"),
         )
+
+    def _build_state(self, tcfg: TrainConfig, pcfg: PSConfig) -> int:
+        """Initialise and shard the state; returns the parameter count."""
         shape = input_shape_for(tcfg.network)
         state = init_ps_state(
             self.model, self.tx, pcfg, jax.random.key(tcfg.seed), shape
         )
         self.state = shard_state(state, self.mesh, pcfg)
+        # flat layout: the true count is static metadata (the padded
+        # buffer would over-count by the alignment tail, and
+        # materializing the tree view just to count would waste a
+        # params-sized device allocation)
+        n_params = (
+            state.params.layout.total
+            if isinstance(state.params, FlatVector)
+            else param_count(state.params)
+        )
         # adaptive per-bucket precision: the host half that picks each
         # window's traced tag vector (the train step takes it as an
         # argument — VALUES into one compiled program, never a retrace).
@@ -284,11 +343,6 @@ class Trainer:
         if pcfg.precision_adapt:
             from .parallel.ps import state_plan
 
-            n_params = (
-                state.params.layout.total
-                if isinstance(state.params, FlatVector)
-                else param_count(state.params)
-            )
             self._precision = PrecisionController(
                 pcfg,
                 state_plan(pcfg, n_params).sizes,
@@ -310,6 +364,9 @@ class Trainer:
                     else None
                 ),
             )
+        return n_params
+
+    def _build_step(self, tcfg: TrainConfig, pcfg: PSConfig) -> None:
         pre_train = make_preprocessor(tcfg.dataset, train=True)
         pre_eval = make_preprocessor(tcfg.dataset, train=False)
         self._train_step = make_ps_train_step(
@@ -323,42 +380,6 @@ class Trainer:
         self._ckpt = ckpt.AsyncCheckpointer(
             event_sink=lambda rec: append_metrics_line(tcfg.metrics_file, rec),
             faults=self.faults,
-        )
-        # one run id ties this run's streams together (metrics JSONL run
-        # header + the per-process span trace file) — broadcast from
-        # process 0 so every host agrees on it
-        self.run_id = _shared_run_id()
-        self.tracer = NULL_TRACER
-        if tcfg.trace_dir:
-            self.tracer = Tracer(
-                "train",
-                path=os.path.join(
-                    tcfg.trace_dir,
-                    f"trace_train_p{jax.process_index()}.jsonl",
-                ),
-                run_id=self.run_id,
-                pid=jax.process_index(),
-                # host spans double as jax.profiler.TraceAnnotation
-                # scopes, so a --profile-dir capture shows the named
-                # phases on the profiler timeline too
-                annotate=True,
-                geometry=self._geometry(),
-            )
-        logger.info(
-            "model %s (%d params), dataset %s%s, %d workers",
-            tcfg.network,
-            # flat layout: the true count is static metadata (the padded
-            # buffer would over-count by the alignment tail, and
-            # materializing the tree view just to count would waste a
-            # params-sized device allocation)
-            (
-                state.params.layout.total
-                if isinstance(state.params, FlatVector)
-                else param_count(state.params)
-            ),
-            self.dataset.name,
-            " [synthetic]" if self.dataset.synthetic else "",
-            pcfg.num_workers,
         )
 
     def _geometry(self) -> dict:
@@ -803,6 +824,7 @@ class Trainer:
                 pw.start, pw.stop, first_step, t.max_steps,
             )
         tr = self.tracer
+        flush_due = False  # a log window closed; flush after next dispatch
         last_saved = None
         try:
             for epoch in range(1, t.epochs + 1):
@@ -812,11 +834,14 @@ class Trainer:
 
                 def _host_batches(eis=epochs_iters):
                     for _ in range(steps_per_epoch):
-                        parts = [next(ei) for ei in eis]
-                        yield {
-                            k: np.concatenate([p[k] for p in parts])
-                            for k in parts[0]
-                        }
+                        # the synchronous host gather, apart from h2d
+                        with tr.span("gather"):
+                            parts = [next(ei) for ei in eis]
+                            batch = {
+                                k: np.concatenate([p[k] for p in parts])
+                                for k in parts[0]
+                            }
+                        yield batch
 
                 # batches land on the mesh PRE-SHARDED (leading dim split
                 # across workers), so the step consumes them directly
@@ -829,7 +854,7 @@ class Trainer:
                 prefetched = prefetch_to_device(
                     _host_batches(), size=2,
                     device=batch_sharding(self.mesh, self.pcfg),
-                    tracer=tr,  # h2d dispatch spans, nested under fetch
+                    tracer=tr,  # gather and h2d spans nest under fetch
                 )
                 for batch_idx in range(steps_per_epoch):
                     if step_no >= t.max_steps:
@@ -837,229 +862,260 @@ class Trainer:
                         # is a no-op instead of overshooting max_steps
                         done = True
                         break
-                    pw.before_step(step_no + 1, sync=self.state.params)
-                    timer.reset()
-                    with timer.phase("fetch"), tr.span(
-                        "fetch", step=step_no + 1
-                    ):
-                        sharded = next(prefetched)
-                    with timer.phase("step"):
-                        with tr.span("dispatch", step=step_no + 1):
-                            # traced per-window controller outputs, in
-                            # the step's declared extras order: same
-                            # compiled program for every value
-                            extras = []
-                            if self._adaptive is not None:
-                                extras.append(
-                                    np.int32(self._adaptive.count)
+                    with tr.span("step", step=step_no + 1):
+                        pw.before_step(step_no + 1, sync=self.state.params)
+                        timer.reset()
+                        with timer.phase("fetch"), tr.span(
+                            "fetch", step=step_no + 1
+                        ):
+                            sharded = next(prefetched)
+                        with timer.phase("step"):
+                            with tr.span("dispatch", step=step_no + 1):
+                                # traced per-window controller outputs, in
+                                # the step's declared extras order: same
+                                # compiled program for every value
+                                extras = []
+                                if self._adaptive is not None:
+                                    extras.append(
+                                        np.int32(self._adaptive.count)
+                                    )
+                                if self._precision is not None:
+                                    extras.append(np.asarray(
+                                        self._precision.tags, np.int32
+                                    ))
+                                self.state, metrics = self._train_step(
+                                    self.state, sharded, self._key, *extras
                                 )
-                            if self._precision is not None:
-                                extras.append(np.asarray(
-                                    self._precision.tags, np.int32
-                                ))
-                            self.state, metrics = self._train_step(
-                                self.state, sharded, self._key, *extras
-                            )
+                            if flush_due:
+                                # the per-window flush, once the device
+                                # is busy again: between the window's
+                                # sync and this dispatch it stood idle,
+                                # and span I/O there would lengthen the
+                                # gap a traced run is there to measure
+                                with tr.span("trace_flush"):
+                                    tr.flush()
+                                flush_due = False
+                            if self.faults is not None:
+                                # injected host stall, inside the timed phase
+                                # so the watchdog sees it as a real slow step
+                                self.faults.maybe_sleep(step_no + 1)
+                            if t.straggler_threshold_s is not None:
+                                # the watchdog times real step walltime, not
+                                # dispatch — an intentional per-step barrier,
+                                # only when the watchdog is armed (the span
+                                # observes the EXISTING barrier; tracing off
+                                # or on, the sync set is identical)
+                                with tr.span("sync", step=step_no + 1):
+                                    jax.block_until_ready(metrics)
+                        step_no += 1
                         if self.faults is not None:
-                            # injected host stall, inside the timed phase
-                            # so the watchdog sees it as a real slow step
-                            self.faults.maybe_sleep(step_no + 1)
-                        if t.straggler_threshold_s is not None:
-                            # the watchdog times real step walltime, not
-                            # dispatch — an intentional per-step barrier,
-                            # only when the watchdog is armed (the span
-                            # observes the EXISTING barrier; tracing off
-                            # or on, the sync set is identical)
-                            with tr.span("sync", step=step_no + 1):
-                                jax.block_until_ready(metrics)
-                    step_no += 1
-                    if self.faults is not None:
-                        # injected preemption: SIGTERM ourselves at the
-                        # planned step boundary; the installed handler
-                        # raises the stop flag and _stop_consensus below
-                        # turns it into a graceful checkpointed stop
-                        self.faults.maybe_sigterm(step_no)
-                    window_steps += 1
-                    if self._adaptive is not None and step_no != first_step:
-                        # the controller eats the same walltime the
-                        # watchdog reads (real: its barrier is armed);
-                        # the compile step is exempt like the watchdog's
-                        self._adaptive.record(step_no, timer.total)
-                    if self._precision is not None:
-                        # pop BEFORE any window fetch/float-sweep sees
-                        # it: bucket_sqnorm is a vector row among scalar
-                        # metrics. The fetch is an intentional per-step
-                        # sync, armed only with precision_adapt — the
-                        # controller's telemetry, same opt-in cost shape
-                        # as the watchdog's barrier (a few dozen floats).
-                        self._precision.record(
-                            step_no,
-                            jax.device_get(  # psl: sync-ok
-                                metrics.pop("bucket_sqnorm")
-                            ),
-                        )
-                    # counts even with the watchdog's per-step barrier:
-                    # block_until_ready syncs but never FETCHES, and the
-                    # guard's host half (skip events + the abort) needs
-                    # values — the backpressure block below is what keeps
-                    # it live when log windows don't fetch
-                    unsynced += 1
-                    if (
-                        t.straggler_threshold_s is not None
-                        and timer.total > t.straggler_threshold_s
-                        and step_no != first_step  # compilation step exempt
-                    ):
-                        # watchdog ACTION (not just a log line): count the
-                        # event and emit a machine-readable record, so
-                        # --mode's semantics are observable — dashboards /
-                        # the analysis layer aggregate straggler_steps the
-                        # way the reference's notebooks scraped worker
-                        # time-cost distributions. (Killing is meaningless
-                        # under SPMD: there is no per-worker process to
-                        # kill; slow steps indicate input stalls or host
-                        # interference instead.)
-                        self.straggler_steps += 1
-                        self._straggler_streak += 1
-                        if self._straggler_streak < t.straggler_storm_n:
+                            # injected preemption: SIGTERM ourselves at the
+                            # planned step boundary; the installed handler
+                            # raises the stop flag and _stop_consensus below
+                            # turns it into a graceful checkpointed stop
+                            self.faults.maybe_sigterm(step_no)
+                        window_steps += 1
+                        if self._adaptive is not None and step_no != first_step:
+                            # the controller eats the same walltime the
+                            # watchdog reads (real: its barrier is armed);
+                            # the compile step is exempt like the watchdog's
+                            self._adaptive.record(step_no, timer.total)
+                        if self._precision is not None:
+                            # pop BEFORE any window fetch/float-sweep sees
+                            # it: bucket_sqnorm is a vector row among scalar
+                            # metrics. The fetch is an intentional per-step
+                            # sync, armed only with precision_adapt — the
+                            # controller's telemetry, same opt-in cost shape
+                            # as the watchdog's barrier (a few dozen floats).
+                            self._precision.record(
+                                step_no,
+                                jax.device_get(  # psl: sync-ok
+                                    metrics.pop("bucket_sqnorm")
+                                ),
+                            )
+                        # counts even with the watchdog's per-step barrier:
+                        # block_until_ready syncs but never FETCHES, and the
+                        # guard's host half (skip events + the abort) needs
+                        # values — the backpressure block below is what keeps
+                        # it live when log windows don't fetch
+                        unsynced += 1
+                        if (
+                            t.straggler_threshold_s is not None
+                            and timer.total > t.straggler_threshold_s
+                            and step_no != first_step  # compilation step exempt
+                        ):
+                            # watchdog ACTION (not just a log line): count the
+                            # event and emit a machine-readable record, so
+                            # --mode's semantics are observable — dashboards /
+                            # the analysis layer aggregate straggler_steps the
+                            # way the reference's notebooks scraped worker
+                            # time-cost distributions. (Killing is meaningless
+                            # under SPMD: there is no per-worker process to
+                            # kill; slow steps indicate input stalls or host
+                            # interference instead.)
+                            self.straggler_steps += 1
+                            self._straggler_streak += 1
+                            if self._straggler_streak < t.straggler_storm_n:
+                                logger.warning(
+                                    "straggler step: Step: %d took %.4fs (threshold %.4fs)",
+                                    step_no,
+                                    timer.total,
+                                    t.straggler_threshold_s,
+                                )
+                                append_metrics_line(
+                                    t.metrics_file,
+                                    {
+                                        "kind": "straggler",
+                                        "step": step_no,
+                                        "time_cost": round(timer.total, 6),
+                                        "threshold": t.straggler_threshold_s,
+                                    },
+                                )
+                            elif self._straggler_streak == t.straggler_storm_n:
+                                # escalation: N consecutive slow steps is one
+                                # CONDITION, not N incidents — emit a single
+                                # storm event and go quiet until it breaks
+                                # (straggler_steps keeps counting throughout)
+                                self.straggler_storms += 1
+                                logger.warning(
+                                    "straggler storm: %d consecutive slow steps "
+                                    "(through step %d, threshold %.4fs) — "
+                                    "suppressing per-step warnings until it "
+                                    "clears",
+                                    self._straggler_streak,
+                                    step_no,
+                                    t.straggler_threshold_s,
+                                )
+                                append_metrics_line(
+                                    t.metrics_file,
+                                    {
+                                        "kind": "straggler_storm",
+                                        "step": step_no,
+                                        "start_step": (
+                                            step_no - t.straggler_storm_n + 1
+                                        ),
+                                        "consecutive": self._straggler_streak,
+                                        "threshold": t.straggler_threshold_s,
+                                    },
+                                )
+                        elif t.straggler_threshold_s is not None:
+                            # a fast step breaks the streak: if a storm was
+                            # open, close its window (last slow step was the
+                            # previous one)
+                            self._maybe_end_storm(step_no - 1)
+                            self._straggler_streak = 0
+                        if t.log_interval > 0 and (
+                            step_no % t.log_interval == 0 or step_no == 1
+                        ):
+                            # the once-per-window transfer: draining here makes
+                            # the window walltime below include every in-flight
+                            # step, so the per-step average stays honest.
+                            # (time_cost is the authoritative per-step number;
+                            # the Fetch/Forward fields remain raw host phase
+                            # durations — with the watchdog disarmed, Forward
+                            # is dispatch time, not compute.)
+                            # One block boundary: from the end of `sync`
+                            # (the device has drained and now waits) to
+                            # the end of the next step's dispatch, the
+                            # host holds the chip — every piece below is
+                            # its own span so that wait has names.
+                            with tr.span(
+                                "window_close", step=step_no,
+                                block=window_steps,
+                            ):
+                                # the wait and the read apart: `sync` ends
+                                # when the window's last step has finished
+                                # (the device goes idle), `metrics_fetch` is
+                                # the values' way to the host after that
+                                # (1.2 ms for five scalars on the v5e), which
+                                # one `device_get` span hid inside the wait
+                                with tr.span("sync", step=step_no):
+                                    jax.block_until_ready(metrics)
+                                with tr.span("metrics_fetch"):
+                                    metrics = jax.device_get(metrics)  # psl: sync-ok
+                                unsynced = 0
+                                step_time = (
+                                    time.perf_counter() - window_t0
+                                ) / max(window_steps, 1)
+                                window_t0, window_steps = time.perf_counter(), 0
+                                with tr.span("log"):
+                                    logger.info(
+                                        format_iter_line(
+                                            rank="mesh",
+                                            step=step_no,
+                                            epoch=epoch,
+                                            seen=batch_idx * global_batch,
+                                            total=total * self.pcfg.num_workers,
+                                            loss=float(metrics["loss"]),
+                                            time_cost=step_time,
+                                            fetch=timer.durations.get("fetch", 0.0),
+                                            forward=timer.durations.get("step", 0.0),
+                                        )
+                                    )
+                                with tr.span("metrics_write"):
+                                    append_metrics_line(
+                                        t.metrics_file,
+                                        {
+                                            "kind": "train",
+                                            "step": step_no,
+                                            "epoch": epoch,
+                                            "time_cost": round(step_time, 6),
+                                            **{k: float(v) for k, v in metrics.items()},
+                                        },
+                                    )
+                                # guard host half piggybacks on the window
+                                # fetch: skip events + the consecutive-skip
+                                # abort. Runs AFTER the window's train record
+                                # lands (unlike the backpressure block below)
+                                # so an aborting window is still in the JSONL
+                                with tr.span("guard", step=step_no):
+                                    self._guard_check(metrics, step_no)
+                            # span I/O waits for the next dispatch (above):
+                            # here the device is idle
+                            flush_due = True
+                        if unsynced >= max_unsynced:
+                            # backpressure barrier + periodic fetch (reached
+                            # when no log window fetched recently, e.g.
+                            # log_interval=0 or very large): bounds dispatch
+                            # run-ahead and keeps the guard abort live when
+                            # logging is off — with the watchdog armed the
+                            # buffers are already ready, so this is fetch-only
+                            with tr.span("sync", step=step_no):
+                                metrics = jax.device_get(metrics)  # psl: sync-ok
+                            with tr.span("guard", step=step_no):
+                                self._guard_check(metrics, step_no)
+                            unsynced = 0
+                        if (
+                            t.save_checkpoints
+                            # 0 = no periodic saves (the final checkpoint after
+                            # the loop still writes; use save_checkpoints=False
+                            # to suppress every write)
+                            and t.eval_freq > 0
+                            and step_no % t.eval_freq == 0
+                        ):
+                            # the span covers the host half (state gather +
+                            # submit); the write itself is async
+                            with tr.span("ckpt_save", step=step_no):
+                                self._record_geometry(step_no)
+                                self._ckpt.save(
+                                    self.state,
+                                    t.train_dir,
+                                    step_no,
+                                    compress=t.compress_checkpoints,
+                                )
+                            last_saved = step_no
+                        if step_no >= t.max_steps:
+                            done = True
+                            break
+                        with tr.span("stop_check"):
+                            stop = self._stop_consensus()
+                        if stop:
                             logger.warning(
-                                "straggler step: Step: %d took %.4fs (threshold %.4fs)",
+                                "graceful stop at step %d (resume with --resume)",
                                 step_no,
-                                timer.total,
-                                t.straggler_threshold_s,
                             )
-                            append_metrics_line(
-                                t.metrics_file,
-                                {
-                                    "kind": "straggler",
-                                    "step": step_no,
-                                    "time_cost": round(timer.total, 6),
-                                    "threshold": t.straggler_threshold_s,
-                                },
-                            )
-                        elif self._straggler_streak == t.straggler_storm_n:
-                            # escalation: N consecutive slow steps is one
-                            # CONDITION, not N incidents — emit a single
-                            # storm event and go quiet until it breaks
-                            # (straggler_steps keeps counting throughout)
-                            self.straggler_storms += 1
-                            logger.warning(
-                                "straggler storm: %d consecutive slow steps "
-                                "(through step %d, threshold %.4fs) — "
-                                "suppressing per-step warnings until it "
-                                "clears",
-                                self._straggler_streak,
-                                step_no,
-                                t.straggler_threshold_s,
-                            )
-                            append_metrics_line(
-                                t.metrics_file,
-                                {
-                                    "kind": "straggler_storm",
-                                    "step": step_no,
-                                    "start_step": (
-                                        step_no - t.straggler_storm_n + 1
-                                    ),
-                                    "consecutive": self._straggler_streak,
-                                    "threshold": t.straggler_threshold_s,
-                                },
-                            )
-                    elif t.straggler_threshold_s is not None:
-                        # a fast step breaks the streak: if a storm was
-                        # open, close its window (last slow step was the
-                        # previous one)
-                        self._maybe_end_storm(step_no - 1)
-                        self._straggler_streak = 0
-                    if t.log_interval > 0 and (
-                        step_no % t.log_interval == 0 or step_no == 1
-                    ):
-                        # the once-per-window transfer: draining here makes
-                        # the window walltime below include every in-flight
-                        # step, so the per-step average stays honest.
-                        # (time_cost is the authoritative per-step number;
-                        # the Fetch/Forward fields remain raw host phase
-                        # durations — with the watchdog disarmed, Forward
-                        # is dispatch time, not compute.)
-                        with tr.span("sync", step=step_no):
-                            metrics = jax.device_get(metrics)  # psl: sync-ok
-                        unsynced = 0
-                        step_time = (
-                            time.perf_counter() - window_t0
-                        ) / max(window_steps, 1)
-                        window_t0, window_steps = time.perf_counter(), 0
-                        logger.info(
-                            format_iter_line(
-                                rank="mesh",
-                                step=step_no,
-                                epoch=epoch,
-                                seen=batch_idx * global_batch,
-                                total=total * self.pcfg.num_workers,
-                                loss=float(metrics["loss"]),
-                                time_cost=step_time,
-                                fetch=timer.durations.get("fetch", 0.0),
-                                forward=timer.durations.get("step", 0.0),
-                            )
-                        )
-                        append_metrics_line(
-                            t.metrics_file,
-                            {
-                                "kind": "train",
-                                "step": step_no,
-                                "epoch": epoch,
-                                "time_cost": round(step_time, 6),
-                                **{k: float(v) for k, v in metrics.items()},
-                            },
-                        )
-                        # guard host half piggybacks on the window fetch:
-                        # skip events + the consecutive-skip abort. Runs
-                        # AFTER the window's train record lands (unlike
-                        # the backpressure block below) so an aborting
-                        # window is still in the JSONL
-                        with tr.span("guard", step=step_no):
-                            self._guard_check(metrics, step_no)
-                        # the per-window flush: span I/O lands where the
-                        # host already stalled on the device fetch above
-                        tr.flush()
-                    if unsynced >= max_unsynced:
-                        # backpressure barrier + periodic fetch (reached
-                        # when no log window fetched recently, e.g.
-                        # log_interval=0 or very large): bounds dispatch
-                        # run-ahead and keeps the guard abort live when
-                        # logging is off — with the watchdog armed the
-                        # buffers are already ready, so this is fetch-only
-                        with tr.span("sync", step=step_no):
-                            metrics = jax.device_get(metrics)  # psl: sync-ok
-                        with tr.span("guard", step=step_no):
-                            self._guard_check(metrics, step_no)
-                        unsynced = 0
-                    if (
-                        t.save_checkpoints
-                        # 0 = no periodic saves (the final checkpoint after
-                        # the loop still writes; use save_checkpoints=False
-                        # to suppress every write)
-                        and t.eval_freq > 0
-                        and step_no % t.eval_freq == 0
-                    ):
-                        # the span covers the host half (state gather +
-                        # submit); the write itself is async
-                        with tr.span("ckpt_save", step=step_no):
-                            self._record_geometry(step_no)
-                            self._ckpt.save(
-                                self.state,
-                                t.train_dir,
-                                step_no,
-                                compress=t.compress_checkpoints,
-                            )
-                        last_saved = step_no
-                    if step_no >= t.max_steps:
-                        done = True
-                        break
-                    if self._stop_consensus():
-                        logger.warning(
-                            "graceful stop at step %d (resume with --resume)",
-                            step_no,
-                        )
-                        done = True
-                        break
+                            done = True
+                            break
             if t.save_checkpoints and metrics and last_saved != step_no:
                 with tr.span("ckpt_save", step=step_no):
                     self._record_geometry(step_no)

@@ -138,8 +138,10 @@ def test_tracer_flush_writes_header_then_spans(tmp_path):
     assert lines[0]["schema_version"] == SCHEMA_VERSION
     assert lines[0]["pid"] == 3
     assert lines[0]["geometry"] == {"n": 1}
-    # the header is written ONCE; spans append across flushes
-    assert [ln["name"] for ln in lines[1:]] == ["a", "b"]
+    # the header is written ONCE; spans append across flushes, each
+    # flush closed by its clock_sync (the construction-time one first)
+    assert [ln["name"] for ln in lines[1:]] == [
+        "clock_sync", "a", "clock_sync", "b", "clock_sync"]
     assert all(ln["kind"] == "span" for ln in lines[1:])
 
 
@@ -302,6 +304,8 @@ def test_instrumented_paths_stay_psl004_clean():
     block_until_ready flags) lint clean after pragmas."""
     paths = [
         os.path.join(REPO, "ps_pytorch_tpu", "trainer.py"),
+        os.path.join(REPO, "ps_pytorch_tpu", "data", "loader.py"),
+        os.path.join(REPO, "ps_pytorch_tpu", "cli", "train_lm.py"),
         os.path.join(REPO, "ps_pytorch_tpu", "serve", "engine.py"),
         os.path.join(REPO, "ps_pytorch_tpu", "obs"),
     ]
@@ -408,6 +412,277 @@ def test_traced_training_run_emits_phases_and_headers(tmp_path, monkeypatch):
     trains = [e for e in events if e["kind"] == "train"]
     assert trains and all(isinstance(e["skipped_steps"], int) for e in trains)
     assert all("t_wall" in e for e in events)
+
+
+# ------------------------------------------- the loop's spans, by structure
+
+def _read_stream(path):
+    lines = [json.loads(line) for line in open(path)]
+    return lines[0], lines[1:]
+
+
+def _end(s):
+    return s["t"] + s["dur"]
+
+
+@pytest.fixture(scope="module")
+def traced_loop(tmp_path_factory):
+    """A LeNet run of a dozen steps, log_interval 4, traced; every flush
+    of the span file is timed on the tracer's own clock."""
+    tmp = tmp_path_factory.mktemp("loop")
+    ds = make_synthetic("MNIST", train_size=256, test_size=32, seed=1)
+    tcfg = TrainConfig(
+        network="LeNet", dataset="MNIST", batch_size=8, test_batch_size=32,
+        epochs=4, max_steps=12, eval_freq=0, log_interval=4,
+        save_checkpoints=False, train_dir=str(tmp / "models"),
+        metrics_file=str(tmp / "m.jsonl"), trace_dir=str(tmp / "trace"),
+    )
+    trainer = Trainer(tcfg, PSConfig(num_workers=N), dataset=ds)
+    tracer, flushes = trainer.tracer, []
+    real_flush = tracer.flush
+
+    def timed_flush():
+        t0 = tracer.now()
+        n = real_flush()
+        flushes.append((t0, tracer.now(), n))
+        return n
+
+    tracer.flush = timed_flush
+    trainer.train()
+    header, spans = _read_stream(tmp / "trace" / "trace_train_p0.jsonl")
+    return {"header": header, "spans": spans, "flushes": flushes}
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def test_every_span_of_an_iteration_nests_under_its_step(traced_loop):
+    spans = traced_loop["spans"]
+    steps = {s["step"]: s for s in _named(spans, "step")}
+    assert sorted(steps) == list(range(1, 13))
+    assert all(s["depth"] == 0 and "parent" not in s for s in steps.values())
+    in_loop = [s for s in spans if not s.get("async")
+               and not s["name"].startswith("build") and s["name"] != "step"]
+    assert {s["name"] for s in in_loop} == {
+        "fetch", "gather", "h2d", "dispatch", "window_close", "sync",
+        "metrics_fetch", "log", "metrics_write", "guard", "stop_check",
+        "trace_flush"}
+    for s in in_loop:
+        # it carries its iteration's number and lies inside that `step`
+        parent = steps[s["step"]]
+        assert parent["t"] <= s["t"] + 1e-6, s
+        assert _end(s) <= _end(parent) + 5e-6, s
+        assert s["depth"] >= 1 and "parent" in s
+    for name in ("fetch", "dispatch"):
+        assert [s["step"] for s in _named(spans, name)] == list(range(1, 13))
+        assert all(s["parent"] == "step" for s in _named(spans, name))
+    # the run ends at max_steps, before the last iteration's stop check
+    assert [s["step"] for s in _named(spans, "stop_check")] == list(range(1, 12))
+
+
+def test_each_log_step_has_one_window_close_holding_its_parts(traced_loop):
+    spans = traced_loop["spans"]
+    closes = _named(spans, "window_close")
+    assert [(w["step"], w["block"]) for w in closes] == [(1, 1), (4, 3), (8, 4), (12, 4)]
+    for w in closes:
+        parts = [s for s in spans if s.get("parent") == "window_close"
+                 and s["step"] == w["step"]]
+        assert [p["name"] for p in sorted(parts, key=lambda p: p["t"])] == [
+            "sync", "metrics_fetch", "log", "metrics_write", "guard"]
+        assert all(w["t"] <= p["t"] + 1e-6 and _end(p) <= _end(w) + 5e-6 for p in parts)
+        assert sum(p["dur"] for p in parts) <= w["dur"] + 5e-6
+        assert all(isinstance(w[k], int) for k in ("step", "block"))
+
+
+def test_gather_and_h2d_lie_inside_fetch(traced_loop):
+    spans = traced_loop["spans"]
+    fetches = {s["step"]: s for s in _named(spans, "fetch")}
+    inner = _named(spans, "gather") + _named(spans, "h2d")
+    # one gather and one upload per fetch, two in the first: the prefetch
+    # queue stays one batch ahead, so twelve steps gather thirteen
+    assert len(inner) == 2 * 13
+    for s in inner:
+        f = fetches[s["step"]]
+        assert s["parent"] == "fetch" and s["depth"] == f["depth"] + 1
+        assert f["t"] <= s["t"] + 1e-6 and _end(s) <= _end(f) + 5e-6
+    # 8 workers x 8 rows of 28x28x1 uint8 (or float32) and their labels
+    uploads = {s["bytes"] for s in _named(spans, "h2d")}
+    assert len(uploads) == 1 and uploads.pop() >= 64 * 28 * 28
+
+
+def test_build_and_its_four_parts_precede_step_one(traced_loop):
+    spans = traced_loop["spans"]
+    (build,) = _named(spans, "build")
+    parts = [s for s in spans if s.get("parent") == "build"]
+    assert [p["name"] for p in sorted(parts, key=lambda p: p["t"])] == [
+        "build.data", "build.model", "build.state", "build.step"]
+    assert all(build["t"] <= p["t"] + 1e-6 and _end(p) <= _end(build) + 5e-6
+               for p in parts)
+    assert sum(p["dur"] for p in parts) <= build["dur"] + 5e-6
+    first = min(_named(spans, "step"), key=lambda s: s["t"])
+    assert first["step"] == 1 and _end(build) <= first["t"]
+    # the header was created before anything was built and still names
+    # the geometry
+    assert traced_loop["header"]["geometry"]["network"] == "LeNet"
+    assert build["t"] < 0.05
+
+
+def test_clock_sync_once_per_flush_on_the_headers_wall_clock(traced_loop):
+    header, spans = traced_loop["header"], traced_loop["spans"]
+    syncs = _named(spans, "clock_sync")
+    wrote = [f for f in traced_loop["flushes"] if f[2] > 0]
+    # one taken when the tracer was made, one closing every flush that wrote
+    assert len(syncs) == len(wrote) + 1
+    assert syncs[0]["t"] < 0.05 and spans[0] is syncs[0]
+    for sync, (t0, t1, _) in zip(syncs[1:], wrote):
+        assert t0 <= sync["t"] <= t1
+    for s in syncs:
+        assert isinstance(s["wall_ns"], int) and isinstance(s["err_ns"], int)
+        assert 0 < s["err_ns"] < 1_000_000
+        assert abs(s["wall_ns"] * 1e-9 - (header["t_wall"] + s["t"])) < 1e-3
+        assert s["async"] and s["dur"] == 0
+
+
+def test_no_flush_between_a_syncs_end_and_the_next_dispatchs_end(traced_loop):
+    """After `sync` returns the device is idle until the next dispatch has
+    been enqueued: span I/O there would lengthen the gap a traced run is
+    there to measure."""
+    spans, flushes = traced_loop["spans"], traced_loop["flushes"]
+    dispatch_end = {s["step"]: _end(s) for s in _named(spans, "dispatch")}
+    gaps = [(_end(s), dispatch_end[s["step"] + 1])
+            for s in _named(spans, "sync") if s["step"] + 1 in dispatch_end]
+    assert len(gaps) == 3  # after steps 1, 4 and 8
+    for t0, t1, _ in flushes:
+        assert not any(a - 1e-6 < t1 and t0 < b + 1e-6 for a, b in gaps), (t0, t1, gaps)
+    # one flush per window, right after the dispatch that follows it, and
+    # the trailing one in `finally`
+    assert len([f for f in flushes if f[2] > 0]) == 4
+    flushed_in = [s["step"] for s in _named(spans, "trace_flush")]
+    assert flushed_in == [2, 5, 9]
+    for s in _named(spans, "trace_flush"):
+        assert dispatch_end[s["step"]] <= s["t"] + 1e-6
+
+
+def test_trace_dir_none_records_nothing(tmp_path, monkeypatch):
+    """Tracing off: the loop runs the same call sites against the shared
+    no-op, no Tracer is ever made and no file appears."""
+    made = []
+    monkeypatch.setattr(
+        "ps_pytorch_tpu.trainer.Tracer",
+        lambda *a, **kw: made.append(a) or pytest.fail("a Tracer was made"),
+    )
+    ds = make_synthetic("MNIST", train_size=128, test_size=32, seed=1)
+    tcfg = TrainConfig(
+        network="LeNet", dataset="MNIST", batch_size=8, max_steps=5,
+        epochs=2, eval_freq=0, log_interval=2, save_checkpoints=False,
+        train_dir=str(tmp_path / "models"),
+    )
+    trainer = Trainer(tcfg, PSConfig(num_workers=N), dataset=ds)
+    assert trainer.tracer is NULL_TRACER
+    out = trainer.train()
+    assert np.isfinite(out["loss"]) and not made
+    assert NULL_TRACER.drain() == [] and NULL_TRACER.flush() == 0
+    assert not list(tmp_path.rglob("trace_*.jsonl"))
+    # (the PSL004 sweep over the loop, the loader and obs/ is
+    # test_instrumented_paths_stay_psl004_clean)
+
+
+def test_child_spans_inherit_step_and_name_their_parent():
+    t = Tracer("t")
+    with t.span("step", step=7):
+        with t.span("fetch"):
+            with t.span("gather"):
+                pass
+        with t.span("dispatch", step=8):  # an explicit step wins
+            pass
+    with t.span("ckpt_save"):
+        pass
+    by = {s["name"]: s for s in t.drain()}
+    assert by["fetch"]["step"] == 7 and by["fetch"]["parent"] == "step"
+    assert by["gather"]["step"] == 7 and by["gather"]["parent"] == "fetch"
+    assert by["dispatch"]["step"] == 8
+    assert "parent" not in by["step"] and "step" not in by["ckpt_save"]
+    assert [by[n]["depth"] for n in ("step", "fetch", "gather")] == [0, 1, 2]
+
+
+def test_chrome_trace_places_spans_by_the_newest_clock_sync():
+    """A long run's monotonic clock drifts off the wall clock; the merged
+    timeline follows the stream's clock_sync records, not the header."""
+    header = run_header("train")
+    spans = [
+        {"kind": "span", "name": "clock_sync", "t": 0.0, "dur": 0.0,
+         "async": True, "wall_ns": int(header["t_wall"] * 1e9), "err_ns": 300},
+        {"kind": "span", "name": "a", "t": 1.0, "dur": 0.1},
+        {"kind": "span", "name": "clock_sync", "t": 100.0, "dur": 0.0,
+         "async": True, "wall_ns": int((header["t_wall"] + 100.25) * 1e9),
+         "err_ns": 300},
+        {"kind": "span", "name": "b", "t": 101.0, "dur": 0.1},
+    ]
+    evs = {e["name"]: e for e in chrome_trace_events(
+        header, spans, t0_wall=header["t_wall"]) if e.get("ph") == "X"}
+    assert evs["a"]["ts"] == pytest.approx(1.0e6, abs=50)
+    assert evs["b"]["ts"] == pytest.approx(101.25e6, abs=50)
+
+
+def test_trace_report_splits_loop_time_by_the_phases_under_step(tmp_path):
+    t = Tracer("train", path=str(tmp_path / "trace_train_p0.jsonl"))
+    with t.span("build"):
+        time.sleep(0.002)
+    for n in (1, 2):
+        with t.span("step", step=n):
+            with t.span("fetch"):
+                with t.span("gather"):
+                    time.sleep(0.001)
+            with t.span("dispatch"):
+                time.sleep(0.001)
+    t.flush()
+    _, summary = trace_report.merge([str(tmp_path / "trace_train_p0.jsonl")], [])
+    frac = summary["fraction_of_loop_walltime"]["train"]
+    assert set(frac) == {"fetch", "dispatch", "step.self"}  # no build, no gather
+    assert sum(frac.values()) == pytest.approx(1.0, abs=1e-3)
+    assert frac["fetch"] > 0.3 and frac["dispatch"] > 0.3
+    assert summary["nesting_ok"] and summary["phases"]["clock_sync"]["count"] == 2
+
+
+def test_profile_window_captures_with_the_benchmarks_options(tmp_path, monkeypatch):
+    """An operator's --profile-dir must not distort the loop it watches:
+    no Python call tracing, host tracer at level 1 (PERF.md section 3)."""
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: seen.update(dir=d, opts=profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: seen.update(stopped=True))
+    pw = ProfileWindow(str(tmp_path / "prof"), start_step=1, num_steps=1)
+    pw.before_step(1)
+    pw.close()
+    assert seen["opts"].python_tracer_level == 0
+    assert seen["opts"].host_tracer_level == 1 and seen["stopped"]
+
+
+def test_train_lm_trace_records_the_trainers_names(tmp_path):
+    from ps_pytorch_tpu.cli import train_lm
+
+    train_lm.main([
+        "--dim", "32", "--depth", "1", "--heads", "2", "--seq-len", "32",
+        "--vocab-size", "64", "--batch-size", "8", "--max-steps", "6",
+        "--log-interval", "3", "--trace", str(tmp_path),
+        "--metrics-file", str(tmp_path / "m.jsonl"),
+    ])
+    header, spans = _read_stream(tmp_path / "trace_train_lm_p0.jsonl")
+    assert header["component"] == "train_lm"
+    events = [json.loads(line) for line in open(tmp_path / "m.jsonl")]
+    assert events[0]["run_id"] == header["run_id"]
+    steps = {s["step"]: s for s in _named(spans, "step")}
+    assert sorted(steps) == [1, 2, 3, 4, 5, 6]
+    for name, at in (("fetch", [1, 2, 3, 4, 5, 6]), ("dispatch", [1, 2, 3, 4, 5, 6]),
+                     ("log", [1, 3, 6]), ("metrics_write", [1, 3, 6]),
+                     ("sync", [1, 1, 3, 3, 6, 6])):
+        got = _named(spans, name)
+        assert [s["step"] for s in got] == at, name
+        assert all(s["parent"] == "step" for s in got)
+        assert all(steps[s["step"]]["t"] <= s["t"] + 1e-6
+                   and _end(s) <= _end(steps[s["step"]]) + 5e-6 for s in got)
 
 
 def test_tracer_off_is_null(tmp_path):
@@ -651,8 +926,8 @@ def test_trace_report_segments_appended_reruns(tmp_path):
     trace, _ = trace_report.merge([str(p)], [])
     spans = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
     # run 2 merged at its own (later) wall time, not run 1's start
-    s1 = next(e for e in spans if e["args"]["step"] == 1)
-    s2 = next(e for e in spans if e["args"]["step"] == 2)
+    s1 = next(e for e in spans if e["args"].get("step") == 1)
+    s2 = next(e for e in spans if e["args"].get("step") == 2)
     want = (t2.header["t_wall"] - t1.header["t_wall"]) * 1e6
     assert s2["ts"] - s1["ts"] == pytest.approx(want, abs=1e4)
 

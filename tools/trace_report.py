@@ -8,17 +8,21 @@ monotonic clock. This tool:
 
 - merges any number of streams (train + serve, multiple hosts) into ONE
   perfetto-loadable Chrome trace (``--out``). Multihost merge rule: a
-  span's absolute time is ``header.t_wall + span.t`` — monotonic
-  offsets keep durations drift-free, the per-process wall base places
-  the streams on a shared timeline (hosts are NTP-aligned to well under
+  span's absolute time is its stream's newest ``clock_sync`` wall time
+  at or before it plus the monotonic offset since (``header.t_wall +
+  span.t`` in a stream without one) — monotonic offsets keep durations
+  drift-free, the per-process wall base places the streams on a shared
+  timeline (hosts are NTP-aligned to well under
   a log window, and each process keeps its own ``pid`` lane so skew
   never interleaves within a track);
 - overlays metrics-JSONL events (``--metrics``: grad_skip, straggler
   storms, mask_adapt, resume_reshape, checkpoint quarantine/failure) as
   instant markers via their ``t_wall`` stamps;
 - prints a summary: per-phase count and p50/p99/total duration,
-  per-component fraction of loop walltime by top-level phase (where
-  does a step's time go: dispatch vs sync vs fetch), and a nesting
+  per-component fraction of loop walltime by phase (where does a
+  step's time go: the spans opened directly under the trainers' `step`
+  span — fetch vs dispatch vs window_close — or the serve tick's
+  depth-0 spans), and a nesting
   check (child spans must sit inside their parents — a violation means
   a tracer bug, not a workload property);
 - ``--require-phases a,b,c`` exits nonzero unless every named phase is
@@ -201,12 +205,25 @@ def merge(
     # AGGREGATED over every stream of the component: a multihost merge
     # has one stream per process and a straggler host's dispatch/sync
     # split must weigh in, not be overwritten by the last-listed file.
+    # A stream whose loop iterations are `step` spans (the trainers)
+    # splits THEIR time by the phases opened directly under them, the
+    # rest as `step.self`; set-up (`build`) is not loop time. A stream
+    # without them (the serve tick) splits by its depth-0 spans.
     totals: Dict[str, Dict[str, float]] = {}
     for _, header, spans in streams:
         by = totals.setdefault(header.get("component", "?"), {})
-        for s in spans:
-            if s.get("depth", 0) == 0 and not s.get("async"):
-                by[s["name"]] = by.get(s["name"], 0.0) + float(s["dur"])
+        stacked = [s for s in spans if not s.get("async")]
+        steps = [s for s in stacked
+                 if s["name"] == "step" and s.get("depth", 0) == 0]
+        if steps:
+            phases_of = [s for s in stacked if s.get("parent") == "step"]
+            by["step.self"] = by.get("step.self", 0.0) + max(
+                sum(float(s["dur"]) for s in steps)
+                - sum(float(s["dur"]) for s in phases_of), 0.0)
+        else:
+            phases_of = [s for s in stacked if s.get("depth", 0) == 0]
+        for s in phases_of:
+            by[s["name"]] = by.get(s["name"], 0.0) + float(s["dur"])
     fractions: Dict[str, Dict[str, float]] = {}
     for comp, by in totals.items():
         total = sum(by.values())
