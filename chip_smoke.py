@@ -287,7 +287,10 @@ def leg_kernels(devices):
     )
     for dtype, bound in ((jnp.float32, F32_DEFAULT_PRECISION_BOUND),
                          (jnp.bfloat16, BF16_BOUND)):
-        for t_len in (t, t - 24):  # the second is the pad-and-mask path
+        # plan_flash's tiles: T = 1024 x D = 64 is the LM cell's shape
+        # (512-wide, the tile above the diagonal skipped); the second T
+        # is the pad-and-mask path
+        for t_len in (t, t - 24):
             compare(f"flash {jnp.dtype(dtype).name} T={t_len}", flash_loss,
                     *qkv(b, t_len, h, d, dtype=dtype), bound)
 
@@ -300,8 +303,13 @@ def leg_kernels(devices):
         o = ring(q, k, v)
         return jnp.sum(o.astype(jnp.float32) ** 2), o
 
-    compare(f"ring-flash over {len(devices)} device(s)", ring_loss,
-            *qkv(2, t, 4, d, dtype=jnp.float32), F32_DEFAULT_PRECISION_BOUND)
+    # float32, then bfloat16: the LM cell's own call (dp_sp hands the
+    # kernels a ring even of one, so it runs the partials with float32
+    # results and offsets read at run time)
+    for dtype, bound in ((jnp.float32, F32_DEFAULT_PRECISION_BOUND),
+                         (jnp.bfloat16, BF16_BOUND)):
+        compare(f"ring-flash {jnp.dtype(dtype).name} over {len(devices)} "
+                f"device(s)", ring_loss, *qkv(2, t, 4, d, dtype=dtype), bound)
 
     # quantizers on one 4 MiB bucket: the kernel's integers against the
     # jnp twin's, and the round trip against the scale

@@ -424,6 +424,25 @@ def main(argv=None) -> dict:
             geometry=geometry,
         )
 
+    if cfg.attention_impl == "flash":
+        # the kernels' tile plan is static: how often the skip engages is
+        # known here, from the shapes every attention call will have
+        from ..ops.flash_attention import plan_flash
+
+        ring = (args.parallelism in ("dp_sp", "ep_sp")
+                and cfg.sp_attention == "ring")
+        t_att = args.seq_len // num_sp if ring else args.seq_len
+        plan = plan_flash(t_att, t_att, cfg.head_dim,
+                          cfg.effective_compute_dtype, cfg.causal)
+        flash_plan = {f: getattr(plan, f) for f in (
+            "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total")}
+        logger.info(
+            "flash plan for T %d x D %d: %s (per head%s)", t_att,
+            cfg.head_dim, flash_plan,
+            "; ring hops decide from their offsets" if ring else "",
+        )
+        tr.instant("flash_plan", **flash_plan)
+
     def save_lm_checkpoint(step_no):
         if args.train_dir is None:
             return

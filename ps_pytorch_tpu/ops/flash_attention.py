@@ -8,35 +8,53 @@ softmax in VMEM, O(T) memory instead of O(T^2) HBM traffic, MXU-shaped
 
 Layout: inputs [B, T, H, D] are folded to [B*H, T, D]; the grid walks
 (batch*head, q_block, k_block) with the k axis innermost, accumulating
-(acc, row-max m, row-sum l) in VMEM scratch and writing the normalized
-output plus the logsumexp L = m + log(l) at the last k step. The backward
-pass recomputes p = exp(q k^T * scale - L) per block (flash-attention-2
-style) in two kernels: one accumulating dq over k blocks, one accumulating
-(dk, dv) over q blocks, seeded with delta = rowsum(do * o) computed in
-plain XLA.
+(acc, running max m, running sum l) in VMEM scratch and writing the
+normalized output plus the logsumexp L = m + log(l) at the last k step.
+The backward pass recomputes p = exp(q k^T * scale - L) per block
+(flash-attention-2 style) in two kernels: one accumulating dq over k
+blocks, one accumulating (dk, dv) over q blocks, seeded with delta =
+rowsum(do * o) computed in plain XLA.
 
-Causality is enforced by masking with global positions (uniform grid —
-fully-masked blocks still run; the win is memory, not skipped FLOPs).
+Every kernel works on the TRANSPOSED score tile k q^T, [block_k, block_q]
+with keys down the rows. The per-query statistics (m, l, lse, delta) are
+then lane-major [1, block_q] rows: a handful of vregs instead of one per
+eight queries, reduced over keys by plain elementwise max/add down the
+rows, and the same shape in which they cross the kernel boundary
+([BH, 1, T] with (1, 1, block_q) blocks, whose second-to-last block dim
+equals the array's, as Mosaic wants). The forward and dq accumulators are
+held transposed too ([D, block_q]) and turned once, at the last k step.
+Compiled calls need block sizes that are multiples of 128 or cover the
+whole (padded) sequence; plan_flash gives that.
+
+The tile plan (plan_flash) is what makes a grid step worth its fixed
+cost: tiles as large as the sequence and VMEM_BUDGET allow (512 x 512 at
+T = 1024, D = 64: 4 steps a head where 128-wide tiles walked 64), planned
+from what the call can observe: T_q, T_k, D, the operand dtype, causal.
+Any T works: it is padded up to the block grid and the padded keys are
+masked (k_len), so tiles stay MXU-shaped.
+
+Causality is enforced by masking with global positions (_mask_scores),
+and a tile that the mask would blank entirely does no work (_tile_live:
+the same positions, read from SMEM at run time, so a ring hop whose
+visiting shard lies wholly in the future costs a grid walk and nothing
+else, and still comes out as m = NEG_INF, l = 0, zero gradients).
+
+Precision: p and ds are cast to the dtype of the operand they multiply,
+so bfloat16 inputs give the MXU bfloat16 operands in all nine products of
+a training step; scores, m, l, lse, delta, the exponentials and every
+accumulator stay float32. float32 inputs are never cast.
 
 Selection is ops/pallas_mode.py's: Mosaic-compiled on TPU backends,
 interpret mode under PS_TPU_PALLAS_INTERPRET=1 (how CPU CI exercises the
 kernels), pure-jnp reference otherwise (PS_TPU_DISABLE_PALLAS=1 forces
 it). The jnp reference is ring_attention.full_attention — also the test
 oracle.
-
-What Mosaic needs of the layout: the per-row softmax stats (lse, delta,
-and the ring partials' m and l) cross the kernel boundary as [BH, 1, T]
-with (1, 1, block_q) blocks — a lane-major row whose second-to-last block
-dim equals the array's — and are transposed to/from the [block_q, 1]
-column the score tile broadcasts against inside the kernel. Compiled
-calls therefore need block sizes that are multiples of 128 or cover the
-whole (padded) sequence; the defaults and _plan_one's padding give that.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,27 +63,33 @@ from .pallas_mode import kernel_mode, pallas_mode
 
 NEG_INF = -1e30
 
+# What one grid step may hold in VMEM by plan_flash's estimate: under the
+# 16 MiB a v5e kernel gets by default, with room for what the estimate
+# leaves out (Mosaic's own temporaries).
+VMEM_BUDGET = 12 * 2 ** 20
+MAX_BLOCK = 1024
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the last dim of both
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b: contract the first dim of both
+
 
 def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
                  q_off=0, k_off=0):
-    """Apply the causal and/or key-padding mask to one [block_q, block_k]
-    score tile, with positions taken from the grid indices plus GLOBAL
-    offsets (q_off/k_off are 0 single-chip; on a sequence-parallel ring
-    they are the traced shard offsets of the local q block and the
-    visiting k block). `k_len` (static) masks key positions >= k_len —
-    how flash_attention supports sequence lengths that are not block
-    multiples: inputs are zero-padded to the block grid and the padded
-    keys are masked here. The ONE masking implementation shared by the
-    forward, dq, and dkv kernels — they must never diverge or gradients
-    silently stop matching the forward."""
-    k_local = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+    """Apply the causal and/or key-padding mask to one [block_k, block_q]
+    score tile (keys down the rows), with positions taken
+    from the grid indices plus GLOBAL offsets (q_off/k_off are 0
+    single-chip; on a sequence-parallel ring they are the traced shard
+    offsets of the local q block and the visiting k block). `k_len`
+    (static) masks key positions >= k_len — how flash_attention supports
+    sequence lengths that are not block multiples: inputs are zero-padded
+    to the block grid and the padded keys are masked here. The ONE masking
+    implementation shared by the forward, dq, and dkv kernels — they must
+    never diverge or gradients silently stop matching the forward."""
+    iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, scores.shape, axis)
+    k_local = ki * block_k + iota(0)
     keep = None
     if causal:
-        q_pos = q_off + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
+        q_pos = q_off + qi * block_q + iota(1)
         keep = (k_off + k_local) <= q_pos
     if k_len is not None:
         # k_len is the LOCAL (unpadded) length of this k/v operand — the
@@ -76,6 +100,61 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
     return jnp.where(keep, scores, NEG_INF)
 
 
+def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
+    """False where _mask_scores would blank every score of tile (qi, ki):
+    its first key lies past its last query (causal), or past k_len. None
+    when no tile can be blank. Plain arithmetic on the positions
+    _mask_scores uses, so it serves the kernels (traced grid indices and
+    SMEM offsets) and plan_flash's count (ints) alike."""
+    live = None
+    if causal:
+        live = k_off + ki * block_k <= q_off + qi * block_q + (block_q - 1)
+    if k_len is not None:
+        in_len = ki * block_k < k_len
+        live = in_len if live is None else live & in_len
+    return live
+
+
+def _when_live(live, tile):
+    """Run tile() where the tile is live (always, when none can be blank)."""
+    from jax.experimental import pallas as pl
+
+    if live is None:
+        tile()
+    else:
+        pl.when(live)(tile)
+
+
+def _guard_masked_rows(stat):
+    """A query whose every key is masked has m (or lse) == NEG_INF, and
+    exp(NEG_INF - NEG_INF) = 1 would count its masked scores. Put
+    -NEG_INF in its place, so exp(scores - stat) is 0 there: the guard
+    costs one pass over the [1, block_q] statistics, not over the tile."""
+    return jnp.where(stat > NEG_INF / 2, stat, -NEG_INF)
+
+
+def _make_tile(scale, causal, block_q, block_k, k_len):
+    """(scores, live) of one tile geometry, shared by all three kernels:
+    scores(q, k, qi, ki, q_off, k_off) is the masked [block_k, block_q]
+    float32 tile k q^T * scale, live(qi, ki, q_off, k_off) is _tile_live."""
+    masked = causal or k_len is not None
+
+    def scores(q, k, qi, ki, q_off, k_off):
+        s = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32
+        ) * scale
+        if masked:
+            s = _mask_scores(s, qi, ki, block_q, block_k, causal, k_len,
+                             q_off, k_off)
+        return s
+
+    def live(qi, ki, q_off, k_off):
+        return _tile_live(qi, ki, block_q, block_k, causal, k_len,
+                          q_off, k_off)
+
+    return scores, live
+
+
 # --------------------------------------------------------------- forward
 
 
@@ -83,7 +162,7 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
                      k_len=None):
     from jax.experimental import pallas as pl
 
-    masked = causal or k_len is not None
+    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
 
     def kernel(off_ref, q_ref, k_ref, v_ref, *out_and_scratch):
         if normalize:
@@ -92,6 +171,7 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
             pv_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref = out_and_scratch
         qi = pl.program_id(1)
         ki = pl.program_id(2)
+        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
 
         @pl.when(ki == 0)
         def _init():
@@ -99,44 +179,34 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        q = q_ref[0]  # [Bq, D]
-        k = k_ref[0]  # [Bk, D]
-        v = v_ref[0]  # [Bk, D]
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        def _tile():
+            v = v_ref[0]  # [Bk, D]
+            s = scores(q_ref[0], k_ref[0], qi, ki, q_off, k_off)  # [Bk, Bq]
+            m_prev = m_ref[:]  # [1, Bq]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - _guard_masked_rows(m_new))
+            alpha = jnp.exp(m_prev - m_new)  # [1, Bq]
+            l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+                v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32
+            )  # (p v)^T, [D, Bq]
+            m_ref[:] = m_new
 
-        if masked:
-            scores = _mask_scores(
-                scores, qi, ki, block_q, block_k, causal, k_len,
-                off_ref[0, 0], off_ref[0, 1],
-            )
-
-        m_prev = m_ref[:]  # [Bq, 1]
-        m_blk = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(scores - m_new)  # [Bq, Bk]
-        if masked:
-            # rows with every key masked: m_new == NEG_INF, exp(0)=1 junk
-            p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)  # [Bq, 1]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        m_ref[:] = m_new
+        _when_live(live(qi, ki, q_off, k_off), _tile)
 
         @pl.when(ki == n_k - 1)
         def _finalize():
             if normalize:
                 l = l_ref[:]
                 l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0
-                o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-                lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).T
+                o_ref[0] = (acc_ref[:] / l_safe).T.astype(o_ref.dtype)
+                lse_ref[0] = m_ref[:] + jnp.log(l_safe)
             else:
                 # partial triple for ring hops: UNNORMALIZED numerator plus
                 # the (m, l) stats, merged across hops by the caller
-                pv_ref[0] = acc_ref[:]
-                mo_ref[0] = m_ref[:].T
-                lo_ref[0] = l_ref[:].T
+                pv_ref[0] = acc_ref[:].T
+                mo_ref[0] = m_ref[:]
+                lo_ref[0] = l_ref[:]
 
     return kernel
 
@@ -201,9 +271,9 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
         ],
         **mode,
     )(_offsets_arr(offsets), q3, k3, v3)
@@ -213,40 +283,55 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
 # --------------------------------------------------------------- backward
 
 
+def _make_ds(scores):
+    """tile(...) -> (p^T, ds^T / scale, q, do): what the dq and dkv kernels
+    both recompute from the FINAL lse and delta of a [block_k, block_q]
+    tile. ds lacks its factor `scale`: each kernel applies it once to
+    what it accumulated."""
+
+    def tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+             qi, ki, q_off, k_off):
+        q, do = q_ref[0], do_ref[0]
+        s = scores(q, k_ref[0], qi, ki, q_off, k_off)
+        # fully-masked rows contributed nothing forward (lse NEG_INF)
+        p = jnp.exp(s - _guard_masked_rows(lse_ref[0]))  # exact probs
+        dp = jax.lax.dot_general(
+            v_ref[0], do, _NT, preferred_element_type=jnp.float32
+        )  # [Bk, Bq]
+        return p, p * (dp - delta_ref[0]), q, do
+
+    return tile
+
+
 def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
     from jax.experimental import pallas as pl
 
-    masked = causal or k_len is not None
+    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
+    ds_tile = _make_ds(scores)
 
     def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, acc_ref):
         qi = pl.program_id(1)
         ki = pl.program_id(2)
+        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
 
         @pl.when(ki == 0)
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0].T  # [1, Bq] -> [Bq, 1]
-        delta = delta_ref[0].T
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if masked:
-            scores = _mask_scores(
-                scores, qi, ki, block_q, block_k, causal, k_len,
-                off_ref[0, 0], off_ref[0, 1],
-            )
-        p = jnp.exp(scores - lse)  # exact softmax probs, [Bq, Bk]
-        # fully-masked rows: lse == NEG_INF and scores == NEG_INF give
-        # exp(0) = 1; such rows contributed nothing forward, so zero them
-        p = jnp.where(lse > NEG_INF / 2, p, 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        def _tile():
+            _, ds, _, _ = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                  delta_ref, qi, ki, q_off, k_off)
+            k = k_ref[0]
+            acc_ref[:] += jax.lax.dot_general(
+                k, ds.astype(k.dtype), _TN, preferred_element_type=jnp.float32
+            )  # (ds k)^T, [D, Bq]
+
+        _when_live(live(qi, ki, q_off, k_off), _tile)
 
         @pl.when(ki == n_k - 1)
         def _finalize():
-            dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+            dq_ref[0] = (acc_ref[:] * scale).T.astype(dq_ref.dtype)
 
     return kernel
 
@@ -254,37 +339,35 @@ def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
 def _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=None):
     from jax.experimental import pallas as pl
 
-    masked = causal or k_len is not None
+    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
+    ds_tile = _make_ds(scores)
 
     def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dk_ref, dv_ref, dk_acc, dv_acc):
         ki = pl.program_id(1)
         qi = pl.program_id(2)
+        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
 
         @pl.when(qi == 0)
         def _init():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        lse = lse_ref[0].T  # [1, Bq] -> [Bq, 1]
-        delta = delta_ref[0].T
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if masked:
-            scores = _mask_scores(
-                scores, qi, ki, block_q, block_k, causal, k_len,
-                off_ref[0, 0], off_ref[0, 1],
+        def _tile():
+            p, ds, q, do = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                   delta_ref, qi, ki, q_off, k_off)
+            dv_acc[:] += jnp.dot(
+                p.astype(do.dtype), do, preferred_element_type=jnp.float32
             )
-        p = jnp.exp(scores - lse)  # [Bq, Bk]
-        p = jnp.where(lse > NEG_INF / 2, p, 0.0)  # fully-masked rows (see dq)
-        dv_acc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale  # [Bq, Bk]
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+            dk_acc[:] += jnp.dot(
+                ds.astype(q.dtype), q, preferred_element_type=jnp.float32
+            )
+
+        _when_live(live(qi, ki, q_off, k_off), _tile)
 
         @pl.when(qi == n_q - 1)
         def _finalize():
-            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+            dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
     return kernel
@@ -326,7 +409,7 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), dq_dt),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         **mode,
     )(off, q3, k3, v3, do3, lse, delta)
 
@@ -360,7 +443,26 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     return dq, dk, dv
 
 
-# --------------------------------------------------------------- public API
+# -------------------------------------------------------------- tile plan
+
+
+class FlashPlan(NamedTuple):
+    """How one call tiles its [T_q, T_k] score square. The counts are per
+    head, with both offsets 0 (what the call can know before it runs: on a
+    ring hop the kernels decide from the hop's offsets)."""
+
+    block_q: int
+    block_k: int
+    tq_pad: int       # T_q and T_k padded up to their blocks
+    tk_pad: int
+    k_len: Optional[int]  # T_k where the kernels must mask a padded tail
+    grid_steps: int   # tiles walked: (tq_pad / block_q) * (tk_pad / block_k)
+    tiles_run: int    # ... of which do work (_tile_live)
+    vmem_bytes: int   # _vmem_bytes of the chosen blocks
+
+    @property
+    def tiles_total(self) -> int:
+        return self.grid_steps
 
 
 def _ceil_pow2(x: int) -> int:
@@ -377,27 +479,72 @@ def _floor_pow2(x: int) -> int:
     return p
 
 
-def _plan_blocks(t: int, want_q: int, want_k: int):
-    """(block_q, block_k, padded_t) for a sequence of length t. When t is
-    not a multiple of the block grid, pad UP to it and mask the tail
-    (k_len) instead of shrinking blocks — a T=1000 call keeps MXU-shaped
-    128-wide tiles over T=1024 rather than degrading to a 1-wide grid
-    (VERDICT r02 weak #3). Requested block sizes are floored to powers of
-    two so the padded length is divisible by both (lcm = max) — a non-pow2
-    request must never leave grid-uncovered tail rows."""
-    bq, _ = _plan_one(t, want_q)
-    bk, _ = _plan_one(t, want_k)
-    lcm = max(bq, bk)  # both are powers of two: lcm = max
-    tp = -(-t // lcm) * lcm
-    return bq, bk, tp
+def _vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """What a grid step of the dkv kernel, the largest of the three, holds:
+    q, do, k, v and the two row statistics double-buffered by the pipeline,
+    dk and dv (float32 on a ring hop) double-buffered beside their two
+    float32 accumulators, four float32 score tiles (s, p, dp, ds) and the
+    two casts of p and ds that feed the MXU."""
+    tile = block_q * block_k
+    operands = 2 * (2 * block_q * d + 2 * block_k * d) * itemsize
+    stats = 2 * 2 * block_q * 4
+    results = (2 * 2 + 2) * block_k * d * 4
+    return operands + stats + results + 4 * tile * 4 + 2 * tile * itemsize
 
 
-def _plan_one(t: int, want: int):
-    """(block, padded_t) for ONE sequence axis (the ring-hop API plans q
-    and k independently — a visiting k/v shard can have a different
-    length than the local q shard)."""
-    b = min(_floor_pow2(want), max(8, _ceil_pow2(t)))
-    return b, -(-t // b) * b
+def _fit_block(t: int, cap: int) -> int:
+    """The block for one sequence axis of length t: the largest power of
+    two up to `cap` whose padding of t stays within an eighth of t as
+    padded to 128 (T = 1000 takes 512-wide blocks over 1024; T = 520 takes
+    128-wide blocks over 640, not 512-wide ones over 1024). A sequence
+    that fits one 128-wide block is that one block, padded to a power of
+    two of at least 8."""
+    if t <= 128:
+        return max(8, _ceil_pow2(t))
+    t128 = -(-t // 128) * 128
+    b = cap
+    while b > 128 and -(-t // b) * b - t128 > t128 // 8:
+        b //= 2
+    return b
+
+
+def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
+               block_q: Optional[int] = None,
+               block_k: Optional[int] = None) -> FlashPlan:
+    """The tile plan of one call, from what it can observe. Pure.
+
+    Both blocks start at the largest square that _vmem_bytes puts under
+    VMEM_BUDGET for this head size and operand dtype, then each axis
+    takes what its length allows (_fit_block), so a visiting ring shard
+    may be tiled otherwise than the local queries. A requested block
+    (the tests) is floored to a power of two and capped at the padded
+    sequence instead: a non-pow2 request must never leave grid-uncovered
+    tail rows."""
+    itemsize = jnp.dtype(dtype).itemsize
+    cap = MAX_BLOCK
+    while cap > 128 and _vmem_bytes(cap, cap, d, itemsize) > VMEM_BUDGET:
+        cap //= 2
+
+    def block(t, want):
+        if want is None:
+            return _fit_block(t, cap)
+        return min(_floor_pow2(want), max(8, _ceil_pow2(t)))
+
+    bq, bk = block(t_q, block_q), block(t_k, block_k)
+    tq_pad, tk_pad = -(-t_q // bq) * bq, -(-t_k // bk) * bk
+    n_q, n_k = tq_pad // bq, tk_pad // bk
+    k_len = t_k if tk_pad != t_k else None
+    live = [_tile_live(qi, ki, bq, bk, causal, k_len)
+            for qi in range(n_q) for ki in range(n_k)]
+    return FlashPlan(
+        block_q=bq, block_k=bk, tq_pad=tq_pad, tk_pad=tk_pad, k_len=k_len,
+        grid_steps=n_q * n_k,
+        tiles_run=sum(x is None or bool(x) for x in live),
+        vmem_bytes=_vmem_bytes(bq, bk, d, itemsize),
+    )
+
+
+# --------------------------------------------------------------- public API
 
 
 def _pad_t(x, tp, value=0.0):
@@ -440,8 +587,8 @@ def flash_attention(
     v: jax.Array,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
 ) -> jax.Array:
     """Drop-in replacement for ring_attention.full_attention ([B, T, H, D]
     in and out), differentiable, Pallas-backed on TPU.
@@ -450,6 +597,7 @@ def flash_attention(
     Any T works: lengths that are not block multiples are zero-padded up
     to the block grid and the padded keys masked inside the kernels, so
     tiles stay MXU-shaped (no silent degradation to tiny blocks).
+    block_q/block_k default to plan_flash's choice; the tests pass them.
     """
     if pallas_mode() is None:
         from ..parallel.ring_attention import full_attention
@@ -460,15 +608,12 @@ def flash_attention(
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bq, bk, tp = _plan_blocks(t, block_q, block_k)
+    plan = plan_flash(t, t, d, q.dtype, causal, block_q, block_k)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    q3, k3, v3 = fold(q), fold(k), fold(v)
-    k_len = None
-    if tp != t:
-        pad = ((0, 0), (0, tp - t), (0, 0))
-        q3, k3, v3 = (jnp.pad(x, pad) for x in (q3, k3, v3))
-        k_len = t
-    o3 = _flash(q3, k3, v3, float(scale), bool(causal), bq, bk, k_len)
+    q3 = _pad_t(fold(q), plan.tq_pad)
+    k3, v3 = _pad_t(fold(k), plan.tk_pad), _pad_t(fold(v), plan.tk_pad)
+    o3 = _flash(q3, k3, v3, float(scale), bool(causal), plan.block_q,
+                plan.block_k, plan.k_len)
     o3 = o3[:, :t]
     return o3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
@@ -479,33 +624,33 @@ def flash_attention(
 
 
 def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
-                  block_q=128, block_k=128, mode=None):
+                  block_q=None, block_k=None, mode=None):
     """One hop's UNNORMALIZED contribution: [BH, Tq, D] queries against a
     visiting [BH, Tk, D] K/V shard -> (pv f32 [BH, Tq, D], m f32 [BH, Tq],
     l f32 [BH, Tq]). q_off/k_off are the shards' global sequence offsets
     (traced scalars are fine — they ride in SMEM, one compiled kernel
-    serves every hop). The caller merges triples across hops with the
-    usual online-softmax rescale and normalizes once at the end.
+    serves every hop, and decides there which tiles the hop runs). The
+    caller merges triples across hops with the usual online-softmax
+    rescale and normalizes once at the end.
 
     Shard lengths need not be block multiples: like flash_attention, odd
     lengths are padded up to the block grid (padded keys masked via
     k_len, padded query rows sliced off) so tiles stay MXU-shaped."""
     tq, tk = q3.shape[1], k3.shape[1]
-    bq, tpq = _plan_one(tq, block_q)
-    bk, tpk = _plan_one(tk, block_k)
-    q3 = _pad_t(q3, tpq)
-    k3, v3 = _pad_t(k3, tpk), _pad_t(v3, tpk)
+    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k)
+    q3 = _pad_t(q3, plan.tq_pad)
+    k3, v3 = _pad_t(k3, plan.tk_pad), _pad_t(v3, plan.tk_pad)
     pv, m, l = _flash_fwd(
-        q3, k3, v3, scale, causal, bq, bk,
+        q3, k3, v3, scale, causal, plan.block_q, plan.block_k,
         kernel_mode("flash_partial") if mode is None else mode,
         offsets=(q_off, k_off), normalize=False,
-        k_len=(tk if tpk != tk else None),
+        k_len=plan.k_len,
     )
     return pv[:, :tq], m[:, :tq], l[:, :tq]
 
 
 def flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, causal,
-                        q_off, k_off, block_q=128, block_k=128, mode=None):
+                        q_off, k_off, block_q=None, block_k=None, mode=None):
     """One hop's gradient contributions (dq [BH, Tq, D], dk [BH, Tk, D],
     dv [BH, Tk, D], all f32) given the FINAL merged lse/delta — per-hop
     pieces sum to the exact flash backward (f32 out so cross-hop
@@ -513,18 +658,19 @@ def flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, causal,
     lengths pad-and-mask exactly like flash_partial (padded q rows carry
     zero do/delta, so they contribute nothing to dk/dv)."""
     tq, tk = q3.shape[1], k3.shape[1]
-    bq, tpq = _plan_one(tq, block_q)
-    bk, tpk = _plan_one(tk, block_k)
-    q3, do3 = _pad_t(q3, tpq), _pad_t(do3, tpq)
+    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k)
+    q3, do3 = _pad_t(q3, plan.tq_pad), _pad_t(do3, plan.tq_pad)
     # lse pads with +inf-ish so padded rows' p = exp(scores - lse)
     # underflows to 0 (their do/delta are zero-padded, so they'd
     # contribute nothing anyway — this just keeps exp() finite)
-    lse, delta = _pad_t(lse, tpq, value=-NEG_INF), _pad_t(delta, tpq)
-    k3, v3 = _pad_t(k3, tpk), _pad_t(v3, tpk)
+    lse = _pad_t(lse, plan.tq_pad, value=-NEG_INF)
+    delta = _pad_t(delta, plan.tq_pad)
+    k3, v3 = _pad_t(k3, plan.tk_pad), _pad_t(v3, plan.tk_pad)
     dq, dk, dv = _flash_bwd(
-        q3, k3, v3, lse, delta, do3, scale, causal, bq, bk,
+        q3, k3, v3, lse, delta, do3, scale, causal, plan.block_q,
+        plan.block_k,
         kernel_mode("flash_grads_partial") if mode is None else mode,
         offsets=(q_off, k_off), out_dtype=jnp.float32,
-        k_len=(tk if tpk != tk else None),
+        k_len=plan.k_len,
     )
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]

@@ -240,8 +240,8 @@ def ring_flash_attention(
     axis_name: str = SEQ_AXIS,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     bidirectional: bool = False,
 ) -> jax.Array:
     """ring_attention with the Pallas flash kernel inside each hop.

@@ -60,11 +60,12 @@ def test_flash_gradients_match_full(causal):
 
 
 def test_flash_uneven_seq_pads_to_full_blocks():
-    from ps_pytorch_tpu.ops.flash_attention import _plan_blocks
+    from ps_pytorch_tpu.ops.flash_attention import plan_flash
 
-    # T=192 with the default 128: pad up to 256 and keep 128-wide tiles
+    # T=192 with 128-wide blocks asked for: pad up to 256 and keep them
     # (the old behavior shrank blocks; padding keeps the MXU shape)
-    assert _plan_blocks(192, 128, 128) == (128, 128, 256)
+    plan = plan_flash(192, 192, D, jnp.float32, True, 128, 128)
+    assert plan[:4] == (128, 128, 256, 256)
     q, k, v = _qkv(2, t=192)
     got = flash_attention(q, k, v, causal=True)
     want = full_attention(q, k, v, causal=True)
@@ -76,16 +77,16 @@ def test_flash_uneven_seq_pads_to_full_blocks():
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
 def test_flash_odd_seq_keeps_mxu_blocks(causal):
     """VERDICT r02 weak #3: T=1000 (small odd factors) must NOT degrade to
-    a 1-wide grid — it pads to 1024 with 128-blocks, masks the tail, and
-    still matches the oracle in value and gradient."""
-    from ps_pytorch_tpu.ops.flash_attention import _plan_blocks
+    a 1-wide grid — it pads to 1024 with MXU-shaped blocks, masks the
+    tail, and still matches the oracle in value and gradient."""
+    from ps_pytorch_tpu.ops.flash_attention import plan_flash
 
-    bq, bk, tp = _plan_blocks(1000, 128, 128)
-    assert (bq, bk, tp) == (128, 128, 1024)
+    bq, bk, tqp, tkp = plan_flash(1000, 1000, D, jnp.float32, causal)[:4]
+    assert bq >= 128 and bk >= 128 and (tqp, tkp) == (1024, 1024)
 
     t = 250  # keep interpret-mode runtime sane; same 1000-style odd factors
-    bq, bk, tp = _plan_blocks(t, 128, 128)
-    assert bq >= 128 and bk >= 128 and tp == 256
+    bq, bk, tqp, tkp = plan_flash(t, t, D, jnp.float32, causal)[:4]
+    assert bq >= 128 and bk >= 128 and (tqp, tkp) == (256, 256)
 
     q, k, v = _qkv(7, t=t)
     got = flash_attention(q, k, v, causal=causal)
@@ -111,10 +112,10 @@ def test_flash_odd_seq_keeps_mxu_blocks(causal):
 def test_flash_non_pow2_block_request_stays_correct():
     """A non-pow2 block size is floored to a pow2 so the padded grid
     covers the whole sequence (code-review r03 finding)."""
-    from ps_pytorch_tpu.ops.flash_attention import _plan_blocks
+    from ps_pytorch_tpu.ops.flash_attention import plan_flash
 
-    bq, bk, tp = _plan_blocks(200, 96, 128)
-    assert tp % bq == 0 and tp % bk == 0
+    bq, bk, tqp, tkp = plan_flash(200, 200, D, jnp.float32, True, 96, 128)[:4]
+    assert (bq, bk) == (64, 128) and tqp % bq == 0 and tkp % bk == 0
     q, k, v = _qkv(9, t=200)
     got = flash_attention(q, k, v, causal=True, block_q=96, block_k=128)
     want = full_attention(q, k, v, causal=True)
@@ -181,3 +182,84 @@ def test_transformer_flash_matches_naive():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4
         )
+
+
+def _rel_err(got, want):
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / max(1.0, np.max(np.abs(want))))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+@pytest.mark.parametrize("t", [96, 1000, 1024])
+def test_planned_path_matches_full(t, causal, dtype):
+    """No block size passed: plan_flash's tiles. T=1024 causal skips the
+    tile above the diagonal, T=1000 pads a tail and masks it, T=96 is one
+    padded tile; bfloat16 puts the cast of p and ds on the path. Output
+    and all three gradients against the float32 oracle on the same
+    (rounded) inputs."""
+    rng = np.random.RandomState(t)
+    q, k, v = (jnp.asarray(rng.randn(1, t, 2, 64) * 0.5, dtype)
+               for _ in range(3))
+
+    def loss(attend):
+        def f(q, k, v):
+            o = attend(q, k, v, causal=causal)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o), grads = loss(flash_attention)(q, k, v)
+    f32 = lambda x: x.astype(jnp.float32)
+    (_, o_ref), grads_ref = loss(full_attention)(f32(q), f32(k), f32(v))
+    assert o.dtype == dtype and all(g.dtype == dtype for g in grads)
+    bound = 2e-5 if dtype == jnp.float32 else 2e-2
+    errs = {"o": _rel_err(o, o_ref)}
+    errs.update((n, _rel_err(g, r))
+                for n, g, r in zip(("dq", "dk", "dv"), grads, grads_ref))
+    assert all(e < bound for e in errs.values()), errs
+
+
+def test_plan_flash():
+    """The tile plan is a pure function of what a call can observe."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        MAX_BLOCK, VMEM_BUDGET, plan_flash)
+
+    # cell 3's call: 512-wide tiles, the one above the diagonal skipped
+    plan = plan_flash(1024, 1024, 64, jnp.bfloat16, True)
+    assert plan[:4] == (512, 512, 1024, 1024)
+    assert (plan.tiles_run, plan.tiles_total, plan.grid_steps) == (3, 4, 4)
+    assert plan_flash(1024, 1024, 64, jnp.bfloat16, False).tiles_run == 4
+    # the old 128-wide plan, as the tests can still ask for it
+    old = plan_flash(1024, 1024, 64, jnp.bfloat16, True, 128, 128)
+    assert (old.tiles_run, old.tiles_total) == (36, 64)
+    for t_q, t_k, d, dtype in [
+        (7, 7, 32, jnp.float32), (96, 96, 64, jnp.bfloat16),
+        (200, 200, 16, jnp.float32), (520, 520, 64, jnp.bfloat16),
+        (1000, 1000, 64, jnp.bfloat16), (1024, 1024, 128, jnp.float32),
+        (10, 10, 16, jnp.float32), (512, 4096, 64, jnp.bfloat16),
+        (8192, 8192, 256, jnp.float32),
+    ]:
+        for causal in (False, True):
+            p = plan_flash(t_q, t_k, d, dtype, causal)
+            for b, t, tp in ((p.block_q, t_q, p.tq_pad),
+                             (p.block_k, t_k, p.tk_pad)):
+                assert b & (b - 1) == 0 and 8 <= b <= MAX_BLOCK
+                assert tp % b == 0 and t <= tp < t + b
+                # compiled blocks: 128-multiples, or the whole padded axis
+                assert b % 128 == 0 or b == tp
+            assert p.vmem_bytes <= VMEM_BUDGET
+            assert p.tiles_total == p.grid_steps == (
+                (p.tq_pad // p.block_q) * (p.tk_pad // p.block_k))
+            assert 1 <= p.tiles_run <= p.tiles_total
+            assert causal or p.tiles_run == p.tiles_total
+    # padding stays small: T=520 takes 128-wide blocks over 640, T=1000
+    # 512-wide ones over 1024
+    assert plan_flash(520, 520, 64, jnp.bfloat16, True)[:4] == (
+        128, 128, 640, 640)
+    assert plan_flash(1000, 1000, 64, jnp.bfloat16, True)[:4] == (
+        512, 512, 1024, 1024)
+    # a visiting shard of another length is tiled on its own axis
+    assert plan_flash(96, 1024, 64, jnp.bfloat16, True)[:4] == (
+        128, 512, 128, 1024)

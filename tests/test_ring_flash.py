@@ -226,6 +226,44 @@ def test_ring_flash_odd_shard_len_pads_not_degrades(causal):
         )
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_ring_hop_wholly_in_the_future_is_a_noop(dtype):
+    """A visiting shard whose every key lies past every local query: the
+    kernels skip all of its tiles (decided from the TRACED offsets) and it
+    must still come out as fully-masked rows — m = NEG_INF, l = 0, a zero
+    numerator and zero gradients. One key earlier, exactly one (query,
+    key) pair is live. T_q != T_k, and both pad."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        NEG_INF, flash_grads_partial, flash_partial)
+
+    bh, tq, tk, d = 2, 96, 160, 16
+    rng = np.random.RandomState(12)
+    mk = lambda t: jnp.asarray(rng.randn(bh, t, d), dtype)
+    q3, do3, k3, v3 = mk(tq), mk(tq), mk(tk), mk(tk)
+    lse = jnp.asarray(rng.randn(bh, tq), jnp.float32)  # merged elsewhere
+    delta = jnp.asarray(rng.randn(bh, tq), jnp.float32)
+
+    @jax.jit
+    def hop(q_off, k_off):
+        triple = flash_partial(q3, k3, v3, 0.25, True, q_off, k_off)
+        grads = flash_grads_partial(q3, k3, v3, do3, lse, delta, 0.25, True,
+                                    q_off, k_off)
+        return triple, grads
+
+    (pv, m, l), grads = jax.device_get(hop(jnp.int32(64), jnp.int32(160)))
+    assert np.all(m == np.float32(NEG_INF)) and not l.any() and not pv.any()
+    assert all(g.dtype == np.float32 and not g.any() for g in grads)
+
+    (pv, m, l), (dq, dk, dv) = jax.device_get(
+        hop(jnp.int32(64), jnp.int32(159)))  # key 159 meets query 64 + 95
+    assert np.all(l[:, :-1] == 0) and np.all(l[:, -1] == 1)
+    np.testing.assert_allclose(
+        pv[:, -1], np.asarray(v3[:, 0], np.float32), rtol=1e-6)
+    assert not dk[:, 1:].any() and dk[:, 0].any() and dq[:, -1].any()
+    assert not dq[:, :-1].any() and not dv[:, 1:].any()
+
+
 def test_sp_transformer_flash_matches_single_device(seq_mesh):
     cfg = TransformerConfig(
         vocab_size=64, dim=64, depth=2, heads=4, max_seq_len=T,

@@ -22,7 +22,8 @@ monotonic clock. This tool:
   per-component fraction of loop walltime by phase (where does a
   step's time go: the spans opened directly under the trainers' `step`
   span — fetch vs dispatch vs window_close — or the serve tick's
-  depth-0 spans), and a nesting
+  depth-0 spans), every instant with its attributes (train_lm's
+  `flash_plan`: the flash kernels' tile plan), and a nesting
   check (child spans must sit inside their parents — a violation means
   a tracer bug, not a workload property);
 - ``--require-phases a,b,c`` exits nonzero unless every named phase is
@@ -232,6 +233,12 @@ def merge(
                 k: round(v / total, 4) for k, v in sorted(by.items())
             }
     nest_bad = sum(check_nesting(spans) for _, _, spans in streams)
+    # an instant carries its payload in attrs (train_lm's `flash_plan`):
+    # print it whole
+    instants = [
+        {k: v for k, v in s.items() if k not in ("kind", "cat", "dur", "depth")}
+        for s in all_spans if s.get("cat") == "instant"
+    ]
     summary = {
         "streams": [
             {
@@ -246,6 +253,7 @@ def merge(
         ],
         "n_overlay_events": len(overlays),
         "phases": phases,
+        "instants": instants,
         "fraction_of_loop_walltime": fractions,
         "nesting_violations": nest_bad,
         "nesting_ok": nest_bad == 0,
