@@ -17,6 +17,9 @@ CLIs expose, on synthetic data made from a seed:
             two checkpoints
   serve     cli.serve on the older checkpoint, a handful of requests and
             one hot rollover onto the newer
+  lm_config cli.train_lm --lm-config on the small preset of the latent-
+            attention / dropless-expert family (192-wide q/k, 128-wide v,
+            8 of 16 routed experts held), bf16, flash, remat, Adam
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -85,6 +88,27 @@ SERVE_ARGS = [
     "--prompt-min", "4", "--prompt-max", "16", "--new-min", "8",
     "--new-max", "32", "--poll-interval", "0.05", "--dtype", "bfloat16",
 ]
+# the small preset of the latent-attention / dropless-expert family: the
+# published head widths (192-wide q/k beside a 128-wide v), 8 of 16 routed
+# experts held, one dense and one expert layer; built through --lm-config
+LM_CONFIG = {
+    "model_type": "deepseek_v3", "vocab_size": 1024, "hidden_size": 512,
+    "num_hidden_layers": 2, "num_attention_heads": 4, "kv_lora_rank": 128,
+    "q_lora_rank": None, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "intermediate_size": 1024, "moe_intermediate_size": 256,
+    "n_routed_experts": 16, "n_shared_experts": 2, "num_experts_per_tok": 3,
+    "first_k_dense_replace": 1, "routed_scaling_factor": 2.448,
+    "norm_topk_prob": True, "rope_theta": 1000000, "rms_norm_eps": 1e-6,
+    "rope_interleave": True, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
+    "experts_held": 8, "expert_offset": 0,
+}
+LM_CONFIG_ARGS = [
+    "--seq-len", "1024", "--batch-size", "2", "--dtype", "bfloat16",
+    "--attention-impl", "flash", "--optimizer", "adam", "--lr", "0.0003",
+    "--max-steps", "4", "--log-interval", "2", "--remat",
+]
+LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm")
 FLASH_SHAPE = (8, 1024, 8, 64)  # B, T, H, D: the LM leg's attention
 BUCKET_ELEMS = (4 << 20) // 4   # one 4 MiB f32 gradient bucket
 
@@ -482,6 +506,64 @@ def leg_lm(train_dir, devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_config(workdir, devices, clog):
+    """The second LM family through `cli.train_lm --lm-config`: the flash
+    kernels at a 192-wide query beside a 128-wide value and the dropless
+    experts' grouped products must be Mosaic calls in the compiled step,
+    and the routing counters must account for every token."""
+    import jax
+    import jax.numpy as jnp
+
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.models.lm import load_lm_config
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel.dp_sp import (
+        init_lm_state,
+        make_lm_train_step,
+        make_mesh_2d,
+        shard_tokens_2d,
+    )
+
+    leg = "lm_config"
+    path = os.path.join(workdir, "lm_config_small.json")
+    with open(path, "w") as f:
+        json.dump(LM_CONFIG, f)
+    out = train_lm_cli.main(
+        ["--lm-config", path, "--num-dp", "1", "--num-sp", str(len(devices))]
+        + LM_CONFIG_ARGS
+    )
+    check_finite(leg, "loss", out["loss"])
+    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+
+    opt = dict(zip(LM_CONFIG_ARGS[::2], LM_CONFIG_ARGS[1::2]))
+    cfg = load_lm_config(path, attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    tx = build_optimizer("adam", 3e-4)
+    mesh = make_mesh_2d(1, len(devices))
+    params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
+    batch, seq = int(opt["--batch-size"]), int(opt["--seq-len"])
+    tokens = shard_tokens_2d(
+        jnp.asarray(train_lm_cli.make_synthetic_tokens(
+            cfg.vocab_size, batch, seq, seed=2)), mesh)
+    step = make_lm_train_step(cfg, tx, mesh).lower(
+        params, opt_state, tokens
+    ).compile()
+    check_kernels(leg, step.as_text(), LM_CONFIG_KERNELS)
+    params, opt_state, loss, counters = step(params, opt_state, tokens)
+    check_finite(leg, "library step loss", jax.device_get(loss))
+    c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
+    held = cfg.experts_held / cfg.n_routed_experts * cfg.num_experts_per_tok
+    n = batch * seq
+    if not (0.5 * held * n < c["moe_rows_here"] < min(2.0 * held, cfg.num_experts_per_tok) * n
+            and 0 <= c["moe_tokens_unserved"] < n
+            and c["moe_min_expert_rows"] <= c["moe_max_expert_rows"]):
+        raise AssertionError(f"{leg}: routing counters out of range: {c}")
+    print(f"[{leg}] routing: {c}", flush=True)
+    check_on_all_devices(leg, "params", params, devices)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -602,6 +684,7 @@ def main() -> int:
                 lambda clog, w=wire: leg_ps(w, workdir, devices, clog))
         run("lm", lambda clog: leg_lm(lm_dir, devices, clog))
         run("serve", lambda clog: leg_serve(lm_dir, devices, clog))
+        run("lm_config", lambda clog: leg_lm_config(workdir, devices, clog))
 
     print(json.dumps({
         "versions": versions,

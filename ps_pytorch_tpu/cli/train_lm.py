@@ -24,6 +24,17 @@ data/datasets.make_synthetic.
   ... --parallelism moe --num-experts 8
   ... --parallelism ep_sp --num-shards 4 --num-sp 2 --num-experts 8
   ... --parallelism pp_moe --num-shards 4 --num-ep 2 --num-experts 8
+
+A published architecture is named by its config.json, not by --dim/--depth/
+--heads: `--lm-config <json>` (the published keys plus the chip's share,
+e.g. `model_type: deepseek_v3` with `experts_held`; models/lm.
+load_lm_config builds the family, the same call the benchmark's driver
+makes). Such a model trains through --parallelism dp_sp; its routing
+counters are logged at log steps and recorded as the `moe_route` instant.
+
+  ... --lm-config benchmark/configs/kanana2_30b_a3b_ep8.json --num-dp 1 \
+      --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
+      --attention-impl flash --optimizer adam --lr 3e-4
 """
 
 from __future__ import annotations
@@ -38,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from ..models.lm import load_lm_config
 from ..models.transformer import TransformerConfig
 from ..optim import build_optimizer
 from ..parallel.dp_sp import (
@@ -93,6 +105,11 @@ def main(argv=None) -> dict:
     parser.add_argument("--dim", type=int, default=128)
     parser.add_argument("--depth", type=int, default=2)
     parser.add_argument("--heads", type=int, default=4)
+    parser.add_argument("--lm-config", type=str, default=None, metavar="JSON",
+                        help="a published config.json (model_type, widths, "
+                             "and the chip's share: experts_held, the "
+                             "vocabulary slice) in place of --vocab-size/"
+                             "--dim/--depth/--heads; dp_sp only")
     parser.add_argument("--seq-len", type=int, default=512)
     parser.add_argument("--batch-size", type=int, default=8,
                         help="global sequences per step (divisible by num-dp)")
@@ -162,12 +179,7 @@ def main(argv=None) -> dict:
             "(the other schemes keep the embedding replicated and would "
             "silently ignore it)"
         )
-    cfg = TransformerConfig(
-        vocab_size=args.vocab_size,
-        dim=args.dim,
-        depth=args.depth,
-        heads=args.heads,
-        max_seq_len=args.seq_len,
+    run_opts = dict(
         remat=args.remat,
         bidirectional_ring=args.bidirectional_ring,
         sp_attention=args.sp_attention,
@@ -176,6 +188,28 @@ def main(argv=None) -> dict:
         # are broken — bf16(0.999) == 1.0); block math runs in bf16
         compute_dtype=jnp.bfloat16 if args.dtype == "bfloat16" else None,
     )
+    if args.lm_config:
+        if args.parallelism != "dp_sp":
+            raise ValueError(
+                f"--lm-config trains through --parallelism dp_sp; "
+                f"{args.parallelism} restates the dense block (ROADMAP D6)"
+            )
+        if args.train_dir:
+            raise ValueError(
+                "--train-dir: cli.evaluate_lm rebuilds the dense and the "
+                "capacity-MoE families only; no checkpoint of an --lm-config model"
+            )
+        cfg = load_lm_config(args.lm_config, **run_opts)
+        args.vocab_size = cfg.vocab_size  # the corpus draws from the slice held
+        args.depth, args.dim, args.heads = (
+            cfg.num_hidden_layers, cfg.hidden_size, cfg.num_attention_heads)
+        d_qk, d_v = cfg.qk_head_dim, cfg.v_head_dim
+    else:
+        cfg = TransformerConfig(
+            vocab_size=args.vocab_size, dim=args.dim, depth=args.depth,
+            heads=args.heads, max_seq_len=args.seq_len, **run_opts,
+        )
+        d_qk = d_v = cfg.head_dim
     if args.lr_schedule == "cosine":
         lr = optax.warmup_cosine_decay_schedule(
             init_value=0.0,
@@ -201,6 +235,7 @@ def main(argv=None) -> dict:
     n_shards = args.num_shards or n_dev
     key = jax.random.key(args.seed)
 
+    counters_box = {}  # the newest step's counters, where the family counts
     # Each scheme yields (params, opt_state, run(params, opt, np_tokens) ->
     # (params, opt, loss)) over its own mesh; the training loop below is
     # scheme-agnostic.
@@ -215,7 +250,13 @@ def main(argv=None) -> dict:
             )
         params, opt_state = init_lm_state(cfg, tx, key, mesh)
         step = make_lm_train_step(cfg, tx, mesh)
-        run = lambda p, o, tok: step(p, o, shard_tokens_2d(jnp.asarray(tok), mesh))
+
+        def run(p, o, tok):  # a family that counts returns a fourth value
+            p, o, loss, *rest = step(p, o, shard_tokens_2d(jnp.asarray(tok), mesh))
+            if rest:
+                counters_box["last"] = rest[0]
+            return p, o, loss
+
         to_plain = lambda p: p
         layout = f"dp {args.num_dp} x sp {num_sp} ({args.sp_attention})"
     elif args.parallelism == "tp":
@@ -409,6 +450,8 @@ def main(argv=None) -> dict:
         "heads": args.heads, "seq_len": args.seq_len,
         "params": n_params,
     }
+    if args.lm_config:
+        geometry["lm_config"] = os.path.basename(args.lm_config)
     append_metrics_line(
         args.metrics_file,
         run_header("train_lm", run_id=run_id, geometry=geometry),
@@ -432,13 +475,14 @@ def main(argv=None) -> dict:
         ring = (args.parallelism in ("dp_sp", "ep_sp")
                 and cfg.sp_attention == "ring")
         t_att = args.seq_len // num_sp if ring else args.seq_len
-        plan = plan_flash(t_att, t_att, cfg.head_dim,
-                          cfg.effective_compute_dtype, cfg.causal)
+        plan = plan_flash(t_att, t_att, d_qk,
+                          cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
         flash_plan = {f: getattr(plan, f) for f in (
             "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total")}
+        flash_plan.update(d_qk=d_qk, d_v=d_v)
         logger.info(
             "flash plan for T %d x D %d: %s (per head%s)", t_att,
-            cfg.head_dim, flash_plan,
+            d_qk, flash_plan,
             "; ring hops decide from their offsets" if ring else "",
         )
         tr.instant("flash_plan", **flash_plan)
@@ -546,6 +590,22 @@ def main(argv=None) -> dict:
                         logger.info(
                             "MoE load-balance aux: %.4f", record["aux_loss"]
                         )
+                    if "last" in counters_box:
+                        # the expert layers' routing over this step's
+                        # global batch (parallel/moe.routing_counters)
+                        c = {k: np.asarray(v).tolist() for k, v in
+                             jax.device_get(counters_box["last"]).items()}
+                        record.update({k: v for k, v in c.items()
+                                       if not k.endswith("_per_layer")})
+                        logger.info(
+                            "MoE routing: %d rows here, fullest expert %d, "
+                            "emptiest %d, %d tokens with no expert here, "
+                            "max over mean %.3f", c["moe_rows_here"],
+                            c["moe_max_expert_rows"], c["moe_min_expert_rows"],
+                            c["moe_tokens_unserved"], c["moe_rows_max_over_mean"],
+                        )
+                        tr.instant("moe_route", **{
+                            k[len("moe_"):]: v for k, v in c.items()})
                     with tr.span("metrics_write"):
                         append_metrics_line(args.metrics_file, record)
                     flush_due = True

@@ -74,8 +74,10 @@ EVENT_KINDS: Dict[str, EventSpec] = {
     ),
     "train_lm": EventSpec(
         required=("step", "loss", "time_cost"),
-        int_fields=("step",),
-        doc="LM trainer log window (cli/train_lm.py)",
+        int_fields=("step", "moe_rows_here", "moe_max_expert_rows",
+                    "moe_min_expert_rows", "moe_tokens_unserved"),
+        doc="LM trainer log window (cli/train_lm.py); the moe_* routing "
+            "counters ride along for a family with dropless expert layers",
     ),
     "grad_skip": EventSpec(
         required=("step", "skipped_steps", "skip_streak"),
@@ -139,7 +141,14 @@ EVENT_KINDS: Dict[str, EventSpec] = {
         required=("name", "t", "dur"),
         int_fields=("depth", "step", "tick", "slot", "rid",
                     "new_tokens", "weights_step", "from_step", "to_step",
-                    "bytes", "block", "wall_ns", "err_ns"),
+                    "bytes", "block", "wall_ns", "err_ns",
+                    # instants of cli/train_lm.py: `flash_plan` (the
+                    # kernels' tiles and widths) and `moe_route` (the
+                    # dropless expert layers' rows, summed over layers;
+                    # `<name>_per_layer` lists ride along)
+                    "block_q", "block_k", "grid_steps", "tiles_run",
+                    "tiles_total", "d_qk", "d_v", "rows_here",
+                    "max_expert_rows", "min_expert_rows", "tokens_unserved"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "
             "that clock with the wall clock (wall_ns +- err_ns at t)",

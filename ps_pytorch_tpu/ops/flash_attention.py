@@ -232,29 +232,29 @@ def _smem_spec():
 
 def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
                offsets=None, normalize=True, k_len=None):
-    """q3/k3/v3: [BH, T, D] -> (o [BH, T, D], lse [BH, T]) when normalize,
-    else the partial triple (pv f32 [BH, T, D], m f32 [BH, T], l f32
-    [BH, T]) for ring-hop merging. `offsets` shifts the causal mask's
-    global positions; static `k_len` masks zero-padded key positions
-    (see _mask_scores)."""
+    """q3/k3: [BH, T, D], v3: [BH, T, Dv] -> (o [BH, T, Dv], lse [BH, T])
+    when normalize, else the partial triple (pv f32 [BH, T, Dv], m f32
+    [BH, T], l f32 [BH, T]) for ring-hop merging. `offsets` shifts the
+    causal mask's global positions; static `k_len` masks zero-padded key
+    positions (see _mask_scores)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
-    tk = k3.shape[1]
+    tk, dv = k3.shape[1], v3.shape[2]
     n_q, n_k = t // block_q, tk // block_k
     kernel = _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
                               k_len=k_len)
     row = pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi))
     row_shape = jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0))]
     if normalize:
         out_specs += [row]
-        out_shape = [jax.ShapeDtypeStruct((bh, t, d), q3.dtype), row_shape]
+        out_shape = [jax.ShapeDtypeStruct((bh, t, dv), q3.dtype), row_shape]
     else:
         out_specs += [row, row]
         out_shape = [
-            jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, t, dv), jnp.float32),
             row_shape,
             row_shape,
         ]
@@ -266,12 +266,12 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
             _smem_spec(),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, qi, ki: (b, ki, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((d, block_q), jnp.float32),
+            pltpu.VMEM((dv, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
         ],
@@ -381,12 +381,13 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     per-hop contributions sum to the exact gradient. k3/v3 may have a
     different sequence length than q3 (a visiting ring shard).
     `out_dtype` overrides the gradients' dtype (the ring passes f32 so
-    per-hop pieces accumulate without a per-hop rounding)."""
+    per-hop pieces accumulate without a per-hop rounding). v3 and do3 are
+    Dv wide where q3 and k3 are D wide."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
-    tk = k3.shape[1]
+    tk, dv = k3.shape[1], v3.shape[2]
     n_q, n_k = t // block_q, tk // block_k
     off = _offsets_arr(offsets)
     lse, delta = lse.reshape(bh, 1, t), delta.reshape(bh, 1, t)
@@ -402,8 +403,8 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
             _smem_spec(),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
             pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
@@ -421,22 +422,22 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
             _smem_spec(),
             pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, ki, qi: (b, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
             pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), dk_dt),
-            jax.ShapeDtypeStruct((bh, tk, d), dv_dt),
+            jax.ShapeDtypeStruct((bh, tk, dv), dv_dt),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         **mode,
     )(off, q3, k3, v3, do3, lse, delta)
@@ -479,16 +480,19 @@ def _floor_pow2(x: int) -> int:
     return p
 
 
-def _vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+def _vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
+                d_v: Optional[int] = None) -> int:
     """What a grid step of the dkv kernel, the largest of the three, holds:
     q, do, k, v and the two row statistics double-buffered by the pipeline,
     dk and dv (float32 on a ring hop) double-buffered beside their two
     float32 accumulators, four float32 score tiles (s, p, dp, ds) and the
-    two casts of p and ds that feed the MXU."""
+    two casts of p and ds that feed the MXU. q and k are `d` wide, v and
+    do `d_v` (the same unless given)."""
     tile = block_q * block_k
-    operands = 2 * (2 * block_q * d + 2 * block_k * d) * itemsize
+    width = d + (d if d_v is None else d_v)
+    operands = 2 * (block_q + block_k) * width * itemsize
     stats = 2 * 2 * block_q * 4
-    results = (2 * 2 + 2) * block_k * d * 4
+    results = (2 + 1) * block_k * width * 4
     return operands + stats + results + 4 * tile * 4 + 2 * tile * itemsize
 
 
@@ -510,8 +514,10 @@ def _fit_block(t: int, cap: int) -> int:
 
 def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
                block_q: Optional[int] = None,
-               block_k: Optional[int] = None) -> FlashPlan:
-    """The tile plan of one call, from what it can observe. Pure.
+               block_k: Optional[int] = None,
+               d_v: Optional[int] = None) -> FlashPlan:
+    """The tile plan of one call, from what it can observe. Pure. `d` is
+    the query/key width, `d_v` the value width where it differs.
 
     Both blocks start at the largest square that _vmem_bytes puts under
     VMEM_BUDGET for this head size and operand dtype, then each axis
@@ -522,7 +528,7 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
     tail rows."""
     itemsize = jnp.dtype(dtype).itemsize
     cap = MAX_BLOCK
-    while cap > 128 and _vmem_bytes(cap, cap, d, itemsize) > VMEM_BUDGET:
+    while cap > 128 and _vmem_bytes(cap, cap, d, itemsize, d_v) > VMEM_BUDGET:
         cap //= 2
 
     def block(t, want):
@@ -540,7 +546,7 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
         block_q=bq, block_k=bk, tq_pad=tq_pad, tk_pad=tk_pad, k_len=k_len,
         grid_steps=n_q * n_k,
         tiles_run=sum(x is None or bool(x) for x in live),
-        vmem_bytes=_vmem_bytes(bq, bk, d, itemsize),
+        vmem_bytes=_vmem_bytes(bq, bk, d, itemsize, d_v),
     )
 
 
@@ -584,14 +590,14 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(
     q: jax.Array,  # [B, T, H, D]
     k: jax.Array,
-    v: jax.Array,
+    v: jax.Array,  # [B, T, H, Dv]; Dv may differ from D (latent attention)
     causal: bool = False,
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
 ) -> jax.Array:
     """Drop-in replacement for ring_attention.full_attention ([B, T, H, D]
-    in and out), differentiable, Pallas-backed on TPU.
+    in, [B, T, H, Dv] out), differentiable, Pallas-backed on TPU.
 
     Falls back to the jnp reference when Pallas is unavailable/disabled.
     Any T works: lengths that are not block multiples are zero-padded up
@@ -606,16 +612,17 @@ def flash_attention(
             return full_attention(q, k, v, causal=causal, scale=scale)
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    plan = plan_flash(t, t, d, q.dtype, causal, block_q, block_k)
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    plan = plan_flash(t, t, d, q.dtype, causal, block_q, block_k, d_v=dv)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
     q3 = _pad_t(fold(q), plan.tq_pad)
     k3, v3 = _pad_t(fold(k), plan.tk_pad), _pad_t(fold(v), plan.tk_pad)
     o3 = _flash(q3, k3, v3, float(scale), bool(causal), plan.block_q,
                 plan.block_k, plan.k_len)
     o3 = o3[:, :t]
-    return o3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return o3.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
 # ------------------------------------------- ring-hop partial-triple API
@@ -626,8 +633,8 @@ def flash_attention(
 def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
                   block_q=None, block_k=None, mode=None):
     """One hop's UNNORMALIZED contribution: [BH, Tq, D] queries against a
-    visiting [BH, Tk, D] K/V shard -> (pv f32 [BH, Tq, D], m f32 [BH, Tq],
-    l f32 [BH, Tq]). q_off/k_off are the shards' global sequence offsets
+    visiting K [BH, Tk, D] / V [BH, Tk, Dv] shard -> (pv f32 [BH, Tq, Dv],
+    m f32 [BH, Tq], l f32 [BH, Tq]). q_off/k_off are the shards' global sequence offsets
     (traced scalars are fine — they ride in SMEM, one compiled kernel
     serves every hop, and decides there which tiles the hop runs). The
     caller merges triples across hops with the usual online-softmax
@@ -637,7 +644,8 @@ def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
     lengths are padded up to the block grid (padded keys masked via
     k_len, padded query rows sliced off) so tiles stay MXU-shaped."""
     tq, tk = q3.shape[1], k3.shape[1]
-    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k)
+    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k,
+                      d_v=v3.shape[2])
     q3 = _pad_t(q3, plan.tq_pad)
     k3, v3 = _pad_t(k3, plan.tk_pad), _pad_t(v3, plan.tk_pad)
     pv, m, l = _flash_fwd(
@@ -652,13 +660,14 @@ def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
 def flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, causal,
                         q_off, k_off, block_q=None, block_k=None, mode=None):
     """One hop's gradient contributions (dq [BH, Tq, D], dk [BH, Tk, D],
-    dv [BH, Tk, D], all f32) given the FINAL merged lse/delta — per-hop
+    dv [BH, Tk, Dv], all f32) given the FINAL merged lse/delta — per-hop
     pieces sum to the exact flash backward (f32 out so cross-hop
     accumulation never rounds per hop, even under bf16 inputs). Odd shard
     lengths pad-and-mask exactly like flash_partial (padded q rows carry
     zero do/delta, so they contribute nothing to dk/dv)."""
     tq, tk = q3.shape[1], k3.shape[1]
-    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k)
+    plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k,
+                      d_v=v3.shape[2])
     q3, do3 = _pad_t(q3, plan.tq_pad), _pad_t(do3, plan.tq_pad)
     # lse pads with +inf-ish so padded rows' p = exp(scores - lse)
     # underflows to 0 (their do/delta are zero-padded, so they'd

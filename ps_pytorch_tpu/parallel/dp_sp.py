@@ -32,11 +32,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (
-    TransformerConfig,
-    apply_transformer,
-    init_transformer,
-)
+from ..models.lm import lm_family
 from .mesh import WORKER_AXIS, replicated_sharding
 from .ring_attention import SEQ_AXIS
 
@@ -66,21 +62,23 @@ def shard_tokens_2d(
 
 
 def lm_loss_local(
-    cfg: TransformerConfig,
+    cfg,
     params,
     tokens: jax.Array,
     sp_axis: str = SEQ_AXIS,
 ):
     """LOCAL slice of the global-mean next-token loss for one (dp, sp) shard
-    of tokens [b_local, t_local].
+    of tokens [b_local, t_local], for any family models/lm.lm_family knows.
 
-    Returns loss_sum_local / count_global. The global loss is the psum of
-    this over sp — do that OUTSIDE the differentiated function (see module
-    docstring: differentiating through the psum overcounts gradients)."""
+    Returns (loss_sum_local / count_global, aux): the global loss is the
+    psum of the first over sp — do that OUTSIDE the differentiated
+    function (see module docstring: differentiating through the psum
+    overcounts gradients); aux is what the family counted on these tokens
+    ({} for the dense family)."""
     b_loc, t_loc = tokens.shape
     n_sp = lax.axis_size(sp_axis)
     s = lax.axis_index(sp_axis)
-    logits = apply_transformer(cfg, params, tokens, seq_axis_name=sp_axis)
+    logits, aux = lm_family(cfg).apply(cfg, params, tokens, seq_axis_name=sp_axis)
     # target of my last token = next shard's first token (ring shift left)
     nxt_first = lax.ppermute(
         tokens[:, :1], sp_axis, [(j, (j - 1) % n_sp) for j in range(n_sp)]
@@ -92,11 +90,11 @@ def lm_loss_local(
     valid = (pos < n_sp * t_loc - 1).astype(jnp.float32)  # drop final position
     loss_sum = jnp.sum(nll * valid[None, :])
     count = jnp.float32(b_loc) * jnp.sum(valid)
-    return loss_sum / lax.psum(count, sp_axis)
+    return loss_sum / lax.psum(count, sp_axis), aux
 
 
 def init_lm_state(
-    cfg: TransformerConfig,
+    cfg,
     tx: optax.GradientTransformation,
     key: jax.Array,
     mesh: Mesh,
@@ -105,12 +103,12 @@ def init_lm_state(
     scheme's init_*_state: state left uncommitted on device 0 makes the
     train step compile twice — once for device-0 inputs, again for its
     own mesh-sharded outputs."""
-    params = init_transformer(cfg, key)
+    params = lm_family(cfg).init(cfg, key)
     return jax.device_put((params, tx.init(params)), replicated_sharding(mesh))
 
 
 def make_lm_train_step(
-    cfg: TransformerConfig,
+    cfg,
     tx: optax.GradientTransformation,
     mesh: Mesh,
     dp_axis: str = WORKER_AXIS,
@@ -119,11 +117,14 @@ def make_lm_train_step(
 ):
     """Jitted 2-D train step: (params, opt_state, tokens) ->
     (params, opt_state, loss). params/opt_state replicated; tokens sharded
-    [B over dp, T over sp]."""
+    [B over dp, T over sp]. A family that counts (models/lm.LMFamily.
+    counters: the expert layers' routing) returns a fourth value, the dict
+    of its counters over the step's global batch."""
+    counters = lm_family(cfg).counters
 
     def worker_fn(params, opt_state, tokens):
-        loss_local, grads = jax.value_and_grad(
-            lambda p: lm_loss_local(cfg, p, tokens, sp_axis)
+        (loss_local, aux), grads = jax.value_and_grad(
+            lambda p: lm_loss_local(cfg, p, tokens, sp_axis), has_aux=True
         )(params)
         # exact sequence gradient: sum local partials over sp exactly once;
         # PS aggregation: mean over dp (each dp shard saw a disjoint slice)
@@ -131,13 +132,15 @@ def make_lm_train_step(
         loss = lax.pmean(lax.psum(loss_local, sp_axis), dp_axis)
         updates, new_opt = tx.update(grads, opt_state, params)
         new_params = optax.apply_updates(params, updates)
-        return new_params, new_opt, loss
+        if counters is None:
+            return new_params, new_opt, loss
+        return new_params, new_opt, loss, counters(lax.psum(aux, (dp_axis, sp_axis)))
 
     mapped = jax.shard_map(
         worker_fn,
         mesh=mesh,
         in_specs=(P(), P(), P(dp_axis, sp_axis)),
-        out_specs=(P(), P(), P()),
+        out_specs=(P(), P(), P()) + (() if counters is None else (P(),)),
         check_vma=False,
     )
     return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())

@@ -20,6 +20,17 @@ collectives:
 - a Switch-style load-balance auxiliary loss (E * sum f_e p_e) is returned
   alongside the task loss.
 
+Beside that capacity layer stands the DROPLESS layer of the sigmoid-routed
+top-k-of-many families (`DroplessSpec`, `moe_dropless_local`): no
+capacity, no token dropped at any imbalance, the (token, expert)
+assignments sorted by expert into a buffer sized for the worst case and
+multiplied by ops/grouped_matmul.py, whose cost follows the rows really
+routed here. The layer is TOLD which experts it holds (`experts_held`,
+`expert_offset`): it routes over all of them and computes the part of the
+result its own experts give, which is what one chip of an expert-parallel
+deployment does. With axis_name=None it runs without its exchange; nothing
+stands in for the absent chips.
+
 Gradients: same shard_map AD rule as tp.py/pp.py — each shard returns its
 LOCAL loss; AD computes exact grads of the sum over shards; differentiate
 local/n, then psum the replicated leaves (all_to_all's transpose is
@@ -61,6 +72,28 @@ class MoEConfig:
     def __post_init__(self):
         if self.top_k not in (1, 2):
             raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
+
+
+@dataclasses.dataclass(frozen=True)
+class DroplessSpec:
+    """A sigmoid-routed dropless expert layer and this chip's share of it."""
+
+    num_experts: int            # the router's outputs, all of them
+    top_k: int
+    experts_held: int           # experts whose weights live here ...
+    expert_offset: int = 0      # ... ids offset .. offset + held - 1
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+
+    def __post_init__(self):
+        if not 0 < self.top_k <= self.num_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.num_experts} experts")
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.num_experts
+                and self.experts_held > 0):
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held - 1} "
+                f"are not a share of {self.num_experts}")
 
 
 def make_ep_mesh(
@@ -228,6 +261,149 @@ def moe_mlp_local(h, blk, moe: MoEConfig, axis_name: Optional[str]):
 
     out = jnp.einsum("nec,ecd->nd", combine, expert_out)
     return out.reshape(b, t, d).astype(h.dtype), aux
+
+
+def dropless_route(n32, router, router_bias, spec: DroplessSpec):
+    """Sigmoid top-k routing of float32 rows n32 [N, D] over ALL experts:
+    (idx int32 [N, k], weights float32 [N, k]). The choice is by score plus
+    `router_bias` (the aux-loss-free correction: a buffer, no gradient),
+    the weights are the scores themselves, normalised over the k chosen,
+    held here or not, and scaled. The products are float32 (`highest`): a
+    bfloat16 router picks other experts."""
+    s = jax.nn.sigmoid(jnp.dot(n32.astype(jnp.float32), router.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(router_bias.astype(jnp.float32)), spec.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if spec.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * spec.routed_scale
+
+
+# The dropless layer moves rows three ways, each a gather whose transpose
+# is written as the inverse gather (a scatter of 98,304 rows is what the
+# device is worst at). `route` = (row_assign, row_live, pos, held): row r
+# of the buffer holds assignment row_assign[r] = n * k + j when
+# row_live[r]; assignment (n, j) lies in row pos[n, j] when held[n, j].
+
+
+def _gather_rows(v, route, per_token: int):
+    row_assign, row_live = route[0], route[1]
+    return jnp.where(row_live[:, None], v[row_assign // per_token], 0)
+
+
+def _gather_assignments(v, route):
+    pos, held = route[2], route[3]
+    return jnp.where(held[..., None], v[pos], 0)
+
+
+@jax.custom_vjp
+def _rows_from_tokens(x, route):
+    """[N, D] -> [M, D]: each live row its token's x, the others zero."""
+    return _gather_rows(x, route, route[2].shape[1])
+
+
+@jax.custom_vjp
+def _tokens_from_rows(y, route):
+    """[M, D] -> [N, D]: each token the sum of its held assignments' rows."""
+    return jnp.sum(_gather_assignments(y, route), axis=1)
+
+
+@jax.custom_vjp
+def _rows_from_assignments(w, route):
+    """[N, k] -> [M]: each live row its assignment's scalar."""
+    return _gather_rows(w.reshape(-1, 1), route, 1)[:, 0]
+
+
+_rows_from_tokens.defvjp(
+    lambda x, route: (_rows_from_tokens(x, route), route),
+    lambda route, g: (_tokens_from_rows(g, route), None))
+_tokens_from_rows.defvjp(
+    lambda y, route: (_tokens_from_rows(y, route), route),
+    lambda route, g: (_rows_from_tokens(g, route), None))
+_rows_from_assignments.defvjp(
+    lambda w, route: (_rows_from_assignments(w, route), route),
+    lambda route, g: (_gather_assignments(g[:, None], route)[..., 0], None))
+
+
+def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
+                       axis_name: Optional[str] = None):
+    """The routed experts' part of a dropless layer on local rows.
+
+    n32 [B, T, D]: the float32 normed hidden. blk: "router" [D, E_all],
+    "router_bias" [E_all], "experts": {"w_gate", "w_up" [held, D, F],
+    "w_down" [held, F, D]} (gated SiLU experts). Returns (y [B, T, D] in
+    compute_dtype, counts int32 [held], unserved int32): the weighted sum
+    over the chosen experts HELD HERE, the rows each of them got, and the
+    tokens none of whose experts is held (they get zeros: the caller adds
+    what every chip computes alike, such as a shared expert).
+
+    Every assignment held here gets a row: the buffer holds the worst case,
+    ops/grouped_matmul skips what is empty. axis_name is the expert axis of
+    a deployment whose exchange this repo does not build yet: only None
+    (this chip's share, no exchange) is accepted."""
+    if axis_name is not None:
+        raise NotImplementedError(
+            "the dropless layer runs one chip's share without its exchange; "
+            "an all_to_all over an expert axis is not built (ROADMAP M3)")
+    from ..ops.grouped_matmul import TILE_M, buffer_rows, group_layout, grouped_matmul
+
+    b, t, d = n32.shape
+    n, k, held_n = b * t, spec.top_k, spec.experts_held
+    x32 = n32.reshape(n, d)
+    idx, w = dropless_route(x32, blk["router"], blk["router_bias"], spec)
+    local = idx - spec.expert_offset
+    held = (local >= 0) & (local < held_n)                       # [N, k]
+    key = jnp.where(held, local, held_n).reshape(-1)             # [A]
+    counts = jnp.sum(key[None] == jnp.arange(held_n, dtype=jnp.int32)[:, None],
+                     axis=1, dtype=jnp.int32)                    # [held]
+    rows = buffer_rows(n * k, held_n, TILE_M)
+    layout = group_layout(counts, rows, TILE_M)
+    # one stable sort by expert orders the assignments; both maps are read
+    # off it: `order` (sorted slot -> assignment) and its inverse `slot`
+    a = jnp.arange(n * k, dtype=jnp.int32)
+    _, order = lax.sort((key, a), num_keys=1, is_stable=True)
+    _, slot = lax.sort((order, a), num_keys=1)
+    first = jnp.cumsum(counts) - counts                          # [held]
+    # an assignment's row: its expert's first row plus its rank among that
+    # expert's assignments
+    e_a = jnp.minimum(key, held_n - 1)
+    pos = jnp.where(held.reshape(-1), layout.starts[e_a] + slot - first[e_a], 0).reshape(n, k)
+    # a row's assignment: the sorted slot of the same rank
+    r = jnp.arange(rows, dtype=jnp.int32)
+    e_r = layout.tile_expert[r // TILE_M]
+    in_e = r - layout.starts[e_r]
+    row_live = (in_e < counts[e_r]) & (r // TILE_M < layout.n_live[0])
+    row_assign = jnp.where(row_live, order[jnp.minimum(first[e_r] + in_e, n * k - 1)], 0)
+    route = (row_assign, row_live, pos, held)
+
+    ex = blk["experts"]
+    xs = _rows_from_tokens(x32.astype(compute_dtype), route)
+    gate = grouped_matmul(xs, ex["w_gate"], layout)
+    up = grouped_matmul(xs, ex["w_up"], layout)
+    ys = grouped_matmul(jax.nn.silu(gate) * up, ex["w_down"], layout)
+    # weighted on the row side, so no [N, k, D] tensor exists in either pass
+    ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
+    y = _tokens_from_rows(ys, route)
+    unserved = jnp.sum(~jnp.any(held, axis=-1), dtype=jnp.int32)
+    return y.reshape(b, t, d), counts, unserved
+
+
+def routing_counters(counts, unserved):
+    """The step's routing counters from per-layer per-expert rows
+    counts [L, held] and unserved [L] (already summed over the mesh):
+    rows_here, max_expert_rows, min_expert_rows and tokens_unserved, each
+    summed over layers under `moe_<name>` and per
+    layer under `moe_<name>_per_layer`; and `moe_rows_max_over_mean`: the
+    fullest expert's rows over the mean expert's, layers summed."""
+    per = {"rows_here": jnp.sum(counts, axis=1), "max_expert_rows": jnp.max(counts, axis=1),
+           "min_expert_rows": jnp.min(counts, axis=1), "tokens_unserved": unserved}
+    out = {}
+    for name, v in per.items():
+        out[f"moe_{name}"] = jnp.sum(v)
+        out[f"moe_{name}_per_layer"] = v
+    mean = jnp.maximum(out["moe_rows_here"], 1).astype(jnp.float32) / counts.shape[1]
+    out["moe_rows_max_over_mean"] = out["moe_max_expert_rows"].astype(jnp.float32) / mean
+    return out
 
 
 def apply_moe_transformer(

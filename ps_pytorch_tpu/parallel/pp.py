@@ -218,6 +218,9 @@ def make_pp_train_step(
     (params_pp, opt_state, loss). Block params/opt state sharded over the
     stage axis; tokens replicated and cut into `num_microbatches` equal
     microbatches inside the step."""
+    from ..models.lm import require_dense
+
+    require_dense(cfg, "pipeline parallelism (parallel/pp.py)")
     specs_tree = pp_param_specs(cfg, axis_name)
 
     def shard_fn(params, opt_state, tokens):
@@ -271,6 +274,9 @@ def init_pp_state(
     axis_name: str = PP_AXIS,
 ):
     """Init (params_pp, opt_state) placed with PP shardings."""
+    from ..models.lm import require_dense
+
+    require_dense(cfg, "pipeline parallelism (parallel/pp.py)")
     from ..models.transformer import init_transformer
 
     params_pp = shard_params_pp(

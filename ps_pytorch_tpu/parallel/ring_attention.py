@@ -114,7 +114,8 @@ def ring_attention(
         k_pos = k_blk * t_loc + jnp.arange(t_loc)
         return k_pos[None, :] <= q_pos[:, None]  # [Tq, Tk]
 
-    o0 = jnp.zeros(q.shape, jnp.float32)  # f32 accumulators (see _block_attend)
+    # f32 accumulators (see _block_attend), as wide as the values
+    o0 = jnp.zeros(q.shape[:-1] + v.shape[-1:], jnp.float32)
     m0 = jnp.full((b, h, t_loc), _NEG_BIG, jnp.float32)
     l0 = jnp.zeros((b, h, t_loc), jnp.float32)
 
@@ -288,7 +289,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, block_q, block_k,
     q3, k3, v3 = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     q_off = me * t_loc
 
-    pv0 = jnp.zeros(q3.shape, jnp.float32)
+    pv0 = jnp.zeros(q3.shape[:2] + v3.shape[2:], jnp.float32)
     m0 = jnp.full(q3.shape[:2], _NEG_BIG, jnp.float32)
     l0 = jnp.zeros(q3.shape[:2], jnp.float32)
     perm_fwd = [(j, (j - 1) % n) for j in range(n)]
@@ -357,21 +358,22 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, block_q, block_k,
     from ..ops.flash_attention import flash_grads_partial
 
     q3, k3, v3, o3, lse = res
-    b, t_loc, h, d = do.shape  # static shape/dtype info rides on the cotangent
+    b, t_loc, h, _ = do.shape  # static shape/dtype info rides on the cotangent
     in_dtype = do.dtype
     n = lax.axis_size(axis_name)
     # same rule as _ring_flash_fwd: only the causal mask consumes global
     # positions, and a dead axis_index strands an unplaceable partition-id
     me = lax.axis_index(axis_name) if causal else 0
     if scale is None:
-        scale = 1.0 / (d ** 0.5)
+        scale = 1.0 / (q3.shape[-1] ** 0.5)  # the query/key width, not do's
     do3 = _fold_heads(do).astype(q3.dtype)
     delta = jnp.sum(do3.astype(jnp.float32) * o3, axis=-1)  # [BH, T_loc]
     q_off = me * t_loc
     perm_fwd = [(j, (j - 1) % n) for j in range(n)]
 
     dq0 = jnp.zeros(q3.shape, jnp.float32)
-    dkv0 = jnp.zeros(k3.shape, jnp.float32)
+    dk0 = jnp.zeros(k3.shape, jnp.float32)
+    dv0 = jnp.zeros(v3.shape, jnp.float32)
 
     def grads_at(k_c, v_c, blk_idx):
         return flash_grads_partial(
@@ -396,7 +398,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, block_q, block_k,
             return (dq, k_c, v_c, dk_c, dv_c), None
 
         (dq, _, _, dk, dv), _ = lax.scan(
-            hop, (dq0, k3, v3, dkv0, dkv0), jnp.arange(n)
+            hop, (dq0, k3, v3, dk0, dv0), jnp.arange(n)
         )
     else:
         perm_bwd = [(j, (j + 1) % n) for j in range(n)]
@@ -425,7 +427,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, block_q, block_k,
 
         (dq, _, _, dk_f, dv_f, _, _, dk_b, dv_b), _ = lax.scan(
             hop2,
-            (dq, k3, v3, dkv0, dkv0, k3, v3, dkv0, dkv0),
+            (dq, k3, v3, dk0, dv0, k3, v3, dk0, dv0),
             (jnp.arange(1, n_hops + 1), jnp.asarray(use_bwd)),
         )
         # deliver the traveling accumulators home in ONE rotation each:

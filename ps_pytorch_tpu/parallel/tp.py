@@ -300,6 +300,9 @@ def init_tp_state(
 ):
     """Init (params_tp, opt_state) already placed with TP shardings —
     momentum buffers shard exactly like their parameters."""
+    from ..models.lm import require_dense
+
+    require_dense(cfg, "tensor parallelism (parallel/tp.py)")
     from ..models.transformer import init_transformer
 
     params_tp = shard_params_tp(
@@ -330,6 +333,9 @@ def make_tp_train_step(
     (the two in-block psums are the only communication). With
     shard_vocab=True the embedding/logits run vocab-parallel (see
     vocab_parallel_nll)."""
+    from ..models.lm import require_dense
+
+    require_dense(cfg, "tensor parallelism (parallel/tp.py)")
 
     specs_tree = tp_param_specs(cfg, axis_name, shard_vocab)
 

@@ -201,6 +201,9 @@ class ServingEngine:
         drain_timeout_s: Optional[float] = None,
         sleep=None,
     ):
+        from ..models.lm import require_dense
+
+        require_dense(cfg, "the serving engine (no latent KV cache yet)")
         if not cfg.causal:
             raise ValueError("serving decode is autoregressive: cfg.causal")
         if serve.max_len > cfg.max_seq_len:
