@@ -5,6 +5,11 @@ Replaces the reference's host-side PIL transform pipeline
 RandomHorizontalFlip -> normalize) with jit-compiled batched jax ops, so
 augmentation rides the accelerator instead of Python workers
 (src/data_loader_ops/my_data_loader.py's multiprocessing pool).
+
+The random crop takes no per-image slice: a `dynamic_slice` under `vmap` is a
+gather, which the TPU compiler runs as a loop of one turn an image (34 ms of
+a 130 ms ResNet step at batch 2048), so each axis instead selects among its
+`2 * pad + 1` static slices of the whole batch.
 """
 
 from __future__ import annotations
@@ -22,22 +27,31 @@ def normalize(images: jax.Array, mean: np.ndarray, std: np.ndarray) -> jax.Array
     return (x - jnp.asarray(mean, jnp.float32)) / jnp.asarray(std, jnp.float32)
 
 
+def _shifted(x: jax.Array, offs: jax.Array, axis: int, size: int) -> jax.Array:
+    """`x[i, ..., offs[i] : offs[i] + size, ...]` along `axis` for every row
+    `i` of the batch: one `where` per possible offset over static slices of
+    the whole batch, which XLA fuses into a single pass."""
+    offs = offs.reshape((-1,) + (1,) * (x.ndim - 1))
+    out = jax.lax.slice_in_dim(x, 0, size, axis=axis)
+    for k in range(1, x.shape[axis] - size + 1):
+        out = jnp.where(
+            offs == k, jax.lax.slice_in_dim(x, k, k + size, axis=axis), out
+        )
+    return out
+
+
 @partial(jax.jit, static_argnames=("pad", "pad_mode"))
 def random_crop_flip(
     key: jax.Array, images: jax.Array, pad: int = 4, pad_mode: str = "reflect"
 ) -> jax.Array:
     """Batched 4px-pad + random crop back to original size + random hflip."""
-    n, h, w, c = images.shape
+    n, h, w, _ = images.shape
     kc, kf = jax.random.split(key)
     padded = jnp.pad(
         images, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode=pad_mode
     )
     offs = jax.random.randint(kc, (n, 2), 0, 2 * pad + 1)
-
-    def crop_one(img, off):
-        return jax.lax.dynamic_slice(img, (off[0], off[1], 0), (h, w, c))
-
-    cropped = jax.vmap(crop_one)(padded, offs)
+    cropped = _shifted(_shifted(padded, offs[:, 0], 1, h), offs[:, 1], 2, w)
     flip = jax.random.bernoulli(kf, 0.5, (n,))
     flipped = jnp.where(flip[:, None, None, None], cropped[:, :, ::-1, :], cropped)
     return flipped

@@ -1,5 +1,8 @@
 """Data layer tests: synthetic datasets, normalization parity, augmentation
-shape/determinism, loader epoch semantics, worker sharding."""
+shape/determinism and its equality with the per-image-slice oracle, loader
+epoch semantics, worker sharding."""
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +20,7 @@ from ps_pytorch_tpu.data import (
     random_crop_flip,
     shard_for_worker,
 )
-from ps_pytorch_tpu.data.datasets import NORM_STATS, NUM_CLASSES
+from ps_pytorch_tpu.data.datasets import NORM_STATS, NUM_CLASSES, PAD_MODE
 
 
 @pytest.mark.parametrize("name", ["MNIST", "Cifar10", "Cifar100", "SVHN"])
@@ -62,6 +65,101 @@ def test_random_crop_flip_shapes_and_determinism():
     assert a.shape == x.shape
     assert jnp.array_equal(a, b)
     assert not jnp.array_equal(a, c)
+
+
+@partial(jax.jit, static_argnames=("pad", "pad_mode"))
+def crop_flip_oracle(key, images, pad=4, pad_mode="reflect"):
+    """The crop written image by image: `vmap` of `lax.dynamic_slice` over
+    per-image offsets (a gather; on the TPU a loop of one turn an image).
+    It says what bytes `random_crop_flip` has to return for a key, which
+    `benchmark/reference/resnet18_cifar10.py` also draws this way."""
+    n, h, w, c = images.shape
+    kc, kf = jax.random.split(key)
+    padded = jnp.pad(
+        images, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode=pad_mode
+    )
+    offs = jax.random.randint(kc, (n, 2), 0, 2 * pad + 1)
+
+    def crop_one(img, off):
+        return jax.lax.dynamic_slice(img, (off[0], off[1], 0), (h, w, c))
+
+    cropped = jax.vmap(crop_one)(padded, offs)
+    flip = jax.random.bernoulli(kf, 0.5, (n,))
+    return jnp.where(flip[:, None, None, None], cropped[:, :, ::-1, :], cropped)
+
+
+def _images(shape, dtype, seed=0):
+    x = np.random.RandomState(seed).randint(0, 256, shape)
+    # float images take values no uint8 holds, so a round trip through
+    # another dtype inside the crop would show
+    return jnp.asarray(x.astype(np.uint8) if dtype == "uint8" else x / 7.0, dtype)
+
+
+@pytest.mark.parametrize("dtype", ["uint8", "float32"])
+@pytest.mark.parametrize("pad", [2, 4])
+@pytest.mark.parametrize(
+    "shape", [(2048, 32, 32, 3), (7, 32, 32, 3), (4, 28, 28, 1), (5, 24, 40, 2)]
+)
+@pytest.mark.parametrize("pad_mode", ["reflect", "constant"])
+@pytest.mark.parametrize("seed", [0, 1, 7, 42, 2700000061])
+def test_random_crop_flip_equals_per_image_slice(seed, pad_mode, shape, pad, dtype):
+    x = _images(shape, dtype)
+    key = jax.random.key(seed)
+    got = random_crop_flip(key, x, pad=pad, pad_mode=pad_mode)
+    want = crop_flip_oracle(key, x, pad=pad, pad_mode=pad_mode)
+    assert got.dtype == want.dtype == x.dtype and got.shape == x.shape
+    assert jnp.array_equal(got, want)
+
+
+def test_random_crop_flip_lowers_to_no_gather_or_dynamic_slice():
+    """The form is the guard (the chip shows the time): a gather or a
+    data-dependent slice is what the TPU compiler turns into a loop of one
+    turn an image. The oracle shows the search finds one where there is."""
+    x = _images((16, 32, 32, 3), "uint8")
+    banned = ("stablehlo.gather", "dynamic_slice", "dynamic_update_slice")
+    for pad_mode in ("reflect", "constant"):
+        text = random_crop_flip.lower(
+            jax.random.key(0), x, pad_mode=pad_mode).as_text()
+        assert not [b for b in banned if b in text]
+    old = crop_flip_oracle.lower(jax.random.key(0), x).as_text()
+    assert "stablehlo.gather" in old or "dynamic_slice" in old
+
+
+def test_ps_step_same_with_shipped_crop_and_oracle(mesh):
+    """Three PS steps on Cifar10 with augmentation: the shipped crop and the
+    per-image-slice oracle feed bit-identical images, so losses and
+    parameters are equal, not close."""
+    from ps_pytorch_tpu.models import build_model
+    from ps_pytorch_tpu.optim import sgd
+    from ps_pytorch_tpu.parallel import (
+        PSConfig, init_ps_state, make_ps_train_step, shard_batch, shard_state,
+    )
+
+    mean, std = NORM_STATS["Cifar10"]
+
+    def oracle_pre(key, images):
+        return normalize(
+            crop_flip_oracle(key, images, pad_mode=PAD_MODE["Cifar10"]), mean, std)
+
+    ds = make_synthetic("Cifar10", train_size=96, test_size=16, seed=5)
+    cfg = PSConfig(num_workers=8)
+    model = build_model("LeNet")
+    tx = sgd(0.05, momentum=0.9)
+    runs = []
+    for pre in (make_preprocessor("Cifar10", train=True), oracle_pre):
+        state = shard_state(
+            init_ps_state(model, tx, cfg, jax.random.key(0), (32, 32, 3)), mesh, cfg)
+        step = make_ps_train_step(model, tx, cfg, mesh, preprocess=pre)
+        it = BatchIterator(ds.train_images, ds.train_labels, batch_size=32, seed=0)
+        losses = []
+        for i, b in zip(range(3), it.forever()):
+            state, m = step(state, shard_batch(b, mesh, cfg), jax.random.key(40 + i))
+            losses.append(float(m["loss"]))
+        runs.append((losses, jax.device_get(jax.tree_util.tree_leaves(state.params))))
+    (l_new, p_new), (l_old, p_old) = runs
+    assert l_new == l_old
+    for a, b in zip(p_new, p_old):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_preprocessor_train_vs_eval():
