@@ -54,7 +54,7 @@ import time
 
 # the model shapes are the repo's own: ResNet-18 / Cifar10 is the
 # reference's canonical job (run_pytorch.sh), d512 x 6 / seq 1024 / batch 8
-# is the LM shape bench.py measures. Steps are few; widths are not cut.
+# is the repo's own small LM shape. Steps are few; widths are not cut.
 PS_ARGS = [
     "--network", "ResNet18", "--dataset", "Cifar10", "--batch-size", "128",
     "--lr", "0.01", "--momentum", "0.9", "--max-steps", "10",

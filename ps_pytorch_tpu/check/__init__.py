@@ -54,7 +54,7 @@ from .core import (
     trace_spec,
     write_contract,
 )
-from .opcount import compiled_op_count, hlo_op_count, update_path_op_count
+from .opcount import update_path_op_count
 from .rules import RULE_IDS
 from .walker import Collective, collect_collectives, summarize
 
@@ -78,9 +78,7 @@ __all__ = [
     "WirePolicy",
     "analyze_numerics",
     "collect_collectives",
-    "compiled_op_count",
     "get_contracts",
-    "hlo_op_count",
     "load_contract",
     "run_checks",
     "summarize",

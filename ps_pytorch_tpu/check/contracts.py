@@ -145,34 +145,6 @@ class AdaptivePolicy:
 
 
 @dataclasses.dataclass(frozen=True)
-class PrecisionPolicy:
-    """PSC108/PSC110 extension: adaptive per-bucket precision.
-
-    A config taking a traced per-bucket precision tag vector
-    (PSConfig.precision_adapt — skip / 4-bit / int8 / hi per wire
-    bucket) inherits the adaptive mask's discipline:
-
-    - PSC108: the gradient-path reduce collectives must stay inside
-      ``envelope_bytes`` — a tag selects which LATTICE a bucket's
-      values occupy (the traced clipping peak), never how many bytes
-      the trace moves; a tag that started resizing payloads or
-      gathering per-tag side channels is the same regression the mask
-      envelope catches. ``n_buckets`` documents the traced tag
-      vector's length (the wire's own state_plan carving).
-    - PSC110: ``consensus`` names the host function that agrees the
-      adopted tag vector across processes (elementwise min) before it
-      is fed to the step — it must resolve in pslint's consensus
-      inventory, exactly like AdaptivePolicy.consensus. Torn tags are
-      torn traced values: each host would quantize the SAME psum
-      payload onto a different lattice and the replicas shear.
-    """
-
-    n_buckets: int
-    envelope_bytes: int
-    consensus: Optional[str] = None
-
-
-@dataclasses.dataclass(frozen=True)
 class OverlapPolicy:
     """PSC109: schedule invariance for the pipelined bucket wire.
 
@@ -275,7 +247,6 @@ class ContractSpec:
     adaptive: Optional[AdaptivePolicy] = None
     overlap: Optional[OverlapPolicy] = None
     numerics: Optional[NumericsPolicy] = None
-    precision: Optional[PrecisionPolicy] = None
 
 
 # metrics / loss pmean: a handful of f32 scalars, every scheme emits it
@@ -385,14 +356,6 @@ def _cnn_ps_built(cfg, network: str) -> Built:
         # the traced per-window aggregation count (same compiled step
         # for every value — the whole point of the adaptive signature)
         args += (jax.ShapeDtypeStruct((), jnp.int32),)
-    if cfg.precision_adapt:
-        # the traced per-bucket precision tag vector, sized by the SAME
-        # state_plan the wire carves (declared extras order: after the
-        # aggregation count when both are on)
-        from ..parallel.ps import state_plan
-
-        n_buckets = state_plan(cfg, payload_bytes(network) // 4).n_buckets
-        args += (jax.ShapeDtypeStruct((n_buckets,), jnp.int32),)
     return Built(
         step=step,
         args=args,
@@ -406,14 +369,12 @@ def _ps_spec(
     dcn_hosts: int = 1,
     bucket_bytes: Optional[int] = None,
     network: str = "LeNet",
-    state_layout: str = "flat",
     adaptive: bool = False,
     overlap: str = "serial",
     bucket_tag: str = "",
     quant_block_size: int = 0,
     wire_domain: str = "dequant",
     error_feedback: bool = False,
-    precision_adapt: bool = False,
 ) -> ContractSpec:
     from ..parallel.mesh import DCN_AXIS, WORKER_AXIS
 
@@ -438,18 +399,11 @@ def _ps_spec(
         name += "_homomorphic"
     if error_feedback:
         name += "_ef"
-    if precision_adapt:
-        name += "_precadapt"
     if adaptive:
         name += "_adaptive"
     if overlap == "pipelined":
         serial_twin = name
         name += "_pipelined"
-    if state_layout != "flat":
-        # layout-parity twins only (layout_parity_pairs) — the registry
-        # itself carries the default layout, and state layout is
-        # compute-side, so its wire rows would duplicate the flat ones
-        name += "_treestate"
     axes: Tuple[str, ...] = (
         (DCN_AXIS, WORKER_AXIS) if dcn_hosts > 1 else (WORKER_AXIS,)
     )
@@ -463,12 +417,10 @@ def _ps_spec(
             opt_placement=placement,
             dcn_hosts=dcn_hosts,
             bucket_bytes=bucket_bytes,
-            state_layout=state_layout,
             overlap=overlap,
             quant_block_size=quant_block_size,
             wire_domain=wire_domain,
             error_feedback=error_feedback,
-            precision_adapt=precision_adapt,
             num_aggregate_min=2 if adaptive else None,
             num_aggregate_max=MESH_DEVICES if adaptive else None,
         )
@@ -612,49 +564,6 @@ def _ps_spec(
         overlap_policy = OverlapPolicy(mode="pipelined",
                                        serial_twin=serial_twin)
 
-    precision_policy = None
-    if precision_adapt:
-        # the envelope: exactly the bytes the STATIC config's gradient
-        # reduce moves — a tag selects the lattice the values occupy
-        # inside the same physical payload, so adaptation may never add
-        # reduce bytes. Per-element reduce cost per scheme: the 2round
-        # all_to_all ships the int8 payload itself; the homomorphic
-        # psum rides the minimal exact accumulator; the dequant int8
-        # psum rides int32.
-        from ..parallel.ps import state_plan
-
-        cfg = make_cfg()
-        splan = state_plan(cfg, payload_bytes(network) // 4)
-        if compress == "int8_2round":
-            per_elem = 1
-        elif homomorphic:
-            import jax.numpy as jnp
-
-            from ..ops.quantize import accum_dtype
-
-            per_elem = jnp.dtype(accum_dtype(MESH_DEVICES)).itemsize
-        else:
-            per_elem = 4
-        precision_policy = PrecisionPolicy(
-            n_buckets=splan.n_buckets,
-            envelope_bytes=splan.padded_total * per_elem,
-            # the host controller's adopted tag vector is min-reduced
-            # across processes before the traced step sees it (PSC110)
-            consensus="trainer.Trainer._tags_consensus",
-        )
-        if wire is not None:
-            # the controller's telemetry: one [n_buckets] f32 pmean of
-            # per-bucket squared gradient norms per step — statistics,
-            # not payload, and byte-bounded by the bucket count
-            wire = dataclasses.replace(wire, allow=wire.allow + (
-                WireAllowance(
-                    kind="psum", dtype="float32",
-                    max_bytes=4 * splan.n_buckets,
-                    reason="per-bucket gradient-norm telemetry pmean "
-                           "(adaptive precision controller)",
-                ),
-            ))
-
     # the precision-flow contract (PSC111-114): which integer
     # accumulator the quantized lattice sums into, per wire scheme —
     # quantized_psum widens int8 -> int32 (homomorphic: the minimal
@@ -687,7 +596,6 @@ def _ps_spec(
         adaptive=adaptive_policy,
         overlap=overlap_policy,
         numerics=num,
-        precision=precision_policy,
     )
 
 
@@ -926,30 +834,6 @@ def _serve_spec(int8_kv: bool) -> ContractSpec:
 RESNET_BUCKET_BYTES = 4 << 20
 
 
-def layout_parity_pairs() -> Tuple[Tuple[ContractSpec, ContractSpec], ...]:
-    """(flat_spec, tree_spec) twins for the state-layout parity gate.
-
-    PSConfig.state_layout is COMPUTE-side: the registry (and the
-    committed artifact) trace the default flat layout, and these twins
-    exist so tests/test_flat_state.py can assert that each pair's traced
-    wire accounting — collective kinds, axes, dtypes, counts, bytes — is
-    byte-identical, i.e. going flat moved zero bytes and added zero
-    collectives. One twin per wire family: the per-leaf psum, the fused
-    quantized bucket wire, and the ZeRO-1 scatter."""
-    combos = (
-        dict(compress=None, placement="replicated"),
-        dict(compress="int8", placement="replicated", bucket_bytes=0),
-        dict(compress="int8", placement="sharded"),
-    )
-    return tuple(
-        (
-            _ps_spec(state_layout="flat", **kw),
-            _ps_spec(state_layout="tree", **kw),
-        )
-        for kw in combos
-    )
-
-
 def get_contracts() -> Tuple[ContractSpec, ...]:
     """The committed registry: the PS matrix (compress x placement, plus
     the hierarchical DCN x ICI composition), the bucketed-wire variants
@@ -1037,18 +921,12 @@ def get_contracts() -> Tuple[ContractSpec, ...]:
         specs.append(_ps_spec("int8", "replicated", bucket_bytes=64 << 10,
                               bucket_tag="64k", overlap=ov,
                               wire_domain="homomorphic"))
-    # adaptive per-bucket precision (PSC108/110 precision half, §6i):
-    # the traced tag vector on the dequant int8 bucketed wire, and the
-    # smoke-leg twin — homomorphic 2round + EF, where the tags retune
-    # round 1's lattice under shared scales while EF closes over the
-    # added error (PSC112 must still prove the residual against the
-    # traced-peak mirror). Both pin "tags reshape values, never bytes".
-    specs.append(_ps_spec("int8", "replicated", bucket_bytes=64 << 10,
-                          bucket_tag="64k", precision_adapt=True))
+    # error feedback: the registry's one EF entry, homomorphic 2round at
+    # 64 KiB buckets — PSC112 proves the residual closes over the
+    # wire's own round-1 quantization site
     specs.append(_ps_spec("int8_2round", "replicated",
                           bucket_bytes=64 << 10, bucket_tag="64k",
-                          wire_domain="homomorphic", error_feedback=True,
-                          precision_adapt=True))
+                          wire_domain="homomorphic", error_feedback=True))
     specs.extend(
         [_dp_tp_spec(), _pp_spec(), _moe_spec(), _dp_tp_pp_spec()]
     )

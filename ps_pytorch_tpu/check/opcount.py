@@ -1,24 +1,15 @@
-"""Op-count probes: how big is the compiled step, and how much of it is
-UPDATE path (everything downstream of the gradient reduce)?
+"""Op-count probe: how much of the traced step is UPDATE path
+(everything downstream of the gradient reduce)?
 
-Two measurements, two tools:
-
-- ``update_path_op_count`` walks the traced jaxpr FORWARD from the
-  outputs of every reduce-kind collective (walker.REDUCE_KINDS — the
-  gradient psum / psum_scatter / all_to_all family) and counts the
-  equations that consume them, directly or transitively. This is the
-  number that collapses when the state goes flat (PSConfig.state_layout
-  = "flat"): the per-leaf scatter -> per-leaf optimizer -> per-leaf
-  apply chain becomes one fused vector update, while the forward/
-  backward half of the program is untouched. Deterministic, CPU-only,
-  nothing executes. The few post-reduce metrics ops (loss pmean
-  consumers) are counted too — identical in both layouts, so they only
-  dilute the ratio, never flip it.
-
-- ``hlo_op_count`` counts instructions in the OPTIMIZED HLO of the
-  compiled step — the whole-program size after XLA fusion, recorded by
-  bench.py on every benchmark record so the trajectory JSONs capture
-  the update-path collapse on real configs.
+``update_path_op_count`` walks the traced jaxpr FORWARD from the
+outputs of every reduce-kind collective (walker.REDUCE_KINDS — the
+gradient psum / psum_scatter / all_to_all family) and counts the
+equations that consume them, directly or transitively. This is the
+number flat state keeps small: one fused vector update where a per-leaf
+state would run a per-leaf scatter -> optimizer -> apply chain, with the
+forward/backward half of the program untouched (tests/test_flat_state.py
+pins it). Deterministic, CPU-only, nothing executes. The few post-reduce
+metrics ops (loss pmean consumers) are counted too.
 
 Sub-jaxpr handling mirrors walker.py: exact through the call-like
 primitives (jit / shard_map / remat / custom_*), conservative inside
@@ -29,30 +20,9 @@ can only raise the count, never hide de-fusion.
 
 from __future__ import annotations
 
-import re
-from typing import Any, Optional, Set, Tuple
+from typing import Any, Set, Tuple
 
 from .walker import COLLECTIVE_PRIMS, REDUCE_KINDS, _is_var, _open, _subjaxprs
-
-# one optimized-HLO instruction per line: "  %name = type op(...)" (the
-# ROOT marker is optional); parameters count too — they appear in both
-# layouts and wash out of any ratio
-_HLO_INSTR = re.compile(r"^\s+(?:ROOT\s+)?[%\w.-]+\s*=\s")
-
-
-def hlo_op_count(hlo_text: str) -> int:
-    """Instruction count of an (optimized) HLO module's text dump."""
-    return sum(1 for line in hlo_text.splitlines() if _HLO_INSTR.match(line))
-
-
-def compiled_op_count(fn, *args) -> Optional[int]:
-    """hlo_op_count of ``fn.lower(*args).compile()``; None when the
-    function cannot be lowered/compiled here (e.g. a backend mismatch) —
-    callers record the absence rather than a wrong number."""
-    try:
-        return hlo_op_count(fn.lower(*args).compile().as_text())
-    except Exception:
-        return None
 
 
 def _total_eqns(jaxpr) -> int:
@@ -130,7 +100,7 @@ def _forward_count(jaxpr, tainted: Set[Any]) -> Tuple[int, Set[Any]]:
 
 def update_path_op_count(fn, *args) -> int:
     """Number of jaxpr equations downstream of the gradient reduce in
-    ``fn(*args)`` — the update-path size the flat state layout collapses.
+    ``fn(*args)`` — the update-path size flat state keeps small.
     Traces only (ShapeDtypeStruct args are fine); nothing executes."""
     import jax
 

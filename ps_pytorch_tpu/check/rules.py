@@ -35,12 +35,7 @@
 |        | still declare its grad-reduce requirement — so PSC102's         |
 |        | dataflow rule keeps pinning the masked reduce — and its         |
 |        | gradient-path reduce bytes must stay inside the declared        |
-|        | envelope: adaptation reshapes values, never wire bytes. The     |
-|        | same discipline covers adaptive per-bucket precision: a config  |
-|        | declaring a PrecisionPolicy (traced tag vector, PSConfig.       |
-|        | precision_adapt) keeps grad_reduce declared and its reduce      |
-|        | bytes inside the precision envelope — a tag picks the LATTICE   |
-|        | the values occupy, never the payload's size                     |
+|        | envelope: adaptation reshapes values, never wire bytes          |
 | PSC109 | schedule-variance on the pipelined wire: a config declaring an  |
 |        | OverlapPolicy (PSConfig.overlap="pipelined") must move EXACTLY  |
 |        | the gradient-path reduce bytes of its named serial twin (same   |
@@ -51,13 +46,13 @@
 |        | dataflow guarantee holds PER BUCKET — a "pipelined" config      |
 |        | whose wire quietly re-fused into one barrier eqn fails          |
 | PSC110 | undeclared host-consensus for adaptive configs: a config        |
-|        | declaring an AdaptivePolicy (or PrecisionPolicy) must NAME the  |
-|        | host-consensus point (``.consensus``, a package-relative dotted |
-|        | path) that agrees the traced values across processes, and that  |
-|        | must resolve in pslint's consensus inventory (lint/diverge.py:  |
-|        | a function whose return passes through broadcast_one_to_all /   |
-|        | process_allgather) — an adaptive knob with no consensus point   |
-|        | is PR 7's per-host agg_count tear waiting to recur              |
+|        | declaring an AdaptivePolicy must NAME the host-consensus point  |
+|        | (``.consensus``, a package-relative dotted path) that agrees    |
+|        | the traced count across processes, and that must resolve in     |
+|        | pslint's consensus inventory (lint/diverge.py: a function whose |
+|        | return passes through broadcast_one_to_all / process_allgather) |
+|        | — an adaptive knob with no consensus point is PR 7's per-host   |
+|        | agg_count tear waiting to recur                                 |
 | PSC111 | fresh or mismatched scale rows: every dequantize's scale must   |
 |        | be a dataflow descendant of the SAME max-abs reduction that     |
 |        | produced its quantize's scale, across every hop of the 2round   |
@@ -283,38 +278,6 @@ def psc108_adaptive(r: TraceResult) -> List[CheckFinding]:
     return out
 
 
-def psc108_precision(r: TraceResult) -> List[CheckFinding]:
-    """The adaptive-precision half of PSC108: a config taking a traced
-    per-bucket tag vector (PrecisionPolicy) keeps the same discipline as
-    the traced mask count — (a) a grad_reduce declaration so PSC102 pins
-    the (re-lattice'd) reduce's dataflow, and (b) the gradient-path
-    reduce bytes inside the declared envelope: a tag selects which
-    LATTICE a bucket's values occupy inside the same physical payload
-    (the traced clipping peak), so per-tag payload resizes or side
-    channels are wire regressions, not adaptation."""
-    pp = r.spec.precision
-    if pp is None:
-        return []
-    out = []
-    if not r.spec.grad_reduce:
-        out.append(CheckFinding(
-            "PSC108", r.spec.name,
-            "adaptive precision declared but no grad_reduce requirement "
-            "— without it PSC102 cannot pin the tagged reduce's dataflow "
-            "to the updated params",
-        ))
-    got = _grad_reduce_bytes(r)
-    if got > pp.envelope_bytes:
-        out.append(CheckFinding(
-            "PSC108", r.spec.name,
-            f"gradient-path reduce collectives move {got} B, but the "
-            f"precision envelope ({pp.n_buckets} traced bucket tags) "
-            f"declares at most {pp.envelope_bytes} B — precision tags "
-            f"must reshape values on the lattice, not add wire bytes",
-        ))
-    return out
-
-
 def _grad_reduce_bytes(r: TraceResult) -> int:
     return sum(
         c.bytes
@@ -399,42 +362,32 @@ def psc110_consensus(results: Sequence[TraceResult]) -> List[CheckFinding]:
 
     out: List[CheckFinding] = []
     inventory = None
-    # (policy object, traced-knob label, example) per adaptive surface:
-    # the mask count and the precision tag vector carry the same torn-
-    # traced-value hazard, so both must name an inventory-backed point
-    knobs = (
-        ("adaptive", "traced aggregation count",
-         "trainer.Trainer._count_consensus"),
-        ("precision", "traced per-bucket precision tag vector",
-         "trainer.Trainer._tags_consensus"),
-    )
     for r in results:
-        for attr, what, example in knobs:
-            pol = getattr(r.spec, attr, None)
-            if pol is None:
-                continue
-            if not pol.consensus:
-                out.append(CheckFinding(
-                    "PSC110", r.spec.name,
-                    f"{type(pol).__name__} declares a {what} but no "
-                    f"host-consensus point — each process would adapt "
-                    f"on its own telemetry and feed the step torn "
-                    f"values; name the function that agrees them "
-                    f"(e.g. '{example}')",
-                ))
-                continue
-            if inventory is None:
-                inventory = consensus_inventory()
-            if pol.consensus not in inventory:
-                known = ", ".join(sorted(inventory)) or "none found"
-                out.append(CheckFinding(
-                    "PSC110", r.spec.name,
-                    f"declared host-consensus point '{pol.consensus}' "
-                    f"is not in the package's consensus inventory "
-                    f"(functions whose return passes through "
-                    f"broadcast_one_to_all/process_allgather; known: "
-                    f"{known}) — renamed, or no longer consensus-shaped",
-                ))
+        pol = r.spec.adaptive
+        if pol is None:
+            continue
+        if not pol.consensus:
+            out.append(CheckFinding(
+                "PSC110", r.spec.name,
+                "AdaptivePolicy declares a traced aggregation count but "
+                "no host-consensus point — each process would adapt on "
+                "its own telemetry and feed the step torn values; name "
+                "the function that agrees them (e.g. "
+                "'trainer.Trainer._count_consensus')",
+            ))
+            continue
+        if inventory is None:
+            inventory = consensus_inventory()
+        if pol.consensus not in inventory:
+            known = ", ".join(sorted(inventory)) or "none found"
+            out.append(CheckFinding(
+                "PSC110", r.spec.name,
+                f"declared host-consensus point '{pol.consensus}' "
+                f"is not in the package's consensus inventory "
+                f"(functions whose return passes through "
+                f"broadcast_one_to_all/process_allgather; known: "
+                f"{known}) — renamed, or no longer consensus-shaped",
+            ))
     return out
 
 
@@ -681,7 +634,6 @@ def check_result(r: TraceResult) -> List[CheckFinding]:
         + psc106_fusion(r)
         + psc107_serve(r)
         + psc108_adaptive(r)
-        + psc108_precision(r)
         + psc111_scale_provenance(r)
         + psc112_error_feedback(r)
         + psc113_capacity(r)

@@ -20,12 +20,12 @@ optionally wrapped in the native C++ codec (ops/codec.py, the Blosc-role
 equivalent: reference compression.py w_compress wraps checkpointed weights
 too). Compressed files carry a 'PSCK' magic; load auto-detects either form.
 
-Layout neutrality: checkpoints are TREE-SHAPED at this boundary even when
-the live state is flat (PSConfig.state_layout="flat" — params/moments as
-padded flat vectors). parallel.buckets.FlatVector registers serialization
-handlers that convert at the edge, so a flat-state run's checkpoint is
-byte-compatible with a tree-state run's, pre-flat-state checkpoints load
-unchanged, and nothing in THIS module knows which layout produced a file.
+Layout neutrality: checkpoints are TREE-SHAPED at this boundary though
+the live PS state is flat (params/moments as padded flat vectors).
+parallel.buckets.FlatVector registers serialization handlers that
+convert at the edge, so a file is byte-compatible with the tree-state
+ones earlier versions wrote, those load unchanged, and nothing in THIS
+module knows the live layout.
 
 Integrity (resilience layer): every file ends with an 8-byte CRC32
 trailer — b'PSC1' + crc32(everything before it) — written inside the same

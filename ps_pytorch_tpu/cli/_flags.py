@@ -101,14 +101,7 @@ def add_train_flags(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     parser.add_argument("--adapt-window", type=int, default=d.adapt_window,
                         help="adaptive aggregation window (steps): how often "
                              "the mask count is re-picked from step-time "
-                             "stats (with --num-aggregate-min/max); also the "
-                             "--precision-adapt telemetry window")
-    parser.add_argument("--wire-budget-bytes", type=int, default=None,
-                        help="with --precision-adapt: cap the per-step "
-                             "EFFECTIVE gradient wire bytes — over budget "
-                             "the controller downgrades the lowest-density "
-                             "buckets one lattice notch at a time (never "
-                             "below 4-bit)")
+                             "stats (with --num-aggregate-min/max)")
     return parser
 
 
@@ -186,14 +179,6 @@ def add_ps_flags(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                              "the CPU A/B shows parity (XLA:CPU runs "
                              "collectives synchronously) — the "
                              "latency-hiding win needs a TPU run to bank")
-    parser.add_argument("--state-layout", type=str, default="flat",
-                        choices=("tree", "flat"),
-                        help="where master params/optimizer moments live: "
-                             "flat (default) = padded flat f32 vectors in "
-                             "the wire's bucket geometry (one fused vector "
-                             "update per step), tree = legacy per-leaf "
-                             "pytree. Compute-side only — wire bytes and "
-                             "checkpoints are identical either way")
     parser.add_argument("--quant-rounding", type=str, default="nearest",
                         choices=("nearest", "stochastic"),
                         help="stochastic = unbiased gradient quantization")
@@ -210,15 +195,6 @@ def add_ps_flags(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                              "reassembly ships int8 instead of f32). "
                              "Needs a --compress-grad mode and nearest "
                              "rounding")
-    parser.add_argument("--precision-adapt", action="store_true",
-                        help="adaptive per-bucket precision: the train step "
-                             "takes a traced skip/4-bit/int8/hi tag per wire "
-                             "bucket (no retrace on change) and a windowed "
-                             "gradient-norm controller re-picks the tags "
-                             "every --adapt-window steps, optionally under "
-                             "--wire-budget-bytes (needs a --compress-grad "
-                             "mode, --bucket-bytes >= 0 and nearest "
-                             "rounding; EF absorbs the added error)")
     parser.add_argument("--opt-placement", type=str, default="replicated",
                         choices=("replicated", "sharded"),
                         help="where optimizer state lives (sharded = ZeRO-1 PS)")
@@ -390,7 +366,6 @@ def train_config_from(args: argparse.Namespace) -> TrainConfig:
         max_consecutive_skips=args.max_consecutive_skips,
         fault_plan=args.fault_plan,
         adapt_window=args.adapt_window,
-        wire_budget_bytes=args.wire_budget_bytes,
     )
 
 
@@ -422,10 +397,8 @@ def ps_config_from(args: argparse.Namespace, num_workers: int) -> PSConfig:
         bucket_bytes=(
             None if args.bucket_bytes < 0 else args.bucket_bytes
         ),
-        state_layout=args.state_layout,
         overlap="pipelined" if args.overlap == "on" else "serial",
         error_feedback=args.error_feedback,
-        precision_adapt=args.precision_adapt,
         opt_placement=args.opt_placement,
         bn_mode=args.bn_mode,
         grad_accum_steps=args.grad_accum_steps,

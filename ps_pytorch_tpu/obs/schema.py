@@ -106,15 +106,6 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "window_steps"),
         doc="adaptive partial-aggregation count change at a window close",
     ),
-    "precision_adapt": EventSpec(
-        required=("step", "window_start", "changed", "n_skip", "n_4bit",
-                  "n_int8", "n_hi", "effective_bytes", "budget_bytes"),
-        int_fields=("step", "window_start", "changed", "n_skip", "n_4bit",
-                    "n_int8", "n_hi", "effective_bytes", "budget_bytes"),
-        doc="adaptive per-bucket precision retag at a window close: the "
-            "tag histogram plus the effective wire bytes it prices "
-            "(budget_bytes 0 = no --wire-budget-bytes cap)",
-    ),
     "resume_reshape": EventSpec(
         required=("step", "from", "to"),
         int_fields=("step",),

@@ -211,190 +211,46 @@ def dequantize_int8(
     return out
 
 
-# ------------------------------------------ int4 lattice codec + traced peak
-
-_INT8_PEAK = 127  # symmetric int8 payloads live in [-127, 127]
-_INT4_PEAK = 7    # symmetric int4 payloads live in [-7, 7] (two per byte)
-_INT4_BIAS = 8    # nibble storage bias: value + 8 in [1, 15]
-
-# per-bucket precision tags (the adaptive-precision wire,
-# PSConfig.precision_adapt): a traced int32 per bucket selects the
-# lattice peak that bucket quantizes onto THIS window. The payload's
-# static dtype (and therefore the traced program and its physical wire
-# bytes) never changes — adaptation reshapes VALUES, never bytes
-# (PSC108's stance); the per-tag EFFECTIVE bytes (what a byte-honest
-# transport ships: 0, half, one, or payload-width bytes per element)
-# are the controller's budget currency and telemetry evidence.
-PREC_SKIP = 0   # peak 0: q == 0, scale == 0 — EF keeps the whole gradient
-PREC_4BIT = 1   # peak 7: the int4 lattice (pack_int4 ships 2/byte)
-PREC_INT8 = 2   # peak 127: the committed-contract int8 lattice
-PREC_HI = 3     # peak precision_hi_peak(cfg): finest the payload carries
-PRECISION_TAGS = (PREC_SKIP, PREC_4BIT, PREC_INT8, PREC_HI)
-PRECISION_TAG_NAMES = ("skip", "4bit", "int8", "hi")
-
-
-def precision_peaks(hi_peak: int) -> np.ndarray:
-    """The tag -> lattice-peak table (f32, indexable by a traced tag)."""
-    return np.asarray(
-        [0.0, float(_INT4_PEAK), float(_INT8_PEAK), float(hi_peak)],
-        np.float32,
-    )
-
-
-def precision_bytes_per_element(hi_peak: int) -> Tuple[float, ...]:
-    """Effective wire bytes per f32 gradient element by tag: skip ships
-    nothing, int4 packs two values per byte, int8 one, and the HI tag
-    costs the minimal integer width that holds its peak."""
-    hi_bytes = 1.0 if hi_peak <= _INT8_PEAK else (
-        2.0 if hi_peak <= 2 ** 15 - 1 else 4.0
-    )
-    return (0.0, 0.5, 1.0, hi_bytes)
-
-
-def pack_int4(q: jax.Array) -> jax.Array:
-    """Pack int4 lattice values (int8 storage, each in [-7, 7]) two per
-    byte: value + 8 in the low/high nibble of a uint8. Odd-length
-    (flattened) inputs pad the final high nibble with the bias (value
-    0), so ``unpack_int4(pack_int4(q), q.size)`` round-trips any bucket
-    length — the carved buckets the adaptive wire prices at size/2
-    effective bytes are exactly this codec's output size."""
-    flat = q.reshape(-1).astype(jnp.int8)
-    n = flat.shape[0]
-    flat = jnp.pad(flat, (0, n % 2))
-    lo = (flat[0::2] + _INT4_BIAS).astype(jnp.uint8)
-    hi = (flat[1::2] + _INT4_BIAS).astype(jnp.uint8)
-    return lo | (hi << 4)
-
-
-def unpack_int4(packed: jax.Array, n: int) -> jax.Array:
-    """Invert ``pack_int4``: uint8 [ceil(n/2)] -> int8 [n] in [-7, 7]."""
-    lo = (packed & 0xF).astype(jnp.int8) - _INT4_BIAS
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8) - _INT4_BIAS
-    return jnp.stack([lo, hi], axis=1).reshape(-1)[:n]
-
-
-def quantize_lattice(
-    x: jax.Array,
-    peak,
-    axis_name=None,
-    block_size: int = 0,
-    hi_peak: int = _INT8_PEAK,
-    out_dtype=jnp.int8,
-) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric quantize onto a lattice whose peak may be TRACED — the
-    adaptive-precision generalization of ``quantize_int8`` (identical
-    arithmetic and scale geometry at ``peak == 127``: same ``peak /
-    absmax`` association, same pmax-shared scales, so an all-int8 tag
-    vector is bit-exact against the static path).
-
-    ``peak`` is a scalar from {0, 7, 127, hi_peak} selected by a traced
-    per-bucket tag (ops-level it is just any non-negative scalar): peak
-    0 gives ``q == 0`` and ``scale == 0`` — the SKIP tag's semantics,
-    the bucket contributes nothing and error feedback keeps the whole
-    gradient as residual. The traced clamp at ±peak is what bounds the
-    runtime values; the OUTER STATIC clamp at ±``hi_peak`` is redundant
-    at runtime (peak <= hi_peak by construction) but is what lets the
-    psnumerics analyzer (check/numerics.py carries scalar bounds only
-    through static clamps) prove PSC113's accumulation-capacity bound
-    for the adaptive wire. Runs on the jnp path — the Pallas kernels
-    stay the static int8 hot path.
-
-    Returns ``(q, scale)``: q in ``out_dtype`` (the wire payload dtype:
-    int8 when hi_peak <= 127, else the minimal wider int), scale =
-    absmax / peak (0 where peak == 0). Per-tensor or per-block geometry
-    exactly as ``quantize_int8``."""
-    x = x.astype(jnp.float32)
-    peak_f = jnp.asarray(peak, jnp.float32)
-
-    def finish(xb, absmax):
-        inv = jnp.where(absmax > 0, peak_f / jnp.maximum(absmax, 1e-30), 0.0)
-        q = jnp.round(xb * inv)
-        q = jnp.clip(q, -peak_f, peak_f)  # traced bound: exact at runtime
-        q = jnp.clip(q, -float(hi_peak), float(hi_peak)).astype(out_dtype)
-        scale = jnp.where(
-            peak_f > 0, absmax / jnp.maximum(peak_f, 1.0), 0.0
-        )
-        return q, scale
-
-    if block_size:
-        flat = x.reshape(-1)
-        n = flat.shape[0]
-        nb = -(-n // block_size)
-        flat = jnp.pad(flat, (0, nb * block_size - n))
-        xb = flat.reshape(nb, block_size)
-        absmax = jnp.max(jnp.abs(xb), axis=1, keepdims=True)
-        if axis_name is not None:
-            absmax = lax.pmax(absmax, axis_name)
-        return finish(xb, absmax)
-    absmax = jnp.max(jnp.abs(x))
-    if axis_name is not None:
-        absmax = lax.pmax(absmax, axis_name)
-    return finish(x, absmax)
-
-
-def quantize_int4(
-    x: jax.Array,
-    axis_name=None,
-    block_size: int = 0,
-) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric 4-bit quantization: the int8 scheme's exact geometry
-    (same block carving, same pmax-shared scales) at peak 7. Returns
-    ``(q, scale)`` with q as int8 STORAGE in [-7, 7] — ``pack_int4``
-    ships two values per byte. Nearest rounding only (the int4 lattice
-    exists for the shared-scale homomorphic wire, where per-worker
-    stochastic draws are incoherent)."""
-    return quantize_lattice(
-        x, float(_INT4_PEAK), axis_name=axis_name, block_size=block_size,
-        hi_peak=_INT4_PEAK, out_dtype=jnp.int8,
-    )
-
-
 # ------------------------------------------- homomorphic (compressed-domain)
 
 
-def accum_capacity(dtype_name: str, peak: int = _INT8_PEAK) -> int:
-    """Largest number of full-scale (|q| = ``peak``) lattice payloads
-    whose sum provably fits ``dtype_name``: floor(dtype_max / peak).
-    The int8 lattice (peak 127) gives int16 a capacity of 258 workers;
-    the int4 lattice (peak 7) more than doubles the doubling — 4681
-    workers before the homomorphic int16 wire must widen."""
+_INT8_PEAK = 127  # symmetric int8 payloads live in [-127, 127]
+
+
+def accum_capacity(dtype_name: str) -> int:
+    """Largest number of full-scale (|q| = 127) int8 payloads whose sum
+    provably fits ``dtype_name``: floor(dtype_max / 127). int16 holds
+    258 workers (258 * 127 = 32766 <= 32767), int32 holds 16_909_320
+    (16_909_320 * 127 = 2_147_483_640 <= 2^31 - 1)."""
     bits = {"int16": 15, "int32": 31}[dtype_name]
-    return (2 ** bits - 1) // int(peak)
+    return (2 ** bits - 1) // _INT8_PEAK
 
 
-# the int8-lattice capacity table (the committed-contract wire): int16
-# holds 258 (258 * 127 = 32766 <= 32767), int32 holds 16_909_320
-# (16_909_320 * 127 = 2_147_483_640 <= 2^31 - 1). Peak-generalized
-# lookups go through accum_capacity(dtype, peak).
 ACCUM_CAPACITY = {
     "int16": accum_capacity("int16"),
     "int32": accum_capacity("int32"),
 }
 
 
-def accum_dtype(num_summands: int, peak: int = _INT8_PEAK):
+def accum_dtype(num_summands: int):
     """Smallest integer dtype whose range provably holds a sum of
-    ``num_summands`` full-scale lattice payloads of ``|q| <= peak`` —
-    the wire dtype of a homomorphic psum (collectives.quantized_psum
-    with wire_domain="homomorphic"). The sum of n values in [-peak,
-    peak] is bounded by n * peak, so the choice is a static function of
-    the mesh size and the lattice: on the int8 lattice (peak 127) int16
-    carries 258 workers (2 bytes/element on the wire vs 4 for the
-    dequant path's int32); on the int4 lattice (peak 7) int16 carries
-    4681. Beyond int32's capacity no supported accumulator is exact —
-    raise rather than wrap."""
+    ``num_summands`` full-scale int8 payloads — the wire dtype of a
+    homomorphic psum (collectives.quantized_psum with
+    wire_domain="homomorphic"). The sum of n values in [-127, 127] is
+    bounded by n * 127, so the choice is a static function of the mesh
+    size: int16 carries 258 workers (2 bytes/element on the wire vs 4
+    for the dequant path's int32). Beyond int32's capacity no supported
+    accumulator is exact — raise rather than wrap."""
     if num_summands < 1:
         raise ValueError(f"accum_dtype needs >= 1 summand, got {num_summands}")
-    if peak < 1:
-        raise ValueError(f"accum_dtype needs peak >= 1, got {peak}")
-    if num_summands <= accum_capacity("int16", peak):
+    if num_summands <= ACCUM_CAPACITY["int16"]:
         return jnp.int16
-    if num_summands <= accum_capacity("int32", peak):
+    if num_summands <= ACCUM_CAPACITY["int32"]:
         return jnp.int32
     raise ValueError(
         f"homomorphic accumulation over {num_summands} full-scale "
-        f"peak-{peak} payloads can overflow int32 (capacity "
-        f"{accum_capacity('int32', peak)}) — use wire_domain='dequant'"
+        f"int8 payloads can overflow int32 (capacity "
+        f"{ACCUM_CAPACITY['int32']}) — use wire_domain='dequant'"
     )
 
 

@@ -29,7 +29,7 @@ def build_optimizer(
     flat: bool = False,
 ) -> optax.GradientTransformation:
     """``flat=True`` returns the whole-vector variant (sgd_flat/adam_flat)
-    for ``PSConfig.state_layout="flat"`` — bit-identical math on the
+    the PS trainer's flat state takes — bit-identical math on the
     padded flat state, no per-leaf tree_map. The tree transforms also
     ACCEPT flat operands (a tree_map over one vector leaf is one vector
     op), so flat is an explicitness/efficiency choice, not a correctness

@@ -95,7 +95,7 @@ def adam_flat(
     amsgrad: bool = False,
 ) -> optax.GradientTransformation:
     """``adam()`` specialized to ONE flat f32 vector — the fused update
-    path for ``PSConfig.state_layout="flat"`` (see optim/sgd.sgd_flat).
+    path for the PS trainer's flat state (see optim/sgd.sgd_flat).
 
     Same math, same ``AdamState`` skeleton; both moments (and the
     AMSGrad max) are whole vectors, so the entire update is one fused

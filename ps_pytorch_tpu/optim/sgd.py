@@ -42,8 +42,8 @@ def _lr_at(lr: ScalarOrSchedule, count):
 
 def _unwrap_vec(x):
     """(vector, rewrap) for a flat-update operand: a bare jnp vector
-    passes through; a ``parallel.buckets.FlatVector`` (state_layout=
-    "flat" master params/moments) contributes its padded buffer and a
+    passes through; a ``parallel.buckets.FlatVector`` (the PS state's
+    master params/moments) contributes its padded buffer and a
     rewrap that preserves the static layout metadata."""
     from ..parallel.buckets import FlatVector  # lazy: optim stays light
 
@@ -106,7 +106,7 @@ def sgd_flat(
     nesterov: bool = False,
 ) -> optax.GradientTransformation:
     """``sgd()`` specialized to ONE flat f32 vector — the fused update
-    path for ``PSConfig.state_layout="flat"``.
+    path for the PS trainer's flat state.
 
     Identical math, identical ``SGDState`` skeleton (so checkpoints are
     interchangeable with the tree transform), but weight decay, the

@@ -31,7 +31,7 @@ enumeration index — position-stable derivation, so a bucket's noise
 stream is a function of where its bytes live, not of how many buckets
 precede it (collectives.py ``key_offsets``).
 
-``FlatVector`` is the third layer (PSConfig.state_layout="flat"): a
+``FlatVector`` is the third layer, the PS state's own layout: a
 param-shaped quantity — master params, an optimizer moment — stored AS
 the padded flat f32 vector, with its TreeLayout/BucketPlan riding along
 as static pytree metadata. The tree view exists only where the forward
@@ -39,8 +39,8 @@ pass needs it (``flat_to_tree``, slices XLA fuses away); the optimizer
 update, the non-finite-guard rollback, and the wire all operate on the
 whole vector. Checkpoints stay TREE-SHAPED at the save/restore boundary:
 FlatVector registers flax serialization handlers that convert at the
-edge, so checkpoints are bit-portable across ``state_layout`` (and
-``bucket_bytes``), and pre-flat-state checkpoints load unchanged.
+edge, so checkpoints are bit-portable across ``bucket_bytes``, and
+pre-flat-state (tree-state) checkpoints load unchanged.
 """
 
 from __future__ import annotations
@@ -317,7 +317,7 @@ def pad_flat(flat: jax.Array, plan: BucketPlan) -> jax.Array:
 
 @flax.struct.dataclass
 class FlatVector:
-    """One param-shaped quantity stored flat (state_layout="flat").
+    """One param-shaped quantity stored flat.
 
     ``flat`` is the alignment-padded f32 vector in ``plan``'s geometry
     (``plan.padded_total`` elements; the pad tail is zero and never feeds
@@ -330,8 +330,8 @@ class FlatVector:
 
     Serialization converts at the edge (see ``_flatvector_to_state_dict``
     below): a FlatVector's state dict is the TREE-shaped nested dict of
-    its leaves, so checkpoints written from a flat-state run are
-    byte-compatible with tree-state runs and with pre-flat checkpoints.
+    its leaves, so checkpoints are byte-compatible with the tree-state
+    ones earlier versions wrote.
     """
 
     flat: jax.Array
@@ -344,7 +344,8 @@ class FlatVector:
 
 
 def tree_view(params):
-    """Tree view of a params-like object under either state layout."""
+    """Tree view of a params-like object: a FlatVector's, or the pytree
+    itself (the LM engines' state)."""
     if isinstance(params, FlatVector):
         return params.tree()
     return params
@@ -392,7 +393,7 @@ def _flatvector_to_state_dict(fv: FlatVector):
 def _flatvector_from_state_dict(fv: FlatVector, state) -> FlatVector:
     # the stored dict is tree-shaped (this handler wrote it, or the
     # checkpoint predates flat state); rebuild the padded vector in the
-    # TARGET's geometry — portability across bucket_bytes/state_layout
+    # TARGET's geometry — portability across bucket_bytes
     # falls out, because the tree is the interchange format
     template = _np_flat_to_tree(
         fv.layout, np.zeros((fv.plan.padded_total,), np.float32)
@@ -430,8 +431,8 @@ def piece_stream(tree, bucket_bytes, align: int = 1,
     - ``rebuild``: maps the per-piece aggregation results (same shapes
       as ``pieces``) back to the original tree structure, restoring
       every leaf's dtype/shape and dropping alignment padding — or, with
-      ``flat_output=True`` (state_layout="flat": the consumer is the
-      fused vector update, not a per-leaf optimizer), to ONE padded flat
+      ``flat_output=True`` (the PS step: the consumer is the fused
+      vector update, not a per-leaf optimizer), to ONE padded flat
       f32 vector in the same ``align`` geometry, skipping the per-leaf
       scatter entirely. The pieces (and therefore the wire) are
       IDENTICAL either way — flat_output changes only the rebuild.

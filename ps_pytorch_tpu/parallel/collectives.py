@@ -27,53 +27,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.quantize import (
-    _INT8_PEAK,
     accum_dtype,
     accumulate_rescale_int8,
     dequantize_int8,
     quantize_int8,
-    quantize_lattice,
 )
 from .buckets import piece_stream
-
-
-# ----------------------------------------- adaptive per-bucket precision
-# (PSConfig.precision_adapt): ``bucket_peaks`` is a traced f32 [n_buckets]
-# vector of lattice peaks (0 | 7 | 127 | hi_peak, one per bucket of the
-# wire's BucketPlan in CANONICAL order) selecting each bucket's
-# quantization lattice THIS step. Every scheme resolves its piece's peak
-# through ``_bucket_ordinal`` and quantizes via ops.quantize_lattice —
-# the same shared-scale geometry, so the EF contribution mirror and the
-# homomorphic lattice algebra are unchanged. Requires a bucketed wire
-# (the tags are per-bucket) and nearest rounding (one shared lattice).
-
-
-def _bucket_ordinal(key_ids):
-    """Canonical bucket ordinal for each piece's PRNG key id. Bucketed
-    key_ids are START OFFSETS in the flat buffer, ascending in canonical
-    order, so a piece's ordinal is its offset's rank — stable across the
-    serial and pipelined (readiness-order) enumerations, which is what
-    lets ``bucket_peaks[ordinal]`` index one tag vector from either."""
-    order = sorted(key_ids)
-    return {i: order.index(i) for i in key_ids}
-
-
-def _lattice_payload_dtype(hi_peak: int):
-    """Minimal integer payload dtype holding the HI tag's peak — the
-    static wire dtype every tag of an adaptive bucket rides (values
-    adapt, bytes do not)."""
-    if hi_peak <= _INT8_PEAK:
-        return jnp.int8
-    if hi_peak <= 2 ** 15 - 1:
-        return jnp.int16
-    return jnp.int32
-
-
-def _resolve_peak(bucket_peaks, ordinal, i):
-    """This piece's traced lattice peak, or None on the static wire."""
-    if bucket_peaks is None:
-        return None
-    return bucket_peaks[ordinal[i]]
 
 
 def aggregation_mask(
@@ -141,10 +100,10 @@ def psum_mean(tree, axis_name: str, denominator: float,
     buckets instead of the raw leaves — bit-exact for f32 gradients
     (same values, same elementwise sum/divide), and the collective
     operands become a few contiguous buffers instead of one per leaf.
-    ``flat_output`` (state_layout="flat") returns the aggregate as one
-    padded flat vector instead of scattering it back into the tree; the
-    collectives themselves are identical (jax batches a whole-tree psum
-    into one eqn either way).
+    ``flat_output`` (what the PS step asks for: its state is flat)
+    returns the aggregate as one padded flat vector instead of
+    scattering it back into the tree; the collectives themselves are
+    identical (jax batches a whole-tree psum into one eqn either way).
 
     ``pipelined`` (PSConfig.overlap) emits ONE psum eqn per bucket, in
     readiness order, over buckets assembled from their own leaves — same
@@ -184,8 +143,6 @@ def quantized_psum(
     bucket_output: bool = False,
     wire_domain: str = "dequant",
     num_workers: Optional[int] = None,
-    bucket_peaks=None,
-    lattice_hi_peak: int = _INT8_PEAK,
 ):
     """int8-quantized gradient all-reduce.
 
@@ -213,20 +170,7 @@ def quantized_psum(
     latter collapses O(n_leaves) pmax+psum pairs into O(n_buckets), with
     bucket boundaries aligned to ``block_size`` so no scale row straddles
     buckets and PRNG keys folded by bucket start offset (position-stable).
-
-    ``bucket_peaks`` (adaptive per-bucket precision — see the module
-    section above) switches each bucket's quantize to the traced-peak
-    lattice. The psum operand's static dtype is unchanged on the
-    dequant wire unless the HI tag's peak exceeds int8 (then the
-    payload intermediate widens to the minimal int that holds it — the
-    int32 psum on the wire is byte-identical either way); the
-    homomorphic wire's payload already rides ``accum_dtype``.
     """
-    if bucket_peaks is not None and rounding == "stochastic":
-        raise ValueError(
-            "adaptive precision needs rounding='nearest' (the traced-"
-            "peak lattice is shared-scale by construction)"
-        )
     if wire_domain == "homomorphic":
         if num_workers is None:
             raise ValueError(
@@ -246,28 +190,13 @@ def quantized_psum(
     def one(i, g):
         g32 = g.astype(jnp.float32)
         leaf_key = jax.random.fold_in(key, i) if key is not None else None
-        peak = _resolve_peak(bucket_peaks, ordinal, i)
-        if peak is not None:
-            q, scale = quantize_lattice(
-                g32,
-                peak,
-                axis_name=axis_name,
-                block_size=block_size,
-                hi_peak=lattice_hi_peak,
-                out_dtype=(
-                    accum_dtype(num_workers)
-                    if wire_domain == "homomorphic"
-                    else _lattice_payload_dtype(lattice_hi_peak)
-                ),
-            )
-        else:
-            q, scale = quantize_int8(
-                g32,
-                axis_name=axis_name,
-                block_size=block_size,
-                rounding=rounding,
-                key=leaf_key,
-            )
+        q, scale = quantize_int8(
+            g32,
+            axis_name=axis_name,
+            block_size=block_size,
+            rounding=rounding,
+            key=leaf_key,
+        )
         if wire_domain == "homomorphic":
             # compressed-domain sum: narrow exact accumulator on the
             # wire, ONE deferred scale-multiply (the denominator folds
@@ -284,7 +213,6 @@ def quantized_psum(
         tree, bucket_bytes, align=block_size or 1, flat_output=flat_output,
         pipelined=pipelined, bucket_output=bucket_output,
     )
-    ordinal = None if bucket_peaks is None else _bucket_ordinal(key_ids)
     outs = []
     for i, g in zip(key_ids, pieces):
         with _bucket_scope(pipelined, i):
@@ -299,26 +227,18 @@ def _slice_len(total: int, n: int, block_size: int) -> int:
     return (-(-total // n) + bs - 1) // bs * bs
 
 
-def _q2r_scatter_stage(g32, axis_name, n, s, block_size, rounding, leaf_key,
-                       peak=None):
+def _q2r_scatter_stage(g32, axis_name, n, s, block_size, rounding, leaf_key):
     """Round 1 of the 2-round scheme for one flat padded [n*s] leaf:
     shared-scale int8 quantize -> all_to_all int8 -> local int32 sum ->
     dequantize MY region. Returns the f32 partial sum [s] — an int8-wire
-    reduce_scatter. ``peak`` (adaptive precision) swaps the quantize for
-    the traced-peak lattice; the a2a payload stays int8 (the 2-round
-    wire's HI tag is capped at the int8 peak its payload carries)."""
-    if peak is not None:
-        q1, scale1 = quantize_lattice(
-            g32, peak, axis_name=axis_name, block_size=block_size,
-        )
-    else:
-        q1, scale1 = quantize_int8(
-            g32,
-            axis_name=axis_name,  # shared (pmax) scales: replicated rows
-            block_size=block_size,
-            rounding=rounding,
-            key=leaf_key,
-        )
+    reduce_scatter."""
+    q1, scale1 = quantize_int8(
+        g32,
+        axis_name=axis_name,  # shared (pmax) scales: replicated rows
+        block_size=block_size,
+        rounding=rounding,
+        key=leaf_key,
+    )
     q1 = q1.reshape(n, s).astype(jnp.int8)
     # row j of the a2a result = device j's slice of MY region
     recv = lax.all_to_all(q1, axis_name, split_axis=0, concat_axis=0,
@@ -337,8 +257,7 @@ def _q2r_scatter_stage(g32, axis_name, n, s, block_size, rounding, leaf_key,
     return partial
 
 
-def _q2r_scatter_stage_hom(g32, wire_axis, scale_axes, n, s, block_size,
-                           peak=None):
+def _q2r_scatter_stage_hom(g32, wire_axis, scale_axes, n, s, block_size):
     """Homomorphic round 1 for one flat padded [n*s] piece: SHARED-scale
     (pmax over ``scale_axes`` — the whole reducing axis set, so one scale
     row set serves every worker) int8 quantize -> all_to_all int8 over
@@ -348,19 +267,10 @@ def _q2r_scatter_stage_hom(g32, wire_axis, scale_axes, n, s, block_size,
     (ops/quantize.accumulate_rescale_int8, one Pallas VPU pass on TPU).
     The scale rows cover the WHOLE padded vector and are replicated on
     every worker by the pmax, so any consumer can dequantize any region
-    with zero scale traffic. ``peak`` (adaptive precision) swaps in the
-    traced-peak lattice — the rescale/deferred-multiply algebra
-    downstream is peak-agnostic (|acc| <= n * peak <= n * 127 still
-    rescales into int8 range; a SKIP bucket's all-zero payload
-    dequantizes through its zero scale)."""
-    if peak is not None:
-        q1, scale1 = quantize_lattice(
-            g32, peak, axis_name=scale_axes, block_size=block_size
-        )
-    else:
-        q1, scale1 = quantize_int8(
-            g32, axis_name=scale_axes, block_size=block_size
-        )
+    with zero scale traffic."""
+    q1, scale1 = quantize_int8(
+        g32, axis_name=scale_axes, block_size=block_size
+    )
     q1 = q1.reshape(n, s).astype(jnp.int8)
     recv = lax.all_to_all(q1, wire_axis, split_axis=0, concat_axis=0,
                           tiled=True)
@@ -415,7 +325,6 @@ def quantized_allreduce_2round(
     pipelined: bool = False,
     bucket_output: bool = False,
     wire_domain: str = "dequant",
-    bucket_peaks=None,
 ):
     """Two-round int8 all-reduce whose WIRE traffic is actually int8.
 
@@ -455,11 +364,6 @@ def quantized_allreduce_2round(
     a shared lattice rescale).
     """
     n = num_workers
-    if bucket_peaks is not None and rounding == "stochastic":
-        raise ValueError(
-            "adaptive precision needs rounding='nearest' (the traced-"
-            "peak lattice is shared-scale by construction)"
-        )
     if wire_domain == "homomorphic" and rounding == "stochastic":
         raise ValueError(
             "homomorphic wire needs rounding='nearest' (per-worker "
@@ -478,10 +382,9 @@ def quantized_allreduce_2round(
         total = g32.shape[0]
         s = _slice_len(total, n, block_size)
         g32 = jnp.pad(g32, (0, n * s - total))
-        peak = _resolve_peak(bucket_peaks, ordinal, i)
         if wire_domain == "homomorphic":
             recv, scale1 = _q2r_scatter_stage_hom(
-                g32, axis_name, axis_name, n, s, block_size, peak=peak
+                g32, axis_name, axis_name, n, s, block_size
             )
             q2 = accumulate_rescale_int8(recv, denominator)
             full = lax.all_gather(q2, axis_name, tiled=True)  # int8, no
@@ -490,7 +393,7 @@ def quantized_allreduce_2round(
             return deq[:total].reshape(g.shape)  # denominator folded in
         leaf_key = jax.random.fold_in(key, i) if key is not None else None
         partial = _q2r_scatter_stage(
-            g32, axis_name, n, s, block_size, rounding, leaf_key, peak=peak
+            g32, axis_name, n, s, block_size, rounding, leaf_key
         )
         k2 = jax.random.fold_in(leaf_key, 1) if leaf_key is not None else None
         deq = _q2r_gather_stage(
@@ -502,7 +405,6 @@ def quantized_allreduce_2round(
         tree, bucket_bytes, align=block_size or 1, flat_output=flat_output,
         pipelined=pipelined, bucket_output=bucket_output,
     )
-    ordinal = None if bucket_peaks is None else _bucket_ordinal(key_ids)
     outs = []
     for i, g in zip(key_ids, pieces):
         with _bucket_scope(pipelined, i):
@@ -523,7 +425,6 @@ def quantized_allreduce_2round_hier(
     pipelined: bool = False,
     bucket_output: bool = False,
     wire_domain: str = "dequant",
-    bucket_peaks=None,
 ):
     """Hierarchical (DCN x ICI) bandwidth-honest int8 all-reduce that
     crosses DCN exactly ONCE per gradient element.
@@ -567,10 +468,6 @@ def quantized_allreduce_2round_hier(
             "homomorphic wire needs rounding='nearest' (per-worker "
             "stochastic noise is incoherent on a shared lattice)"
         )
-    if bucket_peaks is not None and rounding == "stochastic":
-        raise ValueError(
-            "adaptive precision (bucket_peaks) needs rounding='nearest'"
-        )
     if rounding == "stochastic":
         if key is None:
             raise ValueError("stochastic rounding needs a key")
@@ -584,10 +481,9 @@ def quantized_allreduce_2round_hier(
         total = g32.shape[0]
         s1 = _slice_len(total, per_host, block_size)
         g32 = jnp.pad(g32, (0, per_host * s1 - total))
-        peak = _resolve_peak(bucket_peaks, ordinal, i)
         # 1. ICI: shared-GLOBAL-scale quantize, int8 a2a, exact int sum
         recv1, scale1 = _q2r_scatter_stage_hom(
-            g32, ici_axis, axis_names, per_host, s1, block_size, peak=peak
+            g32, ici_axis, axis_names, per_host, s1, block_size
         )
         # 2. DCN hop forwards the accumulated payload on the SAME
         # lattice: fused accumulate+rescale /per_host back into int8
@@ -618,14 +514,11 @@ def quantized_allreduce_2round_hier(
         s1 = _slice_len(total, per_host, block_size)
         g32 = jnp.pad(g32, (0, per_host * s1 - total))
         leaf_key = jax.random.fold_in(key, i) if key is not None else None
-        peak = _resolve_peak(bucket_peaks, ordinal, i)
-        # 1. ICI reduce_scatter: my [s1] region of the host sum —
-        # the EF-mirrored transform, so the adaptive peak applies HERE;
-        # the DCN hop's requantization stays static int8 (untracked
-        # round-2-style noise, same as the flat scheme's round 2)
+        # 1. ICI reduce_scatter: my [s1] region of the host sum (the
+        # EF-mirrored transform; the DCN hop's requantization is
+        # untracked round-2-style noise, same as the flat scheme's)
         partial = _q2r_scatter_stage(
-            g32, ici_axis, per_host, s1, block_size, rounding, leaf_key,
-            peak=peak,
+            g32, ici_axis, per_host, s1, block_size, rounding, leaf_key
         )
         # 2. full 2-round over DCN on the region only
         s2 = _slice_len(s1, hosts, block_size)
@@ -648,7 +541,6 @@ def quantized_allreduce_2round_hier(
         tree, bucket_bytes, align=block_size or 1, flat_output=flat_output,
         pipelined=pipelined, bucket_output=bucket_output,
     )
-    ordinal = None if bucket_peaks is None else _bucket_ordinal(key_ids)
     outs = []
     for i, g in zip(key_ids, pieces):
         with _bucket_scope(pipelined, i):
@@ -664,56 +556,28 @@ def local_quantized_contribution(
     key: Optional[jax.Array] = None,
     bucket_bytes: Optional[int] = None,
     pipelined: bool = False,
-    bucket_peaks=None,
-    lattice_hi_peak: int = _INT8_PEAK,
 ):
     """What THIS worker's gradient becomes after its (shared-scale) int8
     round trip — the transmitted value whose difference from the true
     gradient is the error-feedback residual. Mirrors quantized_psum /
     round 1 of the 2-round scheme exactly (same scales, same rounding
     keys, same bucketing and key-fold discipline), so `residual = g -
-    contribution` is the real on-wire error.
-
-    ``bucket_peaks`` mirrors the adaptive-precision lattice: a tagged
-    bucket's transmitted value is its quantize_lattice round trip at the
-    same traced peak (skip buckets transmit exactly zero, so EF absorbs
-    the WHOLE gradient as residual). The mirror quantizes into the same
-    carrier dtype the wire's round-1 site uses
-    (``_lattice_payload_dtype(lattice_hi_peak)``): numerically the
-    transmitted value is q * scale regardless of carrier width, but
-    matching the wire's (dtype, shape) site geometry is what lets the
-    PSC112 analyzer prove this recomputed transform covers the wire's
-    own quantization site."""
+    contribution` is the real on-wire error."""
     if rounding == "stochastic":
         if key is None:
             raise ValueError("stochastic rounding needs a key")
         key = jax.random.fold_in(key, lax.axis_index(axis_name))
-    if bucket_peaks is not None and rounding == "stochastic":
-        raise ValueError(
-            "adaptive precision (bucket_peaks) needs rounding='nearest'"
-        )
 
     def one(i, g):
         g32 = g.astype(jnp.float32)
         leaf_key = jax.random.fold_in(key, i) if key is not None else None
-        peak = _resolve_peak(bucket_peaks, ordinal, i)
-        if peak is not None:
-            q, scale = quantize_lattice(
-                g32,
-                peak,
-                axis_name=axis_name,
-                block_size=block_size,
-                hi_peak=lattice_hi_peak,
-                out_dtype=_lattice_payload_dtype(lattice_hi_peak),
-            )
-        else:
-            q, scale = quantize_int8(
-                g32,
-                axis_name=axis_name,
-                block_size=block_size,
-                rounding=rounding,
-                key=leaf_key,
-            )
+        q, scale = quantize_int8(
+            g32,
+            axis_name=axis_name,
+            block_size=block_size,
+            rounding=rounding,
+            key=leaf_key,
+        )
         return dequantize_int8(
             q.astype(jnp.int32), scale, block_size=block_size, shape=g.shape
         )
@@ -721,7 +585,6 @@ def local_quantized_contribution(
     pieces, key_ids, rebuild = piece_stream(
         grads, bucket_bytes, align=block_size or 1, pipelined=pipelined
     )
-    ordinal = None if bucket_peaks is None else _bucket_ordinal(key_ids)
     return rebuild([one(i, g) for i, g in zip(key_ids, pieces)])
 
 
@@ -743,8 +606,6 @@ def aggregate_gradients(
     pipelined: bool = False,
     bucket_output: bool = False,
     wire_domain: str = "dequant",
-    bucket_peaks=None,
-    lattice_hi_peak: int = _INT8_PEAK,
 ):
     """The full PS aggregation: mask -> (bucket) -> (quantized) reduce -> / K.
 
@@ -754,13 +615,14 @@ def aggregate_gradients(
     share the same piece stream (buckets.piece_stream), so residuals
     mirror the transmitted values exactly in either granularity.
 
-    ``flat_output`` (state_layout="flat") returns the AGGREGATE as one
-    padded flat f32 vector — the shape the fused vector update consumes —
-    instead of scattering it back into the gradient tree. It is
-    compute-side only: the masking, quantization, and every collective
-    are byte-identical to the tree output, and the EF contribution (when
-    requested) stays TREE-shaped because the per-worker residual state is
-    per-leaf (checkpoint-portable across bucket/layout settings).
+    ``flat_output`` (what the PS step asks for: its state is flat)
+    returns the AGGREGATE as one padded flat f32 vector — the shape the
+    fused vector update consumes — instead of scattering it back into
+    the gradient tree. It is compute-side only: the masking,
+    quantization, and every collective are byte-identical to the tree
+    output, and the EF contribution (when requested) stays TREE-shaped
+    because the per-worker residual state is per-leaf
+    (checkpoint-portable across bucket settings).
 
     return_contribution=True additionally returns THIS worker's
     transmitted (post-mask, post-quantization-round-trip) value — what
@@ -788,17 +650,6 @@ def aggregate_gradients(
     selected set at every count without retracing."""
     if wire_domain not in ("dequant", "homomorphic"):
         raise ValueError(f"bad wire_domain {wire_domain!r}")
-    if bucket_peaks is not None:
-        if compress in (None, "none"):
-            raise ValueError(
-                "adaptive precision (bucket_peaks) needs a compress mode — "
-                "an uncompressed f32 wire has no lattice to retune"
-            )
-        if quant_rounding == "stochastic":
-            raise ValueError(
-                "adaptive precision (bucket_peaks) needs "
-                "quant_rounding='nearest'"
-            )
     if wire_domain == "homomorphic":
         if compress in (None, "none"):
             raise ValueError(
@@ -844,8 +695,6 @@ def aggregate_gradients(
             bucket_output=bucket_output,
             wire_domain=wire_domain,
             num_workers=num_workers,
-            bucket_peaks=bucket_peaks,
-            lattice_hi_peak=lattice_hi_peak,
         )
         contribution = None
     elif hier_2round:
@@ -867,7 +716,6 @@ def aggregate_gradients(
             pipelined=pipelined,
             bucket_output=bucket_output,
             wire_domain=wire_domain,
-            bucket_peaks=bucket_peaks,
         )
         contribution = None
     elif compress == "int8_2round":
@@ -884,7 +732,6 @@ def aggregate_gradients(
             pipelined=pipelined,
             bucket_output=bucket_output,
             wire_domain=wire_domain,
-            bucket_peaks=bucket_peaks,
         )
         contribution = None
     else:
@@ -916,7 +763,5 @@ def aggregate_gradients(
             key=contrib_key,
             bucket_bytes=bucket_bytes,
             pipelined=pipelined,
-            bucket_peaks=bucket_peaks,
-            lattice_hi_peak=lattice_hi_peak,
         )
     return agg, contribution

@@ -46,14 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import apply_model
 from ..ops.metrics import accuracy, cross_entropy_loss
-from ..ops.quantize import (
-    _INT8_PEAK,
-    accum_dtype,
-    dequantize_int8,
-    precision_peaks,
-    quantize_int8,
-    quantize_lattice,
-)
+from ..ops.quantize import accum_dtype, dequantize_int8, quantize_int8
 from ..resilience.guard import (
     init_guard_state,
     tree_all_finite,
@@ -65,8 +58,6 @@ from .buckets import (
     assemble_bucket,
     bucket_leaf_segments,
     concat_buckets,
-    flat_to_tree,
-    leaves_from_buckets,
     pad_flat,
     plan_buckets,
     readiness_bucket_order,
@@ -134,21 +125,6 @@ class PSConfig:
     # dequant wire is an envelope (EF absorbs the difference), while the
     # integer accumulation itself is bit-exact.
     wire_domain: str = "dequant"
-    # adaptive per-bucket precision (--precision-adapt): the train step
-    # takes a traced int32 vector of PER-BUCKET precision tags (one per
-    # state_plan bucket: 0=skip / 1=4-bit / 2=int8 / 3=hi) and quantizes
-    # each bucket onto the lattice its tag names — same block-scale
-    # geometry, shared scales, EF absorbing the extra error exactly as
-    # for static int8 — with NO retrace on tag change (the tag only
-    # selects the traced clipping peak). The host-side
-    # resilience/precision.PrecisionController picks tags per window
-    # from on-device per-bucket gradient-norm telemetry under a
-    # --wire-budget-bytes target. Value-domain adaptation: the physical
-    # trace bytes never change; the tags reshape what the fixed wire
-    # CARRIES (a 4-bit bucket's payload occupies 16 of 256 int8 code
-    # points), so the budget currency is EFFECTIVE bytes. Needs a
-    # compress mode, a bucketed wire, and nearest rounding.
-    precision_adapt: bool = False
     # gradient wire granularity (parallel/buckets.py): None = legacy
     # message-per-leaf collectives (the reference's tag-88+l shape), 0 =
     # ONE fused flat f32 buffer, N = ~N-byte contiguous buckets with
@@ -159,20 +135,6 @@ class PSConfig:
     # bucketing on, the non-finite guard reduces ONE fused isfinite over
     # the flat buffer instead of one per leaf.
     bucket_bytes: Optional[int] = None
-    # where the master params and optimizer moments LIVE (buckets.
-    # FlatVector): "flat" (default) keeps them as padded flat f32
-    # vectors in the same BucketPlan geometry the wire uses — the
-    # reduced flat gradient feeds ONE fused vector update, the tree
-    # view the forward pass needs is materialized once per step
-    # (slices XLA fuses away), the non-finite guard's rollback selects
-    # a handful of whole vectors instead of every leaf, and the ZeRO-1
-    # path drops its per-step tree_to_flat(params) because params
-    # already live flat in shard geometry. "tree" is the legacy
-    # per-leaf layout. Compute-side only: the wire (collective counts,
-    # bytes, quantization noise) is byte-identical either way, and
-    # checkpoints are tree-shaped at the save/restore boundary, so
-    # they stay bit-portable across both settings.
-    state_layout: str = "flat"
     # WHEN the wire moves (--overlap on|off): "serial" (default) reduces
     # after the whole backward — the committed-contract baseline schedule.
     # "pipelined" launches each bucket's collective as soon as its
@@ -180,13 +142,13 @@ class PSConfig:
     # fragments (no global-concat false dependency), streamed in
     # readiness order (reverse-topological bucket enumeration: the last
     # bucket's leaves backprop first), reduced by per-bucket collective
-    # eqns, and — under state_layout="flat" — consumed by PER-BUCKET
-    # optimizer updates as reductions land, so XLA's latency-hiding
-    # scheduler can interleave the wire with the remaining backward AND
-    # the update. Same buckets, same bytes, bit-identical values (PRNG
-    # keys fold bucket START OFFSETS, so the reordered enumeration draws
-    # identical noise; PSC109 pins byte equality against the serial
-    # twin). The per-bucket update requires elementwise optimizer
+    # eqns, and consumed by PER-BUCKET optimizer updates (the state is
+    # flat, in the wire's own geometry) as reductions land, so XLA's
+    # latency-hiding scheduler can interleave the wire with the
+    # remaining backward AND the update. Same buckets, same bytes,
+    # bit-identical values (PRNG keys fold bucket START OFFSETS, so the
+    # reordered enumeration draws identical noise; PSC109 pins byte
+    # equality against the serial twin). The per-bucket update requires elementwise optimizer
     # transforms with per-parameter state (the repo's sgd/adam families;
     # a global-norm-coupled transform would need the whole vector).
     overlap: str = "serial"
@@ -250,8 +212,6 @@ class PSConfig:
             raise ValueError(f"bad compress {self.compress!r}")
         if self.quant_rounding not in ("nearest", "stochastic"):
             raise ValueError(f"bad quant_rounding {self.quant_rounding!r}")
-        if self.state_layout not in ("tree", "flat"):
-            raise ValueError(f"bad state_layout {self.state_layout!r}")
         if self.overlap not in ("serial", "pipelined"):
             raise ValueError(
                 f"bad overlap {self.overlap!r} (serial | pipelined)"
@@ -306,24 +266,6 @@ class PSConfig:
             accum_dtype(self.num_workers)
         if self.error_feedback and self.compress in (None, "none"):
             raise ValueError("error_feedback needs a compress mode")
-        if self.precision_adapt:
-            if self.compress in (None, "none"):
-                raise ValueError(
-                    "precision_adapt needs a compress mode: an "
-                    "uncompressed f32 wire has no lattice to retune"
-                )
-            if self.bucket_bytes is None:
-                raise ValueError(
-                    "precision_adapt needs a bucketed wire: set "
-                    "bucket_bytes (0 = one fused buffer, N = ~N-byte "
-                    "buckets) — the tags are a per-BUCKET property"
-                )
-            if self.quant_rounding != "nearest":
-                raise ValueError(
-                    "precision_adapt needs quant_rounding='nearest': the "
-                    "per-worker stochastic draws are calibrated to the "
-                    "int8 lattice pitch, not a per-bucket traced one"
-                )
         if self.dynamic_loss_scale:
             if self.compress in (None, "none"):
                 raise ValueError("dynamic_loss_scale needs a compress mode")
@@ -414,13 +356,12 @@ class PSConfig:
 @flax.struct.dataclass
 class PSTrainState:
     step: jax.Array
-    # the master parameters: the model pytree (state_layout="tree") or a
-    # buckets.FlatVector — ONE padded flat f32 vector in the wire's
-    # BucketPlan geometry (state_layout="flat", the default). Either way
-    # checkpoints store the TREE shape (FlatVector converts at the
-    # serialization edge), so they are bit-portable across layouts.
+    # the master parameters: a buckets.FlatVector — ONE padded flat f32
+    # vector in the wire's BucketPlan geometry. Checkpoints store the
+    # TREE shape (FlatVector converts at the serialization edge), so a
+    # file is portable across bucket settings and repo versions.
     params: Any
-    # optax state; under "flat" + replicated placement the moments are
+    # optax state; under the replicated placement the moments are
     # FlatVectors too (same geometry, same tree-shaped checkpoint form)
     opt_state: Any
     batch_stats: Any
@@ -476,7 +417,7 @@ def _zero1_shard_size(total: int, cfg: PSConfig) -> int:
 
 
 def state_plan(cfg: PSConfig, total: int) -> BucketPlan:
-    """The flat-state geometry (state_layout="flat"): the SAME BucketPlan
+    """The flat-state geometry: the SAME BucketPlan
     the config's gradient wire uses, so the reduced flat gradient drops
     straight into the vector update with no re-layout. Replicated:
     ``bucket_bytes`` carving aligned to ``wire_align`` (None = one fused
@@ -486,30 +427,6 @@ def state_plan(cfg: PSConfig, total: int) -> BucketPlan:
     if cfg.opt_placement == "sharded":
         return _sharded_plan(cfg, total)
     return plan_buckets(total, cfg.bucket_bytes or 0, align=wire_align(cfg))
-
-
-def precision_hi_peak(cfg: PSConfig) -> int:
-    """The static clipping peak a PREC_HI (f32-passthrough-fidelity)
-    bucket quantizes to under this config's wire — the widest lattice
-    the scheme's narrowest integer hop can carry without overflow:
-
-    - ``int8_2round``: the all_to_all payload is int8 by construction
-      (flat round 2 / hier DCN hop / sharded a2a), so HI caps at 127 —
-      on the 2-round wire the HI tag just means "never downgrade".
-    - homomorphic ``int8``: payloads accumulate exactly in
-      ``accum_dtype(num_workers)``, so the peak is that dtype's max
-      over the worker count (4095 at 8 workers on int16) — an
-      adaptive-precision dividend of PR 14's capacity analysis.
-    - dequant ``int8``: the psum rides int32, bounded only by
-      2^31-1 over the worker count; capped at 32767 so a HI payload
-      never needs more than an int16 carrier.
-    """
-    n = cfg.num_workers
-    if cfg.compress == "int8_2round":
-        return _INT8_PEAK
-    if cfg.wire_domain == "homomorphic":
-        return min(int(jnp.iinfo(accum_dtype(n)).max) // n, 32767)
-    return min((2 ** 31 - 1) // n, 32767)
 
 
 def init_ps_state(
@@ -525,13 +442,10 @@ def init_ps_state(
 
     params_tree, batch_stats = init_model(model, rng, input_shape)
     total = _flat_padded_size(params_tree)
-    if cfg.state_layout == "flat":
-        # master params become ONE padded flat f32 vector in the wire's
-        # own BucketPlan geometry; the tree view is materialized per
-        # step inside the jitted program (and at the checkpoint edge)
-        params = to_flat_vector(params_tree, state_plan(cfg, total))
-    else:
-        params = params_tree
+    # master params become ONE padded flat f32 vector in the wire's
+    # own BucketPlan geometry; the tree view is materialized per
+    # step inside the jitted program (and at the checkpoint edge)
+    params = to_flat_vector(params_tree, state_plan(cfg, total))
     if cfg.opt_placement == "sharded":
         shard = _zero1_shard_size(total, cfg)
         flat_zeros = jnp.zeros((shard,), jnp.float32)
@@ -541,9 +455,9 @@ def init_ps_state(
             lambda x: jnp.broadcast_to(x, (cfg.num_workers,) + jnp.shape(x)), one_state
         )
     else:
-        # under "flat", params is a FlatVector: moments initialize as
-        # whole padded vectors carrying the same static layout (the
-        # checkpoint edge converts them tree-shaped like the params)
+        # params is a FlatVector: moments initialize as whole padded
+        # vectors carrying the same static layout (the checkpoint edge
+        # converts them tree-shaped like the params)
         opt_state = tx.init(params)
     if cfg.bn_mode == "local" and batch_stats:
         batch_stats = tree_map(
@@ -560,8 +474,8 @@ def init_ps_state(
             )
         else:
             # zero residual per worker per param leaf, worker-stacked —
-            # per-leaf in BOTH state layouts, so EF checkpoints stay
-            # portable across bucket/layout settings
+            # per-leaf though the state is flat, so EF checkpoints stay
+            # portable across bucket settings
             comm_state = tree_map(
                 lambda p: jnp.zeros(
                     (cfg.num_workers,) + jnp.shape(p), jnp.float32
@@ -730,41 +644,23 @@ def _pipelined_flat_update(tx, agg_buckets, opt_state, params: FlatVector,
 
 
 def _shard_reduce_bucket(bucket, size: int, axis, n: int, w, k, cfg,
-                         bkey, want_contrib: bool, peak=None,
-                         hi_peak: int = _INT8_PEAK):
+                         bkey, want_contrib: bool):
     """One bucket of the ZeRO-1 wire: (quantize) -> psum_scatter / int8
     all_to_all -> THIS worker's dequantized 1/n shard divided by the
     aggregation count. Shared by the serial and pipelined schedules so
     the per-bucket transform (and therefore the bytes and the values)
     can never diverge between them. Returns ``(g_shard [size//n],
-    contribution [size] or None)``.
-
-    ``peak`` (adaptive precision): a traced f32 scalar selecting this
-    bucket's lattice — quantize_lattice at that peak instead of the
-    static int8 quantizer, same shared scales, same downstream sums
-    (a lattice payload is just an int8-or-narrower payload with fewer
-    live code points; ``hi_peak`` bounds the static clip so the int
-    casts below stay exact)."""
+    contribution [size] or None)``."""
     s = size // n
     bsz = cfg.quant_block_size
     if cfg.compress in ("int8", "int8_2round"):
-        if peak is not None:
-            q, scale = quantize_lattice(
-                bucket,
-                peak,
-                axis_name=axis,
-                block_size=bsz,
-                hi_peak=hi_peak,
-                out_dtype=jnp.int32,
-            )
-        else:
-            q, scale = quantize_int8(
-                bucket,
-                axis_name=axis,
-                block_size=bsz,
-                rounding=cfg.quant_rounding,
-                key=bkey,
-            )
+        q, scale = quantize_int8(
+            bucket,
+            axis_name=axis,
+            block_size=bsz,
+            rounding=cfg.quant_rounding,
+            key=bkey,
+        )
         contrib = None
         if want_contrib:
             # what the wire carries after the int8 round trip — the
@@ -812,8 +708,7 @@ def _shard_reduce_bucket(bucket, size: int, axis, n: int, w, k, cfg,
 
 
 def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
-                       quant_key=None, err=None, agg_count=None,
-                       bucket_peaks=None):
+                       quant_key=None, err=None, agg_count=None):
     """ZeRO-1 "sharded PS": (EF add-back) -> mask -> (quantize) ->
     reduce_scatter per bucket -> per-shard optax update -> all_gather the
     parameter delta. The flat geometry comes from the buckets engine
@@ -836,11 +731,8 @@ def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
     collectives.piece_stream), so the noise stream a byte sees depends on
     where it lives, not on how many buckets precede it.
 
-    `params` may be the replicated tree (state_layout="tree": flattened
-    here, scattered back after the gather) or a FlatVector
-    (state_layout="flat": ALREADY in this wire's shard geometry — the
-    per-step tree_to_flat/flat_to_tree round trip disappears and the
-    gathered update adds straight onto the flat buffer).
+    `params` is a FlatVector ALREADY in this wire's shard geometry, so
+    the gathered update adds straight onto the flat buffer.
 
     `err` (error feedback) is this worker's residual on the FLAT padded
     gradient vector; returns (new_params, new_opt, new_err).
@@ -885,23 +777,20 @@ def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
     if cfg.overlap == "pipelined":
         return _sharded_ps_update_pipelined(
             params, opt_state, grads, tx, cfg, layout, plan, w, k, sel,
-            bucket_key, err, bucket_peaks=bucket_peaks,
+            bucket_key, err,
         )
 
-    hi = precision_hi_peak(cfg) if bucket_peaks is not None else _INT8_PEAK
     flat_g = pad_flat(tree_to_flat(grads), plan)
     if err is not None:
         flat_g = flat_g + err
     sent = flat_g * sel if sel is not None else flat_g
     new_err = None
     g_shards, contribs = [], []
-    for bi, (start, size) in enumerate(zip(plan.starts, plan.sizes)):
+    for start, size in zip(plan.starts, plan.sizes):
         bucket = lax.slice(sent, (start,), (start + size,))
         g_b, contrib = _shard_reduce_bucket(
             bucket, size, axis, n, w, k, cfg, bucket_key(start),
             want_contrib=err is not None,
-            peak=None if bucket_peaks is None else bucket_peaks[bi],
-            hi_peak=hi,
         )
         g_shards.append(g_b)
         if contrib is not None:
@@ -909,10 +798,7 @@ def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
     g_shard = concat_buckets(g_shards)
     if err is not None:
         new_err = flat_g - concat_buckets(contribs)
-    if isinstance(params, FlatVector):
-        flat_p = params.flat  # already padded in this plan's geometry
-    else:
-        flat_p = pad_flat(tree_to_flat(params), plan)
+    flat_p = params.flat  # already padded in this plan's geometry
     p_shard = _worker_region(flat_p, plan, w, n)
     upd_shard, new_opt = tx.update(g_shard, opt_state, p_shard)
     # reassemble: each bucket's shard segment gathers back tiled, in
@@ -924,21 +810,14 @@ def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
             lax.slice(upd_shard, (off,), (off + s,)), axis, tiled=True
         ))
         off += s
-    if isinstance(params, FlatVector):
-        # flat state: one vector add, no per-leaf scatter (the pad tail
-        # stays zero — zero gradient => zero update)
-        new_params = params.replace(flat=flat_p + concat_buckets(full))
-    else:
-        upd_full = concat_buckets(full)[:total]
-        new_params = optax.apply_updates(
-            params, flat_to_tree(layout, upd_full)
-        )
+    # one vector add, no per-leaf scatter (the pad tail stays zero —
+    # zero gradient => zero update)
+    new_params = params.replace(flat=flat_p + concat_buckets(full))
     return new_params, new_opt, new_err
 
 
 def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
-                                 plan, w, k, sel, bucket_key, err,
-                                 bucket_peaks=None):
+                                 plan, w, k, sel, bucket_key, err):
     """The ZeRO-1 update as a per-bucket stream (overlap="pipelined"):
     every bucket is assembled from its own gradient leaves
     (``assemble_bucket`` — no global ``tree_to_flat`` concat, so bucket
@@ -950,12 +829,9 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
     the serial schedule; only the dataflow (and therefore what a
     latency-hiding scheduler may interleave) changes."""
     axis, n = cfg.axis_name, cfg.num_workers
-    hi = precision_hi_peak(cfg) if bucket_peaks is not None else _INT8_PEAK
     segs = bucket_leaf_segments(layout, plan)
     order = readiness_bucket_order(plan)
     g_leaves = jax.tree_util.tree_leaves(grads)
-    p_is_flat = isinstance(params, FlatVector)
-    p_leaves = None if p_is_flat else jax.tree_util.tree_leaves(params)
     shard_len = plan.padded_total // n
     opt_bare = _strip_flat(opt_state)
     opt_leaves, opt_def, is_seg = _bucket_opt_views(opt_bare, shard_len)
@@ -969,7 +845,6 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
     new_p = [None] * nb
     new_opt = [None] * nb
     err_parts = [None] * nb
-    upd_full = [None] * nb
     for b in order:
         start, size = plan.starts[b], plan.sizes[b]
         s = size // n
@@ -981,20 +856,11 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
             g_shard_b, contrib = _shard_reduce_bucket(
                 sent_b, size, axis, n, w, k, cfg, bucket_key(start),
                 want_contrib=err is not None,
-                peak=None if bucket_peaks is None else bucket_peaks[b],
-                hi_peak=hi,
             )
             if err is not None:
                 err_parts[b] = g_b - contrib
         with jax.named_scope(f"bucket_update_o{start}"):
-            if p_is_flat:
-                p_b = lax.dynamic_slice(
-                    params.flat, (start + w * s,), (s,)
-                )
-            else:
-                p_b = lax.dynamic_slice(
-                    assemble_bucket(p_leaves, segs[b]), (w * s,), (s,)
-                )
+            p_b = lax.dynamic_slice(params.flat, (start + w * s,), (s,))
             opt_b = jax.tree_util.tree_unflatten(opt_def, [
                 lax.slice(l, (shard_off[b],), (shard_off[b] + s,))
                 if seg else l
@@ -1002,24 +868,14 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
             ])
             u_b, opt_b_new = tx.update(g_shard_b, opt_b, p_b)
             gathered = lax.all_gather(_strip_flat(u_b), axis, tiled=True)
-            if p_is_flat:
-                new_p[b] = (
-                    lax.slice(params.flat, (start,), (start + size,))
-                    + gathered
-                )
-            else:
-                upd_full[b] = gathered
+            new_p[b] = (
+                lax.slice(params.flat, (start,), (start + size,))
+                + gathered
+            )
             new_opt[b] = jax.tree_util.tree_leaves(_strip_flat(opt_b_new))
     stitched = _stitch_opt(opt_def, new_opt, is_seg, order[0])
     new_opt_state = _rewrap_flat(opt_state, stitched)
-    if p_is_flat:
-        new_params = params.replace(flat=concat_buckets(new_p))
-    else:
-        # per-leaf rebuild of the gathered updates — each leaf waits on
-        # its own buckets only (the pipelined mirror of flat_to_tree)
-        new_params = optax.apply_updates(
-            params, leaves_from_buckets(layout, plan, upd_full)
-        )
+    new_params = params.replace(flat=concat_buckets(new_p))
     new_err = concat_buckets(err_parts) if err is not None else None
     return new_params, new_opt_state, new_err
 
@@ -1044,16 +900,14 @@ def make_ps_train_step(
     all-finite reduction over the gradients, one int32 pmin for mesh
     consensus (4 B on the wire, no host transfer), and a `jnp.where` select
     that turns the whole state update into the identity on a bad step —
-    the guard decision never leaves the device. Under state_layout="flat"
-    that rollback selects a handful of whole flat vectors (params + each
-    optimizer moment) instead of every pytree leaf.
+    the guard decision never leaves the device. That rollback selects a
+    handful of whole flat vectors (params + each optimizer moment), not
+    every pytree leaf.
 
-    cfg.state_layout="flat" (default) keeps master params and optimizer
-    moments as padded flat f32 vectors end to end: the forward pass reads
-    a once-per-step tree view, the reduced flat gradient feeds one fused
-    vector update, and the ZeRO-1 path skips its per-step
-    tree_to_flat(params). Compute-side only — the wire is byte-identical
-    to "tree" (pscheck's layout-parity gate pins this).
+    Master params and optimizer moments are padded flat f32 vectors end
+    to end: the forward pass reads a once-per-step tree view, the reduced
+    flat gradient feeds one fused vector update, and the ZeRO-1 path
+    needs no per-step tree_to_flat(params).
 
     `faults` (resilience.FaultPlan) bakes deterministic NaN/Inf gradient
     injection into the compiled step at the planned global steps — the
@@ -1066,13 +920,6 @@ def make_ps_train_step(
     declared bounds so a host bug can never divide by zero or mask out
     everything. Same compiled program for every count — no retrace on
     adaptation.
-
-    cfg.precision_adapt appends a traced int32 ``prec_tags`` [n_buckets]
-    argument (after ``agg_count`` when both are on): per-bucket lattice
-    tags (skip/4-bit/int8/hi) the host-side PrecisionController updates
-    per window from the ``bucket_sqnorm`` metrics row this step emits.
-    Tags are clamped on device and only select traced clipping peaks, so
-    — like the count — every tag vector runs the same compiled program.
     """
     axis, n = cfg.axis_name, cfg.num_workers
     specs = state_specs(cfg)
@@ -1085,11 +932,8 @@ def make_ps_train_step(
 
     def worker_fn(step_idx, params, opt_state, batch_stats, comm_state,
                   guard_state, images, labels, key, *extras):
-        # traced per-window controller inputs, in declaration order:
-        # agg_count (cfg.adaptive_aggregate), prec_tags (cfg.precision_adapt)
-        extras = list(extras)
-        agg_count = extras.pop(0) if cfg.adaptive_aggregate else None
-        prec_tags = extras.pop(0) if cfg.precision_adapt else None
+        # the traced per-window controller input (cfg.adaptive_aggregate)
+        agg_count = extras[0] if cfg.adaptive_aggregate else None
         if agg_count is not None:
             # device-side clamp to the declared bounds: the contract the
             # PSC108 envelope relies on must hold even against a buggy
@@ -1097,17 +941,6 @@ def make_ps_train_step(
             agg_count = jnp.clip(
                 agg_count, cfg.num_aggregate_min, cfg.num_aggregate_max
             ).astype(jnp.int32)
-        bucket_peaks = None
-        hi_peak = _INT8_PEAK
-        if prec_tags is not None:
-            # same defense for the precision controller: clamp every tag
-            # into the declared lattice set, then gather the traced
-            # clipping peaks (0 / 7 / 127 / hi) the quantizer selects on
-            hi_peak = precision_hi_peak(cfg)
-            prec_tags = jnp.clip(prec_tags, 0, 3).astype(jnp.int32)
-            bucket_peaks = jnp.asarray(
-                precision_peaks(hi_peak), jnp.float32
-            )[prec_tags]
         w = lax.axis_index(axis)
         k_step = jax.random.fold_in(key, step_idx)
         k_mask = jax.random.fold_in(k_step, 0xA66)
@@ -1118,10 +951,10 @@ def make_ps_train_step(
         params_in, opt_in, bs_in_raw, comm_in = (
             params, opt_state, batch_stats, comm_state
         )
-        # tree view for the forward/backward pass; under state_layout=
-        # "flat" this is the once-per-step flat_to_tree materialization
-        # (static slices/reshapes XLA fuses into the consumers), and the
-        # master `params` stays the padded flat vector end to end
+        # tree view for the forward/backward pass: the once-per-step
+        # flat_to_tree materialization (static slices/reshapes XLA fuses
+        # into the consumers); the master `params` stays the padded flat
+        # vector end to end
         params_t = tree_view(params)
         scale = (
             guard_state.scale
@@ -1202,28 +1035,6 @@ def make_ps_train_step(
                         lambda g, h=hit, v=val: jnp.where(h, v, g), grads
                     )
 
-        bucket_sqnorm = None
-        if cfg.precision_adapt:
-            # per-bucket telemetry for the host-side PrecisionController:
-            # mesh-mean squared gradient norm per state_plan bucket,
-            # measured on the RAW per-worker gradients (pre-EF add-back,
-            # pre-mask — the controller ranks signal density, not wire
-            # artifacts). Static slices over the same flat buffer the
-            # guard probe flattens, so XLA CSEs the concat; one [n_buckets]
-            # f32 pmean rides the metrics dict the host already fetches.
-            lay = tree_layout(grads)
-            splan = state_plan(cfg, lay.total)
-            flat_raw = pad_flat(tree_to_flat(grads), splan)
-            bucket_sqnorm = lax.pmean(
-                jnp.stack([
-                    jnp.sum(
-                        jnp.square(lax.slice(flat_raw, (s0,), (s0 + sz,)))
-                    )
-                    for s0, sz in zip(splan.starts, splan.sizes)
-                ]),
-                axis,
-            )
-
         finite = None
         if cfg.nonfinite_guard:
             # mesh-wide agreement on "every worker's gradients are
@@ -1250,7 +1061,6 @@ def make_ps_train_step(
             params, new_opt, new_err = _sharded_ps_update(
                 params, opt_state, grads, tx, cfg, k_mask,
                 quant_key=quant_key, err=err, agg_count=agg_count,
-                bucket_peaks=bucket_peaks,
             )
             new_opt = tree_map(lambda a: a[None], new_opt)
             if cfg.error_feedback:
@@ -1264,14 +1074,11 @@ def make_ps_train_step(
                 # backup-worker mode)
                 err = tree_map(lambda a: a[0], comm_state)
                 grads = tree_map(jnp.add, grads, err)
-            is_flat = cfg.state_layout == "flat"
             pipelined = cfg.overlap == "pipelined"
-            # pipelined x flat x bucketed: the aggregate stays a LIST of
+            # pipelined x bucketed: the aggregate stays a LIST of
             # per-bucket vectors so the optimizer can start per bucket —
             # the only spelling with no whole-vector barrier at all
-            bucket_out = (
-                pipelined and is_flat and cfg.bucket_bytes is not None
-            )
+            bucket_out = pipelined and cfg.bucket_bytes is not None
             out = aggregate_gradients(
                 grads,
                 axis,
@@ -1288,16 +1095,14 @@ def make_ps_train_step(
                 return_contribution=cfg.error_feedback,
                 axis_sizes=hier_sizes,
                 bucket_bytes=cfg.bucket_bytes,
-                flat_output=is_flat and not bucket_out,
+                flat_output=not bucket_out,
                 pipelined=pipelined,
                 bucket_output=bucket_out,
                 wire_domain=cfg.wire_domain,
-                bucket_peaks=bucket_peaks,
-                lattice_hi_peak=hi_peak,
             )
             if cfg.error_feedback:
                 # the contribution (and the residual it defines) stays
-                # per-leaf in both layouts — checkpoint portability
+                # per-leaf — checkpoint portability
                 agg, contribution = out
                 new_err = tree_map(lambda a, b: a - b, grads, contribution)
                 new_comm = tree_map(lambda a: a[None], new_err)
@@ -1312,12 +1117,10 @@ def make_ps_train_step(
                     tx, agg, opt_state, params, params.plan
                 )
             else:
-                if is_flat:
-                    # the reduced flat gradient, already in the state's
-                    # BucketPlan geometry (piece_stream and state_plan
-                    # share wire_align) — wrap it and run ONE fused
-                    # vector update
-                    agg = params.replace(flat=agg)
+                # the reduced flat gradient, already in the state's
+                # BucketPlan geometry (piece_stream and state_plan share
+                # wire_align) — wrap it and run ONE fused vector update
+                agg = params.replace(flat=agg)
                 updates, new_opt = tx.update(agg, opt_state, params)
                 params = optax.apply_updates(params, updates)
 
@@ -1329,10 +1132,6 @@ def make_ps_train_step(
         metrics = lax.pmean(
             {"loss": loss, "prec1": prec1, "prec5": prec5}, axis
         )
-        if bucket_sqnorm is not None:
-            # already pmean'd; a VECTOR row in the metrics dict — the
-            # trainer pops it before its scalar float() sweep
-            metrics["bucket_sqnorm"] = bucket_sqnorm
         new_guard = guard_state
         if cfg.nonfinite_guard:
             # skip-step: a non-finite step becomes the identity update —
@@ -1381,15 +1180,10 @@ def make_ps_train_step(
         specs.guard_state,
         P(),
     )
-    # the adaptive signatures thread the traced controller inputs through
-    # shard_map (replicated scalar count, replicated [n_buckets] tag
-    # vector — in that order); the static path keeps the 9-arg shape so
+    # the adaptive signature threads the traced count through shard_map
+    # (a replicated scalar); the static path keeps the 9-arg shape so
     # its jaxpr — and the committed comm contract — is untouched
-    extra_specs = ()
-    if cfg.adaptive_aggregate:
-        extra_specs += (P(),)
-    if cfg.precision_adapt:
-        extra_specs += (P(),)
+    extra_specs = (P(),) if cfg.adaptive_aggregate else ()
     mapped = jax.shard_map(
         worker_fn,
         mesh=mesh,
@@ -1423,28 +1217,16 @@ def make_ps_train_step(
         )
         return new_state, metrics
 
-    # fixed-arity wrappers so the jitted signature names its extra args
-    # (count first, tags second — matching extra_specs above). The
-    # `donate_argnums=... if donate else ()` conditional stays inline in
-    # each return: pslint's PSL005 donor discovery reads exactly this
-    # idiom to learn the factory's donated positions and honor callers'
-    # donate=False opt-outs.
-    if cfg.adaptive_aggregate and cfg.precision_adapt:
-        def step_both(state: PSTrainState, batch, key, agg_count,
-                      prec_tags):
-            return step(state, batch, key, agg_count, prec_tags)
-
-        return jax.jit(step_both, donate_argnums=(0,) if donate else ())
+    # a fixed-arity wrapper so the jitted signature names its extra arg.
+    # The `donate_argnums=... if donate else ()` conditional stays
+    # inline in each return: pslint's PSL005 donor discovery reads
+    # exactly this idiom to learn the factory's donated positions and
+    # honor callers' donate=False opt-outs.
     if cfg.adaptive_aggregate:
         def step_adaptive(state: PSTrainState, batch, key, agg_count):
             return step(state, batch, key, agg_count)
 
         return jax.jit(step_adaptive, donate_argnums=(0,) if donate else ())
-    if cfg.precision_adapt:
-        def step_precision(state: PSTrainState, batch, key, prec_tags):
-            return step(state, batch, key, prec_tags)
-
-        return jax.jit(step_precision, donate_argnums=(0,) if donate else ())
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 

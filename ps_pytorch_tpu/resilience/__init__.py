@@ -3,7 +3,7 @@
 The reference PS design already treats failure as a first-class input —
 a straggler kill-threshold on workers and an evaluator that survives on
 checkpoints alone. This package gives the TPU-native reproduction the
-matching machinery, in three parts:
+matching machinery, in four parts:
 
 - ``guard``:  the device-side non-finite gradient guard fused into the PS
   train step (parallel/ps.py) — a skipped step is the identity update,
@@ -19,10 +19,6 @@ matching machinery, in three parts:
   (shrink/grow, replicated<->ZeRO-1), and the adaptive aggregation
   controller turns the static backup-worker mask into a per-window
   response to observed stragglers.
-- ``precision``: the adaptive per-bucket precision controller — windowed
-  gradient-norm telemetry picks each wire bucket's lattice (skip / 4-bit
-  / int8 / hi) under an optional byte budget, in the mask controller's
-  exact mold (debounce, multihost consensus, schema-validated events).
 """
 
 from .elastic import (
@@ -36,7 +32,6 @@ from .elastic import (
 )
 from .faults import FaultPlan, resolve_fault_plan
 from .guard import GuardState, init_guard_state, tree_all_finite
-from .precision import PrecisionController, effective_wire_bytes
 from .retry import retry_io
 
 __all__ = [
@@ -44,8 +39,6 @@ __all__ = [
     "FaultPlan",
     "GuardState",
     "MeshGeometry",
-    "PrecisionController",
-    "effective_wire_bytes",
     "geometry_of",
     "init_guard_state",
     "load_geometry",

@@ -91,8 +91,8 @@ _SHAPE_FIELDS = (
 class MeshGeometry:
     """Everything about a run's mesh/placement that decides the SHAPES
     of its checkpointed state (the trainer's ``elastic.json`` manifest).
-    ``state_layout`` rides along for the record but never matters:
-    checkpoints are tree-shaped at the boundary in both layouts."""
+    A manifest an earlier version wrote may carry more keys (``from_json``
+    drops them): checkpoints are tree-shaped at the boundary."""
 
     num_workers: int
     opt_placement: str = "replicated"
@@ -101,7 +101,6 @@ class MeshGeometry:
     compress: Optional[str] = None
     error_feedback: bool = False
     bn_mode: str = "pmean"
-    state_layout: str = "flat"
     dcn_hosts: int = 1
 
     def to_json(self) -> dict:
@@ -125,7 +124,6 @@ def geometry_of(cfg) -> MeshGeometry:
         compress=None if cfg.compress in (None, "none") else cfg.compress,
         error_feedback=cfg.error_feedback,
         bn_mode=cfg.bn_mode,
-        state_layout=cfg.state_layout,
         dcn_hosts=cfg.dcn_hosts,
     )
 
@@ -250,7 +248,6 @@ def _ps_config(geom: MeshGeometry):
         compress=geom.compress,
         error_feedback=geom.error_feedback,
         bn_mode=geom.bn_mode,
-        state_layout=geom.state_layout,
     )
 
 

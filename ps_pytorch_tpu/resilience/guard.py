@@ -4,11 +4,10 @@ The guard lives INSIDE the jitted PS train step (parallel/ps.py): each
 worker reduces its gradient leaves to one all-finite flag, a single
 int32 ``lax.pmin`` agrees on it mesh-wide (4 bytes on the wire, no host
 transfer), and the whole state update is selected against the flag —
-a bad step applies the identity instead of the optimizer. Under
-``PSConfig.state_layout="flat"`` that rollback is a ``jnp.where`` over a
-handful of whole flat vectors (params + each optimizer moment ride as
-single padded buffers) instead of one select per pytree leaf — the
-select itself is the same tree_map either way. Counters are
+a bad step applies the identity instead of the optimizer. The state is
+flat, so that rollback is a ``jnp.where`` over a handful of whole
+vectors (params + each optimizer moment ride as single padded buffers),
+not one select per pytree leaf. Counters are
 carried in ``GuardState`` (part of PSTrainState, so they checkpoint and
 resume) and surfaced through the metrics dict the host already fetches
 once per log window, so a healthy run pays zero extra host syncs.

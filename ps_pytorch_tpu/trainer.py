@@ -36,10 +36,9 @@ from .data import (
     prepare_data,
     shard_for_worker,
 )
-from .models import build_model, input_shape_for, param_count
+from .models import build_model, input_shape_for
 from .optim import build_optimizer
 from .parallel import (
-    FlatVector,
     PSConfig,
     batch_sharding,
     init_ps_state,
@@ -58,7 +57,6 @@ from .obs import (
 )
 from .resilience import AdaptiveMaskController, resolve_fault_plan
 from .resilience import elastic
-from .resilience.precision import PrecisionController
 from .utils import PhaseTimer, format_eval_line, format_iter_line, get_logger
 
 logger = get_logger()
@@ -174,11 +172,6 @@ class TrainConfig:
     # (resilience/elastic.AdaptiveMaskController; needs the watchdog
     # armed — straggler_threshold_s is the slow-step criterion)
     adapt_window: int = 20
-    # adaptive per-bucket precision budget (bytes): with PSConfig.
-    # precision_adapt on, caps the per-step EFFECTIVE gradient wire
-    # bytes the PrecisionController may tag (resilience/precision.py;
-    # None = density ladder only, no cap). Windows share adapt_window.
-    wire_budget_bytes: Optional[int] = None
     # deterministic fault injection: a JSON FaultPlan ('@path' to read a
     # file), resilience/faults.py; PS_TPU_FAULTS env var when unset here
     fault_plan: Optional[str] = None
@@ -313,9 +306,9 @@ class Trainer:
             tcfg.lr,
             momentum=tcfg.momentum,
             weight_decay=tcfg.weight_decay,
-            # flat state (the default) takes the whole-vector update
-            # variants — same math, no per-leaf tree_map
-            flat=(pcfg.state_layout == "flat"),
+            # the state is flat: the whole-vector update variants —
+            # same math, no per-leaf tree_map
+            flat=True,
         )
 
     def _build_state(self, tcfg: TrainConfig, pcfg: PSConfig) -> int:
@@ -325,46 +318,11 @@ class Trainer:
             self.model, self.tx, pcfg, jax.random.key(tcfg.seed), shape
         )
         self.state = shard_state(state, self.mesh, pcfg)
-        # flat layout: the true count is static metadata (the padded
-        # buffer would over-count by the alignment tail, and
-        # materializing the tree view just to count would waste a
-        # params-sized device allocation)
-        n_params = (
-            state.params.layout.total
-            if isinstance(state.params, FlatVector)
-            else param_count(state.params)
-        )
-        # adaptive per-bucket precision: the host half that picks each
-        # window's traced tag vector (the train step takes it as an
-        # argument — VALUES into one compiled program, never a retrace).
-        # Sized from the SAME BucketPlan the wire carves (state_plan),
-        # so tag b always names wire bucket b.
-        self._precision = None
-        if pcfg.precision_adapt:
-            from .parallel.ps import state_plan
-
-            self._precision = PrecisionController(
-                pcfg,
-                state_plan(pcfg, n_params).sizes,
-                tcfg.adapt_window,
-                budget_bytes=tcfg.wire_budget_bytes,
-                event_sink=lambda rec: append_metrics_line(
-                    tcfg.metrics_file, rec
-                ),
-                # multi-host: telemetry is pmean'd (every host sees the
-                # same stats in exact arithmetic) but the tag vector
-                # feeds a traced collective, so a paranoid elementwise
-                # min-over-hosts is applied at each window close —
-                # coarsest lattice wins, consensus can only shrink the
-                # effective bytes. One small int32 DCN allgather per
-                # window, like the mask controller's.
-                consensus=(
-                    self._tags_consensus
-                    if jax.process_count() > 1
-                    else None
-                ),
-            )
-        return n_params
+        # the true count is static metadata (the padded buffer would
+        # over-count by the alignment tail, and materializing the tree
+        # view just to count would waste a params-sized device
+        # allocation)
+        return state.params.layout.total
 
     def _build_step(self, tcfg: TrainConfig, pcfg: PSConfig) -> None:
         pre_train = make_preprocessor(tcfg.dataset, train=True)
@@ -390,7 +348,6 @@ class Trainer:
             "network": self.tcfg.network,
             "dataset": self.tcfg.dataset,
             "opt_placement": self.pcfg.opt_placement,
-            "state_layout": self.pcfg.state_layout,
             "processes": jax.process_count(),
         }
 
@@ -648,22 +605,6 @@ class Trainer:
             np.asarray([proposed], np.int32)
         )))
 
-    @staticmethod
-    def _tags_consensus(proposed: np.ndarray) -> np.ndarray:
-        """Mesh-wide agreement on the next window's per-bucket precision
-        tags: elementwise min over hosts' adopted vectors — the coarsest
-        lattice ANY host wants wins, so consensus only ever shrinks the
-        effective wire bytes (never breaks a budget a host enforced).
-        Collective (host allgather): window boundaries are step-counted,
-        so every host closes the same window on the same step, like
-        _count_consensus."""
-        from jax.experimental import multihost_utils
-
-        gathered = multihost_utils.process_allgather(
-            np.asarray(proposed, np.int32)
-        )
-        return np.min(gathered, axis=0).astype(np.int32)
-
     def _record_geometry(self, step_no: int) -> None:
         """Record this run's mesh geometry in the elastic.json manifest
         (single writer), keyed by checkpoint step — an elastically
@@ -871,18 +812,14 @@ class Trainer:
                             sharded = next(prefetched)
                         with timer.phase("step"):
                             with tr.span("dispatch", step=step_no + 1):
-                                # traced per-window controller outputs, in
-                                # the step's declared extras order: same
-                                # compiled program for every value
-                                extras = []
-                                if self._adaptive is not None:
-                                    extras.append(
-                                        np.int32(self._adaptive.count)
-                                    )
-                                if self._precision is not None:
-                                    extras.append(np.asarray(
-                                        self._precision.tags, np.int32
-                                    ))
+                                # the traced per-window controller
+                                # output: same compiled program for
+                                # every value
+                                extras = (
+                                    ()
+                                    if self._adaptive is None
+                                    else (np.int32(self._adaptive.count),)
+                                )
                                 self.state, metrics = self._train_step(
                                     self.state, sharded, self._key, *extras
                                 )
@@ -920,19 +857,6 @@ class Trainer:
                             # watchdog reads (real: its barrier is armed);
                             # the compile step is exempt like the watchdog's
                             self._adaptive.record(step_no, timer.total)
-                        if self._precision is not None:
-                            # pop BEFORE any window fetch/float-sweep sees
-                            # it: bucket_sqnorm is a vector row among scalar
-                            # metrics. The fetch is an intentional per-step
-                            # sync, armed only with precision_adapt — the
-                            # controller's telemetry, same opt-in cost shape
-                            # as the watchdog's barrier (a few dozen floats).
-                            self._precision.record(
-                                step_no,
-                                jax.device_get(  # psl: sync-ok
-                                    metrics.pop("bucket_sqnorm")
-                                ),
-                            )
                         # counts even with the watchdog's per-step barrier:
                         # block_until_ready syncs but never FETCHES, and the
                         # guard's host half (skip events + the abort) needs
@@ -1147,13 +1071,6 @@ class Trainer:
         if self._adaptive is not None:
             out["agg_count"] = float(self._adaptive.count)
             out["mask_adaptations"] = float(self._adaptive.adaptations)
-        if self._precision is not None:
-            out["precision_adaptations"] = float(
-                self._precision.adaptations
-            )
-            out["effective_wire_bytes"] = float(
-                self._precision.effective_bytes()
-            )
         return out
 
     # ---------------------------------------------------------------- validate

@@ -46,8 +46,6 @@ import json
 import os
 from typing import Dict, List, Optional, Sequence
 
-import numpy as np
-
 # the committed alpha-beta model whose link numbers the default profile
 # inherits (tools/predicted_scaling.py wrote it; tests pin the format)
 DEFAULT_SCALING_MODEL = "runs/predicted_scaling.json"
@@ -87,9 +85,9 @@ class HardwareProfile:
     (dispatch + rendezvous latency) — the term that separates a
     62-collective per-leaf wire from an 11-bucket fused one even when
     both move the same bytes. ``op_cost_s`` prices one update-path
-    jaxpr equation (the term the flat state layout collapses 386 -> 120
-    on ResNet18). ``compute_s`` is the per-step forward+backward floor
-    communication hides behind."""
+    jaxpr equation (the term flat state holds at 120 on ResNet18, where
+    a per-leaf state read 386). ``compute_s`` is the per-step
+    forward+backward floor communication hides behind."""
 
     name: str = "tpu_v5e_defaults"
     ici_gbs: float = 45.0           # one-way per-link GB/s
@@ -170,59 +168,6 @@ def comm_seconds_from_rows(
         total += _kind_factor(row["kind"], g) * row["bytes"] / (gbs * 1e9)
         total += int(row["count"]) * profile.collective_launch_s
     return total
-
-
-def precision_mix_fraction(
-    tags: Sequence[int],
-    sizes: Sequence[int],
-    hi_peak: int,
-) -> float:
-    """Effective-over-static wire fraction for an adaptive-precision tag
-    vector: the bytes a byte-honest transport ships under ``tags``
-    (resilience.precision.effective_wire_bytes — skip 0, 4-bit half,
-    int8 one, hi the minimal width holding ``hi_peak``) divided by the
-    static-int8 baseline of one byte per element. The controller's tag
-    histogram prices to a single scalar the expected-mixed comm model
-    can scale the traced wire with; > 1.0 is legal (HI tags on a wide
-    payload cost more than int8)."""
-    from ..resilience.precision import effective_wire_bytes
-
-    sizes = np.asarray(sizes, np.int64)
-    static = float(sizes.sum())  # static int8: 1 byte / element
-    if static <= 0:
-        return 1.0
-    return effective_wire_bytes(tags, sizes, hi_peak) / static
-
-
-def expected_mixed_comm_seconds(
-    rows: Sequence[dict],
-    axis_sizes: Dict[str, int],
-    profile: HardwareProfile,
-    fraction: float,
-) -> float:
-    """Alpha-beta comm time for an adaptive-precision candidate whose
-    quantized gradient payload ships ``fraction`` of its traced bytes
-    (``precision_mix_fraction``). Only integer-dtype rows scale — the
-    quantized wire is the step's integer traffic (int8 a2a/gather
-    payloads, the homomorphic accumulator psum), while float rows
-    (block scales, bucket peaks, the telemetry pmean) and every launch
-    cost are tag-invariant. The tiny int32 guard pmin rides the scaled
-    set; at 4 bytes the mispricing is below the model's noise floor.
-
-    PSC108's stance makes this an EXPECTED time, not a traced one: the
-    traced program's physical bytes never change with the tags, so the
-    artifact rows stay honest and this projection is the autotuner's
-    view of what a byte-honest transport would realise."""
-    if fraction < 0.0:
-        raise ValueError(f"fraction must be >= 0, got {fraction}")
-    scaled = []
-    for row in rows:
-        dt = str(row.get("dtype", ""))
-        if dt.startswith(("int", "uint")):
-            row = dict(row)
-            row["bytes"] = row["bytes"] * fraction
-        scaled.append(row)
-    return comm_seconds_from_rows(scaled, axis_sizes, profile)
 
 
 def modeled_step_seconds(

@@ -18,8 +18,8 @@ The pipeline per candidate:
 3. cost the survivors with the trace-only model (tune/costmodel.py) and
    rank ascending by modeled step time;
 4. optionally run short measured probes on the top-K (real steps on the
-   live backend, bench.py's warmup/sync discipline, an in-memory obs
-   tracer splitting dispatch vs sync) — the span-derived overlap
+   live backend, warm-up first and host reads as the sync, an in-memory
+   obs tracer splitting dispatch vs sync) — the span-derived overlap
    fraction feeds back into the SAME step-time formula as a calibrated
    estimate, and every probe stamps its backend so mixed-backend
    comparisons are refused, never averaged.
@@ -84,7 +84,6 @@ class Knobs:
     overlap: str = "serial"             # "serial" | "pipelined"
     opt_placement: str = "replicated"   # "replicated" | "sharded"
     quant_block_size: int = 0
-    state_layout: str = "flat"
     wire_domain: str = "dequant"        # "dequant" | "homomorphic"
 
     def bucket_tag(self) -> str:
@@ -108,7 +107,6 @@ class Knobs:
             "--overlap": "on" if self.overlap == "pipelined" else "off",
             "--opt-placement": self.opt_placement,
             "--quant-block-size": self.quant_block_size,
-            "--state-layout": self.state_layout,
             "--wire-domain": self.wire_domain,
         }
 
@@ -129,10 +127,8 @@ def build_grid(model: str, grid: str = "default") -> List[Knobs]:
     - ``default``: the full compress x bucket x overlap x placement
       product (sharded skips the per-leaf rung — its wire is flat by
       construction, so None would duplicate the fused point), plus two
-      showcase points: the fused 2-round wire with block-32 scales
-      (PSC103 prunes it — scale rows overflow the declared allowance)
-      and the flagship quantized bucketed config in the legacy tree
-      state layout (the update-path op term separates the twins).
+      the showcase point: the fused 2-round wire with block-32 scales
+      (PSC103 prunes it — scale rows overflow the declared allowance).
     - ``smoke``: a trimmed replicated-only LeNet-scale grid for
       tools/smoke.sh — still contains config-invalid AND
       contract-pruned points.
@@ -154,8 +150,6 @@ def build_grid(model: str, grid: str = "default") -> List[Knobs]:
                         ))
         out.append(Knobs(compress="int8_2round", bucket_bytes=fused,
                          quant_block_size=32))
-        out.append(Knobs(compress="int8", bucket_bytes=bucketed,
-                         state_layout="tree"))
         # the wire_domain axis (§6h): the compressed-domain twins of the
         # quantized points — the model prices the narrowed psum / the
         # dropped f32 rows straight from the candidates' own traced
@@ -209,7 +203,6 @@ def spec_for(knobs: Knobs, network: str):
         knobs.opt_placement,
         bucket_bytes=knobs.bucket_bytes,
         network=network,
-        state_layout=knobs.state_layout,
         overlap=knobs.overlap,
         bucket_tag=knobs.bucket_tag(),
         quant_block_size=knobs.quant_block_size,
@@ -254,11 +247,10 @@ def measure_probe(
     steps: int = 4,
     batch: int = 64,
 ) -> Dict[str, Any]:
-    """One short measured probe: real steps on the live backend with
-    bench.py's sync discipline (host reads, not block_until_ready) and
-    an in-memory span tracer splitting dispatch from sync. Returns the
-    measured step time, the span-derived overlap fraction, and the
-    backend stamp."""
+    """One short measured probe: real steps on the live backend, synced
+    by host reads (not block_until_ready), with an in-memory span tracer
+    splitting dispatch from sync. Returns the measured step time, the
+    span-derived overlap fraction, and the backend stamp."""
     import jax
 
     from ..data import IMAGE_SHAPES, make_preprocessor, make_synthetic
@@ -284,12 +276,9 @@ def measure_probe(
         overlap=knobs.overlap,
         opt_placement=knobs.opt_placement,
         quant_block_size=knobs.quant_block_size,
-        state_layout=knobs.state_layout,
         wire_domain=knobs.wire_domain,
     )
-    tx = build_optimizer(
-        "sgd", 0.01, momentum=0.9, flat=(knobs.state_layout == "flat")
-    )
+    tx = build_optimizer("sgd", 0.01, momentum=0.9, flat=True)
     model = build_model(network)
     ds = make_synthetic(dataset, train_size=batch, test_size=8, seed=0)
     data = {"image": ds.train_images, "label": ds.train_labels}

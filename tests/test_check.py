@@ -329,29 +329,67 @@ def test_lint_sh_refuses_write_with_explicit_paths():
 
 # ------------------------------------------------------------ tier-1 gate
 
+# one case per configuration, named from the committed artifact (reading
+# a JSON file is all collection costs); the whole-file case below fails
+# if the registry and the artifact disagree about which names exist
+CONFIG_NAMES = sorted(json.loads(CONTRACT.read_text())["configs"])
+
+
 @pytest.fixture(scope="module")
 def registry_results():
     return trace_registry(get_contracts())
 
 
-def test_registry_contracts_hold(registry_results):
-    """THE gate (rules PSC101/102/103/105): every scheme's traced step
-    satisfies its declared communication contract."""
-    findings = run_checks(registry_results, contract=None)
-    assert findings == [], "\n".join(
-        f"{f.config}: {f.rule} {f.message}" for f in findings
-    )
-
-
-def test_committed_contract_roundtrips(registry_results):
-    """PSC104: the committed artifact matches the live trace bit-for-bit
-    (both through run_checks and as raw JSON)."""
+@pytest.fixture(scope="module")
+def registry_checked(registry_results):
+    """The registry checked once without and once against the committed
+    artifact, beside both JSON forms; each per-configuration case reads
+    its own findings and its own entry."""
     committed = load_contract(str(CONTRACT))
-    findings = run_checks(registry_results, committed)
-    assert findings == [], "\n".join(
-        f"{f.config}: {f.rule} {f.message}" for f in findings
+    return {
+        "live_findings": run_checks(registry_results, contract=None),
+        "committed_findings": run_checks(registry_results, committed),
+        "live_json": to_contract_json(registry_results),
+        "committed_json": committed,
+    }
+
+
+def _report(findings):
+    return "\n".join(f"{f.config}: {f.rule} {f.message}" for f in findings)
+
+
+@pytest.mark.parametrize("name", CONFIG_NAMES)
+def test_registry_contracts_hold(registry_checked, name):
+    """THE gate (rules PSC101/102/103/105): this scheme's traced step
+    satisfies its declared communication contract."""
+    assert name in registry_checked["live_json"]["configs"]
+    mine = [f for f in registry_checked["live_findings"] if f.config == name]
+    assert mine == [], _report(mine)
+
+
+@pytest.mark.parametrize("name", CONFIG_NAMES)
+def test_committed_contract_roundtrips(registry_checked, name):
+    """PSC104: this configuration's committed entry matches its live
+    trace bit-for-bit (both through run_checks and as raw JSON) — a
+    failure names the configuration whose wire moved."""
+    mine = [
+        f for f in registry_checked["committed_findings"] if f.config == name
+    ]
+    assert mine == [], _report(mine)
+    assert (
+        registry_checked["live_json"]["configs"][name]
+        == registry_checked["committed_json"]["configs"][name]
     )
-    assert to_contract_json(registry_results) == committed
+
+
+def test_committed_contract_file_roundtrips(registry_checked):
+    """The artifact as a whole: no finding of either run (one charged to
+    a name outside the artifact would escape the per-configuration
+    cases), and the file equals the live trace's JSON — same names, same
+    header."""
+    for key in ("live_findings", "committed_findings"):
+        assert registry_checked[key] == [], _report(registry_checked[key])
+    assert registry_checked["live_json"] == registry_checked["committed_json"]
 
 
 def test_committed_contract_pins_an_int8_wire():

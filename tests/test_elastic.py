@@ -303,8 +303,7 @@ def _train_steps(cfg, steps=3, seed=0, faults=None):
 
     mesh = make_mesh(num_workers=cfg.num_workers)
     model = build_model("LeNet", num_classes=10)
-    tx = build_optimizer("sgd", 0.05, momentum=0.9,
-                         flat=(cfg.state_layout == "flat"))
+    tx = build_optimizer("sgd", 0.05, momentum=0.9, flat=True)
     state = shard_state(
         init_ps_state(model, tx, cfg, jax.random.key(seed), (1, 28, 28, 1)),
         mesh, cfg,
@@ -349,7 +348,7 @@ def _reshape_to(host_state, src_geom, dst_cfg, seed=99):
     )
     model = build_model("LeNet", num_classes=10)
     tx = build_optimizer("sgd", 0.05, momentum=0.9,
-                         flat=(dst_cfg.state_layout == "flat"))
+                         flat=True)
     target = jax.device_get(init_ps_state(
         model, tx, dst_cfg, jax.random.key(seed), (1, 28, 28, 1)
     ))

@@ -1,34 +1,33 @@
-"""Flat-state training engine (PSConfig.state_layout, parallel/buckets.
-FlatVector) acceptance suite.
+"""Flat-state training engine (parallel/buckets.FlatVector) acceptance
+suite. The PS state is flat; what that must not change is pinned against
+per-leaf (tree) references written here from pieces that remain:
 
-What going flat must (and must not) change, pinned:
-
-- ``compress=None`` flat-state training is BIT-EXACT vs tree-state at
-  both the collective level (aggregate_gradients flat_output moves no
-  values) and the step level; the int8/EF paths are bit-exact too (the
-  wire transform is shared, only the state container differs);
+- flat-state training is BIT-EXACT vs a tree-state step at both the
+  collective level (aggregate_gradients flat_output moves no values)
+  and the step level (``_tree_oracle_step``: the model's per-leaf
+  gradients, the per-leaf aggregate, the per-leaf optimizer), the
+  int8/EF paths included;
 - the fused whole-vector optimizer variants (optim.sgd_flat/adam_flat)
   produce bit-identical updates to the per-leaf tree transforms;
-- checkpoints are TREE-SHAPED at the save/restore boundary: a
-  tree-layout checkpoint (byte-identical to the pre-flat-state format)
-  resumes bit-exact into a flat-layout run and vice versa, guard
-  counters and the EF residual included;
+- checkpoints are TREE-SHAPED at the save/restore boundary (the
+  pre-flat-state on-disk format), and a run resumed from one continues
+  bit-identically to its donor, guard counters and the EF residual
+  included;
 - the non-finite guard's skip-step rollback works on flat state (the
   jnp.where select covers the flat params/moment vectors);
-- the wire is LAYOUT-BLIND: for each contracts.layout_parity_pairs twin
-  the traced collective accounting is byte-identical and every PSC rule
-  stays clean;
-- the point of the exercise: ResNet18's update path (jaxpr ops
-  downstream of the gradient reduce) collapses >= 2x under flat state.
+- ResNet18's update path (jaxpr ops downstream of the gradient reduce)
+  stays the handful of fused vector ops flat state made it.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ps_pytorch_tpu.models import build_model
+from ps_pytorch_tpu.models import apply_model, build_model
+from ps_pytorch_tpu.ops.metrics import cross_entropy_loss
 from ps_pytorch_tpu.optim import adam, adam_flat, sgd, sgd_flat
 from ps_pytorch_tpu.parallel import (
     WORKER_AXIS,
@@ -43,6 +42,7 @@ from ps_pytorch_tpu.parallel import (
     tree_view,
 )
 from ps_pytorch_tpu.parallel.buckets import (
+    flat_to_tree,
     pad_flat,
     to_flat_vector,
     tree_layout,
@@ -183,6 +183,143 @@ def _train(mesh, cfg, tx=None, steps=3, faults=None):
     return state, jax.device_get(m)
 
 
+def _tree_oracle_step(model, tx, cfg, mesh):
+    """The PS step over per-leaf (tree) state, written from pieces that
+    remain: the model's per-leaf gradients, the per-leaf aggregate
+    (``aggregate_gradients`` without flat_output) and the per-leaf
+    optimizer transform. Same key folds as parallel/ps.py's worker. The
+    ZeRO-1 wire transforms the padded flat gradient vector, so its
+    oracle aggregates that vector as ONE leaf (same blocks, same shared
+    scales; a psum where the step scatters — the integer sums are the
+    same) and updates per leaf; its residual stays on the flat vector,
+    and it returns the UPDATE in place of the new parameters."""
+    axis, n = cfg.axis_name, cfg.num_workers
+    sharded = cfg.opt_placement == "sharded"
+
+    def worker(step_idx, params, opt_state, err, images, labels, key):
+        w = lax.axis_index(axis)
+        k_step = jax.random.fold_in(key, step_idx)
+        k_mask = jax.random.fold_in(k_step, 0xA66)
+        _, k_drop = jax.random.split(jax.random.fold_in(k_step, w + 1))
+        x = images.astype(jnp.float32)
+
+        def fwd_bwd(xi, yi, kd):
+            def loss_fn(p):
+                logits, _ = apply_model(
+                    model, p, {}, xi, train=True, dropout_rng=kd
+                )
+                return cross_entropy_loss(logits, yi)
+
+            return jax.value_and_grad(loss_fn)(params)
+
+        a = cfg.grad_accum_steps
+        if a > 1:
+            xm = x.reshape(a, x.shape[0] // a, *x.shape[1:])
+            ym = labels.reshape(a, -1)
+
+            def micro(carry, inp):
+                gsum, lsum = carry
+                i, xi, yi = inp
+                l_i, g_i = fwd_bwd(xi, yi, jax.random.fold_in(k_drop, i))
+                return (jax.tree_util.tree_map(jnp.add, gsum, g_i),
+                        lsum + l_i), None
+
+            (gsum, lsum), _ = lax.scan(
+                micro,
+                (jax.tree_util.tree_map(jnp.zeros_like, params), 0.0),
+                (jnp.arange(a), xm, ym),
+            )
+            grads = jax.tree_util.tree_map(lambda g: g / a, gsum)
+            loss = lsum / a
+        else:
+            loss, grads = fwd_bwd(x, labels, k_drop)
+
+        layout = tree_layout(grads)
+        if sharded:
+            plan = state_plan(cfg, layout.total)
+            grads = {"flat": pad_flat(tree_to_flat(grads), plan)}
+        if cfg.error_feedback:
+            grads = jax.tree_util.tree_map(
+                jnp.add, grads, jax.tree_util.tree_map(lambda e: e[0], err)
+            )
+        out = aggregate_gradients(
+            grads, axis, n,
+            num_aggregate=cfg.num_aggregate,
+            mask_key=k_mask,
+            mask_mode=cfg.mask_mode,
+            compress=cfg.compress,
+            quant_block_size=cfg.quant_block_size,
+            quant_rounding=cfg.quant_rounding,
+            quant_key=(
+                jax.random.fold_in(k_step, 0x5E) if cfg.compress else None
+            ),
+            return_contribution=cfg.error_feedback,
+            bucket_bytes=None if sharded else cfg.bucket_bytes,
+        )
+        if cfg.error_feedback:
+            agg, contribution = out
+            err = jax.tree_util.tree_map(
+                lambda g, c: (g - c)[None], grads, contribution
+            )
+        else:
+            agg = out
+        if sharded:
+            agg = flat_to_tree(layout, agg["flat"])
+        updates, opt_state = tx.update(agg, opt_state, params)
+        if not sharded:
+            params = jax.tree_util.tree_map(jnp.add, params, updates)
+        else:
+            # the ZeRO-1 step all_gathers the update before adding it, so
+            # XLA:CPU cannot contract -lr * buf + p into one fused
+            # multiply-add there as it does here: hand the update out
+            # and let the caller add it in a program of its own
+            params = updates
+        return params, opt_state, err, lax.pmean(loss, axis)
+
+    mapped = jax.shard_map(
+        worker, mesh=mesh,
+        in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P()),
+        out_specs=(P(), P(), P(axis), P()),
+        check_vma=False,
+    )
+    return jax.jit(mapped)
+
+
+@jax.jit
+def _apply_updates(params, updates):
+    return jax.tree_util.tree_map(jnp.add, params, updates)
+
+
+def _tree_oracle_train(mesh, cfg, steps=3):
+    """``_train``'s twin on the oracle: same init, same batch, same keys.
+    Returns (params tree, EF residual in the step's own shape, loss)."""
+    model = build_model("LeNet")
+    tx = sgd(0.05, momentum=0.9)
+    init = init_ps_state(model, tx, cfg, jax.random.key(0), (28, 28, 1))
+    params = jax.device_get(tree_view(init.params))
+    opt_state = tx.init(params)
+    err = None
+    if cfg.error_feedback:
+        err = jax.tree_util.tree_map(jnp.zeros_like, init.comm_state)
+        if cfg.opt_placement == "sharded":
+            err = {"flat": err}
+    step = _tree_oracle_step(model, tx, cfg, mesh)
+    b = shard_batch(_batch(), mesh, cfg)
+    loss = None
+    for i in range(steps):
+        out, opt_state, err, loss = step(
+            jnp.int32(i), params, opt_state, err, b["image"], b["label"],
+            jax.random.key(i),
+        )
+        params = (
+            _apply_updates(params, out)
+            if cfg.opt_placement == "sharded" else out
+        )
+    if err is not None and cfg.opt_placement == "sharded":
+        err = err["flat"]
+    return jax.device_get(params), jax.device_get(err), jax.device_get(loss)
+
+
 @pytest.mark.parametrize(
     "extra",
     [
@@ -202,29 +339,23 @@ def _train(mesh, cfg, tx=None, steps=3, faults=None):
          "2round_mask_accum_stochastic"],
 )
 def test_step_flat_bit_exact_vs_tree(mesh, extra):
-    """The flagship acceptance pin: the same config trained under both
-    state layouts produces bit-identical parameters, metrics, and (when
-    on) EF residuals — flat state is a container change, not a math
+    """The flagship acceptance pin: the PS step on flat state produces
+    bit-identical parameters, loss, and (when on) EF residuals to the
+    per-leaf oracle — flat state is a container change, not a math
     change. Covers the uncompressed per-leaf wire, the fused int8+EF
     wire, the ZeRO-1 placement, and a stacked 2round/mask/accum/
     stochastic config (the per-leaf flat rebuild path)."""
-    out = {}
-    for layout in ("tree", "flat"):
-        cfg = PSConfig(num_workers=N, state_layout=layout, **extra)
-        state, m = _train(mesh, cfg)
-        out[layout] = (
-            jax.device_get(tree_view(state.params)),
-            jax.device_get(state.comm_state),
-            m["loss"],
-        )
-    assert _leaves_equal(out["tree"][0], out["flat"][0])
-    assert _leaves_equal(out["tree"][1], out["flat"][1])
-    assert out["tree"][2] == out["flat"][2]
+    cfg = PSConfig(num_workers=N, **extra)
+    state, m = _train(mesh, cfg)
+    params, comm, loss = _tree_oracle_train(mesh, cfg)
+    assert _leaves_equal(params, jax.device_get(tree_view(state.params)))
+    assert _leaves_equal(comm, jax.device_get(state.comm_state))
+    assert loss == m["loss"]
 
 
 def test_flat_state_structure(mesh):
-    """Under flat layout the live params/moments really ARE flat vectors
-    (one padded leaf each), and tree layout really is per-leaf."""
+    """The live params/moments really ARE flat vectors (one padded leaf
+    each), and the tree view really is per-leaf."""
     cfg = PSConfig(num_workers=N)
     tx = sgd_flat(0.05, momentum=0.9)
     state, _ = _train(mesh, cfg, tx=tx, steps=1)
@@ -243,64 +374,55 @@ def test_flat_state_structure(mesh):
 
 # --------------------------------------------------- checkpoint portability
 
-def _ckpt_cfg(layout):
-    return PSConfig(
-        num_workers=N, state_layout=layout, compress="int8",
-        quant_block_size=64, error_feedback=True,
-    )
-
-
 def test_checkpoint_cross_layout_bit_exact(mesh, tmp_path):
-    """A tree-layout checkpoint (byte-identical to the pre-flat-state
-    on-disk format) resumes bit-exact into a flat-layout run and vice
-    versa — params, optimizer moments, guard counters, and the EF
-    residual all survive, and CONTINUED training from either restore is
-    bit-identical to the donor run."""
+    """The file a flat run writes is TREE-shaped (the pre-flat-state
+    on-disk format: nested per-leaf dicts, no padded buffer), and a run
+    resumed from it — params, optimizer moments, guard counters, and the
+    EF residual — CONTINUES bit-identically to the donor run."""
+    from flax import serialization
+
     import ps_pytorch_tpu.checkpoint as ckpt
 
     model = build_model("LeNet")
-    d = {"tree": str(tmp_path / "tree"), "flat": str(tmp_path / "flat")}
-    states, steps_fn = {}, {}
-    for layout in ("tree", "flat"):
-        cfg = _ckpt_cfg(layout)
-        tx = sgd(0.05, momentum=0.9)
-        s = shard_state(
-            init_ps_state(model, tx, cfg, jax.random.key(0), (28, 28, 1)),
-            mesh, cfg,
+    cfg = PSConfig(
+        num_workers=N, compress="int8", quant_block_size=64,
+        error_feedback=True,
+    )
+    tx = sgd(0.05, momentum=0.9)
+    donor = shard_state(
+        init_ps_state(model, tx, cfg, jax.random.key(0), (28, 28, 1)),
+        mesh, cfg,
+    )
+    step = make_ps_train_step(model, tx, cfg, mesh, donate=False)
+    b = shard_batch(_batch(), mesh, cfg)
+    for i in range(2):
+        donor, _ = step(donor, b, jax.random.key(i))
+    host = jax.device_get(donor)
+    ckpt.save_checkpoint(host, str(tmp_path), 2)
+    # the serialization edge: per-leaf dicts shaped like the tree view
+    raw = serialization.to_state_dict(host)
+    view = jax.device_get(tree_view(donor.params))
+    for stored in (raw["params"], raw["opt_state"]["momentum_buffer"]):
+        assert jax.tree_util.tree_structure(stored) == (
+            jax.tree_util.tree_structure(serialization.to_state_dict(view))
         )
-        step = make_ps_train_step(model, tx, cfg, mesh, donate=False)
-        b = shard_batch(_batch(), mesh, cfg)
-        for i in range(2):
-            s, _ = step(s, b, jax.random.key(i))
-        ckpt.save_checkpoint(jax.device_get(s), d[layout], 2)
-        states[layout], steps_fn[layout] = s, step
-    for src, dst in (("tree", "flat"), ("flat", "tree")):
-        cfg = _ckpt_cfg(dst)
-        target = jax.device_get(
-            init_ps_state(
-                model, sgd(0.05, momentum=0.9), cfg, jax.random.key(7),
-                (28, 28, 1),
-            )
-        )
-        restored = ckpt.load_checkpoint(target, d[src], 2)
-        # bit-exact restore across layouts (tree views compare the math)
-        assert _leaves_equal(
-            tree_view(restored.params), tree_view(states[src].params)
-        ), (src, dst)
-        assert _leaves_equal(restored.comm_state, states[src].comm_state)
-        assert _leaves_equal(restored.guard_state, states[src].guard_state)
-        assert int(restored.step) == 2
-        # continuation parity: two more steps in the DST layout match
-        # two more steps of the SRC donor bit-for-bit
-        cont = shard_state(restored, mesh, cfg)
-        donor = states[src]
-        b = shard_batch(_batch(), mesh, cfg)
-        for i in range(2, 4):
-            cont, _ = steps_fn[dst](cont, b, jax.random.key(i))
-            donor, _ = steps_fn[src](donor, b, jax.random.key(i))
-        assert _leaves_equal(
-            tree_view(cont.params), tree_view(donor.params)
-        ), (src, dst)
+    assert _leaves_equal(raw["params"], view)
+
+    target = jax.device_get(
+        init_ps_state(model, tx, cfg, jax.random.key(7), (28, 28, 1))
+    )
+    restored = ckpt.load_checkpoint(target, str(tmp_path), 2)
+    assert _leaves_equal(tree_view(restored.params), view)
+    assert _leaves_equal(restored.opt_state, host.opt_state)
+    assert _leaves_equal(restored.comm_state, host.comm_state)
+    assert _leaves_equal(restored.guard_state, host.guard_state)
+    assert int(restored.step) == 2
+    cont = shard_state(restored, mesh, cfg)
+    for i in range(2, 4):
+        cont, _ = step(cont, b, jax.random.key(i))
+        donor, _ = step(donor, b, jax.random.key(i))
+    assert _leaves_equal(tree_view(cont.params), tree_view(donor.params))
+    assert _leaves_equal(cont.comm_state, donor.comm_state)
 
 
 def test_flatvector_state_dict_is_tree_shaped():
@@ -332,7 +454,7 @@ def test_guard_skip_rolls_back_flat_state(mesh):
     counter advances, and the run continues."""
     from ps_pytorch_tpu.resilience import FaultPlan
 
-    cfg = PSConfig(num_workers=N, state_layout="flat")
+    cfg = PSConfig(num_workers=N)
     tx = sgd_flat(0.05, momentum=0.9)
     model = build_model("LeNet")
     state = shard_state(
@@ -365,23 +487,6 @@ def test_guard_skip_rolls_back_flat_state(mesh):
     )
 
 
-# ------------------------------------------------------ wire is layout-blind
-
-def test_wire_accounting_identical_across_layouts():
-    """pscheck layout-parity gate: for each (flat, tree) twin the traced
-    collective accounting — kind, axes, dtype, count, bytes — is
-    byte-identical, and every PSC rule stays clean. State layout is
-    compute-side only; going flat moves ZERO bytes on the wire."""
-    from ps_pytorch_tpu.check.contracts import layout_parity_pairs
-    from ps_pytorch_tpu.check.core import run_checks, trace_spec
-
-    for flat_spec, tree_spec in layout_parity_pairs():
-        rf, rt = trace_spec(flat_spec), trace_spec(tree_spec)
-        assert rf.summary == rt.summary, flat_spec.name
-        findings = run_checks([rf, rt], contract=None)
-        assert findings == [], (flat_spec.name, findings)
-
-
 # -------------------------------------------------- the update-path collapse
 
 @pytest.mark.parametrize("config_kw", [
@@ -389,41 +494,13 @@ def test_wire_accounting_identical_across_layouts():
 ])
 def test_resnet18_update_path_collapses(config_kw):
     """Acceptance pin: ResNet18's update path — jaxpr ops downstream of
-    the gradient reduce (the per-leaf scatter + per-leaf optimizer +
-    per-leaf apply chain) — shrinks >= 2x under state_layout='flat'.
-    Trace-only: nothing compiles or executes."""
+    the gradient reduce — stays the fused vector update. 120 is what the
+    flat state read when a per-leaf state still existed beside it (whose
+    scatter + per-leaf optimizer + per-leaf apply chain read 386), so a
+    regression to per-leaf updates fails. Trace-only: nothing compiles
+    or executes."""
     from ps_pytorch_tpu.check.contracts import RESNET_BUCKET_BYTES, _ps_spec
     from ps_pytorch_tpu.check.opcount import update_path_op_count
 
-    counts = {}
-    for layout in ("tree", "flat"):
-        spec = _ps_spec(
-            state_layout=layout, bucket_bytes=RESNET_BUCKET_BYTES,
-            **config_kw,
-        )
-        built = spec.build()
-        counts[layout] = update_path_op_count(built.step, *built.args)
-    assert counts["flat"] > 0
-    assert counts["tree"] >= 2 * counts["flat"], counts
-
-
-# ----------------------------------------------------------------- CLI flag
-
-def test_state_layout_cli_flag_mapping():
-    import argparse
-
-    from ps_pytorch_tpu.cli._flags import add_ps_flags, ps_config_from
-
-    parser = argparse.ArgumentParser()
-    add_ps_flags(parser)
-    for argv, want in (
-        ([], "flat"),
-        (["--state-layout", "tree"], "tree"),
-        (["--state-layout", "flat"], "flat"),
-    ):
-        args = parser.parse_args(argv)
-        assert ps_config_from(args, 8).state_layout == want
-    with pytest.raises(SystemExit):
-        parser.parse_args(["--state-layout", "diagonal"])
-    with pytest.raises(ValueError):
-        PSConfig(num_workers=4, state_layout="diagonal")
+    built = _ps_spec(bucket_bytes=RESNET_BUCKET_BYTES, **config_kw).build()
+    assert 0 < update_path_op_count(built.step, *built.args) <= 120

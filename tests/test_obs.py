@@ -86,7 +86,7 @@ def test_tracer_ring_is_bounded():
 
 
 def test_pathless_flush_keeps_spans_for_drain():
-    """A memory-only tracer (the bench serve leg) must survive the serve
+    """A memory-only tracer (tune's measured probe) must survive the serve
     engine's periodic flush: flush() without a path is a no-op, not a
     silent discard."""
     t = Tracer("bench")
@@ -208,10 +208,6 @@ SAMPLE_EVENTS = {
     "mask_adapt": {"kind": "mask_adapt", "step": 20, "window_start": 11,
                    "from": 4, "to": 3, "slow_steps": 1,
                    "window_steps": 10},
-    "precision_adapt": {"kind": "precision_adapt", "step": 20,
-                        "window_start": 11, "changed": 7, "n_skip": 0,
-                        "n_4bit": 7, "n_int8": 0, "n_hi": 0,
-                        "effective_bytes": 215552, "budget_bytes": 250000},
     "resume_reshape": {"kind": "resume_reshape", "step": 6,
                        "from": {"num_workers": 8}, "to": {"num_workers": 4}},
     "ckpt_quarantined": {"kind": "ckpt_quarantined", "step": 6,

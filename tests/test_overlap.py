@@ -310,8 +310,6 @@ _HEAVY = pytest.mark.slow
                  bucket_bytes=4096),
             marks=_HEAVY,
         ),
-        dict(state_layout="tree", compress="int8", quant_block_size=64,
-             bucket_bytes=4096),
         pytest.param(
             dict(compress="int8", quant_block_size=64,
                  quant_rounding="stochastic", bucket_bytes=4096),
@@ -332,15 +330,15 @@ _HEAVY = pytest.mark.slow
             marks=_HEAVY,
         ),
     ],
-    ids=["none_flat", "int8_ef", "2round", "zero1_int8_ef", "tree_int8",
+    ids=["none_flat", "int8_ef", "2round", "zero1_int8_ef",
          "int8_stochastic", "static_mask", "int8_homomorphic",
          "2round_homomorphic_ef"],
 )
 def test_pipelined_bit_exact_vs_serial(mesh, extra):
     """The flagship pin: same config, both schedules, bit-identical
     params, optimizer moments, EF residuals, guard counters, and loss —
-    across every wire scheme, both placements, both state layouts, and
-    position-stable stochastic-rounding keys."""
+    across every wire scheme, both placements, and position-stable
+    stochastic-rounding keys."""
     _assert_schedules_bit_exact(mesh, extra)
 
 

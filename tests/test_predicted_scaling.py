@@ -1,6 +1,5 @@
-"""Unit tests for the predicted-scaling model math and bench chaining
-helpers (no compiles — the compile-level paths are smoked by the tools
-themselves and the bench workloads)."""
+"""Unit tests for the predicted-scaling model math (no compiles — the
+compile-level paths are smoked by the tools themselves)."""
 
 import importlib.util
 import os
@@ -158,22 +157,3 @@ def test_unknown_collective_kind_uses_conservative_factor(ps_mod):
     assert out["modeled_comm_s"] == pytest.approx(
         1_000_000 * (2 * 3 / 4) / 1e9, abs=1e-9
     )
-
-
-@pytest.fixture()
-def bench(monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        "bench_chain_under_test", os.path.join(REPO, "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_chain_default_and_override(bench, monkeypatch):
-    monkeypatch.delenv("BENCH_CHAIN", raising=False)
-    assert bench._chain() == 1
-    monkeypatch.setenv("BENCH_CHAIN", "10")
-    assert bench._chain() == 10
-    monkeypatch.setenv("BENCH_CHAIN", "0")  # floor at 1: never a 0-iter loop
-    assert bench._chain() == 1

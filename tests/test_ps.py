@@ -62,7 +62,7 @@ def test_dp_step_matches_single_device(mesh):
     batch = _batch(16)
     sharded = shard_batch(batch, mesh, cfg)
     # snapshot params BEFORE the step: the step donates its input state.
-    # tree_view: the default flat state layout stores params as one flat
+    # tree_view: the flat state stores params as one flat
     # vector; the single-device reference math below needs the pytree
     params0 = jax.device_get(tree_view(state.params))
     new_state, metrics = step(state, sharded, jax.random.key(1))

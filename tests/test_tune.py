@@ -348,13 +348,12 @@ def test_search_flags_round_trip_through_the_real_cli_parser(
 
 
 def test_grid_presets_shape():
-    # the default grids carry the showcase points: a quant-block PSC103
-    # prune candidate and a tree-state twin for the op-count term
+    # the default grids carry the showcase point: a quant-block PSC103
+    # prune candidate
     for model in MODELS:
         grid = build_grid(model, "default")
         assert len(grid) >= 30
         assert any(k.quant_block_size for k in grid)
-        assert any(k.state_layout == "tree" for k in grid)
         assert DEFAULT_KNOBS in grid
     smoke = build_grid("lenet", "smoke")
     assert all(k.opt_placement == "replicated" for k in smoke)

@@ -1,7 +1,7 @@
 """Autotune CLI: one command instead of ten flags.
 
 Searches the declared knob grid for a model (compress x bucket_bytes x
-overlap x opt_placement x quant block x state layout), pruning invalid
+overlap x opt_placement x quant block x wire domain), pruning invalid
 points with the PSC101-109 contract rules BEFORE costing them, ranking
 the survivors with the trace-only cost model, and (optionally) running
 short measured probes on the top-K. Writes a ranked, schema-validated

@@ -2,7 +2,7 @@
 # pslint entry point: JAX/TPU-aware static analysis over the package.
 #
 #   tools/lint.sh                 # gate: package + tests/ + tools/ +
-#                                 # analysis/ + bench.py vs committed baseline
+#                                 # analysis/ vs committed baseline
 #   tools/lint.sh cli/foo.py      # lint other trees (ad hoc; the committed
 #                                 # baseline still applies if entries match)
 #   tools/lint.sh --write-baseline  # refresh lint_baseline.json over the
@@ -17,13 +17,13 @@ source tools/_gate_common.sh
 
 # tests/ is in the gate on purpose: donated-buffer reuse (PSL005) and
 # axis literals live there, and CPU-only CI cannot catch donation bugs
-# at runtime (donation is a warning on CPU, a crash on TPU). tools/,
-# analysis/, and bench.py are gated because their host loops drive the
+# at runtime (donation is a warning on CPU, a crash on TPU). tools/
+# and analysis/ are gated because their host loops drive the
 # TPU (PSL002 recompilation and PSL004 sync hazards live there too).
 # The psdiverge pass (PSL006-008, multihost divergence) rides the same
 # gate; run it alone with `tools/lint.sh --select PSL006,PSL007,PSL008`
 # (smoke.sh's first leg).
-GATE_PATHS=(ps_pytorch_tpu tests tools analysis bench.py)
+GATE_PATHS=(ps_pytorch_tpu tests tools analysis)
 
 REFUSE="tools/lint.sh: --write-baseline always refreshes over the gate's
 paths (${GATE_PATHS[*]}); drop the explicit paths, or call

@@ -4,19 +4,16 @@
 # gate, pscheck jaxpr contract gate), the multi-chip dryrun (all
 # parallelism axes), the PS CNN trainer + evaluator, the elasticity
 # drill (SIGTERM on 8 workers -> resume-reshape on 4 with an adaptive
-# mask under a straggler storm), the flat-state
-# default (int8 + EF + guard NaN-inject), the homomorphic
-# compressed-domain wire (2round int8 + EF + 64 KiB buckets + pipelined
-# overlap + NaN-inject), the adaptive per-bucket precision wire
-# (telemetry-driven skip/4-bit/int8/hi retag under a byte budget), the
-# LM trainer on tp with
+# mask under a straggler storm), the flat state under the int8 wire
+# (EF + guard NaN-inject), the homomorphic compressed-domain wire
+# (2round int8 + EF + 64 KiB buckets + pipelined overlap + NaN-inject),
+# the LM trainer on tp with
 # vocab-parallel embedding + the LM evaluator with KV-cache sampling,
 # the serving engine under open-loop traffic with one hot checkpoint
 # rollover, the observability leg (traced train + serve merged into one
 # Chrome timeline by tools/trace_report.py), the serve-chaos leg
 # (traffic spike + decode stalls + corrupt staged rollover -> shed
-# events, full lifecycle accounting, rollover abort onto old weights),
-# and the headline benchmark in its trimmed form (an explicit CPU run).
+# events, full lifecycle accounting, rollover abort onto old weights).
 # Budget ~8 minutes of CPU (compiles dominate). The chip has its own
 # smoke: python chip_smoke.py.
 #
@@ -26,10 +23,8 @@ cd "$(dirname "$0")/.."
 
 run() {
   echo "== $*"
-  # env -i strips everything else, so forward the bench knobs explicitly
   env -i PATH="$PATH" HOME="$HOME" \
       JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      BENCH_STEPS="${BENCH_STEPS:-2}" \
       "$@"
 }
 
@@ -123,14 +118,14 @@ print("elastic smoke: 8->4 reshape ok, mask %d->%d under storm, loss %.3f"
       % (adapt[0]["from"], adapt[0]["to"], trains[-1]["loss"]))
 PYEOF
 
-# flat-state leg (ARCHITECTURE §6f, the default --state-layout): int8
-# wire + error feedback + a NaN gradient at step 3 — the guard must
-# skip-step by rolling back the FLAT params/moment vectors, and training
-# must continue to a clean finish on the 8-device CPU mesh
+# flat-state leg (ARCHITECTURE §6f): int8 wire + error feedback + a NaN
+# gradient at step 3 — the guard must skip-step by rolling back the FLAT
+# params/moment vectors, and training must continue to a clean finish
+# on the 8-device CPU mesh
 run python -m ps_pytorch_tpu.cli.train \
     --network LeNet --dataset MNIST --num-workers 8 --batch-size 64 \
     --max-steps 6 --eval-freq 3 --log-interval 1 \
-    --state-layout flat --compress-grad compress --quant-block-size 32 \
+    --compress-grad compress --quant-block-size 32 \
     --error-feedback --bucket-bytes 65536 \
     --fault-plan '{"nan_grads":[3]}' \
     --train-dir "$TMP/flat"
@@ -160,44 +155,6 @@ assert trains and math.isfinite(trains[-1]["loss"]), trains
 print("homomorphic smoke: guard skipped %d step(s) on the int8 "
       "compressed-domain wire, final loss %.3f"
       % (skips[-1]["skipped_steps"], trains[-1]["loss"]))
-PYEOF
-
-# adaptive-precision leg (ARCHITECTURE §6i, --precision-adapt): the same
-# homomorphic 2round+EF wire, but every 64 KiB bucket carries a traced
-# precision tag (skip/4-bit/int8/hi) the host PrecisionController
-# retags from per-step gradient-norm telemetry — values, never bytes,
-# no retrace. The --wire-budget-bytes cap sits just ABOVE the all-4-bit
-# floor (27 x 16 Ki elements / 2 = 215552 B) and well below the static
-# int8 wire (431104 B), so budget enforcement drives every window's
-# proposal to the same all-4-bit vector — the debounce adopts it at the
-# second window close regardless of how the per-bucket densities move.
-# The run must land >= 1 schema-valid precision_adapt event whose
-# effective bytes respect the budget, and train to a clean finish
-run python -m ps_pytorch_tpu.cli.train \
-    --network LeNet --dataset MNIST --num-workers 8 --batch-size 64 \
-    --max-steps 6 --eval-freq 3 --log-interval 1 \
-    --compress-grad 2round --quant-block-size 32 --error-feedback \
-    --bucket-bytes 65536 --wire-domain homomorphic \
-    --precision-adapt --adapt-window 2 --wire-budget-bytes 220000 \
-    --metrics-file "$TMP/precadapt/metrics.jsonl" \
-    --train-dir "$TMP/precadapt"
-run python - "$TMP/precadapt/metrics.jsonl" <<'PYEOF'
-import json, math, sys
-from ps_pytorch_tpu.obs.schema import validate_event
-events = [json.loads(l) for l in open(sys.argv[1])]
-prec = [e for e in events if e.get("kind") == "precision_adapt"]
-assert prec and prec[0]["changed"] >= 1, prec
-for e in prec:
-    validate_event(dict(e))
-    assert e["effective_bytes"] <= e["budget_bytes"], e
-trains = [e for e in events if e.get("kind") == "train"]
-assert trains and all(math.isfinite(e["loss"]) for e in trains), trains
-last = prec[-1]
-print("precision smoke: %d retag(s), tags skip=%d 4bit=%d int8=%d hi=%d, "
-      "effective %d B under budget %d B, final loss %.3f"
-      % (len(prec), last["n_skip"], last["n_4bit"], last["n_int8"],
-         last["n_hi"], last["effective_bytes"], last["budget_bytes"],
-         trains[-1]["loss"]))
 PYEOF
 
 run python -m ps_pytorch_tpu.cli.train_lm \
@@ -339,17 +296,6 @@ assert rec["best"]["flag_line"].startswith("--network LeNet"), rec["best"]
 print("autotune smoke: %d ranked, %d pruned (%s), best %s"
       % (rec["n_candidates"], rec["n_pruned"], sorted(stages),
          rec["best"]["name"]))
-PYEOF
-
-# the headline benchmark, trimmed, as an EXPLICIT CPU run: run() sets
-# JAX_PLATFORMS=cpu and the record must say so itself (bench.py has no
-# fallback; a backend that cannot start is a traceback and a non-zero exit)
-run python bench.py | tee "$TMP/bench.json"
-python - "$TMP/bench.json" <<'PYEOF'
-import json, sys
-rec = json.loads(open(sys.argv[1]).read().strip().splitlines()[-1])
-assert rec["backend"]["platform"] == "cpu", rec["backend"]
-print("bench smoke: %s on %s" % (rec["metric"], rec["backend"]))
 PYEOF
 
 echo "SMOKE OK"
