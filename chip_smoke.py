@@ -327,9 +327,9 @@ def leg_kernels(devices):
         o = ring(q, k, v)
         return jnp.sum(o.astype(jnp.float32) ** 2), o
 
-    # float32, then bfloat16: the LM cell's own call (dp_sp hands the
-    # kernels a ring even of one, so it runs the partials with float32
-    # results and offsets read at run time)
+    # float32, then bfloat16: what a sequence axis of two or more chips
+    # runs (the partials with float32 results and offsets read at run
+    # time); over one chip dp_sp takes flash_attention proper, above
     for dtype, bound in ((jnp.float32, F32_DEFAULT_PRECISION_BOUND),
                          (jnp.bfloat16, BF16_BOUND)):
         compare(f"ring-flash {jnp.dtype(dtype).name} over {len(devices)} "

@@ -50,7 +50,7 @@ import numpy as np
 import optax
 
 from ..models.lm import load_lm_config
-from ..models.transformer import TransformerConfig
+from ..models.transformer import TransformerConfig, attention_path
 from ..optim import build_optimizer
 from ..parallel.dp_sp import (
     init_lm_state,
@@ -469,21 +469,23 @@ def main(argv=None) -> dict:
 
     if cfg.attention_impl == "flash":
         # the kernels' tile plan is static: how often the skip engages is
-        # known here, from the shapes every attention call will have
+        # known here, from the shapes every attention call will have, and
+        # so is the path select_attention takes (the same function decides)
         from ..ops.flash_attention import plan_flash
 
-        ring = (args.parallelism in ("dp_sp", "ep_sp")
-                and cfg.sp_attention == "ring")
-        t_att = args.seq_len // num_sp if ring else args.seq_len
+        seq_shards = num_sp if args.parallelism in ("dp_sp", "ep_sp") else 1
+        path = attention_path(cfg, seq_shards)
+        t_att = args.seq_len // seq_shards if path == "ring" else args.seq_len
         plan = plan_flash(t_att, t_att, d_qk,
                           cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
         flash_plan = {f: getattr(plan, f) for f in (
             "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total")}
-        flash_plan.update(d_qk=d_qk, d_v=d_v)
+        flash_plan.update(d_qk=d_qk, d_v=d_v, attention_path=path,
+                          seq_shards=seq_shards)
         logger.info(
             "flash plan for T %d x D %d: %s (per head%s)", t_att,
             d_qk, flash_plan,
-            "; ring hops decide from their offsets" if ring else "",
+            "; ring hops decide from their offsets" if path == "ring" else "",
         )
         tr.instant("flash_plan", **flash_plan)
 

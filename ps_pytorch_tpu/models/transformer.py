@@ -122,45 +122,63 @@ def local_attention(cfg: TransformerConfig):
     raise ValueError(f"unknown attention_impl {cfg.attention_impl!r}")
 
 
+def attention_path(cfg, n_seq: int) -> str:
+    """Which attention a model runs over a sequence axis of `n_seq`
+    members: "local" | "ring" | "ulysses". A sequence axis with one member
+    has no ring: K and V would be sent to the chip they are on and every
+    partial merged with nothing, so one member takes the within-chip
+    attention whatever cfg.sp_attention names. The ONE decision, read by
+    select_attention and by cli/train_lm.py's log line and `flash_plan`
+    instant."""
+    if cfg.sp_attention not in ("ring", "ulysses"):
+        raise ValueError(f"unknown sp_attention {cfg.sp_attention!r}")
+    return "local" if n_seq == 1 else cfg.sp_attention
+
+
 def select_attention(cfg: TransformerConfig, seq_axis_name: Optional[str] = None):
     """The attention callable for this config — the ONE selection point.
 
-    seq_axis_name=None: within-chip (naive jnp or Pallas flash).
-    Otherwise: the sequence-parallel scheme (cfg.sp_attention) over that
-    mesh axis — ring (jnp, or flash-per-hop under attention_impl="flash")
-    or Ulysses (a2a re-shard, local attention in cfg.attention_impl).
-    Shared by the dense transformer (apply_transformer) and the MoE
-    transformer (parallel/moe.apply_moe_transformer) so the dense and MoE
-    paths can never diverge in attention math."""
+    seq_axis_name=None, or a mesh axis with one member (attention_path):
+    within-chip (naive jnp or Pallas flash). Two or more members: the
+    sequence-parallel scheme (cfg.sp_attention) over that mesh axis — ring
+    (jnp, or flash-per-hop under attention_impl="flash") or Ulysses (a2a
+    re-shard, local attention in cfg.attention_impl). Call it with an axis
+    name where the axis is bound (inside the mapped function, as every
+    model apply does): its size is a Python int at trace time.
+    Shared by the dense transformer (apply_transformer), the MLA/MoE one
+    (models/mla_moe.apply_mla_moe) and the MoE transformer
+    (parallel/moe.apply_moe_transformer) so no family can diverge in
+    attention math."""
     if seq_axis_name is None:
         return local_attention(cfg)
-    if cfg.sp_attention == "ulysses":
+    path = attention_path(cfg, jax.lax.axis_size(seq_axis_name))
+    if path == "local":
+        return local_attention(cfg)
+    if path == "ulysses":
         from ..parallel.ulysses import ulysses_attention
 
         return partial(
             ulysses_attention, axis_name=seq_axis_name, causal=cfg.causal,
             impl=cfg.attention_impl,
         )
-    if cfg.sp_attention == "ring":
-        if cfg.attention_impl == "flash":
-            # flash INSIDE each ring hop: no [T_loc, T_loc] block ever
-            # materializes (ops/flash_attention partial-triple kernels);
-            # bidirectional_ring rotates K/V both ways, two triples/hop
-            from ..parallel.ring_attention import ring_flash_attention
+    if cfg.attention_impl == "flash":
+        # flash INSIDE each ring hop: no [T_loc, T_loc] block ever
+        # materializes (ops/flash_attention partial-triple kernels);
+        # bidirectional_ring rotates K/V both ways, two triples/hop
+        from ..parallel.ring_attention import ring_flash_attention
 
-            return partial(
-                ring_flash_attention,
-                axis_name=seq_axis_name,
-                causal=cfg.causal,
-                bidirectional=cfg.bidirectional_ring,
-            )
         return partial(
-            ring_attention,
+            ring_flash_attention,
             axis_name=seq_axis_name,
             causal=cfg.causal,
             bidirectional=cfg.bidirectional_ring,
         )
-    raise ValueError(f"unknown sp_attention {cfg.sp_attention!r}")
+    return partial(
+        ring_attention,
+        axis_name=seq_axis_name,
+        causal=cfg.causal,
+        bidirectional=cfg.bidirectional_ring,
+    )
 
 
 def transformer_block(cfg: TransformerConfig, x, blk, attend, mlp=None):
@@ -200,9 +218,11 @@ def apply_transformer(
 ) -> jax.Array:
     """Forward -> logits [B, T_local, vocab].
 
-    Under shard_map pass seq_axis_name: attention runs on the ring and
-    positional embeddings index by GLOBAL position (shard offset). Outside
-    shard_map (seq_axis_name=None) this is the plain single-device model.
+    Under shard_map pass seq_axis_name: attention runs over that axis
+    (select_attention: the sequence-parallel scheme, or the within-chip
+    attention where the axis has one member) and positional embeddings
+    index by GLOBAL position (shard offset). Outside shard_map
+    (seq_axis_name=None) this is the plain single-device model.
     """
     b, t_loc = tokens.shape
     if seq_axis_name is not None:

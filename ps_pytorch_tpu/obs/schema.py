@@ -134,11 +134,13 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "new_tokens", "weights_step", "from_step", "to_step",
                     "bytes", "block", "wall_ns", "err_ns",
                     # instants of cli/train_lm.py: `flash_plan` (the
-                    # kernels' tiles and widths) and `moe_route` (the
+                    # kernels' tiles and widths, `seq_shards` and, a
+                    # string, the `attention_path` they run on:
+                    # models/transformer.attention_path) and `moe_route` (the
                     # dropless expert layers' rows, summed over layers;
                     # `<name>_per_layer` lists ride along)
                     "block_q", "block_k", "grid_steps", "tiles_run",
-                    "tiles_total", "d_qk", "d_v", "rows_here",
+                    "tiles_total", "d_qk", "d_v", "seq_shards", "rows_here",
                     "max_expert_rows", "min_expert_rows", "tokens_unserved"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "

@@ -19,6 +19,12 @@ aggregation (sync_replicas_master_nn.py:204-208 semantics, batch-mean form).
 Next-token targets cross sequence-shard boundaries: the target of a shard's
 last token is the NEXT shard's first token, fetched with one ppermute; the
 final global position is masked out of the loss.
+
+A sequence axis with one member (sp 1: both LM benchmark cells) has no
+ring: the model takes the within-chip attention
+(models/transformer.attention_path) and the "next shard" is this one, so
+the target of the last token is read from the shard itself and no
+collective over sp moves anything.
 """
 
 from __future__ import annotations
@@ -79,10 +85,13 @@ def lm_loss_local(
     n_sp = lax.axis_size(sp_axis)
     s = lax.axis_index(sp_axis)
     logits, aux = lm_family(cfg).apply(cfg, params, tokens, seq_axis_name=sp_axis)
-    # target of my last token = next shard's first token (ring shift left)
-    nxt_first = lax.ppermute(
-        tokens[:, :1], sp_axis, [(j, (j - 1) % n_sp) for j in range(n_sp)]
-    )
+    # target of my last token = next shard's first token (ring shift left);
+    # with one member that shard is this one (the position is masked below)
+    nxt_first = tokens[:, :1]
+    if n_sp > 1:
+        nxt_first = lax.ppermute(
+            nxt_first, sp_axis, [(j, (j - 1) % n_sp) for j in range(n_sp)]
+        )
     tgt = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]

@@ -6,7 +6,9 @@ models with long contexts. One (expert x seq) mesh:
 - batch sharded over the expert axis (it doubles as data parallelism, as
   in parallel/moe.py), sequence sharded over the seq axis;
 - attention: ring (or ring-flash / Ulysses, via TransformerConfig) over
-  `seq` — K/V blocks rotate within each expert row;
+  `seq` — K/V blocks rotate within each expert row (a `seq` axis of one
+  member takes the within-chip attention: models/transformer.
+  attention_path);
 - MoE MLP: two all_to_alls over `expert` — token routing within each seq
   column. The two collectives touch ORTHOGONAL mesh dimensions, so the
   composition needs no new communication primitive at all: exactly the

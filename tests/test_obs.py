@@ -681,6 +681,27 @@ def test_train_lm_trace_records_the_trainers_names(tmp_path):
                    and _end(s) <= _end(steps[s["step"]]) + 5e-6 for s in got)
 
 
+@pytest.mark.parametrize("num_sp, path, t_att", [(1, "local", 32), (2, "ring", 16)])
+def test_train_lm_flash_plan_says_which_attention_runs(tmp_path, num_sp, path, t_att):
+    """The engagement counter of the one-member selection: the `flash_plan`
+    instant carries models/transformer.attention_path's answer for the mesh
+    the run built, and plans the tiles for the length that path attends."""
+    from ps_pytorch_tpu.cli import train_lm
+    from ps_pytorch_tpu.obs.schema import validate_event
+
+    train_lm.main([
+        "--dim", "32", "--depth", "1", "--heads", "2", "--seq-len", "32",
+        "--vocab-size", "64", "--batch-size", "2", "--max-steps", "1",
+        "--num-dp", "1", "--num-sp", str(num_sp), "--attention-impl", "flash",
+        "--trace", str(tmp_path),
+    ])
+    _, spans = _read_stream(tmp_path / "trace_train_lm_p0.jsonl")
+    (plan,) = _named(spans, "flash_plan")
+    assert plan["attention_path"] == path and plan["seq_shards"] == num_sp
+    assert plan["block_q"] == plan["block_k"] == t_att
+    assert validate_event(dict(plan))["seq_shards"] == num_sp
+
+
 def test_tracer_off_is_null(tmp_path):
     ds = make_synthetic("MNIST", train_size=64, test_size=32, seed=1)
     tcfg = TrainConfig(
