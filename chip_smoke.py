@@ -20,6 +20,11 @@ CLIs expose, on synthetic data made from a seed:
   lm_config cli.train_lm --lm-config on the small preset of the latent-
             attention / dropless-expert family (192-wide q/k, 128-wide v,
             8 of 16 routed experts held), bf16, flash, remat, Adam
+  lm_ssm    cli.train_lm --lm-config on a small preset of the hybrid
+            state-space / attention family (Mamba-2 heads of 64, state 128,
+            chunks of 256; 4 query over 2 key/value heads; m m a m), bf16,
+            flash, remat, Adam; then the chunked scan on the chip against
+            the token-by-token recurrence
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -102,6 +107,21 @@ LM_CONFIG = {
     "rope_interleave": True, "scoring_func": "sigmoid",
     "topk_method": "noaux_tc", "n_group": 1, "topk_group": 1,
     "experts_held": 8, "expert_offset": 0,
+}
+# a small preset of the hybrid state-space / attention family at the
+# published head widths (mamba heads of 64 over a state of 128, chunks of
+# 256, attention heads of 64, two of them sharing each key/value head)
+LM_SSM_CONFIG = {
+    "model_type": "granitemoehybrid", "vocab_size": 1024, "hidden_size": 256,
+    "num_hidden_layers": 4, "layer_types": ["mamba", "mamba", "attention", "mamba"],
+    "num_attention_heads": 4, "num_key_value_heads": 2,
+    "shared_intermediate_size": 512, "mamba_n_heads": 8, "mamba_d_head": 64,
+    "mamba_d_state": 128, "mamba_n_groups": 1, "mamba_d_conv": 4,
+    "mamba_chunk_size": 256, "mamba_expand": 2, "mamba_conv_bias": True,
+    "attention_multiplier": 0.015625, "embedding_multiplier": 12,
+    "residual_multiplier": 0.22, "logits_scaling": 8, "rms_norm_eps": 1e-5,
+    "position_embedding_type": "nope", "num_local_experts": 0,
+    "tie_word_embeddings": True,
 }
 LM_CONFIG_ARGS = [
     "--seq-len", "1024", "--batch-size", "2", "--dtype", "bfloat16",
@@ -564,6 +584,45 @@ def leg_lm_config(workdir, devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_ssm(workdir, devices, clog):
+    """The third LM family through `cli.train_lm --lm-config` (data parallel
+    over the chips: its recurrent state crosses no sequence shard), then the
+    chunked scan by itself, bfloat16 products on the chip, against the
+    float32 recurrence token by token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.ops import ssd
+
+    leg = "lm_ssm"
+    path = os.path.join(workdir, "lm_ssm_small.json")
+    with open(path, "w") as f:
+        json.dump(LM_SSM_CONFIG, f)
+    out = train_lm_cli.main(
+        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
+        + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
+    check_finite(leg, "loss", out["loss"])
+    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+
+    k = jax.random.split(jax.random.key(3), 6)
+    t, h, p, n = 1024, 8, 64, 128
+    x = jax.random.normal(k[0], (1, t, h, p), jnp.bfloat16)
+    bm, cm = (jax.random.normal(kk, (1, t, 1, n), jnp.bfloat16) for kk in k[1:3])
+    dt = jnp.exp(jax.random.uniform(k[3], (1, t, h), minval=np.log(1e-3), maxval=np.log(1e-1)))
+    a = -jax.random.uniform(k[4], (h,), minval=1.0, maxval=16.0)
+    d = jnp.ones((h,))
+    got, _ = jax.jit(ssd.ssd_chunked, static_argnums=6)(x, dt, a, bm, cm, d, 256)
+    want = jax.jit(ssd.ssd_recurrence)(x, dt, a, bm, cm, d)
+    gap = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    if not gap < 0.02:      # bfloat16 operands, float32 sums: under 2% of the range
+        raise AssertionError(f"{leg}: chunked scan is {gap:.4f} of its range off the recurrence")
+    print(f"[{leg}] chunked scan vs recurrence: {gap:.5f} of the range", flush=True)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -685,6 +744,7 @@ def main() -> int:
         run("lm", lambda clog: leg_lm(lm_dir, devices, clog))
         run("serve", lambda clog: leg_serve(lm_dir, devices, clog))
         run("lm_config", lambda clog: leg_lm_config(workdir, devices, clog))
+        run("lm_ssm", lambda clog: leg_lm_ssm(workdir, devices, clog))
 
     print(json.dumps({
         "versions": versions,

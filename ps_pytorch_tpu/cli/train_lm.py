@@ -27,13 +27,19 @@ data/datasets.make_synthetic.
 
 A published architecture is named by its config.json, not by --dim/--depth/
 --heads: `--lm-config <json>` (the published keys plus the chip's share,
-e.g. `model_type: deepseek_v3` with `experts_held`; models/lm.
-load_lm_config builds the family, the same call the benchmark's driver
-makes). Such a model trains through --parallelism dp_sp; its routing
-counters are logged at log steps and recorded as the `moe_route` instant.
+e.g. `model_type: deepseek_v3` with `experts_held`, or `model_type:
+granitemoehybrid` without routed experts; models/lm.load_lm_config builds
+the family, the same call the benchmark's driver makes). Such a model
+trains through --parallelism dp_sp; what it counts is logged at log steps
+and recorded as an instant: the expert layers' routing as `moe_route`, the
+state-space scan's cut-off chunks as `ssd_state` (after one `ssd_plan` at
+the start; that family runs --num-sp 1 only).
 
   ... --lm-config benchmark/configs/kanana2_30b_a3b_ep8.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
+      --attention-impl flash --optimizer adam --lr 3e-4
+  ... --lm-config benchmark/configs/granite4_h_micro_1period.json --num-dp 1 \
+      --num-sp 1 --seq-len 8192 --batch-size 1 --dtype bfloat16 --remat \
       --attention-impl flash --optimizer adam --lr 3e-4
 """
 
@@ -488,6 +494,13 @@ def main(argv=None) -> dict:
             "; ring hops decide from their offsets" if path == "ring" else "",
         )
         tr.instant("flash_plan", **flash_plan)
+    if getattr(cfg, "mamba_layers", 0):
+        # the state-space scan's shapes are static too
+        from ..models.ssm_hybrid import ssd_plan
+
+        plan = ssd_plan(cfg, args.seq_len)
+        logger.info("ssd plan for T %d: %s (per row)", args.seq_len, plan)
+        tr.instant("ssd_plan", **plan)
 
     def save_lm_checkpoint(step_no):
         if args.train_dir is None:
@@ -593,12 +606,26 @@ def main(argv=None) -> dict:
                             "MoE load-balance aux: %.4f", record["aux_loss"]
                         )
                     if "last" in counters_box:
-                        # the expert layers' routing over this step's
-                        # global batch (parallel/moe.routing_counters)
+                        # what the family counted over this step's global
+                        # batch (models/lm.LMFamily.counters)
                         c = {k: np.asarray(v).tolist() for k, v in
                              jax.device_get(counters_box["last"]).items()}
                         record.update({k: v for k, v in c.items()
                                        if not k.endswith("_per_layer")})
+                    if "ssd_chunks_cut_off" in record:
+                        # the state-space layers' scan (models/ssm_hybrid.
+                        # ssd_counters): where a whole chunk's decay is
+                        # zero in float32, the carried state does no work
+                        logger.info(
+                            "SSD scan: %d (row, chunk, head) cut off from the "
+                            "chunk before, per layer %s", c["ssd_chunks_cut_off"],
+                            c["ssd_chunks_cut_off_per_layer"],
+                        )
+                        tr.instant("ssd_state", **{
+                            k[len("ssd_"):]: v for k, v in c.items()})
+                    if "moe_rows_here" in record:
+                        # the expert layers' routing (parallel/moe.
+                        # routing_counters)
                         logger.info(
                             "MoE routing: %d rows here, fullest expert %d, "
                             "emptiest %d, %d tokens with no expert here, "

@@ -11,11 +11,14 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
                               beside the loss, from the summed aux
 
 `load_lm_config` builds a config from a published config.json-shaped dict
-by its `model_type`; TransformerConfig is built from sizes as before.
+by its `model_type` (`_PUBLISHED_FAMILIES`: deepseek_v3 -> models/mla_moe.py,
+granitemoehybrid -> models/ssm_hybrid.py); TransformerConfig is built from
+sizes as before.
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 from typing import Callable, Dict, NamedTuple, Optional, Union
 
@@ -32,18 +35,56 @@ def _apply_dense(cfg, params, tokens, seq_axis_name=None, pos_offset=None):
     return apply_transformer(cfg, params, tokens, seq_axis_name, pos_offset), {}
 
 
+def _mla_moe_family(cfg) -> LMFamily:
+    from ..parallel.moe import routing_counters
+    from .mla_moe import apply_mla_moe, init_mla_moe
+
+    counters = (lambda aux: routing_counters(aux["counts"], aux["unserved"])) \
+        if cfg.moe_layers else None
+    return LMFamily(init_mla_moe, apply_mla_moe, counters)
+
+
+def _ssm_hybrid_family(cfg) -> LMFamily:
+    from .ssm_hybrid import apply_ssm_hybrid, init_ssm_hybrid, ssd_counters
+
+    return LMFamily(init_ssm_hybrid, apply_ssm_hybrid,
+                    ssd_counters if cfg.mamba_layers else None)
+
+
+class _Published(NamedTuple):
+    module: str          # under models/
+    config: str          # its config class
+    family: Callable     # the LMFamily of such a config
+    refuses: str         # what from_published turns down, for require_dense's message
+
+
+# The families built from a published config.json, by its `model_type`. The
+# ONE table: load_lm_config, lm_family and require_dense read it, and so do
+# their messages.
+_PUBLISHED_FAMILIES = {
+    "deepseek_v3": _Published(
+        "mla_moe", "MlaMoeConfig", _mla_moe_family,
+        "query compression, rope scaling, grouped routing, a tied head"),
+    "granitemoehybrid": _Published(
+        "ssm_hybrid", "SsmHybridConfig", _ssm_hybrid_family,
+        "routed experts, a positional term, a sequence axis of more than one member"),
+}
+
+
+def _config_class(model_type: str):
+    entry = _PUBLISHED_FAMILIES[model_type]
+    return getattr(importlib.import_module(f".{entry.module}", __package__), entry.config)
+
+
 def lm_family(cfg) -> LMFamily:
     if isinstance(cfg, TransformerConfig):
         return LMFamily(init_transformer, _apply_dense, None)
-    from .mla_moe import MlaMoeConfig, apply_mla_moe, init_mla_moe
-
-    if isinstance(cfg, MlaMoeConfig):
-        from ..parallel.moe import routing_counters
-
-        counters = (lambda aux: routing_counters(aux["counts"], aux["unserved"])) \
-            if cfg.moe_layers else None
-        return LMFamily(init_mla_moe, apply_mla_moe, counters)
-    raise TypeError(f"no LM family for a {type(cfg).__name__}")
+    for model_type, entry in _PUBLISHED_FAMILIES.items():
+        if isinstance(cfg, _config_class(model_type)):
+            return entry.family(cfg)
+    raise TypeError(
+        f"no LM family for a {type(cfg).__name__} (has: TransformerConfig, "
+        + ", ".join(entry.config for entry in _PUBLISHED_FAMILIES.values()) + ")")
 
 
 def require_dense(cfg, where: str) -> None:
@@ -52,7 +93,10 @@ def require_dense(cfg, where: str) -> None:
     if not isinstance(cfg, TransformerConfig):
         raise NotImplementedError(
             f"{where} runs the dense TransformerConfig family only; a "
-            f"{type(cfg).__name__} model trains through --parallelism dp_sp (ROADMAP D6)")
+            f"{type(cfg).__name__} model trains through --parallelism dp_sp (ROADMAP D6), "
+            "where each family refuses by name what it cannot express ("
+            + "; ".join(f"{kind}: {entry.refuses}" for kind, entry in _PUBLISHED_FAMILIES.items())
+            + ")")
 
 
 def load_lm_config(published: Union[str, Dict], **run):
@@ -63,8 +107,7 @@ def load_lm_config(published: Union[str, Dict], **run):
         with open(published) as f:
             published = json.load(f)
     kind = published.get("model_type")
-    if kind == "deepseek_v3":
-        from .mla_moe import MlaMoeConfig
-
-        return MlaMoeConfig.from_published(published, **run)
-    raise ValueError(f"model_type {kind!r} has no family here (has: deepseek_v3)")
+    if kind not in _PUBLISHED_FAMILIES:
+        raise ValueError(f"model_type {kind!r} has no family here "
+                         f"(has: {', '.join(_PUBLISHED_FAMILIES)})")
+    return _config_class(kind).from_published(published, **run)

@@ -75,9 +75,11 @@ EVENT_KINDS: Dict[str, EventSpec] = {
     "train_lm": EventSpec(
         required=("step", "loss", "time_cost"),
         int_fields=("step", "moe_rows_here", "moe_max_expert_rows",
-                    "moe_min_expert_rows", "moe_tokens_unserved"),
+                    "moe_min_expert_rows", "moe_tokens_unserved",
+                    "ssd_chunks_cut_off"),
         doc="LM trainer log window (cli/train_lm.py); the moe_* routing "
-            "counters ride along for a family with dropless expert layers",
+            "counters ride along for a family with dropless expert layers, "
+            "ssd_chunks_cut_off for one with state-space layers",
     ),
     "grad_skip": EventSpec(
         required=("step", "skipped_steps", "skip_streak"),
@@ -138,10 +140,17 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # string, the `attention_path` they run on:
                     # models/transformer.attention_path) and `moe_route` (the
                     # dropless expert layers' rows, summed over layers;
-                    # `<name>_per_layer` lists ride along)
+                    # `<name>_per_layer` lists ride along); for a family
+                    # with state-space layers `ssd_plan` (the scan's
+                    # shapes and, a string, its `scan_path`: models/
+                    # ssm_hybrid.ssd_plan) and `ssd_state` at log steps
+                    # (`chunks_cut_off`, with its `_per_layer` list)
                     "block_q", "block_k", "grid_steps", "tiles_run",
                     "tiles_total", "d_qk", "d_v", "seq_shards", "rows_here",
-                    "max_expert_rows", "min_expert_rows", "tokens_unserved"),
+                    "max_expert_rows", "min_expert_rows", "tokens_unserved",
+                    "chunk", "n_chunks", "heads", "d_head", "d_state",
+                    "groups", "mamba_layers", "attention_layers",
+                    "chunks_cut_off"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "
             "that clock with the wall clock (wall_ns +- err_ns at t)",

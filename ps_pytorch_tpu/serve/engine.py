@@ -203,7 +203,7 @@ class ServingEngine:
     ):
         from ..models.lm import require_dense
 
-        require_dense(cfg, "the serving engine (no latent KV cache yet)")
+        require_dense(cfg, "the serving engine (no latent KV cache and no recurrent-state cache yet)")
         if not cfg.causal:
             raise ValueError("serving decode is autoregressive: cfg.causal")
         if serve.max_len > cfg.max_seq_len:
