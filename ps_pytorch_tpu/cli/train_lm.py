@@ -132,7 +132,12 @@ def main(argv=None) -> dict:
                         choices=["float32", "bfloat16"])
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--log-interval", type=int, default=10)
-    parser.add_argument("--remat", action="store_true")
+    parser.add_argument("--remat", action="store_true",
+                        help="rematerialize each block in backward: dp_sp "
+                             "keeps a block's input and the within-chip flash "
+                             "kernels' o and lse (the `flash_plan` line says "
+                             "how many bytes a layer) and runs the rest "
+                             "again; the other schemes keep the input only")
     parser.add_argument("--bidirectional-ring", action="store_true")
     parser.add_argument("--parallelism", default="dp_sp",
                         choices=["dp_sp", "dp_tp", "tp", "pp", "moe",
@@ -477,7 +482,7 @@ def main(argv=None) -> dict:
         # the kernels' tile plan is static: how often the skip engages is
         # known here, from the shapes every attention call will have, and
         # so is the path select_attention takes (the same function decides)
-        from ..ops.flash_attention import plan_flash
+        from ..ops.flash_attention import FLASH_SAVED, plan_flash
 
         seq_shards = num_sp if args.parallelism in ("dp_sp", "ep_sp") else 1
         path = attention_path(cfg, seq_shards)
@@ -488,6 +493,15 @@ def main(argv=None) -> dict:
             "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total")}
         flash_plan.update(d_qk=d_qk, d_v=d_v, attention_path=path,
                           seq_shards=seq_shards)
+        # what `remat` keeps of each attention layer beside the block's
+        # input (models/transformer.remat_block): the kernel's o and lse,
+        # where the families' own apply runs the within-chip kernels
+        saves = cfg.remat and args.parallelism == "dp_sp" and path == "local"
+        rows = args.batch_size // args.num_dp * args.heads * plan.tq_pad
+        itemsize = jnp.dtype(cfg.effective_compute_dtype).itemsize
+        flash_plan.update(
+            remat_saves=",".join(FLASH_SAVED) if saves else "",
+            saved_bytes_per_layer=rows * (d_v * itemsize + 4) if saves else 0)
         logger.info(
             "flash plan for T %d x D %d: %s (per head%s)", t_att,
             d_qk, flash_plan,

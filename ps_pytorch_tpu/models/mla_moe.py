@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.moe import DroplessSpec, moe_dropless_local
-from .transformer import select_attention
+from .transformer import remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -263,7 +263,7 @@ def apply_mla_moe(
         return mla_moe_block(cfg, x, blk, attend, pos)
 
     if cfg.remat:
-        block = jax.checkpoint(block)
+        block = remat_block(block)
     x = params["embed"][tokens].astype(cd)
     counts, unserved = [], []
     for blk in params["blocks"]:

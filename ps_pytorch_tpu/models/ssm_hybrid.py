@@ -47,7 +47,7 @@ import jax.numpy as jnp
 
 from ..ops.ssd import ssd_chunked
 from .mla_moe import _rms32
-from .transformer import select_attention
+from .transformer import remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -284,7 +284,7 @@ def apply_ssm_hybrid(
         return ssm_hybrid_block(cfg, x, blk, attend)
 
     if cfg.remat:
-        block = jax.checkpoint(block)
+        block = remat_block(block)
     x = (params["embed"][tokens] * cfg.embedding_multiplier).astype(cd)
     cut_off = []
     for blk in params["blocks"]:

@@ -36,9 +36,12 @@ class TransformerConfig:
     max_seq_len: int = 2048
     causal: bool = True
     dtype: Any = jnp.float32
-    # rematerialize each block's activations in backward (jax.checkpoint):
-    # trades ~1/3 more FLOPs for O(depth) -> O(1) activation memory, the
-    # standard lever for long-context training
+    # rematerialize each block in backward (remat_block): a block keeps its
+    # input and, where the within-chip flash kernels attend, their o and lse
+    # (B*H*T*(d_v*itemsize + 4) bytes a layer: o is as large as the input in
+    # compute_dtype here, where H*d_v == dim) and runs everything else again.
+    # ~1/3 more FLOPs for O(depth) -> O(1) of a block's other ~30 activations,
+    # the standard lever for long-context training
     remat: bool = False
     # rotate K/V both ways on the sequence ring (half the sequential hops,
     # both ICI directions of a physical ring) — see parallel/ring_attention
@@ -181,6 +184,19 @@ def select_attention(cfg: TransformerConfig, seq_axis_name: Optional[str] = None
     )
 
 
+def remat_block(block):
+    """`block` under jax.checkpoint as every LM family's `remat` means it:
+    the backward keeps the block's input and whatever inside it carries
+    ops/flash_attention.FLASH_SAVED's names (the flash forward kernel's o
+    and lse), and recomputes the rest. So the block runs twice and
+    ps_flash_fwd once. Where no value has those names (the jnp attention,
+    the ring's hops, a state-space block) this is jax.checkpoint(block)."""
+    from ..ops.flash_attention import FLASH_SAVED
+
+    return jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
+
+
 def transformer_block(cfg: TransformerConfig, x, blk, attend, mlp=None):
     """One pre-norm block: attention + GELU MLP, both residual.
 
@@ -239,7 +255,7 @@ def apply_transformer(
         return transformer_block(cfg, x, blk, attend)
 
     if cfg.remat:
-        block = jax.checkpoint(block)
+        block = remat_block(block)
     for blk in params["blocks"]:
         x = block(x, blk)
 

@@ -138,7 +138,9 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # instants of cli/train_lm.py: `flash_plan` (the
                     # kernels' tiles and widths, `seq_shards` and, a
                     # string, the `attention_path` they run on:
-                    # models/transformer.attention_path) and `moe_route` (the
+                    # models/transformer.attention_path; what `remat` keeps
+                    # of a layer, `saved_bytes_per_layer` under the names in
+                    # the string `remat_saves`) and `moe_route` (the
                     # dropless expert layers' rows, summed over layers;
                     # `<name>_per_layer` lists ride along); for a family
                     # with state-space layers `ssd_plan` (the scan's
@@ -146,7 +148,8 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # ssm_hybrid.ssd_plan) and `ssd_state` at log steps
                     # (`chunks_cut_off`, with its `_per_layer` list)
                     "block_q", "block_k", "grid_steps", "tiles_run",
-                    "tiles_total", "d_qk", "d_v", "seq_shards", "rows_here",
+                    "tiles_total", "d_qk", "d_v", "seq_shards",
+                    "saved_bytes_per_layer", "rows_here",
                     "max_expert_rows", "min_expert_rows", "tokens_unserved",
                     "chunk", "n_chunks", "heads", "d_head", "d_state",
                     "groups", "mamba_layers", "attention_layers",

@@ -58,10 +58,18 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .pallas_mode import kernel_mode, pallas_mode
 
 NEG_INF = -1e30
+
+# The names _flash_vjp_fwd gives the two residuals only the forward kernel
+# can make (o and lse). A jax.checkpoint whose policy saves them
+# (models/transformer.remat_block) recomputes its block in the backward
+# without running ps_flash_fwd again; without such a policy the names
+# lower to nothing.
+FLASH_SAVED = ("ps_flash_o", "ps_flash_lse")
 
 # What one grid step may hold in VMEM by plan_flash's estimate: under the
 # 16 MiB a v5e kernel gets by default, with room for what the estimate
@@ -573,6 +581,7 @@ def _flash_vjp_fwd(q3, k3, v3, scale, causal, block_q, block_k, k_len):
     mode = kernel_mode("flash_attention")
     o, lse = _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
                         k_len=k_len)
+    o, lse = map(checkpoint_name, (o, lse), FLASH_SAVED)
     return o, (q3, k3, v3, o, lse)
 
 
