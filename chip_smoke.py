@@ -25,6 +25,11 @@ CLIs expose, on synthetic data made from a seed:
             chunks of 256; 4 query over 2 key/value heads; m m a m), bf16,
             flash, remat, Adam; then the chunked scan on the chip against
             the token-by-token recurrence
+  lm_kda    cli.train_lm --lm-config on a small preset of the hybrid
+            delta-rule / latent-attention expert family (KDA heads of 128,
+            chunks of 64; k k k a k; 8 of 16 routed experts held), bf16,
+            flash, remat, Adam; then the chunked delta rule on the chip
+            against the token-by-token recurrence, past -88 a chunk too
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -122,6 +127,24 @@ LM_SSM_CONFIG = {
     "residual_multiplier": 0.22, "logits_scaling": 8, "rms_norm_eps": 1e-5,
     "position_embedding_type": "nope", "num_local_experts": 0,
     "tie_word_embeddings": True,
+}
+# a small preset of the hybrid delta-rule / latent-attention expert family
+# at the published head widths (KDA heads of 128 for keys and values, 4
+# taps, chunks of 64; latent attention 128 + 64 and 128 without rotation)
+LM_KDA_CONFIG = {
+    "model_type": "kimi_linear", "vocab_size": 1024, "hidden_size": 512,
+    "num_hidden_layers": 5, "num_attention_heads": 4, "kv_lora_rank": 128,
+    "q_lora_rank": None, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "intermediate_size": 1024, "moe_intermediate_size": 256,
+    "num_experts": 16, "num_experts_per_token": 3, "num_shared_experts": 1,
+    "first_k_dense_replace": 1, "routed_scaling_factor": 2.446,
+    "moe_renormalize": True, "moe_router_activation_func": "sigmoid",
+    "num_expert_group": 1, "topk_group": 1, "mla_use_nope": True,
+    "rope_theta": 10000, "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
+    "linear_attn_config": {"kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+                           "num_heads": 4, "head_dim": 128,
+                           "short_conv_kernel_size": 4},
+    "experts_held": 8, "expert_offset": 0,
 }
 LM_CONFIG_ARGS = [
     "--seq-len", "1024", "--batch-size", "2", "--dtype", "bfloat16",
@@ -623,6 +646,52 @@ def leg_lm_ssm(workdir, devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_kda(workdir, devices, clog):
+    """The fourth LM family through `cli.train_lm --lm-config` (data
+    parallel over the chips: the delta rule's state crosses no sequence
+    shard), then the chunked rule by itself, bfloat16 products on the chip,
+    against the float32 recurrence token by token: at the source's decays
+    and with every fourth channel losing 3 a token (192 a chunk of 64, where
+    exp(G_i) * exp(-G_j) would overflow)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.ops import kda
+
+    leg = "lm_kda"
+    path = os.path.join(workdir, "lm_kda_small.json")
+    with open(path, "w") as f:
+        json.dump(LM_KDA_CONFIG, f)
+    out = train_lm_cli.main(
+        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
+        + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
+    check_finite(leg, "loss", out["loss"])
+    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+
+    k = jax.random.split(jax.random.key(4), 7)
+    t, h, d = 1024, 4, 128
+    q = kda.l2_normalize(jax.random.normal(k[0], (1, t, h, d)), d ** -0.5).astype(jnp.bfloat16)
+    key = kda.l2_normalize(jax.random.normal(k[1], (1, t, h, d))).astype(jnp.bfloat16)
+    v = jax.random.normal(k[2], (1, t, h, d), jnp.bfloat16)
+    beta = jax.nn.sigmoid(jax.random.normal(k[3], (1, t, h)))
+    a = jax.random.uniform(k[4], (h, 1), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(k[5], (1, t, h, d), minval=np.log(1e-3), maxval=np.log(1e-1)))
+    harsh = jnp.where(jnp.arange(d) % 4 == 0, -3.0, -0.01) * jnp.ones((1, t, h, d))
+    for name, g in (("source decays", -a * dt), ("past -88 a chunk", harsh)):
+        got, _ = jax.jit(kda.kda_chunked, static_argnums=5)(q, key, v, g, beta, 64)
+        want = jax.jit(kda.kda_recurrence)(q, key, v, g, beta)
+        gap = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+        if not gap < 0.03:      # bfloat16 operands, float32 sums: under 3% of the range
+            raise AssertionError(
+                f"{leg}: chunked delta rule ({name}) is {gap:.4f} of its range off the recurrence")
+        print(f"[{leg}] chunked delta rule vs recurrence, {name}: {gap:.5f} of the range",
+              flush=True)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -745,6 +814,7 @@ def main() -> int:
         run("serve", lambda clog: leg_serve(lm_dir, devices, clog))
         run("lm_config", lambda clog: leg_lm_config(workdir, devices, clog))
         run("lm_ssm", lambda clog: leg_lm_ssm(workdir, devices, clog))
+        run("lm_kda", lambda clog: leg_lm_kda(workdir, devices, clog))
 
     print(json.dumps({
         "versions": versions,

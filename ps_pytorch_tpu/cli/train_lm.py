@@ -33,13 +33,18 @@ the family, the same call the benchmark's driver makes). Such a model
 trains through --parallelism dp_sp; what it counts is logged at log steps
 and recorded as an instant: the expert layers' routing as `moe_route`, the
 state-space scan's cut-off chunks as `ssd_state` (after one `ssd_plan` at
-the start; that family runs --num-sp 1 only).
+the start; that family runs --num-sp 1 only), the delta rule's as
+`kda_state` (after one `kda_plan`; `model_type: kimi_linear`, --num-sp 1
+only, and its expert layers' `moe_route` beside it).
 
   ... --lm-config benchmark/configs/kanana2_30b_a3b_ep8.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
       --attention-impl flash --optimizer adam --lr 3e-4
   ... --lm-config benchmark/configs/granite4_h_micro_1period.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 1 --dtype bfloat16 --remat \
+      --attention-impl flash --optimizer adam --lr 3e-4
+  ... --lm-config benchmark/configs/kimi_linear_48b_a3b_ep32.json --num-dp 1 \
+      --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
       --attention-impl flash --optimizer adam --lr 3e-4
 """
 
@@ -515,6 +520,13 @@ def main(argv=None) -> dict:
         plan = ssd_plan(cfg, args.seq_len)
         logger.info("ssd plan for T %d: %s (per row)", args.seq_len, plan)
         tr.instant("ssd_plan", **plan)
+    if getattr(cfg, "kda_layers", ()):
+        # and so are the delta rule's
+        from ..models.kda_hybrid import kda_plan
+
+        plan = kda_plan(cfg, args.seq_len)
+        logger.info("kda plan for T %d: %s (per row)", args.seq_len, plan)
+        tr.instant("kda_plan", **plan)
 
     def save_lm_checkpoint(step_no):
         if args.train_dir is None:
@@ -637,6 +649,19 @@ def main(argv=None) -> dict:
                         )
                         tr.instant("ssd_state", **{
                             k[len("ssd_"):]: v for k, v in c.items()})
+                    if "kda_chunks_cut_off" in record:
+                        # the delta rule's chunks (models/kda_hybrid.
+                        # kda_counters): where even the slowest channel's
+                        # decay over a chunk is under 2^-24, the carried
+                        # state does no work
+                        logger.info(
+                            "KDA: %d (row, chunk, head) cut off from the "
+                            "chunk before, per layer %s", c["kda_chunks_cut_off"],
+                            c["kda_chunks_cut_off_per_layer"],
+                        )
+                        tr.instant("kda_state", **{
+                            k[len("kda_"):]: v for k, v in c.items()
+                            if k.startswith("kda_")})
                     if "moe_rows_here" in record:
                         # the expert layers' routing (parallel/moe.
                         # routing_counters)
@@ -648,7 +673,8 @@ def main(argv=None) -> dict:
                             c["moe_tokens_unserved"], c["moe_rows_max_over_mean"],
                         )
                         tr.instant("moe_route", **{
-                            k[len("moe_"):]: v for k, v in c.items()})
+                            k[len("moe_"):]: v for k, v in c.items()
+                            if k.startswith("moe_")})
                     with tr.span("metrics_write"):
                         append_metrics_line(args.metrics_file, record)
                     flush_due = True

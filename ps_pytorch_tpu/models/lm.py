@@ -12,8 +12,8 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
 
 `load_lm_config` builds a config from a published config.json-shaped dict
 by its `model_type` (`_PUBLISHED_FAMILIES`: deepseek_v3 -> models/mla_moe.py,
-granitemoehybrid -> models/ssm_hybrid.py); TransformerConfig is built from
-sizes as before.
+granitemoehybrid -> models/ssm_hybrid.py, kimi_linear -> models/kda_hybrid.py);
+TransformerConfig is built from sizes as before.
 """
 
 from __future__ import annotations
@@ -51,6 +51,13 @@ def _ssm_hybrid_family(cfg) -> LMFamily:
                     ssd_counters if cfg.mamba_layers else None)
 
 
+def _kda_hybrid_family(cfg) -> LMFamily:
+    from .kda_hybrid import apply_kda_hybrid, init_kda_hybrid, kda_counters
+
+    return LMFamily(init_kda_hybrid, apply_kda_hybrid,
+                    kda_counters if cfg.moe_layers or cfg.kda_layers else None)
+
+
 class _Published(NamedTuple):
     module: str          # under models/
     config: str          # its config class
@@ -68,6 +75,11 @@ _PUBLISHED_FAMILIES = {
     "granitemoehybrid": _Published(
         "ssm_hybrid", "SsmHybridConfig", _ssm_hybrid_family,
         "routed experts, a positional term, a sequence axis of more than one member"),
+    "kimi_linear": _Published(
+        "kda_hybrid", "KdaHybridConfig", _kda_hybrid_family,
+        "a router activation other than sigmoid, expert groups, query compression, rope "
+        "scaling, a tied head, next-token-prediction layers, a sequence axis of more than "
+        "one member"),
 }
 
 

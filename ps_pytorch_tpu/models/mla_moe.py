@@ -78,6 +78,9 @@ class MlaMoeConfig:
     norm_topk_prob: bool = True
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-6
+    # no rotation of the 64 "rope" channels: no deepseek_v3 config sets it;
+    # models/kda_hybrid.py's latent attention layers are this one with it on
+    mla_use_nope: bool = False
     # this chip's share of the routed experts (all of them by default)
     experts_held: Optional[int] = None
     expert_offset: int = 0
@@ -204,8 +207,10 @@ def _gated_mlp(n, w, cd):
         @ w["w_down"].astype(cd)
 
 
-def mla_attention(cfg: MlaMoeConfig, n, blk, attend, pos):
-    """n [B, T, D] in the compute dtype -> the attention branch [B, T, D]."""
+def mla_attention(cfg, n, blk, attend, pos):
+    """n [B, T, D] in the compute dtype -> the attention branch [B, T, D].
+    `cfg.mla_use_nope` leaves both rotations out: the shared 64 channels
+    then are plain key channels and `pos` is unused."""
     cd = n.dtype
     b, t, _ = n.shape
     h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
@@ -214,28 +219,45 @@ def mla_attention(cfg: MlaMoeConfig, n, blk, attend, pos):
     kva = n @ blk["wkv_a"].astype(cd)
     c = _rms32(kva[..., :cfg.kv_lora_rank], blk["kv_norm"]["scale"], cfg.rms_norm_eps).astype(cd)
     kvb = (c @ blk["wkv_b"].astype(cd)).reshape(b, t, h, dn + dv)
-    q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
-    k_rope = _rope(kva[..., None, cfg.kv_lora_rank:], pos, cfg.rope_theta)
-    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    if cfg.mla_use_nope:
+        k_rope = kva[..., None, cfg.kv_lora_rank:]
+    else:
+        q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+        k_rope = _rope(kva[..., None, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
     k = jnp.concatenate(
         [kvb[..., :dn], jnp.broadcast_to(k_rope, (b, t, h, dr))], axis=-1)
     o = attend(q, k, kvb[..., dn:])                                    # [B, T, H, dv]
     return o.reshape(b, t, h * dv) @ blk["wo"].astype(cd)
 
 
-def mla_moe_block(cfg: MlaMoeConfig, x, blk, attend, pos):
-    """One block -> (x, counts int32 [held], unserved int32); the counters
-    are zeros for a dense layer."""
-    cd = cfg.effective_compute_dtype
-    x = x.astype(cd)
-    x = x + mla_attention(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd),
-                          blk, attend, pos)
+def mla_mixer_half(cfg, x, blk, attend, pos):
+    """x [B, T, D] in the compute dtype -> x + Attn(norm(x))."""
+    cd = x.dtype
+    return x + mla_attention(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd),
+                             blk, attend, pos)
+
+
+def ffn_half(cfg, x, blk):
+    """x [B, T, D] in the compute dtype -> (x + FFN(norm(x)), counts int32
+    [held], unserved int32): the dense MLP where the block holds `mlp`
+    (zero counters), else the routed experts held here plus the shared
+    expert. The half every block of this family and of models/kda_hybrid.py
+    ends in, whatever its mixer."""
+    cd = x.dtype
     n32 = _rms32(x, blk["ln2"], cfg.rms_norm_eps)
     if "mlp" in blk:
         return (x + _gated_mlp(n32.astype(cd), blk["mlp"], cd),
                 jnp.zeros((cfg.experts_held,), jnp.int32), jnp.int32(0))
     routed, counts, unserved = moe_dropless_local(n32, blk, cfg.routing, cd)
     return x + routed.astype(cd) + _gated_mlp(n32.astype(cd), blk["shared"], cd), counts, unserved
+
+
+def mla_moe_block(cfg: MlaMoeConfig, x, blk, attend, pos):
+    """One block -> (x, counts int32 [held], unserved int32); the counters
+    are zeros for a dense layer."""
+    x = mla_mixer_half(cfg, x.astype(cfg.effective_compute_dtype), blk, attend, pos)
+    return ffn_half(cfg, x, blk)
 
 
 def apply_mla_moe(

@@ -76,10 +76,11 @@ EVENT_KINDS: Dict[str, EventSpec] = {
         required=("step", "loss", "time_cost"),
         int_fields=("step", "moe_rows_here", "moe_max_expert_rows",
                     "moe_min_expert_rows", "moe_tokens_unserved",
-                    "ssd_chunks_cut_off"),
+                    "ssd_chunks_cut_off", "kda_chunks_cut_off"),
         doc="LM trainer log window (cli/train_lm.py); the moe_* routing "
             "counters ride along for a family with dropless expert layers, "
-            "ssd_chunks_cut_off for one with state-space layers",
+            "ssd_chunks_cut_off for one with state-space layers, "
+            "kda_chunks_cut_off for one with delta-rule layers",
     ),
     "grad_skip": EventSpec(
         required=("step", "skipped_steps", "skip_streak"),
@@ -146,14 +147,18 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # with state-space layers `ssd_plan` (the scan's
                     # shapes and, a string, its `scan_path`: models/
                     # ssm_hybrid.ssd_plan) and `ssd_state` at log steps
-                    # (`chunks_cut_off`, with its `_per_layer` list)
+                    # (`chunks_cut_off`, with its `_per_layer` list); for
+                    # one with delta-rule layers `kda_plan` (the chunk,
+                    # the smallest block its scores and inverse are built
+                    # from, the padded length: models/kda_hybrid.kda_plan)
+                    # and `kda_state` at log steps (`chunks_cut_off` too)
                     "block_q", "block_k", "grid_steps", "tiles_run",
                     "tiles_total", "d_qk", "d_v", "seq_shards",
                     "saved_bytes_per_layer", "rows_here",
                     "max_expert_rows", "min_expert_rows", "tokens_unserved",
                     "chunk", "n_chunks", "heads", "d_head", "d_state",
                     "groups", "mamba_layers", "attention_layers",
-                    "chunks_cut_off"),
+                    "chunks_cut_off", "sub_block", "padded_len", "kda_layers"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "
             "that clock with the wall clock (wall_ns +- err_ns at t)",

@@ -622,16 +622,20 @@ def test_chrome_trace_places_spans_by_the_newest_clock_sync():
 
 
 def test_trace_report_splits_loop_time_by_the_phases_under_step(tmp_path):
+    """Sleeps of 30 ms: under six xdist workers a sleep of 1 ms took two or
+    three and a span's own overhead as long, so the shares of 1 ms spans fell
+    under 0.3 (it passed alone); a sleep that overruns by 3 ms moves a share
+    of these by a twentieth."""
     t = Tracer("train", path=str(tmp_path / "trace_train_p0.jsonl"))
     with t.span("build"):
-        time.sleep(0.002)
+        time.sleep(0.005)
     for n in (1, 2):
         with t.span("step", step=n):
             with t.span("fetch"):
                 with t.span("gather"):
-                    time.sleep(0.001)
+                    time.sleep(0.03)
             with t.span("dispatch"):
-                time.sleep(0.001)
+                time.sleep(0.03)
     t.flush()
     _, summary = trace_report.merge([str(tmp_path / "trace_train_p0.jsonl")], [])
     frac = summary["fraction_of_loop_walltime"]["train"]
