@@ -152,6 +152,7 @@ LM_CONFIG_ARGS = [
     "--max-steps", "4", "--log-interval", "2", "--remat",
 ]
 LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm")
+LM_KDA_KERNELS = LM_CONFIG_KERNELS + ("ps_kda_inverse", "ps_kda_within_fwd", "ps_kda_within_bwd")
 FLASH_SHAPE = (8, 1024, 8, 64)  # B, T, H, D: the LM leg's attention
 BUCKET_ELEMS = (4 << 20) // 4   # one 4 MiB f32 gradient bucket
 
@@ -285,6 +286,38 @@ def jnp_twins():
         yield
     finally:
         del os.environ["PS_TPU_DISABLE_PALLAS"]
+
+
+def library_lm_step(config_path, num_dp, num_sp, batch):
+    """The step `cli.train_lm --lm-config <config_path>` + LM_CONFIG_ARGS
+    runs, built once more through the library and compiled: (cfg, the
+    compiled step, its (params, opt_state, tokens))."""
+    import jax
+    import jax.numpy as jnp
+
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.models.lm import load_lm_config
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel.dp_sp import (
+        init_lm_state,
+        make_lm_train_step,
+        make_mesh_2d,
+        shard_tokens_2d,
+    )
+
+    cfg = load_lm_config(config_path, attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    tx = build_optimizer("adam", 3e-4)
+    mesh = make_mesh_2d(num_dp, num_sp)
+    params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
+    seq = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--seq-len") + 1])
+    tokens = shard_tokens_2d(
+        jnp.asarray(train_lm_cli.make_synthetic_tokens(
+            cfg.vocab_size, batch, seq, seed=2)), mesh)
+    step = make_lm_train_step(cfg, tx, mesh).lower(
+        params, opt_state, tokens
+    ).compile()
+    return cfg, step, (params, opt_state, tokens)
 
 
 # ------------------------------------------------------------------ legs
@@ -555,17 +588,8 @@ def leg_lm_config(workdir, devices, clog):
     experts' grouped products must be Mosaic calls in the compiled step,
     and the routing counters must account for every token."""
     import jax
-    import jax.numpy as jnp
 
     from ps_pytorch_tpu.cli import train_lm as train_lm_cli
-    from ps_pytorch_tpu.models.lm import load_lm_config
-    from ps_pytorch_tpu.optim import build_optimizer
-    from ps_pytorch_tpu.parallel.dp_sp import (
-        init_lm_state,
-        make_lm_train_step,
-        make_mesh_2d,
-        shard_tokens_2d,
-    )
 
     leg = "lm_config"
     path = os.path.join(workdir, "lm_config_small.json")
@@ -578,25 +602,14 @@ def leg_lm_config(workdir, devices, clog):
     check_finite(leg, "loss", out["loss"])
     programs = clog.check_steps(leg, ["jit(worker_fn)"])
 
-    opt = dict(zip(LM_CONFIG_ARGS[::2], LM_CONFIG_ARGS[1::2]))
-    cfg = load_lm_config(path, attention_impl="flash", remat=True,
-                         compute_dtype=jnp.bfloat16)
-    tx = build_optimizer("adam", 3e-4)
-    mesh = make_mesh_2d(1, len(devices))
-    params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
-    batch, seq = int(opt["--batch-size"]), int(opt["--seq-len"])
-    tokens = shard_tokens_2d(
-        jnp.asarray(train_lm_cli.make_synthetic_tokens(
-            cfg.vocab_size, batch, seq, seed=2)), mesh)
-    step = make_lm_train_step(cfg, tx, mesh).lower(
-        params, opt_state, tokens
-    ).compile()
+    batch = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--batch-size") + 1])
+    cfg, step, (params, opt_state, tokens) = library_lm_step(path, 1, len(devices), batch)
     check_kernels(leg, step.as_text(), LM_CONFIG_KERNELS)
     params, opt_state, loss, counters = step(params, opt_state, tokens)
     check_finite(leg, "library step loss", jax.device_get(loss))
     c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
     held = cfg.experts_held / cfg.n_routed_experts * cfg.num_experts_per_tok
-    n = batch * seq
+    n = tokens.size
     if not (0.5 * held * n < c["moe_rows_here"] < min(2.0 * held, cfg.num_experts_per_tok) * n
             and 0 <= c["moe_tokens_unserved"] < n
             and c["moe_min_expert_rows"] <= c["moe_max_expert_rows"]):
@@ -659,6 +672,7 @@ def leg_lm_kda(workdir, devices, clog):
 
     from ps_pytorch_tpu.cli import train_lm as train_lm_cli
     from ps_pytorch_tpu.ops import kda
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
 
     leg = "lm_kda"
     path = os.path.join(workdir, "lm_kda_small.json")
@@ -669,6 +683,17 @@ def leg_lm_kda(workdir, devices, clog):
         + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
     check_finite(leg, "loss", out["loss"])
     programs = clog.check_steps(leg, ["jit(worker_fn)"])
+    # what the trainer's step holds: the chunk's own part as Mosaic kernels
+    # (the system solved once a KDA layer, `remat` or not), no XLA twin
+    cfg, step, _ = library_lm_step(path, len(devices), 1, 2 * len(devices))
+    text = step.as_text()
+    del step
+    check_kernels(leg, text, LM_KDA_KERNELS)
+    census = kernel_census(text)
+    if census["jnp"].get("ps_kda_within") or census["mosaic"]["ps_kda_inverse"] != len(cfg.kda_layers):
+        raise AssertionError(
+            f"{leg}: wanted ps_kda_inverse once a KDA layer ({len(cfg.kda_layers)}) and no "
+            f"ps_kda_within_jnp in the compiled step; census {census}")
 
     k = jax.random.split(jax.random.key(4), 7)
     t, h, d = 1024, 4, 128
@@ -679,6 +704,8 @@ def leg_lm_kda(workdir, devices, clog):
     a = jax.random.uniform(k[4], (h, 1), minval=1.0, maxval=16.0)
     dt = jnp.exp(jax.random.uniform(k[5], (1, t, h, d), minval=np.log(1e-3), maxval=np.log(1e-1)))
     harsh = jnp.where(jnp.arange(d) % 4 == 0, -3.0, -0.01) * jnp.ones((1, t, h, d))
+    if kda.scan_path(64, d, d) != "pallas_within+xla_scan":
+        raise AssertionError(f"{leg}: the chunked rule takes {kda.scan_path(64, d, d)} on the chip")
     for name, g in (("source decays", -a * dt), ("past -88 a chunk", harsh)):
         got, _ = jax.jit(kda.kda_chunked, static_argnums=5)(q, key, v, g, beta, 64)
         want = jax.jit(kda.kda_recurrence)(q, key, v, g, beta)

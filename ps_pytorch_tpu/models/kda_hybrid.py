@@ -347,14 +347,14 @@ def kda_plan(cfg: KdaHybridConfig, seq_len: int) -> Dict:
     alone (cli/train_lm.py logs it and records it as the `kda_plan`
     instant). `sub_block` is the smallest block the pair scores and the
     triangular inverse are built up from."""
-    from ..ops.kda import SCAN_PATH
+    from ..ops.kda import padded_len, scan_path
 
-    chunk = cfg.kda_chunk_size
-    n_chunks = -(-seq_len // chunk)
-    return {"chunk": chunk, "sub_block": 1, "n_chunks": n_chunks,
-            "padded_len": n_chunks * chunk, "heads": cfg.kda_heads,
+    chunk, d = cfg.kda_chunk_size, cfg.kda_head_dim
+    padded = padded_len(seq_len, chunk, d, d)
+    return {"chunk": chunk, "sub_block": 1, "n_chunks": padded // chunk,
+            "padded_len": padded, "heads": cfg.kda_heads,
             "d_head": cfg.kda_head_dim, "kda_layers": len(cfg.kda_layers),
-            "attention_layers": len(cfg.full_attn_layers), "scan_path": SCAN_PATH}
+            "attention_layers": len(cfg.full_attn_layers), "scan_path": scan_path(chunk, d, d)}
 
 
 def kda_counters(aux) -> Dict:

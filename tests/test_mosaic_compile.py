@@ -80,3 +80,86 @@ def test_grouped_products_compile_at_the_published_widths(shape, k, n):
 
     assert _mosaic_calls(jax.jit(fwd).lower(x, w, layout).compile()) == 1
     assert _mosaic_calls(jax.jit(bwd).lower(x, w, layout, dy).compile()) == 2
+
+
+# ------------------------------------------------ the delta rule's kernels
+
+
+@pytest.fixture()
+def as_on_a_tpu(monkeypatch):
+    """ops/pallas_mode.py picks from what the process observes: say TPU, so
+    the public entries take their compiled kernels."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("PS_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("PS_TPU_DISABLE_PALLAS", raising=False)
+
+
+def _kda_grads(*args):
+    from ps_pytorch_tpu.ops import kda
+
+    return jax.grad(lambda *a: jnp.sum(kda.kda_chunked(*a, 64)[0]), argnums=range(5))(*args)
+
+
+def test_kda_kernels_compile_at_the_cells_shapes_with_their_time_inside_kda_ms(shape, as_on_a_tpu):
+    """`kda_ms` goes by the shape at the end of an op's short name (the HLO
+    name and its largest result): every `ps_kda_*` call of the op lowered at
+    the kimi cell's shapes must match the pattern of
+    benchmark/layer_metrics/kda_ms.json, or its time falls out of the metric
+    and inflates `kda_roofline` with no work saved."""
+    import json
+    import os
+    import re
+
+    from benchmark.reducers.trace import short_name
+    from ps_pytorch_tpu.ops import kda
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics", "kda_ms.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    b, t, h, d = 2, 8192, 32, 128
+    q, g, beta = shape((b, t, h, d)), shape((b, t, h, d), jnp.float32), shape((b, t, h), jnp.float32)
+    assert kda.scan_path(64, d, d) == "pallas_within+xla_scan"
+    text = jax.jit(_kda_grads).lower(q, q, q, g, beta).compile().as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {
+        "ps_kda_inverse": 1, "ps_kda_within_fwd": 1, "ps_kda_within_bwd": 1}}
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and "ps_kda_" in line]
+    assert len(calls) == 3
+    for line in calls:
+        assert pattern.search(short_name(line)), short_name(line)
+
+
+def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu):
+    """The small preset of chip_smoke.py's `lm_kda` leg (KDA heads of 128,
+    chunks of 64; k k k a k) as `cli.train_lm` builds its step, `remat` on:
+    four KDA layers hold `ps_kda_inverse` four times (the forward that
+    `remat` runs again takes the kept inverse), the forward kernel eight
+    times, the backward four; nothing of the XLA twin; and `kda_plan` says
+    what ran."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import chip_smoke
+    from ps_pytorch_tpu.models import kda_hybrid
+    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+    from ps_pytorch_tpu.parallel.dp_sp import SEQ_AXIS, WORKER_AXIS, make_lm_train_step, make_mesh_2d
+
+    cfg = load_lm_config(dict(chip_smoke.LM_KDA_CONFIG), attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    assert kda_hybrid.kda_plan(cfg, 512)["scan_path"] == "pallas_within+xla_scan"
+    tx = optax.adam(1e-3)
+    mesh = make_mesh_2d(1, 1, devices=[topo.devices[0]])
+    state = jax.eval_shape(lambda k: (lambda p: (p, tx.init(p)))(lm_family(cfg).init(cfg, k)),
+                           jax.random.key(0))
+    on = lambda spec: (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                      sharding=NamedSharding(mesh, spec)))
+    params, opt = jax.tree_util.tree_map(on(P()), state)
+    tokens = on(P(WORKER_AXIS, SEQ_AXIS))(jax.ShapeDtypeStruct((2, 512), jnp.int32))
+    text = make_lm_train_step(cfg, tx, mesh).lower(params, opt, tokens).compile().as_text()
+    census = kernel_census(text)
+    layers = len(cfg.kda_layers)
+    assert layers == 4 and "ps_kda_within" not in census["jnp"]
+    assert {k: v for k, v in census["mosaic"].items() if k.startswith("ps_kda_")} == {
+        "ps_kda_inverse": layers, "ps_kda_within_fwd": 2 * layers, "ps_kda_within_bwd": layers}
