@@ -255,6 +255,14 @@ def check_memory_in_use(leg, devices):
           flush=True)
 
 
+def step_program(name):
+    """A train step's program as jax's compile log names it: the builders
+    name it after their function and the sources' stamp (obs/scopes.py)."""
+    from ps_pytorch_tpu.obs.scopes import stamped_name
+
+    return f"jit({stamped_name(name)})"
+
+
 def check_kernels(leg, hlo_text, expect):
     """The compiled program runs each expected kernel as a Mosaic custom
     call; an entry that took its jnp twin or interpret mode is named."""
@@ -270,6 +278,24 @@ def check_kernels(leg, hlo_text, expect):
             f"program (jnp twin or interpret mode took them); census "
             f"{census}"
         )
+
+
+def check_scopes(leg, hlo_text, remat):
+    """The compiled step still carries the program's scopes (obs/scopes.py):
+    its census places at least 98% of the result bytes and holds every
+    phase the step has. A scope lost in a refactor fails here, on the chip
+    path, before a benchmark metric falls silent."""
+    from ps_pytorch_tpu.obs.hlo import census as read
+
+    census = read(hlo_text)
+    want = {"forward", "backward", "update"} | ({"remat"} if remat else set())
+    print(f"[{leg}] scopes: {census['placed_bytes_pct']:.2f}% of result bytes "
+          f"placed, phases {census['phases']}", flush=True)
+    if census["placed_bytes_pct"] < 98.0 or not want <= set(census["phases"]):
+        raise AssertionError(
+            f"{leg}: the step's census places {census['placed_bytes_pct']:.2f}% of "
+            f"result bytes (98 wanted) with phases {census['phases']} ({sorted(want)} "
+            f"wanted); by place: {census['by_place'][:8]}")
 
 
 def check_finite(leg, name, value):
@@ -474,7 +500,7 @@ def leg_ps(wire, workdir, devices, clog):
 
     out = train_cli.main(argv + ["--train-dir", train_dir])
     # before the library pass below compiles the same step a second time
-    programs = clog.check_steps(leg, ["jit(step)"])
+    programs = clog.check_steps(leg, [step_program("step")])
     check_finite(leg, "train loss", out["train"]["loss"])
     check_finite(leg, "val loss", out["val"]["loss"])
     if ckpt.latest_valid_step(train_dir) != steps:
@@ -519,6 +545,7 @@ def leg_ps(wire, workdir, devices, clog):
         trainer.state, batch, trainer._key
     ).compile()
     check_kernels(leg, step.as_text(), PS_KERNELS[wire])
+    check_scopes(leg, step.as_text(), remat=False)
     state, metrics = step(trainer.state, batch, trainer._key)
     check_finite(leg, "library step loss", jax.device_get(metrics["loss"]))
     check_on_all_devices(leg, "params", state.params, devices)
@@ -549,7 +576,7 @@ def leg_lm(train_dir, devices, clog):
     steps = ckpt.available_steps(train_dir)
     if steps != [3, 6]:
         raise AssertionError(f"{leg}: wanted checkpoints [3, 6], got {steps}")
-    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
 
     # the same step once more through the library (cli.train_lm's dp_sp
     # branch, by the functions it calls), to look inside it
@@ -573,6 +600,7 @@ def leg_lm(train_dir, devices, clog):
         params, opt_state, tokens
     ).compile()
     check_kernels(leg, step.as_text(), LM_KERNELS)
+    check_scopes(leg, step.as_text(), remat=False)
     params, opt_state, loss = step(params, opt_state, tokens)
     check_finite(leg, "library step loss", jax.device_get(loss))
     check_on_all_devices(leg, "params", params, devices)
@@ -600,11 +628,12 @@ def leg_lm_config(workdir, devices, clog):
         + LM_CONFIG_ARGS
     )
     check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
 
     batch = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--batch-size") + 1])
     cfg, step, (params, opt_state, tokens) = library_lm_step(path, 1, len(devices), batch)
     check_kernels(leg, step.as_text(), LM_CONFIG_KERNELS)
+    check_scopes(leg, step.as_text(), remat="--remat" in LM_CONFIG_ARGS)
     params, opt_state, loss, counters = step(params, opt_state, tokens)
     check_finite(leg, "library step loss", jax.device_get(loss))
     c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
@@ -640,7 +669,11 @@ def leg_lm_ssm(workdir, devices, clog):
         ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
         + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
     check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
+    _, step, _ = library_lm_step(path, len(devices), 1, 2 * len(devices))
+    check_kernels(leg, step.as_text(), LM_KERNELS)
+    check_scopes(leg, step.as_text(), remat="--remat" in LM_CONFIG_ARGS)
+    del step
 
     k = jax.random.split(jax.random.key(3), 6)
     t, h, p, n = 1024, 8, 64, 128
@@ -682,13 +715,14 @@ def leg_lm_kda(workdir, devices, clog):
         ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
         + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
     check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, ["jit(worker_fn)"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
     # what the trainer's step holds: the chunk's own part as Mosaic kernels
     # (the system solved once a KDA layer, `remat` or not), no XLA twin
     cfg, step, _ = library_lm_step(path, len(devices), 1, 2 * len(devices))
     text = step.as_text()
     del step
     check_kernels(leg, text, LM_KDA_KERNELS)
+    check_scopes(leg, text, remat="--remat" in LM_CONFIG_ARGS)
     census = kernel_census(text)
     if census["jnp"].get("ps_kda_within") or census["mosaic"]["ps_kda_inverse"] != len(cfg.kda_layers):
         raise AssertionError(

@@ -172,7 +172,10 @@ def main(argv=None) -> dict:
     parser.add_argument("--metrics-file", type=str, default=None)
     parser.add_argument("--profile-dir", type=str, default=None,
                         help="write a jax.profiler device trace for steps "
-                             "3..12 (view with tensorboard/xprof)")
+                             "3..12 (view with tensorboard/xprof) and, for "
+                             "--parallelism dp_sp, step_scopes.json beside "
+                             "it: `tools/trace_report.py device <dir>` "
+                             "prints the device time by phase and scope")
     parser.add_argument("--trace", type=str, default=None, metavar="DIR",
                         help="write this process's host-phase span stream "
                              "(trace_train_lm_p<i>.jsonl) here: one `step` "
@@ -255,6 +258,7 @@ def main(argv=None) -> dict:
     # Each scheme yields (params, opt_state, run(params, opt, np_tokens) ->
     # (params, opt, loss)) over its own mesh; the training loop below is
     # scheme-agnostic.
+    scoped_step = None  # the scheme's step where it keeps its own census
     if args.parallelism == "dp_sp":
         num_sp = args.num_sp or max(n_dev // args.num_dp, 1)
         mesh = make_mesh_2d(args.num_dp, num_sp)
@@ -265,7 +269,7 @@ def main(argv=None) -> dict:
                 f"--batch-size must be divisible by num_dp={args.num_dp}"
             )
         params, opt_state = init_lm_state(cfg, tx, key, mesh)
-        step = make_lm_train_step(cfg, tx, mesh)
+        step = scoped_step = make_lm_train_step(cfg, tx, mesh)
 
         def run(p, o, tok):  # a family that counts returns a fourth value
             p, o, loss, *rest = step(p, o, shard_tokens_2d(jnp.asarray(tok), mesh))
@@ -457,6 +461,7 @@ def main(argv=None) -> dict:
         args.depth, args.dim, args.heads, n_params, args.seq_len, layout,
     )
     from ..obs import NULL_TRACER, Tracer, run_header
+    from ..obs.scopes import step_scopes_instant, write_step_scopes
 
     # one id for the metrics and the span stream, on every host
     run_id = _shared_run_id()
@@ -675,6 +680,11 @@ def main(argv=None) -> dict:
                         tr.instant("moe_route", **{
                             k[len("moe_"):]: v for k, v in c.items()
                             if k.startswith("moe_")})
+                    if step_no == 1 and args.trace and scoped_step is not None:
+                        # the census of the executable that just ran, once
+                        # (obs/scopes.ScopedStep.scopes; the device is idle
+                        # here: the log step's sync has drained it)
+                        tr.instant("step_scopes", **step_scopes_instant(scoped_step))
                     with tr.span("metrics_write"):
                         append_metrics_line(args.metrics_file, record)
                     flush_due = True
@@ -685,6 +695,8 @@ def main(argv=None) -> dict:
                     logger.info(
                         "profiler trace written to %s", args.profile_dir
                     )
+                    # what `tools/trace_report.py device` joins it with
+                    write_step_scopes(args.profile_dir, scoped_step)
                 if args.eval_freq > 0 and step_no % args.eval_freq == 0:
                     save_lm_checkpoint(step_no)
     finally:

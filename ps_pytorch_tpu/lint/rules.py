@@ -617,7 +617,8 @@ class HostSyncRule:
 # ------------------------------------------------------------------- PSL005
 
 def collect_donor_factories(tree: ast.AST) -> Dict[str, Tuple[int, ...]]:
-    """Functions that return ``jax.jit(..., donate_argnums=...)``: their
+    """Functions that return ``jax.jit(..., donate_argnums=...)``, bare or
+    as an argument of a wrapper (obs/scopes.ScopedStep): their
     name -> the donated positions. The repo idiom is
     ``return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())`` —
     the enabled branch of the conditional is what callers get unless they
@@ -629,12 +630,14 @@ def collect_donor_factories(tree: ast.AST) -> Dict[str, Tuple[int, ...]]:
         for ret in ast.walk(node):
             if not (isinstance(ret, ast.Return) and isinstance(ret.value, ast.Call)):
                 continue
-            call = ret.value
-            if _tail(call.func) not in ("jit", "pjit"):
-                continue
-            nums = _donate_argnums(call)
-            if nums:
-                out[node.name] = nums
+            # the jit call itself, or one handed to a wrapper in the
+            # return (`return ScopedStep(name, jax.jit(...))`)
+            for call in [ret.value] + [a for a in ret.value.args if isinstance(a, ast.Call)]:
+                if _tail(call.func) not in ("jit", "pjit"):
+                    continue
+                nums = _donate_argnums(call)
+                if nums:
+                    out[node.name] = nums
     return out
 
 

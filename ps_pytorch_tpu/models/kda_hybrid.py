@@ -48,6 +48,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import EMBED, HEAD_LOSS, MIXER_KDA, scope
 from ..ops.flash_attention import FLASH_SAVED
 from ..ops.kda import KDA_SAVED, kda_chunked, l2_normalize
 from ..parallel.moe import DroplessSpec, routing_counters
@@ -281,11 +282,12 @@ def mixer_half(cfg: KdaHybridConfig, x, blk, attend, pos):
     holds) -> (x in the compute dtype, the recurrence's cut-off count: zero
     for an MLA block)."""
     cd = cfg.effective_compute_dtype
-    x = x.astype(cd)
     if "a_log" not in blk:
-        return mla_mixer_half(cfg, x, blk, attend, pos), jnp.int32(0)
-    mixed, cut_off = kda_mixer(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd), blk)
-    return x + mixed, cut_off
+        return mla_mixer_half(cfg, x.astype(cd), blk, attend, pos), jnp.int32(0)
+    with scope(MIXER_KDA):
+        x = x.astype(cd)
+        mixed, cut_off = kda_mixer(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd), blk)
+        return x + mixed, cut_off
 
 
 def apply_kda_hybrid(
@@ -323,7 +325,8 @@ def apply_kda_hybrid(
         # triangular inverses kept beside the flash kernel's o and lse
         keep = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED, *KDA_SAVED)
         mixer, ffn = jax.checkpoint(mixer, policy=keep), jax.checkpoint(ffn, policy=keep)
-    x = params["embed"][tokens].astype(cd)
+    with scope(EMBED):
+        x = params["embed"][tokens].astype(cd)
     counts, unserved, cut_off = [], [], []
     for blk in params["blocks"]:
         x, cut = mixer(x, blk)
@@ -333,13 +336,15 @@ def apply_kda_hybrid(
             unserved.append(u)
         if "a_log" in blk:
             cut_off.append(cut)
-    n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
+    with scope(HEAD_LOSS):
+        n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
     aux = {}
     if counts:
         aux.update(counts=jnp.stack(counts), unserved=jnp.stack(unserved))
     if cut_off:
         aux["kda_cut_off"] = jnp.stack(cut_off)
-    return n @ params["head"].astype(cd), aux
+    with scope(HEAD_LOSS):
+        return n @ params["head"].astype(cd), aux
 
 
 def kda_plan(cfg: KdaHybridConfig, seq_len: int) -> Dict:

@@ -43,6 +43,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_MLA, MLP, MOE, scope
 from ..parallel.moe import DroplessSpec, moe_dropless_local
 from .transformer import remat_block, select_attention
 
@@ -234,8 +235,9 @@ def mla_attention(cfg, n, blk, attend, pos):
 def mla_mixer_half(cfg, x, blk, attend, pos):
     """x [B, T, D] in the compute dtype -> x + Attn(norm(x))."""
     cd = x.dtype
-    return x + mla_attention(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd),
-                             blk, attend, pos)
+    with scope(MIXER_MLA):
+        return x + mla_attention(cfg, _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd),
+                                 blk, attend, pos)
 
 
 def ffn_half(cfg, x, blk):
@@ -245,12 +247,17 @@ def ffn_half(cfg, x, blk):
     expert. The half every block of this family and of models/kda_hybrid.py
     ends in, whatever its mixer."""
     cd = x.dtype
-    n32 = _rms32(x, blk["ln2"], cfg.rms_norm_eps)
-    if "mlp" in blk:
-        return (x + _gated_mlp(n32.astype(cd), blk["mlp"], cd),
-                jnp.zeros((cfg.experts_held,), jnp.int32), jnp.int32(0))
-    routed, counts, unserved = moe_dropless_local(n32, blk, cfg.routing, cd)
-    return x + routed.astype(cd) + _gated_mlp(n32.astype(cd), blk["shared"], cd), counts, unserved
+    with scope(FFN):
+        n32 = _rms32(x, blk["ln2"], cfg.rms_norm_eps)
+        if "mlp" in blk:
+            with scope(MLP):
+                y = x + _gated_mlp(n32.astype(cd), blk["mlp"], cd)
+            return y, jnp.zeros((cfg.experts_held,), jnp.int32), jnp.int32(0)
+        with scope(MOE):
+            routed, counts, unserved = moe_dropless_local(n32, blk, cfg.routing, cd)
+        y = x + routed.astype(cd)
+        with scope(MLP):
+            return y + _gated_mlp(n32.astype(cd), blk["shared"], cd), counts, unserved
 
 
 def mla_moe_block(cfg: MlaMoeConfig, x, blk, attend, pos):
@@ -286,13 +293,16 @@ def apply_mla_moe(
 
     if cfg.remat:
         block = remat_block(block)
-    x = params["embed"][tokens].astype(cd)
+    with scope(EMBED):
+        x = params["embed"][tokens].astype(cd)
     counts, unserved = [], []
     for blk in params["blocks"]:
         x, c, u = block(x, blk)
         if "mlp" not in blk:
             counts.append(c)
             unserved.append(u)
-    n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
+    with scope(HEAD_LOSS):
+        n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
     routing = {"counts": jnp.stack(counts), "unserved": jnp.stack(unserved)} if counts else {}
-    return n @ params["head"].astype(cd), routing
+    with scope(HEAD_LOSS):
+        return n @ params["head"].astype(cd), routing

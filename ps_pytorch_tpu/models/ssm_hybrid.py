@@ -45,6 +45,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_ATTENTION, MIXER_SSD, MLP, scope
 from ..ops.ssd import ssd_chunked
 from .mla_moe import _rms32
 from .transformer import remat_block, select_attention
@@ -248,15 +249,18 @@ def ssm_hybrid_block(cfg: SsmHybridConfig, x, blk, attend):
     """One block of either kind (by the leaves it holds) -> (x, the scan's
     cut-off count: zero for an attention block)."""
     cd = cfg.effective_compute_dtype
-    x = x.astype(cd)
-    n = _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd)
-    if "in_proj" in blk:
-        mixed, cut_off = mamba_mixer(cfg, n, blk)
-    else:
-        mixed, cut_off = gqa_attention(cfg, n, blk, attend), jnp.int32(0)
-    x = x + (cfg.residual_multiplier * mixed).astype(cd)
-    n = _rms32(x, blk["ln2"], cfg.rms_norm_eps).astype(cd)
-    return x + (cfg.residual_multiplier * _gated_mlp(n, blk["mlp"], cd)).astype(cd), cut_off
+    with scope(MIXER_SSD if "in_proj" in blk else MIXER_ATTENTION):
+        x = x.astype(cd)
+        n = _rms32(x, blk["ln1"], cfg.rms_norm_eps).astype(cd)
+        if "in_proj" in blk:
+            mixed, cut_off = mamba_mixer(cfg, n, blk)
+        else:
+            mixed, cut_off = gqa_attention(cfg, n, blk, attend), jnp.int32(0)
+        x = x + (cfg.residual_multiplier * mixed).astype(cd)
+    with scope(FFN):
+        n = _rms32(x, blk["ln2"], cfg.rms_norm_eps).astype(cd)
+        with scope(MLP):
+            return x + (cfg.residual_multiplier * _gated_mlp(n, blk["mlp"], cd)).astype(cd), cut_off
 
 
 def apply_ssm_hybrid(
@@ -285,14 +289,16 @@ def apply_ssm_hybrid(
 
     if cfg.remat:
         block = remat_block(block)
-    x = (params["embed"][tokens] * cfg.embedding_multiplier).astype(cd)
+    with scope(EMBED):
+        x = (params["embed"][tokens] * cfg.embedding_multiplier).astype(cd)
     cut_off = []
     for blk in params["blocks"]:
         x, c = block(x, blk)
         if "in_proj" in blk:
             cut_off.append(c)
-    n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
-    logits = (n @ params["embed"].T.astype(cd)) / cfg.logits_scaling
+    with scope(HEAD_LOSS):
+        n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
+        logits = (n @ params["embed"].T.astype(cd)) / cfg.logits_scaling
     return logits, ({"ssd_cut_off": jnp.stack(cut_off)} if cut_off else {})
 
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_ATTENTION, MLP, scope
 from ..parallel.ring_attention import SEQ_AXIS, full_attention, ring_attention
 
 
@@ -197,6 +198,14 @@ def remat_block(block):
         block, policy=jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
 
 
+_MIXER_LEAVES = ("ln1", "wqkv", "wo")
+
+
+def _cast(v, dtype, name):
+    with scope(name):
+        return v.astype(dtype)
+
+
 def transformer_block(cfg: TransformerConfig, x, blk, attend, mlp=None):
     """One pre-norm block: attention + GELU MLP, both residual.
 
@@ -208,21 +217,26 @@ def transformer_block(cfg: TransformerConfig, x, blk, attend, mlp=None):
     the dense GELU MLP, mapping the normed hidden [B,T,D] -> [B,T,D].
     """
     cd = cfg.effective_compute_dtype
-    x = x.astype(cd)
+    x = _cast(x, cd, MIXER_ATTENTION)
     # cast weights at use, not at init: params (and grads/moments) keep
-    # their storage dtype; only the block math runs in compute_dtype
-    blk = {k: v.astype(cd) for k, v in blk.items()}
-    b, t = x.shape[0], x.shape[1]
-    h = _rms_norm(x, blk["ln1"])
-    qkv = h @ blk["wqkv"]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    split_heads = lambda a: a.reshape(b, t, cfg.heads, cfg.head_dim)
-    o = attend(split_heads(q), split_heads(k), split_heads(v))
-    x = x + o.reshape(b, t, cfg.dim) @ blk["wo"]
-    h = _rms_norm(x, blk["ln2"])
-    if mlp is not None:
-        return x + mlp(h)
-    return x + jax.nn.gelu(h @ blk["w_up"]) @ blk["w_down"]
+    # their storage dtype; only the block math runs in compute_dtype (each
+    # cast under the scope of the half that reads it, in the leaves' order)
+    blk = {k: _cast(v, cd, MIXER_ATTENTION if k in _MIXER_LEAVES else FFN)
+           for k, v in blk.items()}
+    with scope(MIXER_ATTENTION):
+        b, t = x.shape[0], x.shape[1]
+        h = _rms_norm(x, blk["ln1"])
+        qkv = h @ blk["wqkv"]
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        split_heads = lambda a: a.reshape(b, t, cfg.heads, cfg.head_dim)
+        o = attend(split_heads(q), split_heads(k), split_heads(v))
+        x = x + o.reshape(b, t, cfg.dim) @ blk["wo"]
+    with scope(FFN):
+        h = _rms_norm(x, blk["ln2"])
+        if mlp is not None:
+            return x + mlp(h)
+        with scope(MLP):
+            return x + jax.nn.gelu(h @ blk["w_up"]) @ blk["w_down"]
 
 
 def apply_transformer(
@@ -249,7 +263,8 @@ def apply_transformer(
     if pos_offset is not None:
         shard = shard + pos_offset
     pos = shard + jnp.arange(t_loc)
-    x = params["embed"][tokens] + params["pos_embed"][pos][None]
+    with scope(EMBED):
+        x = params["embed"][tokens] + params["pos_embed"][pos][None]
 
     def block(x, blk):
         return transformer_block(cfg, x, blk, attend)
@@ -260,8 +275,9 @@ def apply_transformer(
         x = block(x, blk)
 
     cd = cfg.effective_compute_dtype
-    xf = _rms_norm(x.astype(cd), params["out_norm"].astype(cd))
-    return xf @ params["embed"].T.astype(cd)
+    with scope(HEAD_LOSS):
+        xf = _rms_norm(x.astype(cd), params["out_norm"].astype(cd))
+        return xf @ params["embed"].T.astype(cd)
 
 
 def make_sp_forward(

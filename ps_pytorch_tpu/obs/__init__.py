@@ -1,6 +1,6 @@
 """Observability: structured tracing, event schema, profiler windows.
 
-Three layers (ARCHITECTURE §7g):
+Five layers (ARCHITECTURE §7g; the last two: PERF.md section 3):
 
 - ``obs.schema`` — the unified JSONL event registry (kind -> required
   fields + int contract), ``run_header`` records, run ids;
@@ -8,7 +8,13 @@ Three layers (ARCHITECTURE §7g):
   once per window while the device is busy, Chrome-trace exportable)
   and NULL_TRACER, the zero-cost off switch;
 - ``obs.profiler`` — bounded ``jax.profiler`` capture windows for
-  ``--profile-dir``.
+  ``--profile-dir``;
+- ``obs.scopes`` — the names a step program writes INSIDE itself
+  (``scope(MIXER_KDA)``: one vocabulary) and ``ScopedStep``, the jitted
+  step that gives the census of its own executable;
+- ``obs.hlo`` — the one reader of a compiled program's text: phase,
+  scope and kind of work of every instruction, joined to a device
+  capture by instruction name (``tools/trace_report.py device``).
 
 Contract: tracer-off adds zero host syncs, tracer-on reuses the
 driver's existing per-window sync points — pslint PSL004 patrols this

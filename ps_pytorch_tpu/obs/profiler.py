@@ -15,9 +15,12 @@ other sync in this tree.
 
 from __future__ import annotations
 
+import glob
+import os
 from typing import Optional
 
 from ..utils import get_logger
+from .scopes import write_step_scopes
 
 logger = get_logger()
 
@@ -30,10 +33,12 @@ class ProfileWindow:
     block so a run that ends or raises inside the window still writes a
     valid trace. ``sync`` is any pytree to block on before stopping —
     the trainer passes its params so the captured window contains
-    retired device work, not just dispatch."""
+    retired device work, not just dispatch. With `step` (a step that
+    keeps its own census: obs/scopes.ScopedStep) the capture gets
+    `step_scopes.json` beside it when it stops."""
 
     def __init__(self, profile_dir: Optional[str], start_step: int,
-                 num_steps: int = 10):
+                 num_steps: int = 10, step=None):
         # validate only when profiling is actually requested: the trainer
         # constructs this unconditionally, and a stray --profile-steps 0
         # without --profile-dir must not abort the run it doesn't affect
@@ -43,6 +48,9 @@ class ProfileWindow:
         self.start = int(start_step)
         self.stop = int(start_step) + int(num_steps)
         self.active = False
+        # the step whose census goes beside the capture (obs/scopes.
+        # ScopedStep), read when the capture stops
+        self.step = step
 
     def before_step(self, step: int, sync=None) -> None:
         if self.dir is None:
@@ -82,3 +90,19 @@ class ProfileWindow:
         jax.profiler.stop_trace()
         self.active = False
         logger.info("profiler trace written to %s", self.dir)
+        # what `tools/trace_report.py device` joins the capture with
+        write_step_scopes(self.dir, self.step)
+
+
+def device_planes(profile_dir: str):
+    """(the newest capture `plugins/profile/*/*.xplane.pb` under a
+    `--profile-dir`, its `/device:` planes as jax.profiler.ProfileData
+    gives them); (None, []) where there is no capture."""
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(profile_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    if not files:
+        return None, []
+    newest = max(files, key=os.path.getmtime)
+    return newest, [plane for plane in ProfileData.from_file(newest).planes
+                    if plane.name.startswith("/device:")]

@@ -158,7 +158,12 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "max_expert_rows", "min_expert_rows", "tokens_unserved",
                     "chunk", "n_chunks", "heads", "d_head", "d_state",
                     "groups", "mamba_layers", "attention_layers",
-                    "chunks_cut_off", "sub_block", "padded_len", "kda_layers"),
+                    "chunks_cut_off", "sub_block", "padded_len", "kda_layers",
+                    # `step_scopes`, once after the first step of a
+                    # dp_sp run: the census of the compiled step (obs/
+                    # scopes.step_scopes_instant; `phases` and `scopes`
+                    # are comma-joined strings)
+                    "instructions", "mixed_instructions", "mosaic_calls"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "
             "that clock with the wall clock (wall_ns +- err_ns at t)",

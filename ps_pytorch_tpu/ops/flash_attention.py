@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..obs.scopes import FLASH, scope
 from .pallas_mode import kernel_mode, pallas_mode
 
 NEG_INF = -1e30
@@ -596,6 +597,7 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, k_len, res, do3):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+@scope(FLASH)
 def flash_attention(
     q: jax.Array,  # [B, T, H, D]
     k: jax.Array,

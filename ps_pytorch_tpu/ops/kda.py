@@ -73,6 +73,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..obs.scopes import DELTA_RULE, scope
 from .pallas_mode import kernel_mode, pallas_mode
 
 # the one value of a chunk that `remat` is worth keeping (models/kda_hybrid.py
@@ -620,6 +621,7 @@ def _across_chunks(qk, w, u0, kend, qg, total, zero_carried: bool = False):
     return jnp.moveaxis(o.reshape((nc,) + o.shape[2:]), 0, 1)
 
 
+@scope(DELTA_RULE)
 def kda_chunked(q, k, v, g, beta, chunk: int = 64, zero_carried: bool = False):
     """The chunked form -> (o float32 [B, T, H, V], cut_off int32): shapes
     as `kda_recurrence`; the products run in q.dtype. `cut_off` counts the

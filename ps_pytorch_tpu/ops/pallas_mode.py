@@ -19,7 +19,6 @@ on the chip for the first twenty PRs of this repo).
 from __future__ import annotations
 
 import os
-import re
 from typing import Dict, Optional
 
 import jax
@@ -63,34 +62,11 @@ def describe(mode: Optional[dict]) -> str:
     return "interpret" if mode.get("interpret") else "compiled"
 
 
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
-_KERNEL = re.compile(r"ps_[a-z0-9_]+")
-
-
 def kernel_census(hlo_text: str) -> Dict[str, Dict[str, int]]:
     """Which path each Pallas entry took in a COMPILED program, read from
-    its text (``jitted.lower(...).compile().as_text()``).
+    its text (``jitted.lower(...).compile().as_text()``): ``{"mosaic":
+    {kernel: n}, "jnp": {kernel: n_ops}}``. A view of the one reader of
+    compiled text, obs/hlo.py (``kernel_census`` there has the rules)."""
+    from ..obs.hlo import kernel_census as read
 
-    Every ``pl.pallas_call`` in ops/ carries a ``name="ps_<kernel>"`` and
-    every jnp twin runs under ``jax.named_scope("ps_<kernel>_jnp")``; both
-    survive into the optimized HLO's ``op_name`` metadata. Returns
-    ``{"mosaic": {kernel: n}, "jnp": {kernel: n_ops}}``: Mosaic custom
-    calls by kernel, and the kernels whose jnp twin is in the program. A
-    kernel that ran in interpret mode is in neither — it lowered to plain
-    HLO with no scope of its own — which is how a caller that expects it
-    under "mosaic" finds out."""
-    census: Dict[str, Dict[str, int]] = {"mosaic": {}, "jnp": {}}
-    for line in hlo_text.splitlines():
-        m = _OP_NAME.search(line)
-        if m is None:
-            continue
-        names = _KERNEL.findall(m.group(1))
-        if not names:
-            continue
-        name = names[-1]
-        if 'custom_call_target="tpu_custom_call"' in line:
-            census["mosaic"][name] = census["mosaic"].get(name, 0) + 1
-        elif name.endswith("_jnp"):
-            name = name[: -len("_jnp")]
-            census["jnp"][name] = census["jnp"].get(name, 0) + 1
-    return census
+    return read(hlo_text)

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.scopes import SCAN, scope
+
 SCAN_PATH = "xla"
 # float32 exp(x) is 0 below this: a chunk whose whole log-decay is under it
 # hands nothing of the state it was given to the next chunk
@@ -83,6 +85,7 @@ def _carry(states, total):
     return jnp.moveaxis(before, 0, 1)
 
 
+@scope(SCAN)
 def ssd_chunked(x, dt, a, b, c, d, chunk: int):
     """The chunked scan -> (y float32 [B, T, H, P], cut_off int32): shapes
     as `ssd_recurrence`; the products run in x.dtype. `cut_off` counts the

@@ -39,8 +39,13 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.lm import lm_family
+from ..obs.scopes import GRAD_REDUCE, HEAD_LOSS, UPDATE, ScopedStep, scope, stamped
 from .mesh import WORKER_AXIS, replicated_sharding
 from .ring_attention import SEQ_AXIS
+
+
+# the name obs/scopes.last_step finds this module's step under
+LM_TRAIN_STEP = "lm_train_step"
 
 
 def make_mesh_2d(
@@ -87,19 +92,20 @@ def lm_loss_local(
     logits, aux = lm_family(cfg).apply(cfg, params, tokens, seq_axis_name=sp_axis)
     # target of my last token = next shard's first token (ring shift left);
     # with one member that shard is this one (the position is masked below)
-    nxt_first = tokens[:, :1]
-    if n_sp > 1:
-        nxt_first = lax.ppermute(
-            nxt_first, sp_axis, [(j, (j - 1) % n_sp) for j in range(n_sp)]
-        )
-    tgt = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    pos = s * t_loc + jnp.arange(t_loc)
-    valid = (pos < n_sp * t_loc - 1).astype(jnp.float32)  # drop final position
-    loss_sum = jnp.sum(nll * valid[None, :])
-    count = jnp.float32(b_loc) * jnp.sum(valid)
-    return loss_sum / lax.psum(count, sp_axis), aux
+    with scope(HEAD_LOSS):
+        nxt_first = tokens[:, :1]
+        if n_sp > 1:
+            nxt_first = lax.ppermute(
+                nxt_first, sp_axis, [(j, (j - 1) % n_sp) for j in range(n_sp)]
+            )
+        tgt = jnp.concatenate([tokens[:, 1:], nxt_first], axis=1)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        pos = s * t_loc + jnp.arange(t_loc)
+        valid = (pos < n_sp * t_loc - 1).astype(jnp.float32)  # drop final position
+        loss_sum = jnp.sum(nll * valid[None, :])
+        count = jnp.float32(b_loc) * jnp.sum(valid)
+        return loss_sum / lax.psum(count, sp_axis), aux
 
 
 def init_lm_state(
@@ -137,13 +143,16 @@ def make_lm_train_step(
         )(params)
         # exact sequence gradient: sum local partials over sp exactly once;
         # PS aggregation: mean over dp (each dp shard saw a disjoint slice)
-        grads = lax.pmean(lax.psum(grads, sp_axis), dp_axis)
-        loss = lax.pmean(lax.psum(loss_local, sp_axis), dp_axis)
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with scope(GRAD_REDUCE):
+            grads = lax.pmean(lax.psum(grads, sp_axis), dp_axis)
+            loss = lax.pmean(lax.psum(loss_local, sp_axis), dp_axis)
+        with scope(UPDATE):
+            updates, new_opt = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         if counters is None:
             return new_params, new_opt, loss
-        return new_params, new_opt, loss, counters(lax.psum(aux, (dp_axis, sp_axis)))
+        with scope(GRAD_REDUCE):
+            return new_params, new_opt, loss, counters(lax.psum(aux, (dp_axis, sp_axis)))
 
     mapped = jax.shard_map(
         worker_fn,
@@ -152,4 +161,5 @@ def make_lm_train_step(
         out_specs=(P(), P(), P()) + (() if counters is None else (P(),)),
         check_vma=False,
     )
-    return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())
+    return ScopedStep(
+        LM_TRAIN_STEP, jax.jit(stamped(mapped), donate_argnums=(0, 1) if donate else ()))

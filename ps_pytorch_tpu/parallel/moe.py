@@ -49,6 +49,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.scopes import MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTE, scope
 from ..ops.metrics import next_token_nll
 from .tp import opt_state_specs
 
@@ -345,12 +346,36 @@ def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
         raise NotImplementedError(
             "the dropless layer runs one chip's share without its exchange; "
             "an all_to_all over an expert axis is not built (ROADMAP M3)")
-    from ..ops.grouped_matmul import TILE_M, buffer_rows, group_layout, grouped_matmul
+    from ..ops.grouped_matmul import grouped_matmul
 
     b, t, d = n32.shape
-    n, k, held_n = b * t, spec.top_k, spec.experts_held
+    n = b * t
     x32 = n32.reshape(n, d)
-    idx, w = dropless_route(x32, blk["router"], blk["router_bias"], spec)
+    with scope(MOE_ROUTE):
+        idx, w = dropless_route(x32, blk["router"], blk["router_bias"], spec)
+    with scope(MOE_DISPATCH):
+        route, counts, layout = _dispatch_plan(idx, spec, n)
+        xs = _rows_from_tokens(x32.astype(compute_dtype), route)
+    ex = blk["experts"]
+    with scope(MOE_EXPERTS):
+        gate = grouped_matmul(xs, ex["w_gate"], layout)
+        up = grouped_matmul(xs, ex["w_up"], layout)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, ex["w_down"], layout)
+    with scope(MOE_COMBINE):
+        # weighted on the row side, so no [N, k, D] tensor exists in either pass
+        ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
+        y = _tokens_from_rows(ys, route)
+    unserved = jnp.sum(~jnp.any(route[3], axis=-1), dtype=jnp.int32)
+    return y.reshape(b, t, d), counts, unserved
+
+
+def _dispatch_plan(idx, spec: DroplessSpec, n: int):
+    """(route, counts, layout) from the chosen experts idx [N, k]: which
+    buffer row holds which assignment (`route`, see above), the rows each
+    held expert got, and the grouped products' layout."""
+    from ..ops.grouped_matmul import TILE_M, buffer_rows, group_layout
+
+    k, held_n = spec.top_k, spec.experts_held
     local = idx - spec.expert_offset
     held = (local >= 0) & (local < held_n)                       # [N, k]
     key = jnp.where(held, local, held_n).reshape(-1)             # [A]
@@ -374,18 +399,7 @@ def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
     in_e = r - layout.starts[e_r]
     row_live = (in_e < counts[e_r]) & (r // TILE_M < layout.n_live[0])
     row_assign = jnp.where(row_live, order[jnp.minimum(first[e_r] + in_e, n * k - 1)], 0)
-    route = (row_assign, row_live, pos, held)
-
-    ex = blk["experts"]
-    xs = _rows_from_tokens(x32.astype(compute_dtype), route)
-    gate = grouped_matmul(xs, ex["w_gate"], layout)
-    up = grouped_matmul(xs, ex["w_up"], layout)
-    ys = grouped_matmul(jax.nn.silu(gate) * up, ex["w_down"], layout)
-    # weighted on the row side, so no [N, k, D] tensor exists in either pass
-    ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
-    y = _tokens_from_rows(ys, route)
-    unserved = jnp.sum(~jnp.any(held, axis=-1), dtype=jnp.int32)
-    return y.reshape(b, t, d), counts, unserved
+    return (row_assign, row_live, pos, held), counts, layout
 
 
 def routing_counters(counts, unserved):

@@ -45,6 +45,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import apply_model
+from ..obs.scopes import AUGMENT, GRAD_REDUCE, MODEL, UPDATE, ScopedStep, scope, stamped
 from ..ops.metrics import accuracy, cross_entropy_loss
 from ..ops.quantize import accum_dtype, dequantize_int8, quantize_int8
 from ..resilience.guard import (
@@ -70,6 +71,8 @@ from .collectives import aggregate_gradients, aggregation_mask
 from .mesh import WORKER_AXIS
 
 tree_map = jax.tree_util.tree_map
+# the name obs/scopes.last_step finds this module's step under
+PS_TRAIN_STEP = "ps_train_step"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -780,39 +783,41 @@ def _sharded_ps_update(params, opt_state, grads, tx, cfg, mask_key,
             bucket_key, err,
         )
 
-    flat_g = pad_flat(tree_to_flat(grads), plan)
-    if err is not None:
-        flat_g = flat_g + err
-    sent = flat_g * sel if sel is not None else flat_g
-    new_err = None
-    g_shards, contribs = [], []
-    for start, size in zip(plan.starts, plan.sizes):
-        bucket = lax.slice(sent, (start,), (start + size,))
-        g_b, contrib = _shard_reduce_bucket(
-            bucket, size, axis, n, w, k, cfg, bucket_key(start),
-            want_contrib=err is not None,
-        )
-        g_shards.append(g_b)
-        if contrib is not None:
-            contribs.append(contrib)
-    g_shard = concat_buckets(g_shards)
-    if err is not None:
-        new_err = flat_g - concat_buckets(contribs)
-    flat_p = params.flat  # already padded in this plan's geometry
-    p_shard = _worker_region(flat_p, plan, w, n)
-    upd_shard, new_opt = tx.update(g_shard, opt_state, p_shard)
-    # reassemble: each bucket's shard segment gathers back tiled, in
-    # bucket order, inverting _worker_region's layout exactly
-    off, full = 0, []
-    for size in plan.sizes:
-        s = size // n
-        full.append(lax.all_gather(
-            lax.slice(upd_shard, (off,), (off + s,)), axis, tiled=True
-        ))
-        off += s
-    # one vector add, no per-leaf scatter (the pad tail stays zero —
-    # zero gradient => zero update)
-    new_params = params.replace(flat=flat_p + concat_buckets(full))
+    with scope(GRAD_REDUCE):
+        flat_g = pad_flat(tree_to_flat(grads), plan)
+        if err is not None:
+            flat_g = flat_g + err
+        sent = flat_g * sel if sel is not None else flat_g
+        new_err = None
+        g_shards, contribs = [], []
+        for start, size in zip(plan.starts, plan.sizes):
+            bucket = lax.slice(sent, (start,), (start + size,))
+            g_b, contrib = _shard_reduce_bucket(
+                bucket, size, axis, n, w, k, cfg, bucket_key(start),
+                want_contrib=err is not None,
+            )
+            g_shards.append(g_b)
+            if contrib is not None:
+                contribs.append(contrib)
+        g_shard = concat_buckets(g_shards)
+        if err is not None:
+            new_err = flat_g - concat_buckets(contribs)
+    with scope(UPDATE):
+        flat_p = params.flat  # already padded in this plan's geometry
+        p_shard = _worker_region(flat_p, plan, w, n)
+        upd_shard, new_opt = tx.update(g_shard, opt_state, p_shard)
+        # reassemble: each bucket's shard segment gathers back tiled, in
+        # bucket order, inverting _worker_region's layout exactly
+        off, full = 0, []
+        for size in plan.sizes:
+            s = size // n
+            full.append(lax.all_gather(
+                lax.slice(upd_shard, (off,), (off + s,)), axis, tiled=True
+            ))
+            off += s
+        # one vector add, no per-leaf scatter (the pad tail stays zero —
+        # zero gradient => zero update)
+        new_params = params.replace(flat=flat_p + concat_buckets(full))
     return new_params, new_opt, new_err
 
 
@@ -848,7 +853,7 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
     for b in order:
         start, size = plan.starts[b], plan.sizes[b]
         s = size // n
-        with jax.named_scope(f"bucket_reduce_o{start}"):
+        with scope(GRAD_REDUCE), jax.named_scope(f"bucket_reduce_o{start}"):
             g_b = assemble_bucket(g_leaves, segs[b])
             if err is not None:
                 g_b = g_b + lax.slice(err, (start,), (start + size,))
@@ -859,7 +864,7 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
             )
             if err is not None:
                 err_parts[b] = g_b - contrib
-        with jax.named_scope(f"bucket_update_o{start}"):
+        with scope(UPDATE), jax.named_scope(f"bucket_update_o{start}"):
             p_b = lax.dynamic_slice(params.flat, (start + w * s,), (s,))
             opt_b = jax.tree_util.tree_unflatten(opt_def, [
                 lax.slice(l, (shard_off[b],), (shard_off[b] + s,))
@@ -946,7 +951,8 @@ def make_ps_train_step(
         k_mask = jax.random.fold_in(k_step, 0xA66)
         k_aug, k_drop = jax.random.split(jax.random.fold_in(k_step, w + 1))
 
-        x = preprocess(k_aug, images) if preprocess else images.astype(jnp.float32)
+        with scope(AUGMENT):
+            x = preprocess(k_aug, images) if preprocess else images.astype(jnp.float32)
 
         params_in, opt_in, bs_in_raw, comm_in = (
             params, opt_state, batch_stats, comm_state
@@ -967,6 +973,7 @@ def make_ps_train_step(
         bs = tree_map(lambda a: a[0], batch_stats) if cfg.bn_mode == "local" else batch_stats
 
         def fwd_bwd(bs_in, xi, yi, kd):
+            @scope(MODEL)
             def loss_fn(p):
                 logits, new_bs = apply_model(
                     model, p, bs_in, xi, train=True, dropout_rng=kd
@@ -1043,20 +1050,22 @@ def make_ps_train_step(
             # With bucketing on, the per-worker half reduces ONE fused
             # isfinite over the flat buffer (XLA CSEs the concat with
             # the wire's own flatten) instead of one reduction per leaf.
-            probe = (
-                tree_to_flat(grads)
-                if cfg.bucket_bytes is not None
-                else grads
-            )
-            finite = lax.pmin(
-                tree_all_finite(probe).astype(jnp.int32), axis
-            ) > 0
+            with scope(GRAD_REDUCE):
+                probe = (
+                    tree_to_flat(grads)
+                    if cfg.bucket_bytes is not None
+                    else grads
+                )
+                finite = lax.pmin(
+                    tree_all_finite(probe).astype(jnp.int32), axis
+                ) > 0
 
         new_comm = comm_state
         quant_key = (
             jax.random.fold_in(k_step, 0x5E) if cfg.compress else None
         )
         if cfg.opt_placement == "sharded":
+            # _sharded_ps_update names its own two halves
             err = comm_state[0] if cfg.error_feedback else None
             params, new_opt, new_err = _sharded_ps_update(
                 params, opt_state, grads, tx, cfg, k_mask,
@@ -1066,99 +1075,102 @@ def make_ps_train_step(
             if cfg.error_feedback:
                 new_comm = new_err[None]
         else:
-            if cfg.error_feedback:
-                # EF-SGD: add back last step's compression residual before
-                # transmitting; the new residual is what the wire dropped
-                # — including the ENTIRE gradient on mask-excluded steps
-                # (EF subsumes stale-gradient accumulation for the
-                # backup-worker mode)
-                err = tree_map(lambda a: a[0], comm_state)
-                grads = tree_map(jnp.add, grads, err)
-            pipelined = cfg.overlap == "pipelined"
-            # pipelined x bucketed: the aggregate stays a LIST of
-            # per-bucket vectors so the optimizer can start per bucket —
-            # the only spelling with no whole-vector barrier at all
-            bucket_out = pipelined and cfg.bucket_bytes is not None
-            out = aggregate_gradients(
-                grads,
-                axis,
-                n,
-                num_aggregate=(
-                    agg_count if agg_count is not None else cfg.num_aggregate
-                ),
-                mask_key=k_mask,
-                mask_mode=cfg.mask_mode,
-                compress=cfg.compress,
-                quant_block_size=cfg.quant_block_size,
-                quant_rounding=cfg.quant_rounding,
-                quant_key=quant_key,
-                return_contribution=cfg.error_feedback,
-                axis_sizes=hier_sizes,
-                bucket_bytes=cfg.bucket_bytes,
-                flat_output=not bucket_out,
-                pipelined=pipelined,
-                bucket_output=bucket_out,
-                wire_domain=cfg.wire_domain,
-            )
-            if cfg.error_feedback:
-                # the contribution (and the residual it defines) stays
-                # per-leaf — checkpoint portability
-                agg, contribution = out
-                new_err = tree_map(lambda a, b: a - b, grads, contribution)
-                new_comm = tree_map(lambda a: a[None], new_err)
-            else:
-                agg = out
-            if bucket_out:
-                # per-bucket fused vector updates, dispatched as each
-                # bucket's reduction lands (state_plan and the wire share
-                # one BucketPlan, so the per-bucket aggregates drop
-                # straight onto the state's own carving)
-                params, new_opt = _pipelined_flat_update(
-                    tx, agg, opt_state, params, params.plan
+            with scope(GRAD_REDUCE):
+                if cfg.error_feedback:
+                    # EF-SGD: add back last step's compression residual before
+                    # transmitting; the new residual is what the wire dropped
+                    # — including the ENTIRE gradient on mask-excluded steps
+                    # (EF subsumes stale-gradient accumulation for the
+                    # backup-worker mode)
+                    err = tree_map(lambda a: a[0], comm_state)
+                    grads = tree_map(jnp.add, grads, err)
+                pipelined = cfg.overlap == "pipelined"
+                # pipelined x bucketed: the aggregate stays a LIST of
+                # per-bucket vectors so the optimizer can start per bucket —
+                # the only spelling with no whole-vector barrier at all
+                bucket_out = pipelined and cfg.bucket_bytes is not None
+                out = aggregate_gradients(
+                    grads,
+                    axis,
+                    n,
+                    num_aggregate=(
+                        agg_count if agg_count is not None else cfg.num_aggregate
+                    ),
+                    mask_key=k_mask,
+                    mask_mode=cfg.mask_mode,
+                    compress=cfg.compress,
+                    quant_block_size=cfg.quant_block_size,
+                    quant_rounding=cfg.quant_rounding,
+                    quant_key=quant_key,
+                    return_contribution=cfg.error_feedback,
+                    axis_sizes=hier_sizes,
+                    bucket_bytes=cfg.bucket_bytes,
+                    flat_output=not bucket_out,
+                    pipelined=pipelined,
+                    bucket_output=bucket_out,
+                    wire_domain=cfg.wire_domain,
                 )
+                if cfg.error_feedback:
+                    # the contribution (and the residual it defines) stays
+                    # per-leaf — checkpoint portability
+                    agg, contribution = out
+                    new_err = tree_map(lambda a, b: a - b, grads, contribution)
+                    new_comm = tree_map(lambda a: a[None], new_err)
+                else:
+                    agg = out
+            with scope(UPDATE):
+                if bucket_out:
+                    # per-bucket fused vector updates, dispatched as each
+                    # bucket's reduction lands (state_plan and the wire share
+                    # one BucketPlan, so the per-bucket aggregates drop
+                    # straight onto the state's own carving)
+                    params, new_opt = _pipelined_flat_update(
+                        tx, agg, opt_state, params, params.plan
+                    )
+                else:
+                    # the reduced flat gradient, already in the state's
+                    # BucketPlan geometry (piece_stream and state_plan share
+                    # wire_align) — wrap it and run ONE fused vector update
+                    agg = params.replace(flat=agg)
+                    updates, new_opt = tx.update(agg, opt_state, params)
+                    params = optax.apply_updates(params, updates)
+
+        with scope(UPDATE):
+            if cfg.bn_mode == "local":
+                out_bs = tree_map(lambda a: a[None], new_bs)
             else:
-                # the reduced flat gradient, already in the state's
-                # BucketPlan geometry (piece_stream and state_plan share
-                # wire_align) — wrap it and run ONE fused vector update
-                agg = params.replace(flat=agg)
-                updates, new_opt = tx.update(agg, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                out_bs = lax.pmean(new_bs, axis) if new_bs else new_bs
 
-        if cfg.bn_mode == "local":
-            out_bs = tree_map(lambda a: a[None], new_bs)
-        else:
-            out_bs = lax.pmean(new_bs, axis) if new_bs else new_bs
-
-        metrics = lax.pmean(
-            {"loss": loss, "prec1": prec1, "prec5": prec5}, axis
-        )
-        new_guard = guard_state
-        if cfg.nonfinite_guard:
-            # skip-step: a non-finite step becomes the identity update —
-            # params, optimizer state, BN stats, and EF residuals all keep
-            # their pre-step values bit-identically; only the guard
-            # counters (and the loss scale) advance. The aggregation
-            # collectives still ran (NaNs flow through them harmlessly),
-            # so the per-step wire accounting is step-invariant.
-            def sel(new, old):
-                return tree_map(
-                    lambda a, b: jnp.where(finite, a, b), new, old
-                )
-
-            params = sel(params, params_in)
-            new_opt = sel(new_opt, opt_in)
-            out_bs = sel(out_bs, bs_in_raw)
-            new_comm = sel(new_comm, comm_in)
-            new_guard = update_guard_state(
-                guard_state, finite, cfg.dynamic_loss_scale,
-                cfg.loss_scale_growth_interval,
+            metrics = lax.pmean(
+                {"loss": loss, "prec1": prec1, "prec5": prec5}, axis
             )
-            # ride the metrics dict the host already fetches once per log
-            # window — the guard adds no per-step host transfer
-            metrics["skipped_steps"] = new_guard.skipped.astype(jnp.float32)
-            metrics["skip_streak"] = new_guard.consec.astype(jnp.float32)
-            if cfg.dynamic_loss_scale:
-                metrics["loss_scale"] = new_guard.scale
+            new_guard = guard_state
+            if cfg.nonfinite_guard:
+                # skip-step: a non-finite step becomes the identity update —
+                # params, optimizer state, BN stats, and EF residuals all keep
+                # their pre-step values bit-identically; only the guard
+                # counters (and the loss scale) advance. The aggregation
+                # collectives still ran (NaNs flow through them harmlessly),
+                # so the per-step wire accounting is step-invariant.
+                def sel(new, old):
+                    return tree_map(
+                        lambda a, b: jnp.where(finite, a, b), new, old
+                    )
+
+                params = sel(params, params_in)
+                new_opt = sel(new_opt, opt_in)
+                out_bs = sel(out_bs, bs_in_raw)
+                new_comm = sel(new_comm, comm_in)
+                new_guard = update_guard_state(
+                    guard_state, finite, cfg.dynamic_loss_scale,
+                    cfg.loss_scale_growth_interval,
+                )
+                # ride the metrics dict the host already fetches once per log
+                # window — the guard adds no per-step host transfer
+                metrics["skipped_steps"] = new_guard.skipped.astype(jnp.float32)
+                metrics["skip_streak"] = new_guard.consec.astype(jnp.float32)
+                if cfg.dynamic_loss_scale:
+                    metrics["loss_scale"] = new_guard.scale
         return params, new_opt, out_bs, new_comm, new_guard, metrics
 
     base_in_specs = (
@@ -1226,8 +1238,9 @@ def make_ps_train_step(
         def step_adaptive(state: PSTrainState, batch, key, agg_count):
             return step(state, batch, key, agg_count)
 
-        return jax.jit(step_adaptive, donate_argnums=(0,) if donate else ())
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+        return ScopedStep(PS_TRAIN_STEP, jax.jit(
+            stamped(step_adaptive), donate_argnums=(0,) if donate else ()))
+    return ScopedStep(PS_TRAIN_STEP, jax.jit(stamped(step), donate_argnums=(0,) if donate else ()))
 
 
 def make_ps_eval_step(model, cfg: PSConfig, mesh: Mesh, preprocess=None):

@@ -753,6 +753,7 @@ class Trainer:
                 else first_step + 1
             ),
             num_steps=t.profile_steps,
+            step=self._train_step,
         )
         if t.profile_dir and (pw.start > t.max_steps or pw.stop <= first_step):
             # the window misses this run's steps entirely — starts past

@@ -143,71 +143,62 @@ def test_analyze_schedule_no_entry():
     assert "error" in orp.analyze_hlo_schedule("HloModule empty")
 
 
-def _write_trace(tmp_path, events):
-    import gzip
-    import json
-
-    p = tmp_path / "plugins" / "profile" / "run1"
-    p.mkdir(parents=True)
-    with gzip.open(p / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    return tmp_path
+def _device_ops(*events):
+    """Device ops as tools/overlap_report.capture_spans hands them on: an
+    event is named by its instruction, times in microseconds."""
+    return [{"pid": "/device:TPU:0", "name": n, "ts": ts, "dur": dur} for n, ts, dur in events]
 
 
-def test_run_trace_excludes_infra_events_from_compute(tmp_path):
+def test_run_trace_excludes_infra_events_from_compute():
     """Only real op events count as overlapped compute (ADVICE r03): an
     infra span (barrier) fully covering the collective must not inflate
     overlap_fraction; the name breakdowns make the classification
-    auditable."""
-    import argparse
-
-    meta = {"ph": "M", "name": "process_name", "pid": 7,
-            "args": {"name": "/device:TPU:0"}}
-    coll = {"ph": "X", "pid": 7, "name": "all-reduce.1", "ts": 100, "dur": 100}
-    # fusion overlaps the back half of the collective only
-    comp = {"ph": "X", "pid": 7, "name": "fusion.42", "ts": 150, "dur": 100}
-    # infra event spans the WHOLE collective; counting it would make
-    # overlap_fraction 1.0
-    infra = {"ph": "X", "pid": 7, "name": "barrier-wait", "ts": 90, "dur": 200}
-    _write_trace(tmp_path, [meta, coll, comp, infra])
-
-    rep = orp.run_trace(argparse.Namespace(profile_dir=str(tmp_path)))
+    auditable. Which bucket an op belongs to is the census's to say (PR 35:
+    a device event carries its instruction's name, never a scope)."""
+    spans = _device_ops(
+        ("all-reduce.1", 100, 100),
+        ("fusion.42", 150, 100),        # overlaps the back half of the collective only
+        # infra event spans the WHOLE collective; counting it would make
+        # overlap_fraction 1.0
+        ("barrier-wait", 90, 200),
+        ("fusion.7", 300, 50),          # bucket 4096's own update: no overlap of its reduce
+    )
+    census = {
+        "all-reduce.1": ["update", "grad_reduce/bucket_reduce_o4096", "collective", [], ""],
+        "fusion.42": ["update", "update/bucket_update_o0", "other", [], ""],
+        "fusion.7": ["update", "update/bucket_update_o4096", "other", [], ""],
+    }
+    rep = orp.analyze_trace(spans, census)
     assert rep["n_collective_events"] == 1
-    assert rep["n_compute_events"] == 1
+    assert rep["n_compute_events"] == 2
     assert rep["n_skipped_events"] == 1
     assert rep["overlap_fraction"] == 0.5  # fusion half, not barrier whole
-    assert [e["name"] for e in rep["top_compute_events"]] == ["fusion.42"]
+    assert [e["name"] for e in rep["top_compute_events"]] == ["fusion.42", "fusion.7"]
     assert [e["name"] for e in rep["top_skipped_events"]] == ["barrier-wait"]
+    # bucket 4096's reduce is overlapped by ANOTHER bucket's update
+    assert rep["per_bucket"] == [{"bucket_offset": 4096, "ms": 0.1, "overlapped_ms": 0.05,
+                                  "overlap_fraction": 0.5}]
+    # without a census the same capture has no buckets to tell apart
+    assert orp.analyze_trace(spans, {})["per_bucket"] is None
 
 
-def test_run_trace_prefix_anchored_compute_classifier(tmp_path):
+def test_run_trace_prefix_anchored_compute_classifier():
     """Op classification is anchored to the HLO op-name prefix, not free
     substring search (ADVICE r04): copy-start/copy-done DMA bookkeeping and
     address-computation thunks contain 'copy'/'dynamic' as substrings but
     must land in the skipped audit list; the exact 'copy' op and fusion
     kinds (loop_fusion) are real compute."""
-    import argparse
-
-    meta = {"ph": "M", "name": "process_name", "pid": 7,
-            "args": {"name": "/device:TPU:0"}}
-    coll = {"ph": "X", "pid": 7, "name": "all-reduce.1", "ts": 100, "dur": 100}
-    # infra spans whose names would substring-match the old classifier;
-    # each fully covers the collective, so any misclassification shows up
-    # directly in overlap_fraction
-    infra = [
-        {"ph": "X", "pid": 7, "name": "copy-start.2", "ts": 90, "dur": 200},
-        {"ph": "X", "pid": 7, "name": "copy-done.2", "ts": 90, "dur": 200},
-        {"ph": "X", "pid": 7, "name": "dynamic-address-computation.1",
-         "ts": 90, "dur": 200},
-    ]
-    # real compute overlapping only the back half
-    comp = [
-        {"ph": "X", "pid": 7, "name": "copy.3", "ts": 150, "dur": 25},
-        {"ph": "X", "pid": 7, "name": "loop_fusion.8", "ts": 175, "dur": 25},
-    ]
-    _write_trace(tmp_path, [meta, coll] + infra + comp)
-
-    rep = orp.run_trace(argparse.Namespace(profile_dir=str(tmp_path)))
+    spans = _device_ops(
+        ("all-reduce.1", 100, 100),
+        # infra spans whose names would substring-match the old classifier;
+        # each fully covers the collective, so any misclassification shows up
+        # directly in overlap_fraction
+        ("copy-start.2", 90, 200), ("copy-done.2", 90, 200),
+        ("dynamic-address-computation.1", 90, 200),
+        # real compute overlapping only the back half
+        ("copy.3", 150, 25), ("loop_fusion.8", 175, 25),
+    )
+    rep = orp.analyze_trace(spans, {})
     assert rep["n_compute_events"] == 2
     assert rep["n_skipped_events"] == 3
     # copy.3 + loop_fusion.8 merge to [150,200] = half the collective

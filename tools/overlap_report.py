@@ -8,14 +8,17 @@ deletes that machinery and relies on XLA: the gradient psum lowers to async
 places backward compute between them. This tool produces the evidence, three
 ways (most → least direct):
 
-  trace     parse a `--profile-dir` Chrome trace (trace.json.gz) from a real
-            run and measure wall-clock overlap between collective and compute
-            events on the device timeline. Needs a device that emits an
-            op-level timeline (TPU; the CPU backend logs host events only).
-            Knows the pipelined wire's per-bucket span names
-            (`bucket_reduce_o<offset>` / `bucket_update_o<offset>`,
-            jax.named_scope from parallel/collectives.py) and reports a
-            per-bucket overlap breakdown when they appear.
+  trace     read a `--profile-dir` capture (the `XLA Ops` / `Async XLA Ops`
+            lines of its xplane.pb, through jax.profiler.ProfileData) from a
+            real run and measure wall-clock overlap between collective and
+            compute events on the device timeline. Needs a device that emits
+            an op-level timeline (TPU; the CPU backend logs host events only).
+            A device event is named by its INSTRUCTION (`fusion.525`), never
+            by a scope: which bucket of the pipelined wire an instruction
+            belongs to comes from the step's census beside the capture
+            (`step_scopes.json`: the `grad_reduce/bucket_reduce_o<offset>` /
+            `update/bucket_update_o<offset>` scopes, obs/scopes.py), and the
+            per-bucket overlap breakdown is reported when they appear.
   topology  AOT-compile the SPMD train step for an N-chip TPU topology via
             `jax.experimental.topologies` (no chips needed — the compiler
             does the scheduling) and analyze the compiled schedule.
@@ -58,8 +61,6 @@ module remains the implementation).
 from __future__ import annotations
 
 import argparse
-import glob
-import gzip
 import json
 import os
 import re
@@ -423,44 +424,63 @@ def run_topology(args) -> dict:
     return rep
 
 
+def capture_spans(profile_dir: str):
+    """(spans, file): the device ops of the newest capture under
+    `profile_dir` (`plugins/profile/*/*.xplane.pb`, read with
+    jax.profiler.ProfileData: the `XLA Ops` and `Async XLA Ops` lines of
+    every device plane) as {"name", "pid", "ts", "dur"} in microseconds. On
+    the TPU an event's name is its whole HLO line; `name` keeps the
+    instruction's."""
+    from ps_pytorch_tpu.obs.hlo import hlo_line_name
+    from ps_pytorch_tpu.obs.profiler import device_planes
+
+    capture, planes = device_planes(profile_dir)
+    spans = [{"name": hlo_line_name(e.name) or e.name, "pid": plane.name,
+              "ts": e.start_ns * 1e-3, "dur": e.duration_ns * 1e-3}
+             for plane in planes for line in plane.lines
+             if line.name in ("XLA Ops", "Async XLA Ops") for e in line.events]
+    return spans, capture
+
+
 def run_trace(args) -> dict:
-    """Wall-clock overlap from a --profile-dir run's Chrome trace: fraction
-    of collective-event time that coincides with compute events on the
-    device timeline."""
+    """Wall-clock overlap from a --profile-dir run: fraction of
+    collective-event time that coincides with compute events on the device
+    timeline. The capture names an op by its instruction; WHICH bucket of
+    the pipelined wire an instruction belongs to is read from the step's
+    census beside the capture (`step_scopes.json`, which `--profile-dir`
+    writes: ps_pytorch_tpu/obs/scopes.py), by the
+    `grad_reduce/bucket_reduce_o<offset>` / `update/bucket_update_o<offset>`
+    scopes."""
     if not args.profile_dir:
         return {"mode": "trace", "error": "--profile-dir is required"}
-    pats = sorted(glob.glob(
-        os.path.join(args.profile_dir, "**", "*.trace.json.gz"),
-        recursive=True,
-    ))
-    if not pats:
-        return {"mode": "trace", "error": f"no trace.json.gz under {args.profile_dir}"}
-    data = json.load(gzip.open(pats[-1], "rt"))
-    evs = data.get("traceEvents", [])
-    pid_names = {
-        e["pid"]: e["args"]["name"]
-        for e in evs
-        if e.get("ph") == "M" and e.get("name") == "process_name"
-        and isinstance(e.get("args"), dict) and "name" in e["args"]
-    }
-    device_pids = {
-        p for p, n in pid_names.items()
-        if "TPU" in n or "/device" in n.lower() or "XLA" in n
-    }
-    spans = [
-        e for e in evs
-        if e.get("ph") == "X" and e.get("pid") in device_pids
-        and e.get("dur") is not None
-    ]
+    spans, capture = capture_spans(args.profile_dir)
+    # every chip runs the same program: the first device's timeline is read
+    first = min((e["pid"] for e in spans), default=None)
+    spans = [e for e in spans if e["pid"] == first]
+    if not spans:
+        return {"mode": "trace", "error": f"no device ops in a capture "
+                f"(plugins/profile/*/*.xplane.pb) under {args.profile_dir}"}
+    table = {}
+    census = os.path.join(args.profile_dir, "step_scopes.json")
+    if os.path.exists(census):
+        with open(census) as f:
+            table = json.load(f)["instructions"]
+    rep = analyze_trace(spans, table)
+    rep["trace_file"] = capture
+    rep["census"] = census if table else None
+    return rep
+
+
+def analyze_trace(spans, table) -> dict:
+    """`spans`: device ops {"name" (the instruction's), "pid", "ts", "dur"};
+    `table`: the census's {instruction: [phase, scope, work, mixed, via]}
+    ({} without one: no per-bucket rows then)."""
+    scope_of = lambda e: (table.get(e["name"]) or ["", ""])[1]
     is_coll = lambda n: any(
         k in n.lower()
         for k in ("all-reduce", "all_reduce", "allreduce", "all-gather",
                   "all_gather", "reduce-scatter", "reduce_scatter",
-                  "collective", "all-to-all", "psum",
-                  # the pipelined wire's per-bucket named_scope spans
-                  # (parallel/collectives.py): ops under these scopes ARE
-                  # the bucket's reduce chain
-                  "bucket_reduce_o")
+                  "collective", "all-to-all", "psum")
     )
     # compute = real op events only (fusion/conv/dot/elementwise families),
     # NOT every non-collective span: infra/marker events (barriers, infeed,
@@ -548,7 +568,7 @@ def run_trace(args) -> dict:
     bucket_reduce_re = re.compile(r"bucket_reduce_o(\d+)")
     per_bucket = {}
     for e in spans:
-        m = bucket_reduce_re.search(e["name"])
+        m = bucket_reduce_re.search(scope_of(e))
         if not m:
             continue
         per_bucket.setdefault(int(m.group(1)), []).append(
@@ -556,7 +576,7 @@ def run_trace(args) -> dict:
         )
 
     def _comp_offset(e):
-        m = bucket_any_re.search(e["name"])
+        m = bucket_any_re.search(scope_of(e))
         return int(m.group(1)) if m else None
 
     comp_tagged = [(e, _comp_offset(e)) for e in comp_events]
@@ -577,8 +597,7 @@ def run_trace(args) -> dict:
         })
     return {
         "mode": "trace",
-        "trace_file": pats[-1],
-        "device_pids": sorted(device_pids),
+        "device_pids": sorted({e["pid"] for e in spans}),
         "n_collective_events": len(coll),
         "n_compute_events": len(comp),
         "n_skipped_events": len(skipped),

@@ -29,13 +29,26 @@ monotonic clock. This tool:
 - ``--require-phases a,b,c`` exits nonzero unless every named phase is
   present (the smoke gate).
 
+Where a step's DEVICE time goes, by the scopes the program writes inside
+its step (obs/scopes.py) and the phase jax writes around them:
+
+  python tools/trace_report.py device <profile dir>
+      joins `<profile dir>/step_scopes.json` (the compiled step's census:
+      `cli.train_lm --profile-dir`, `cli.train --profile-dir` write it when
+      their capture stops) with the capture's `XLA Ops` lines by
+      instruction name and prints ms a step by phase and by scope, the
+      share in fusions that hold more than one scope (`mixed`, booked to
+      the scope of the instruction that does most work: obs/hlo.py) and the
+      share it could not place.
+
 The earlier one-off analysis tool folds in as a subcommand:
 
   python tools/trace_report.py overlap <hlo|trace|topology|jaxpr> [...]
       -> tools/overlap_report.py (comm/compute overlap evidence;
          `jaxpr --overlap on|off` reports the pipelined wire's
-         schedule-freedom numbers, `trace` knows the per-bucket
-         `bucket_reduce_o<offset>` span names — §6g)
+         schedule-freedom numbers, `trace` reads a capture's device
+         ops and takes the per-bucket `bucket_reduce_o<offset>` scopes
+         from the step_scopes.json beside it — §6g)
 
 Usage:
   python tools/trace_report.py runs/trace/ --metrics runs/metrics.jsonl \\
@@ -262,8 +275,94 @@ def merge(
     return trace, summary
 
 
+def device_report(profile_dir: str) -> dict:
+    """`step_scopes.json` joined with the newest capture under
+    `profile_dir`: ms a step of device time by phase, by scope and by
+    pair of scopes in mixed fusions, per device averaged."""
+    from ps_pytorch_tpu.obs.hlo import instruction_of, is_placed, time_by_place
+    from ps_pytorch_tpu.obs.profiler import device_planes
+
+    with open(os.path.join(profile_dir, "step_scopes.json")) as f:
+        census = json.load(f)
+    table = census["instructions"]
+    capture, planes = device_planes(profile_dir)
+    if capture is None:
+        raise SystemExit(f"no capture (plugins/profile/*/*.xplane.pb) under {profile_dir}")
+    joined, steps = [], 0
+    for plane in planes:
+        events, runs = [], {}
+        for line in plane.lines:
+            if line.name == "XLA Modules":
+                for e in line.events:
+                    name = e.name.split("(")[0]
+                    runs.setdefault(name, []).append(e.duration_ns)
+            elif line.name == "XLA Ops":
+                for e in line.events:
+                    ins = instruction_of(e.name, table)
+                    if ins is None or table[ins][2] != "container":
+                        events.append((e.name, e.duration_ns * 1e-9))
+        if events:
+            joined.append(time_by_place(table, events))
+            # the step program is the module that took most time
+            steps = max(steps, len(max(runs.values(), key=sum, default=[])))
+    if not joined:
+        raise SystemExit(
+            f"the capture under {profile_dir} has no device plane with an `XLA Ops` line "
+            "(a CPU capture holds host events only)")
+    steps, n = max(steps, 1), len(joined)
+    ms = lambda seconds: round(1e3 * seconds / n / steps, 4)
+    by_phase: Dict[str, float] = {}
+    by_scope: Dict[str, float] = {}
+    mixed: Dict[str, float] = {}
+    total = unplaced = unfound = inherited = mixed_all = 0.0
+    for j in joined:
+        total += j["total"]
+        unfound += j["unfound"]
+        inherited += j["inherited"]
+        for (phase, scope, work), s in j["by_place"].items():
+            if is_placed((phase, scope)):
+                by_phase[phase] = by_phase.get(phase, 0.0) + s
+                by_scope[scope] = by_scope.get(scope, 0.0) + s
+            else:
+                unplaced += s
+        for (here, other), s in j["mixed"].items():
+            mixed[f"{here} | {other}"] = mixed.get(f"{here} | {other}", 0.0) + s
+        mixed_all += j["mixed_total"]
+    rank = lambda d: {k: ms(v) for k, v in sorted(d.items(), key=lambda kv: -kv[1])}
+    return {
+        "program": census.get("program"), "devices": n, "steps": steps,
+        "step_ms": ms(total), "ms_by_phase": rank(by_phase), "ms_by_scope": rank(by_scope),
+        "mixed_ms": ms(mixed_all), "mixed_ms_by_pair": dict(list(rank(mixed).items())[:12]),
+        "unplaced_ms": ms(unplaced + unfound), "not_in_the_census_ms": ms(unfound),
+        "placed_by_a_neighbour_ms": ms(inherited),
+    }
+
+
+def print_device_report(report: dict) -> None:
+    step = report["step_ms"] or 1.0
+    pct = lambda v: f"{v:10.3f} ms {100 * v / step:6.2f}%"
+    print(f"{report['program']}: {report['step_ms']:.3f} ms of device time a step "
+          f"({report['steps']} steps, {report['devices']} device(s))")
+    for title, key in (("by phase", "ms_by_phase"), ("by scope", "ms_by_scope")):
+        print(title)
+        for name, v in report[key].items():
+            print(f"  {name:28s}{pct(v)}")
+    print(f"mixed (fusions of more than one scope, booked as obs/hlo.py says){pct(report['mixed_ms'])}")
+    for pair, v in report["mixed_ms_by_pair"].items():
+        print(f"  {pair:60s}{pct(v)}")
+    print(f"{'unplaced':30s}{pct(report['unplaced_ms'])}  (not in the census "
+          f"{report['not_in_the_census_ms']:.3f} ms; placed by a neighbour "
+          f"{report['placed_by_a_neighbour_ms']:.3f} ms)")
+
+
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
+    if argv and argv[0] == "device":
+        if len(argv) != 2:
+            print("usage: tools/trace_report.py device <profile dir>", file=sys.stderr)
+            return 2
+        print_device_report(device_report(argv[1]))
+        return 0
     # the folded one-off tool rides as a subcommand (its module remains
     # the implementation and keeps its own CLI working)
     if argv and argv[0] == "overlap":
