@@ -92,7 +92,10 @@ LM_ARGS = [
     "--batch-size", "8", "--dtype", "bfloat16", "--attention-impl", "flash",
     "--max-steps", "6", "--eval-freq", "3", "--log-interval", "1",
 ]
-LM_KERNELS = ("ps_flash_fwd", "ps_flash_dq", "ps_flash_dkv")
+# the LM legs' shapes are far under plan_flash's cap: the backward is the
+# one fused kernel, and the split pair must not show
+LM_KERNELS = ("ps_flash_fwd", "ps_flash_dqkv")
+LM_KERNELS_ABSENT = ("ps_flash_dq", "ps_flash_dkv")
 SERVE_ARGS = [
     "--step", "3", "--slots", "8", "--requests", "16", "--rate", "20",
     "--prompt-min", "4", "--prompt-max", "16", "--new-min", "8",
@@ -277,6 +280,12 @@ def check_kernels(leg, hlo_text, expect):
             f"{leg}: no Mosaic custom call for {missing} in the compiled "
             f"program (jnp twin or interpret mode took them); census "
             f"{census}"
+        )
+    split = [k for k in LM_KERNELS_ABSENT if census["mosaic"].get(k)]
+    if split:
+        raise AssertionError(
+            f"{leg}: the split flash backward {split} ran where plan_flash "
+            f"fuses it (every leg's shapes are far under its cap)"
         )
 
 

@@ -500,7 +500,8 @@ def main(argv=None) -> dict:
         plan = plan_flash(t_att, t_att, d_qk,
                           cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
         flash_plan = {f: getattr(plan, f) for f in (
-            "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total")}
+            "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total",
+            "bwd", "dq_acc_bytes")}
         flash_plan.update(d_qk=d_qk, d_v=d_v, attention_path=path,
                           seq_shards=seq_shards)
         # what `remat` keeps of each attention layer beside the block's

@@ -139,7 +139,9 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # instants of cli/train_lm.py: `flash_plan` (the
                     # kernels' tiles and widths, `seq_shards` and, a
                     # string, the `attention_path` they run on:
-                    # models/transformer.attention_path; what `remat` keeps
+                    # models/transformer.attention_path; `bwd`, a string,
+                    # "fused" or "split", and the fused backward's
+                    # `dq_acc_bytes` in VMEM; what `remat` keeps
                     # of a layer, `saved_bytes_per_layer` under the names in
                     # the string `remat_saves`) and `moe_route` (the
                     # dropless expert layers' rows, summed over layers;
@@ -154,7 +156,7 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # and `kda_state` at log steps (`chunks_cut_off` too)
                     "block_q", "block_k", "grid_steps", "tiles_run",
                     "tiles_total", "d_qk", "d_v", "seq_shards",
-                    "saved_bytes_per_layer", "rows_here",
+                    "dq_acc_bytes", "saved_bytes_per_layer", "rows_here",
                     "max_expert_rows", "min_expert_rows", "tokens_unserved",
                     "chunk", "n_chunks", "heads", "d_head", "d_state",
                     "groups", "mamba_layers", "attention_layers",

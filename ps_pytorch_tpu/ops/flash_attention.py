@@ -11,9 +11,16 @@ Layout: inputs [B, T, H, D] are folded to [B*H, T, D]; the grid walks
 (acc, running max m, running sum l) in VMEM scratch and writing the
 normalized output plus the logsumexp L = m + log(l) at the last k step.
 The backward pass recomputes p = exp(q k^T * scale - L) per block
-(flash-attention-2 style) in two kernels: one accumulating dq over k
-blocks, one accumulating (dk, dv) over q blocks, seeded with delta =
-rowsum(do * o) computed in plain XLA.
+(flash-attention-2 style), seeded with delta = rowsum(do * o) computed in
+plain XLA, in ONE kernel (ps_flash_dqkv): each live tile's s, p, dp, ds
+are made once and feed all three gradients. Its grid walks (batch*head,
+k_block, q_block) with q innermost: dk and dv accumulate over q blocks
+in VMEM scratch as the forward's output does over k blocks; dq sums over
+k blocks, the OUTER axis, so the whole head's dq ([T_q, D] float32) stays
+in a VMEM scratch across the walk and is written out in the last k
+sweep. Where that scratch does not fit (plan_flash's cap: one chip at T
+well past 32k) the plan takes two kernels instead, ps_flash_dq over k
+blocks and ps_flash_dkv over q blocks, which compute every tile twice.
 
 Every kernel works on the TRANSPOSED score tile k q^T, [block_k, block_q]
 with keys down the rows. The per-query statistics (m, l, lse, delta) are
@@ -22,7 +29,9 @@ eight queries, reduced over keys by plain elementwise max/add down the
 rows, and the same shape in which they cross the kernel boundary
 ([BH, 1, T] with (1, 1, block_q) blocks, whose second-to-last block dim
 equals the array's, as Mosaic wants). The forward and dq accumulators are
-held transposed too ([D, block_q]) and turned once, at the last k step.
+held transposed too ([D, block_q]; the whole head's dq as [n_q, D,
+block_q], indexed by the q block on its leading dimension) and turned
+once, at the last k step.
 Compiled calls need block sizes that are multiples of 128 or cover the
 whole (padded) sequence; plan_flash gives that.
 
@@ -40,9 +49,10 @@ visiting shard lies wholly in the future costs a grid walk and nothing
 else, and still comes out as m = NEG_INF, l = 0, zero gradients).
 
 Precision: p and ds are cast to the dtype of the operand they multiply,
-so bfloat16 inputs give the MXU bfloat16 operands in all nine products of
-a training step; scores, m, l, lse, delta, the exponentials and every
-accumulator stay float32. float32 inputs are never cast.
+so bfloat16 inputs give the MXU bfloat16 operands in all seven products of
+a training step (nine where the backward is split); scores, m, l, lse,
+delta, the exponentials and every accumulator stay float32. float32
+inputs are never cast.
 
 Selection is ops/pallas_mode.py's: Mosaic-compiled on TPU backends,
 interpret mode under PS_TPU_PALLAS_INTERPRET=1 (how CPU CI exercises the
@@ -77,6 +87,13 @@ FLASH_SAVED = ("ps_flash_o", "ps_flash_lse")
 # leaves out (Mosaic's own temporaries).
 VMEM_BUDGET = 12 * 2 ** 20
 MAX_BLOCK = 1024
+# The fused backward also holds the whole head's dq in VMEM, beyond the
+# default limit at the cells' shapes (6 MiB at [8192, 192] beside 8.3 MB
+# of tiles), so it asks Mosaic for its own limit (vmem_limit). The plan
+# takes it while its estimate is under FUSED_BWD_CAP, half of the 128 MiB
+# a v5e core has: T = 65,536 at D = 192 is fused, T = 131,072 is split.
+VMEM_BYTES = 128 * 2 ** 20
+FUSED_BWD_CAP = VMEM_BYTES // 2
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the last dim of both
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b: contract the first dim of both
@@ -312,6 +329,57 @@ def _make_ds(scores):
     return tile
 
 
+def _make_dqkv_kernel(scale, causal, block_q, block_k, n_q, n_k, k_len=None):
+    """The fused backward: grid (bh, k_block, q_block), q innermost. dk and
+    dv are complete when a k block's q sweep ends; dq[qi] gains one term a
+    k block, in ascending ki, and is complete in the last sweep."""
+    from jax.experimental import pallas as pl
+
+    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
+    ds_tile = _make_ds(scores)
+
+    def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        ki = pl.program_id(1)
+        qi = pl.program_id(2)
+        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
+
+        @pl.when(qi == 0)
+        def _init_dkv():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+        def _tile():
+            p, ds, q, do = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                   delta_ref, qi, ki, q_off, k_off)
+            k = k_ref[0]
+            ds = ds.astype(q.dtype)  # q and k share their dtype
+            dv_acc[:] += jnp.dot(
+                p.astype(do.dtype), do, preferred_element_type=jnp.float32
+            )
+            dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            dq_acc[qi] += jax.lax.dot_general(
+                k, ds, _TN, preferred_element_type=jnp.float32
+            )  # (ds k)^T, [D, Bq]
+
+        _when_live(live(qi, ki, q_off, k_off), _tile)
+
+        @pl.when(qi == n_q - 1)
+        def _finalize_dkv():
+            dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+        @pl.when(ki == n_k - 1)
+        def _finalize_dq():
+            dq_ref[0] = (dq_acc[qi] * scale).T.astype(dq_ref.dtype)
+
+    return kernel
+
+
 def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
     from jax.experimental import pallas as pl
 
@@ -391,7 +459,8 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     different sequence length than q3 (a visiting ring shard).
     `out_dtype` overrides the gradients' dtype (the ring passes f32 so
     per-hop pieces accumulate without a per-hop rounding). v3 and do3 are
-    Dv wide where q3 and k3 are D wide."""
+    Dv wide where q3 and k3 are D wide. One kernel or two is plan_bwd's
+    choice, from the shapes here."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -400,9 +469,53 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     n_q, n_k = t // block_q, tk // block_k
     off = _offsets_arr(offsets)
     lse, delta = lse.reshape(bh, 1, t), delta.reshape(bh, 1, t)
-    dq_dt = out_dtype or q3.dtype
-    dk_dt = out_dtype or k3.dtype
-    dv_dt = out_dtype or v3.dtype
+    dq_shape = jax.ShapeDtypeStruct((bh, t, d), out_dtype or q3.dtype)
+    dkv_shape = [
+        jax.ShapeDtypeStruct((bh, tk, d), out_dtype or k3.dtype),
+        jax.ShapeDtypeStruct((bh, tk, dv), out_dtype or v3.dtype),
+    ]
+    by_k_then_q = [
+        _smem_spec(),
+        pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda b, ki, qi: (b, qi, 0)),
+        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+    ]
+    dkv_specs = [
+        pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+        pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
+    ]
+    dkv_scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, dv), jnp.float32),
+    ]
+    args = (off, q3, k3, v3, do3, lse, delta)
+    bwd, _, vmem_bytes = plan_bwd(block_q, block_k, t, d, dv,
+                                  q3.dtype.itemsize)
+
+    if bwd == "fused":
+        # the dq block stays at (b, 0) until the last k sweep, the only one
+        # that writes it, and then follows qi: Pallas writes a block back
+        # when its index moves on, so each is written once, complete
+        return pl.pallas_call(
+            _make_dqkv_kernel(scale, causal, block_q, block_k, n_q, n_k,
+                              k_len=k_len),
+            name="ps_flash_dqkv",
+            grid=(bh, n_k, n_q),
+            in_specs=by_k_then_q,
+            out_specs=[pl.BlockSpec(
+                (1, block_q, d),
+                lambda b, ki, qi: (b, jnp.where(ki == n_k - 1, qi, 0), 0),
+            )] + dkv_specs,
+            out_shape=[dq_shape] + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((n_q, d, block_q), jnp.float32)]
+            + dkv_scratch,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit(vmem_bytes)),
+            **mode,
+        )(*args)
 
     dq = pl.pallas_call(
         _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=k_len),
@@ -418,38 +531,20 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), dq_dt),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         **mode,
-    )(off, q3, k3, v3, do3, lse, delta)
-
+    )(*args)
     dk, dv = pl.pallas_call(
         _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=k_len),
         name="ps_flash_dkv",
         grid=(bh, n_k, n_q),
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), dk_dt),
-            jax.ShapeDtypeStruct((bh, tk, dv), dv_dt),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, dv), jnp.float32),
-        ],
+        in_specs=by_k_then_q,
+        out_specs=dkv_specs,
+        out_shape=dkv_shape,
+        scratch_shapes=dkv_scratch,
         **mode,
-    )(off, q3, k3, v3, do3, lse, delta)
+    )(*args)
     return dq, dk, dv
 
 
@@ -468,7 +563,9 @@ class FlashPlan(NamedTuple):
     k_len: Optional[int]  # T_k where the kernels must mask a padded tail
     grid_steps: int   # tiles walked: (tq_pad / block_q) * (tk_pad / block_k)
     tiles_run: int    # ... of which do work (_tile_live)
-    vmem_bytes: int   # _vmem_bytes of the chosen blocks
+    vmem_bytes: int   # _vmem_bytes of the backward that runs
+    bwd: str          # "fused": ps_flash_dqkv; "split": ps_flash_dq + _dkv
+    dq_acc_bytes: int  # the fused kernel's dq accumulator; 0 where split
 
     @property
     def tiles_total(self) -> int:
@@ -490,19 +587,45 @@ def _floor_pow2(x: int) -> int:
 
 
 def _vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int,
-                d_v: Optional[int] = None) -> int:
-    """What a grid step of the dkv kernel, the largest of the three, holds:
-    q, do, k, v and the two row statistics double-buffered by the pipeline,
-    dk and dv (float32 on a ring hop) double-buffered beside their two
-    float32 accumulators, four float32 score tiles (s, p, dp, ds) and the
-    two casts of p and ds that feed the MXU. q and k are `d` wide, v and
-    do `d_v` (the same unless given)."""
+                d_v: Optional[int] = None, dq_acc_bytes: int = 0) -> int:
+    """What a grid step of the backward holds. The tiles, as the dkv kernel
+    (the largest of the split three, and what decides the blocks) holds
+    them: q, do, k, v and the two row statistics double-buffered by the
+    pipeline, dk and dv (float32 on a ring hop) double-buffered beside
+    their two float32 accumulators, four float32 score tiles (s, p, dp,
+    ds) and the two casts of p and ds that feed the MXU. q and k are `d`
+    wide, v and do `d_v` (the same unless given). The fused kernel holds
+    `dq_acc_bytes` more, the whole head's dq, and its dq block
+    double-buffered (float32 on a ring hop)."""
     tile = block_q * block_k
     width = d + (d if d_v is None else d_v)
     operands = 2 * (block_q + block_k) * width * itemsize
     stats = 2 * 2 * block_q * 4
     results = (2 + 1) * block_k * width * 4
-    return operands + stats + results + 4 * tile * 4 + 2 * tile * itemsize
+    tiles = operands + stats + results + 4 * tile * 4 + 2 * tile * itemsize
+    if not dq_acc_bytes:
+        return tiles
+    return tiles + dq_acc_bytes + 2 * block_q * d * 4
+
+
+def plan_bwd(block_q: int, block_k: int, tq_pad: int, d: int,
+             d_v: Optional[int], itemsize: int):
+    """(bwd, dq_acc_bytes, vmem_bytes) of one backward call, from its
+    shapes alone: "fused" where the tiles and the whole head's float32 dq
+    come under FUSED_BWD_CAP by _vmem_bytes, else "split" with no
+    accumulator. plan_flash reports it and _flash_bwd obeys it."""
+    dq_acc_bytes = tq_pad * d * 4
+    fused = _vmem_bytes(block_q, block_k, d, itemsize, d_v, dq_acc_bytes)
+    if fused <= FUSED_BWD_CAP:
+        return "fused", dq_acc_bytes, fused
+    return "split", 0, _vmem_bytes(block_q, block_k, d, itemsize, d_v)
+
+
+def vmem_limit(vmem_bytes: int) -> int:
+    """The limit the fused backward asks of Mosaic: the plan's estimate,
+    and for what it leaves out (the compiler's own temporaries) the 16 MiB
+    default, in which the split kernels fit whole."""
+    return vmem_bytes + 16 * 2 ** 20
 
 
 def _fit_block(t: int, cap: int) -> int:
@@ -528,8 +651,8 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
     """The tile plan of one call, from what it can observe. Pure. `d` is
     the query/key width, `d_v` the value width where it differs.
 
-    Both blocks start at the largest square that _vmem_bytes puts under
-    VMEM_BUDGET for this head size and operand dtype, then each axis
+    Both blocks start at the largest square whose tiles _vmem_bytes puts
+    under VMEM_BUDGET for this head size and operand dtype, then each axis
     takes what its length allows (_fit_block), so a visiting ring shard
     may be tiled otherwise than the local queries. A requested block
     (the tests) is floored to a power of two and capped at the padded
@@ -551,11 +674,12 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
     k_len = t_k if tk_pad != t_k else None
     live = [_tile_live(qi, ki, bq, bk, causal, k_len)
             for qi in range(n_q) for ki in range(n_k)]
+    bwd, dq_acc_bytes, vmem_bytes = plan_bwd(bq, bk, tq_pad, d, d_v, itemsize)
     return FlashPlan(
         block_q=bq, block_k=bk, tq_pad=tq_pad, tk_pad=tk_pad, k_len=k_len,
         grid_steps=n_q * n_k,
         tiles_run=sum(x is None or bool(x) for x in live),
-        vmem_bytes=_vmem_bytes(bq, bk, d, itemsize, d_v),
+        vmem_bytes=vmem_bytes, bwd=bwd, dq_acc_bytes=dq_acc_bytes,
     )
 
 

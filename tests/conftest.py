@@ -106,3 +106,17 @@ def mesh(devices):
     from ps_pytorch_tpu.parallel.mesh import make_mesh
 
     return make_mesh(num_workers=8)
+
+
+@pytest.fixture(params=["fused", "split"])
+def flash_bwd(request, monkeypatch):
+    """Both backwards of ops/flash_attention.py. plan_flash picks from
+    shapes alone and every test size is far under its cap, so the split
+    pair is reached by putting the cap at 0 (in the test, not through an
+    option of the program)."""
+    from ps_pytorch_tpu.ops import flash_attention as fa
+
+    if request.param == "split":
+        monkeypatch.setattr(fa, "FUSED_BWD_CAP", 0)
+    assert fa.plan_flash(128, 128, 64, "float32", True).bwd == request.param
+    return request.param

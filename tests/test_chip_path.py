@@ -84,11 +84,14 @@ def test_every_pallas_entry_is_compiled_on_tpu(on_tpu, monkeypatch):
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(1, 64, 2, 32), jnp.float32)
                for _ in range(3))
-    # flash_attention forward, then its custom VJP (fwd + dq + dkv)
+    # flash_attention forward, then its custom VJP (fwd + dqkv, and past
+    # the plan's cap fwd + dq + dkv)
     fa.flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
-    jax.grad(
-        lambda q: jnp.sum(fa.flash_attention(q, k, v, causal=True))
-    )(q)
+    loss = lambda q: jnp.sum(fa.flash_attention(q, k, v, causal=True))
+    jax.grad(loss)(q)
+    with monkeypatch.context() as past_the_cap:
+        past_the_cap.setattr(fa, "FUSED_BWD_CAP", 0)
+        jax.grad(loss)(q)
     # the ring-hop partials, with the default mode argument
     q3, k3, v3 = (x.transpose(0, 2, 1, 3).reshape(2, 64, 32)
                   for x in (q, k, v))
@@ -114,7 +117,7 @@ def test_every_pallas_entry_is_compiled_on_tpu(on_tpu, monkeypatch):
             re.findall(r'name="(ps_[a-z0-9_]+)"', inspect.getsource(mod))
         )
     assert {name for name, _ in calls} == in_source
-    assert len(in_source) == 6
+    assert len(in_source) == 7
 
 
 def _quantize_rows_128(a):
@@ -130,8 +133,8 @@ def test_a_shape_that_goes_to_jnp_says_so(on_tpu):
     assert pm.kernel_census(
         'x = f32[] multiply(), metadata={op_name="jit(f)/ps_quantize_rows_jnp/mul"}\n'
         'y = s8[] custom-call(), custom_call_target="tpu_custom_call", '
-        'metadata={op_name="jit(f)/transpose(jvp(ps_flash_dkv))/pallas_call"}'
-    ) == {"mosaic": {"ps_flash_dkv": 1}, "jnp": {"ps_quantize_rows": 1}}
+        'metadata={op_name="jit(f)/transpose(jvp(ps_flash_dqkv))/pallas_call"}'
+    ) == {"mosaic": {"ps_flash_dqkv": 1}, "jnp": {"ps_quantize_rows": 1}}
 
 
 # ------------------------------------------------------------ cache rule

@@ -51,7 +51,7 @@ def test_forward_matches_full(t, causal):
 
 @pytest.mark.parametrize("t", [128, 200], ids=["even", "odd"])
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
-def test_dq_dk_dv_match_full(t, causal):
+def test_dq_dk_dv_match_full(t, causal, flash_bwd):
     q, k, v = _qkv(t, seed=1)
     w = jnp.asarray(np.random.RandomState(2).randn(B, t, H, DV).astype(np.float32))
     loss = lambda f: (lambda q, k, v: jnp.sum(f(q, k, v) * w))
@@ -74,8 +74,38 @@ def test_the_published_widths_in_bfloat16():
     np.testing.assert_allclose(got.astype(np.float32), want, atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("bwd", ["fused", "split"])
+@pytest.mark.parametrize("d, dv", [(192, 128), (64, 64)], ids=["mla", "gpt2"])
+def test_gradients_at_the_published_widths_in_bfloat16(d, dv, bwd, monkeypatch):
+    """The cells' head widths, bfloat16 operands, causal, 128-wide tiles
+    asked for so that T 256 has a skipped tile and two k sweeps of the dq
+    accumulator: all three gradients against the float32 oracle on the
+    same rounded inputs, and split against fused to the last bit (the
+    same sums in the same order)."""
+    from ps_pytorch_tpu.ops import flash_attention as fa
+
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(256, seed=8, d=d, dv=dv))
+    w = jnp.asarray(np.random.RandomState(9).randn(B, 256, H, dv), jnp.bfloat16)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def grads(attend, *xs, **kw):
+        loss = lambda q, k, v: jnp.sum(f32(attend(q, k, v, causal=True, **kw) * w))
+        return jax.grad(loss, (0, 1, 2))(*xs)
+
+    got = fused = grads(flash_attention, q, k, v, block_q=128, block_k=128)
+    if bwd == "split":
+        monkeypatch.setattr(fa, "FUSED_BWD_CAP", 0)
+        got = grads(flash_attention, q, k, v, block_q=128, block_k=128)
+    want = grads(full_attention, f32(q), f32(k), f32(v))
+    for g, f, r, name in zip(got, fused, want, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(f32(g), f32(f), err_msg=name)
+        scale = max(1.0, float(jnp.max(jnp.abs(r))))
+        assert float(jnp.max(jnp.abs(f32(g) - r))) / scale < 2e-2, name
+
+
 @pytest.mark.parametrize("tk", [64, 50], ids=["even", "odd"])
-def test_partial_triples_and_their_gradients(tk):
+def test_partial_triples_and_their_gradients(tk, flash_bwd):
     """One ring hop: queries against a visiting shard of another length."""
     tq, scale = 64, D ** -0.5
     rng = np.random.RandomState(4)
@@ -97,8 +127,11 @@ def test_partial_triples_and_their_gradients(tk):
         np.testing.assert_allclose(g, r, atol=5e-5, rtol=5e-5)
 
 
-@pytest.mark.parametrize("impl", ["flash", "naive"])
-def test_ring_of_four_matches_full_in_value_and_gradient(impl):
+@pytest.mark.parametrize("impl, bwd", [("flash", "fused"), ("flash", "split"), ("naive", None)])
+def test_ring_of_four_matches_full_in_value_and_gradient(impl, bwd, monkeypatch):
+    if bwd == "split":
+        from ps_pytorch_tpu.ops import flash_attention as fa
+        monkeypatch.setattr(fa, "FUSED_BWD_CAP", 0)
     mesh = make_seq_mesh(4)
     q, k, v = _qkv(64, seed=5)
     w = jnp.asarray(np.random.RandomState(6).randn(B, 64, H, DV).astype(np.float32))
@@ -128,8 +161,14 @@ def test_plan_at_the_published_widths():
     four tiles), and MLA at T 8192 takes 512 x 512 tiles, 136 of 256 run."""
     small = plan_flash(1024, 1024, 64, jnp.bfloat16, True)
     assert (small.block_q, small.block_k, small.tiles_run, small.grid_steps) == (512, 512, 3, 4)
-    assert small.vmem_bytes == 2 * (512 + 512) * 128 * 2 + 2 * 2 * 512 * 4 \
+    tiles = 2 * (512 + 512) * 128 * 2 + 2 * 2 * 512 * 4 \
         + 3 * 512 * 128 * 4 + 4 * 512 * 512 * 4 + 2 * 512 * 512 * 2
+    assert _vmem_bytes(512, 512, 64, 2) == tiles
+    # the fused backward: the head's dq and its block, twice, beside them
+    assert (small.bwd, small.dq_acc_bytes) == ("fused", 1024 * 64 * 4)
+    assert small.vmem_bytes == tiles + 1024 * 64 * 4 + 2 * 512 * 64 * 4
     mla = plan_flash(8192, 8192, 192, jnp.bfloat16, True, d_v=128)
     assert (mla.block_q, mla.block_k, mla.tiles_run, mla.grid_steps) == (512, 512, 136, 256)
-    assert mla.k_len is None and mla.vmem_bytes < 12 * 2 ** 20
+    assert mla.k_len is None and _vmem_bytes(512, 512, 192, 2, 128) < 12 * 2 ** 20
+    assert (mla.bwd, mla.dq_acc_bytes) == ("fused", 6 * 2 ** 20)
+    assert mla.vmem_bytes == _vmem_bytes(512, 512, 192, 2, 128) + 6 * 2 ** 20 + 2 * 512 * 192 * 4

@@ -17,6 +17,8 @@ from jax.sharding import SingleDeviceSharding
 from ps_pytorch_tpu.ops import flash_attention as fa
 from ps_pytorch_tpu.ops import grouped_matmul as gm
 
+from .test_flash_attention import CELL_SHAPES
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -43,13 +45,34 @@ def shape(topo):
     return lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
 
+def _mosaic_lines(compiled):
+    return [line for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def _mosaic_calls(compiled) -> int:
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    return len(_mosaic_lines(compiled))
 
 
-@pytest.mark.parametrize("d_qk, d_v", [(192, 128), (64, 64)], ids=["mla", "gpt2"])
-def test_flash_kernels_compile_at_the_published_widths(shape, d_qk, d_v):
-    bh, t = 64, 8192
+# an attention layer in the four LM cells, and the longest head the plan
+# still fuses
+FLASH_SHAPES = dict(CELL_SHAPES, t65536=(1, 65536, 192, 128))
+
+
+@pytest.mark.parametrize("bwd", ["fused", "split"])
+@pytest.mark.parametrize("cell", sorted(FLASH_SHAPES))
+def test_flash_kernels_compile_at_the_cells_shapes(shape, monkeypatch, cell, bwd):
+    """Forward and backward as a ring hop drives them (traced offsets in
+    SMEM, float32 results) and the backward as the custom VJP does (results
+    in the operands' dtype). The fused backward is ONE Mosaic call, given
+    the VMEM limit the plan asks; past the cap (put at 0 here) the pair."""
+    import re
+
+    bh, t, d_qk, d_v = FLASH_SHAPES[cell]
+    if bwd == "split":
+        monkeypatch.setattr(fa, "FUSED_BWD_CAP", 0)
+    plan = fa.plan_flash(t, t, d_qk, jnp.bfloat16, True, d_v=d_v)
+    assert (plan.block_q, plan.block_k, plan.bwd) == (512, 512, bwd)
     q, k, v, do = shape((bh, t, d_qk)), shape((bh, t, d_qk)), shape((bh, t, d_v)), shape((bh, t, d_v))
     row, off = shape((bh, t), jnp.float32), shape((), jnp.int32)
     scale = d_qk ** -0.5
@@ -57,11 +80,21 @@ def test_flash_kernels_compile_at_the_published_widths(shape, d_qk, d_v):
     def fwd(q, k, v, q_off, k_off):
         return fa.flash_partial(q, k, v, scale, True, q_off, k_off, mode={})
 
-    def bwd(q, k, v, do, lse, delta, q_off, k_off):
+    def hop(q, k, v, do, lse, delta, q_off, k_off):
         return fa.flash_grads_partial(q, k, v, do, lse, delta, scale, True, q_off, k_off, mode={})
 
+    def local(q, k, v, do, lse, delta):
+        return fa._flash_bwd(q, k, v, lse, delta, do, scale, True, plan.block_q, plan.block_k, {})
+
     assert _mosaic_calls(jax.jit(fwd).lower(q, k, v, off, off).compile()) == 1
-    assert _mosaic_calls(jax.jit(bwd).lower(q, k, v, do, row, row, off, off).compile()) == 2
+    names = {"fused": ["ps_flash_dqkv"], "split": ["ps_flash_dkv", "ps_flash_dq"]}[bwd]
+    for compiled in (jax.jit(hop).lower(q, k, v, do, row, row, off, off).compile(),
+                     jax.jit(local).lower(q, k, v, do, row, row).compile()):
+        calls = _mosaic_lines(compiled)
+        assert sorted(re.search(r"%(ps_flash_[a-z]+)", c).group(1) for c in calls) == names
+        if bwd == "fused":
+            (limit,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', calls[0])
+            assert int(limit) == fa.vmem_limit(plan.vmem_bytes) > 16 * 2 ** 20
 
 
 @pytest.mark.parametrize("k, n", [(2048, 768), (768, 2048)], ids=["gate_up", "down"])

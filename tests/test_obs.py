@@ -703,6 +703,8 @@ def test_train_lm_flash_plan_says_which_attention_runs(tmp_path, num_sp, path, t
     (plan,) = _named(spans, "flash_plan")
     assert plan["attention_path"] == path and plan["seq_shards"] == num_sp
     assert plan["block_q"] == plan["block_k"] == t_att
+    # one backward kernel, the whole head's float32 dq in VMEM (D = 16)
+    assert (plan["bwd"], plan["dq_acc_bytes"]) == ("fused", t_att * 16 * 4)
     assert validate_event(dict(plan))["seq_shards"] == num_sp
 
 

@@ -61,7 +61,7 @@ def test_ring_flash_matches_full(seq_mesh, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
-def test_ring_flash_gradients_match_full(seq_mesh, causal):
+def test_ring_flash_gradients_match_full(seq_mesh, causal, flash_bwd):
     q, k, v = _qkv(seed=1)
 
     def ring_loss(q, k, v):
@@ -134,7 +134,7 @@ def test_bidirectional_ring_flash_matches_full(seq_mesh, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
-def test_bidirectional_ring_flash_gradients_match_full(seq_mesh, causal):
+def test_bidirectional_ring_flash_gradients_match_full(seq_mesh, causal, flash_bwd):
     """Two counter-rotating dk/dv accumulator streams + the single-hop
     home delivery must sum to the exact flash backward."""
     q, k, v = _qkv(seed=7)
@@ -183,7 +183,7 @@ def test_bidirectional_ring_flash_odd_n():
 
 
 @pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
-def test_ring_flash_odd_shard_len_pads_not_degrades(causal):
+def test_ring_flash_odd_shard_len_pads_not_degrades(causal, flash_bwd):
     """Shard lengths that aren't block multiples (T=50 over a 5-ring ->
     10-token shards) pad-and-mask inside flash_partial/flash_grads_partial
     instead of silently shrinking tiles (code-review r03). Value AND
@@ -228,7 +228,7 @@ def test_ring_flash_odd_shard_len_pads_not_degrades(causal):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_ring_hop_wholly_in_the_future_is_a_noop(dtype):
+def test_ring_hop_wholly_in_the_future_is_a_noop(dtype, flash_bwd):
     """A visiting shard whose every key lies past every local query: the
     kernels skip all of its tiles (decided from the TRACED offsets) and it
     must still come out as fully-masked rows — m = NEG_INF, l = 0, a zero
@@ -262,6 +262,55 @@ def test_ring_hop_wholly_in_the_future_is_a_noop(dtype):
         pv[:, -1], np.asarray(v3[:, 0], np.float32), rtol=1e-6)
     assert not dk[:, 1:].any() and dk[:, 0].any() and dq[:, -1].any()
     assert not dq[:, :-1].any() and not dv[:, 1:].any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("q_off, k_off", [(64, 0), (0, 64), (40, 250)],
+                         ids=["past", "across", "future"])
+def test_ring_hop_of_several_blocks_each_way(q_off, k_off, dtype, flash_bwd):
+    """One hop as the ring drives it, tiled 3 x 5 (32-wide blocks asked
+    for, T_q 96 against T_k 150, whose tail pads and is masked by k_len),
+    traced offsets, float32 gradients: against plain masked attention
+    differentiated by jax. The fused kernel holds dq over five k sweeps;
+    `across` has live, cut and dead tiles in every sweep, `future` none
+    live at all (zero gradients)."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_grads_partial, plan_flash)
+
+    bh, tq, tk, d, dv, scale = 2, 96, 150, 16, 8, 0.25
+    plan = plan_flash(tq, tk, d, dtype, True, 32, 32, d_v=dv)
+    assert (plan.grid_steps, plan.k_len, plan.bwd) == (15, 150, flash_bwd)
+    rng = np.random.RandomState(13)
+    mk = lambda t, w: jnp.asarray(rng.randn(bh, t, w), dtype)
+    q3, do3, k3, v3 = mk(tq, d), mk(tq, dv), mk(tk, d), mk(tk, dv)
+    f32 = lambda x: x.astype(jnp.float32)
+    keep = (k_off + jnp.arange(tk))[None, :] <= (q_off + jnp.arange(tq))[:, None]
+
+    def plain(q, k, v):
+        s = jnp.where(keep, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
+        lse = jax.nn.logsumexp(s, -1)
+        p = jnp.where(keep, jnp.exp(s - lse[..., None]), 0.0)
+        return jnp.einsum("bqk,bkd->bqd", p, v), lse
+
+    (o, lse), vjp = jax.vjp(lambda *a: plain(*a), f32(q3), f32(k3), f32(v3))
+    want = vjp((f32(do3), jnp.zeros_like(lse)))
+    dead = ~jnp.any(keep, -1)  # a query no key of this hop may see
+    lse = jnp.where(dead, -1e30, lse)
+    delta = jnp.sum(f32(do3) * o, -1)
+    @jax.jit
+    def hop(q_off, k_off):  # traced offsets, as the ring passes them
+        return flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, True,
+                                   q_off, k_off, block_q=32, block_k=32)
+
+    got = hop(jnp.int32(q_off), jnp.int32(k_off))
+    bound = 2e-5 if dtype == jnp.float32 else 2e-2
+    for g, w, name in zip(got, want, ("dq", "dk", "dv")):
+        assert g.dtype == jnp.float32 and g.shape == w.shape
+        w = jnp.where(dead[..., None], 0.0, w) if name == "dq" else w
+        err = float(jnp.max(jnp.abs(g - w))) / max(1.0, float(jnp.max(jnp.abs(w))))
+        assert err < bound, (name, err)
+        assert bool(jnp.any(g)) == (k_off < 200), name
 
 
 def test_sp_transformer_flash_matches_single_device(seq_mesh):
