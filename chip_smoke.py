@@ -331,7 +331,8 @@ def library_lm_step(config_path, num_dp, num_sp, batch):
     import jax.numpy as jnp
 
     from ps_pytorch_tpu.cli import train_lm as train_lm_cli
-    from ps_pytorch_tpu.models.lm import load_lm_config
+    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.models.transformer import remat_plan
     from ps_pytorch_tpu.optim import build_optimizer
     from ps_pytorch_tpu.parallel.dp_sp import (
         init_lm_state,
@@ -346,6 +347,11 @@ def library_lm_step(config_path, num_dp, num_sp, batch):
     mesh = make_mesh_2d(num_dp, num_sp)
     params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
     seq = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--seq-len") + 1])
+    if num_sp == 1:  # the ring's hops name nothing
+        saves = remat_plan(lm_family(cfg).saved_layers(cfg, batch // num_dp, seq), params)
+        print(f"[{os.path.basename(config_path)}] remat keeps {','.join(saves.names)}: "
+              f"{saves.saved_bytes / 2 ** 20:.1f} MiB as stored, of a limit of "
+              f"{saves.bytes_limit / 2 ** 20:.0f} MiB", flush=True)
     tokens = shard_tokens_2d(
         jnp.asarray(train_lm_cli.make_synthetic_tokens(
             cfg.vocab_size, batch, seq, seed=2)), mesh)

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from ..models.lm import load_lm_config
+from ..models.lm import lm_family, load_lm_config
 from ..models.transformer import TransformerConfig, attention_path
 from ..optim import build_optimizer
 from ..parallel.dp_sp import (
@@ -488,14 +488,37 @@ def main(argv=None) -> dict:
             geometry=geometry,
         )
 
+    # what `remat` keeps beside each block's input, by the function the
+    # blocks' policy comes from (models/transformer.remat_plan), where the
+    # families' own apply runs (the ring's hops name nothing)
+    seq_shards = num_sp if args.parallelism in ("dp_sp", "ep_sp") else 1
+    path = attention_path(cfg, seq_shards)
+    kept = ()  # one {name: bytes a layer} a kind of layer
+    if cfg.remat and args.parallelism == "dp_sp" and path == "local":
+        from ..models.transformer import remat_plan
+
+        kinds = lm_family(cfg).saved_layers(
+            cfg, args.batch_size // args.num_dp, args.seq_len)
+        saves = remat_plan(kinds, params)
+        kept = saves.kept
+        logger.info(
+            "remat keeps %s: %.0f MiB as stored beside %.0f MiB of state, of %.0f MiB "
+            "(the kernels' operands %s)", saves.names, saves.saved_bytes / 2 ** 20,
+            saves.state_bytes / 2 ** 20, saves.bytes_limit / 2 ** 20,
+            "kept" if saves.operands_kept else "left to recompute: no room")
+
+    def remat_fields(kernels):
+        """The plan's share under one kernel family's names (`ps_flash_`,
+        `ps_kda_`)."""
+        names = {n: b for kind in kept for n, b in kind.items() if n.startswith(kernels)}
+        return dict(remat_saves=",".join(names), saved_bytes_per_layer=sum(names.values()))
+
     if cfg.attention_impl == "flash":
         # the kernels' tile plan is static: how often the skip engages is
         # known here, from the shapes every attention call will have, and
         # so is the path select_attention takes (the same function decides)
-        from ..ops.flash_attention import FLASH_SAVED, plan_flash
+        from ..ops.flash_attention import plan_flash
 
-        seq_shards = num_sp if args.parallelism in ("dp_sp", "ep_sp") else 1
-        path = attention_path(cfg, seq_shards)
         t_att = args.seq_len // seq_shards if path == "ring" else args.seq_len
         plan = plan_flash(t_att, t_att, d_qk,
                           cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
@@ -503,16 +526,7 @@ def main(argv=None) -> dict:
             "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total",
             "bwd", "dq_acc_bytes")}
         flash_plan.update(d_qk=d_qk, d_v=d_v, attention_path=path,
-                          seq_shards=seq_shards)
-        # what `remat` keeps of each attention layer beside the block's
-        # input (models/transformer.remat_block): the kernel's o and lse,
-        # where the families' own apply runs the within-chip kernels
-        saves = cfg.remat and args.parallelism == "dp_sp" and path == "local"
-        rows = args.batch_size // args.num_dp * args.heads * plan.tq_pad
-        itemsize = jnp.dtype(cfg.effective_compute_dtype).itemsize
-        flash_plan.update(
-            remat_saves=",".join(FLASH_SAVED) if saves else "",
-            saved_bytes_per_layer=rows * (d_v * itemsize + 4) if saves else 0)
+                          seq_shards=seq_shards, **remat_fields("ps_flash_"))
         logger.info(
             "flash plan for T %d x D %d: %s (per head%s)", t_att,
             d_qk, flash_plan,
@@ -530,7 +544,7 @@ def main(argv=None) -> dict:
         # and so are the delta rule's
         from ..models.kda_hybrid import kda_plan
 
-        plan = kda_plan(cfg, args.seq_len)
+        plan = {**kda_plan(cfg, args.seq_len), **remat_fields("ps_kda_")}
         logger.info("kda plan for T %d: %s (per row)", args.seq_len, plan)
         tr.instant("kda_plan", **plan)
 

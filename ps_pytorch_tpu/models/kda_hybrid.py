@@ -47,14 +47,14 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.scopes import EMBED, HEAD_LOSS, MIXER_KDA, scope
-from ..ops.flash_attention import FLASH_SAVED
-from ..ops.kda import KDA_SAVED, kda_chunked, l2_normalize
+from ..ops.kda import KDA_OPERANDS, kda_chunked, kda_saves, l2_normalize
 from ..parallel.moe import DroplessSpec, routing_counters
 from .mla_moe import _gated_init, _rms32, ffn_half, mla_mixer_half
 from .ssm_hybrid import _causal_conv
-from .transformer import select_attention
+from .transformer import flash_layers, remat_block, select_attention
 
 # config.json keys this family reads (`linear_attn_config` is a group);
 # every other key is carried by the benchmark's file and ignored here
@@ -268,6 +268,10 @@ def kda_mixer(cfg: KdaHybridConfig, n, blk):
     q = short(n, blk["wq"], blk["conv_q"], h, dk ** -0.5)
     k = short(n, blk["wk"], blk["conv_k"], h, 1.0)
     v = short(n, blk["wv"], blk["conv_v"], h, None)
+    # where the blocks' policy keeps them (KDA_OPERANDS), the backward does
+    # not run the three branches again to run the op again; each branch's
+    # own checkpoint still does, for the conv's and the norm's gradients
+    q, k, v = map(checkpoint_name, (q, k, v), KDA_OPERANDS)
     decay = {name: blk[name] for name in ("f_a", "f_b", "a_log", "dt_bias")}
     g = jax.checkpoint(_log_decay, static_argnums=(2,))(n, decay, h)
     beta = jax.nn.sigmoid(_into32(n, blk["w_beta"]))
@@ -319,12 +323,11 @@ def apply_kda_hybrid(
         return ffn_half(cfg, x, blk)
 
     if cfg.remat:
-        # as models/transformer.remat_block, but the two halves apart (the
-        # backward holds what one half recomputes, never the delta rule's
-        # temporaries beside the expert buffer's) and the delta rule's
-        # triangular inverses kept beside the flash kernel's o and lse
-        keep = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED, *KDA_SAVED)
-        mixer, ffn = jax.checkpoint(mixer, policy=keep), jax.checkpoint(ffn, policy=keep)
+        # the two halves apart: the backward holds what one half
+        # recomputes, never the delta rule's temporaries beside the expert
+        # buffer's
+        kinds = saved_layers(cfg, *tokens.shape)
+        mixer, ffn = remat_block(mixer, kinds, params), remat_block(ffn, kinds, params)
     with scope(EMBED):
         x = params["embed"][tokens].astype(cd)
     counts, unserved, cut_off = [], [], []
@@ -345,6 +348,16 @@ def apply_kda_hybrid(
         aux["kda_cut_off"] = jnp.stack(cut_off)
     with scope(HEAD_LOSS):
         return n @ params["head"].astype(cd), aux
+
+
+def saved_layers(cfg: KdaHybridConfig, batch: int, seq_len: int):
+    """models/transformer.saved_layers for this family: the latent-attention
+    layers' flash kernels, then the delta-rule layers' inverses and q, k, v."""
+    attention = flash_layers(cfg, batch, seq_len, cfg.num_attention_heads, cfg.qk_head_dim,
+                             cfg.v_head_dim, len(cfg.full_attn_layers))
+    return attention + [kda_saves(batch, seq_len, cfg.kda_heads, cfg.kda_head_dim,
+                                  cfg.effective_compute_dtype, cfg.kda_chunk_size,
+                                  len(cfg.kda_layers))]
 
 
 def kda_plan(cfg: KdaHybridConfig, seq_len: int) -> Dict:

@@ -9,6 +9,9 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
                               over the mesh ({} where nothing is counted)
     counters(aux) -> dict     None where aux is {}: what the step returns
                               beside the loss, from the summed aux
+    saved_layers(cfg, batch, seq_len) -> [ops/flash_attention.SavedLayers]
+                              what its blocks name for `remat`'s policy
+                              (models/transformer.remat_block)
 
 `load_lm_config` builds a config from a published config.json-shaped dict
 by its `model_type` (`_PUBLISHED_FAMILIES`: deepseek_v3 -> models/mla_moe.py,
@@ -22,13 +25,14 @@ import importlib
 import json
 from typing import Callable, Dict, NamedTuple, Optional, Union
 
-from .transformer import TransformerConfig, apply_transformer, init_transformer
+from .transformer import TransformerConfig, apply_transformer, init_transformer, saved_layers
 
 
 class LMFamily(NamedTuple):
     init: Callable
     apply: Callable
     counters: Optional[Callable]
+    saved_layers: Callable
 
 
 def _apply_dense(cfg, params, tokens, seq_axis_name=None, pos_offset=None):
@@ -37,25 +41,25 @@ def _apply_dense(cfg, params, tokens, seq_axis_name=None, pos_offset=None):
 
 def _mla_moe_family(cfg) -> LMFamily:
     from ..parallel.moe import routing_counters
-    from .mla_moe import apply_mla_moe, init_mla_moe
+    from .mla_moe import apply_mla_moe, init_mla_moe, saved_layers
 
     counters = (lambda aux: routing_counters(aux["counts"], aux["unserved"])) \
         if cfg.moe_layers else None
-    return LMFamily(init_mla_moe, apply_mla_moe, counters)
+    return LMFamily(init_mla_moe, apply_mla_moe, counters, saved_layers)
 
 
 def _ssm_hybrid_family(cfg) -> LMFamily:
-    from .ssm_hybrid import apply_ssm_hybrid, init_ssm_hybrid, ssd_counters
+    from .ssm_hybrid import apply_ssm_hybrid, init_ssm_hybrid, saved_layers, ssd_counters
 
     return LMFamily(init_ssm_hybrid, apply_ssm_hybrid,
-                    ssd_counters if cfg.mamba_layers else None)
+                    ssd_counters if cfg.mamba_layers else None, saved_layers)
 
 
 def _kda_hybrid_family(cfg) -> LMFamily:
-    from .kda_hybrid import apply_kda_hybrid, init_kda_hybrid, kda_counters
+    from .kda_hybrid import apply_kda_hybrid, init_kda_hybrid, kda_counters, saved_layers
 
     return LMFamily(init_kda_hybrid, apply_kda_hybrid,
-                    kda_counters if cfg.moe_layers or cfg.kda_layers else None)
+                    kda_counters if cfg.moe_layers or cfg.kda_layers else None, saved_layers)
 
 
 class _Published(NamedTuple):
@@ -90,7 +94,7 @@ def _config_class(model_type: str):
 
 def lm_family(cfg) -> LMFamily:
     if isinstance(cfg, TransformerConfig):
-        return LMFamily(init_transformer, _apply_dense, None)
+        return LMFamily(init_transformer, _apply_dense, None, saved_layers)
     for model_type, entry in _PUBLISHED_FAMILIES.items():
         if isinstance(cfg, _config_class(model_type)):
             return entry.family(cfg)

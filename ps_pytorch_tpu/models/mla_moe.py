@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_MLA, MLP, MOE, scope
 from ..parallel.moe import DroplessSpec, moe_dropless_local
-from .transformer import remat_block, select_attention
+from .transformer import flash_layers, remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -267,6 +267,13 @@ def mla_moe_block(cfg: MlaMoeConfig, x, blk, attend, pos):
     return ffn_half(cfg, x, blk)
 
 
+def saved_layers(cfg: MlaMoeConfig, batch: int, seq_len: int):
+    """models/transformer.saved_layers for this family: every layer's
+    latent attention through the flash kernels."""
+    return flash_layers(cfg, batch, seq_len, cfg.num_attention_heads, cfg.qk_head_dim,
+                        cfg.v_head_dim, cfg.num_hidden_layers)
+
+
 def apply_mla_moe(
     cfg: MlaMoeConfig,
     params: Dict,
@@ -280,7 +287,7 @@ def apply_mla_moe(
     of whose experts is held. Under shard_map pass seq_axis_name, as for
     apply_transformer: attention runs over the axis and the rotary angles
     take GLOBAL positions."""
-    t_loc = tokens.shape[1]
+    b, t_loc = tokens.shape
     shard = jax.lax.axis_index(seq_axis_name) * t_loc if seq_axis_name is not None else 0
     if pos_offset is not None:
         shard = shard + pos_offset
@@ -292,7 +299,7 @@ def apply_mla_moe(
         return mla_moe_block(cfg, x, blk, attend, pos)
 
     if cfg.remat:
-        block = remat_block(block)
+        block = remat_block(block, saved_layers(cfg, b, t_loc), params)
     with scope(EMBED):
         x = params["embed"][tokens].astype(cd)
     counts, unserved = [], []

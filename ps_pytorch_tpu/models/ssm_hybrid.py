@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_ATTENTION, MIXER_SSD, MLP, scope
 from ..ops.ssd import ssd_chunked
 from .mla_moe import _rms32
-from .transformer import remat_block, select_attention
+from .transformer import flash_layers, remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -263,6 +263,14 @@ def ssm_hybrid_block(cfg: SsmHybridConfig, x, blk, attend):
             return x + (cfg.residual_multiplier * _gated_mlp(n, blk["mlp"], cd)).astype(cd), cut_off
 
 
+def saved_layers(cfg: SsmHybridConfig, batch: int, seq_len: int):
+    """models/transformer.saved_layers for this family: the attention
+    layers' flash kernels, keys and values already repeated to the query
+    heads; a state-space layer names nothing."""
+    return flash_layers(cfg, batch, seq_len, cfg.num_attention_heads, cfg.head_dim,
+                        cfg.head_dim, cfg.num_hidden_layers - cfg.mamba_layers)
+
+
 def apply_ssm_hybrid(
     cfg: SsmHybridConfig,
     params: Dict,
@@ -288,7 +296,7 @@ def apply_ssm_hybrid(
         return ssm_hybrid_block(cfg, x, blk, attend)
 
     if cfg.remat:
-        block = remat_block(block)
+        block = remat_block(block, saved_layers(cfg, *tokens.shape), params)
     with scope(EMBED):
         x = (params["embed"][tokens] * cfg.embedding_multiplier).astype(cd)
     cut_off = []

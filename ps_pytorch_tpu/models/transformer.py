@@ -40,7 +40,8 @@ class TransformerConfig:
     # rematerialize each block in backward (remat_block): a block keeps its
     # input and, where the within-chip flash kernels attend, their o and lse
     # (B*H*T*(d_v*itemsize + 4) bytes a layer: o is as large as the input in
-    # compute_dtype here, where H*d_v == dim) and runs everything else again.
+    # compute_dtype here, where H*d_v == dim) and, where the device has the
+    # room (remat_plan), their folded q, k, v; it runs everything else again.
     # ~1/3 more FLOPs for O(depth) -> O(1) of a block's other ~30 activations,
     # the standard lever for long-context training
     remat: bool = False
@@ -185,17 +186,46 @@ def select_attention(cfg: TransformerConfig, seq_axis_name: Optional[str] = None
     )
 
 
-def remat_block(block):
-    """`block` under jax.checkpoint as every LM family's `remat` means it:
-    the backward keeps the block's input and whatever inside it carries
-    ops/flash_attention.FLASH_SAVED's names (the flash forward kernel's o
-    and lse), and recomputes the rest. So the block runs twice and
-    ps_flash_fwd once. Where no value has those names (the jnp attention,
-    the ring's hops, a state-space block) this is jax.checkpoint(block)."""
-    from ..ops.flash_attention import FLASH_SAVED
+def flash_layers(cfg, batch: int, seq_len: int, heads: int, d: int, d_v: int, layers: int):
+    """[ops/flash_attention.flash_saves] of `layers` attention layers that
+    attend with q, k [batch, seq_len, heads, d] and v [..., d_v] under any
+    family's `cfg`; [] where the flash kernels do not run."""
+    from ..ops.flash_attention import flash_saves
 
-    return jax.checkpoint(
-        block, policy=jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED))
+    if cfg.attention_impl != "flash":
+        return []
+    return [flash_saves(batch, seq_len, heads, d, d_v, cfg.effective_compute_dtype,
+                        cfg.causal, layers)]
+
+
+def saved_layers(cfg: TransformerConfig, batch: int, seq_len: int):
+    """What this family's blocks name for `remat` at tokens [batch,
+    seq_len] (ops/flash_attention.SavedLayers, one a kind of layer): the
+    flash kernels' values where they run. Every family has one; cli/
+    train_lm.py reads them through models/lm.LMFamily."""
+    return flash_layers(cfg, batch, seq_len, cfg.heads, cfg.head_dim, cfg.head_dim, cfg.depth)
+
+
+def remat_plan(kinds, params):
+    """ops/flash_attention.plan_remat_saves for a model of `kinds` beside
+    the training state of `params`, on this process's device."""
+    from ..ops.flash_attention import device_bytes_limit, plan_remat_saves
+
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    return plan_remat_saves(kinds, n_params, device_bytes_limit())
+
+
+def remat_block(block, kinds, params):
+    """`block` under jax.checkpoint as every LM family's `remat` means it:
+    the backward keeps the block's input and whatever inside it carries a
+    name of remat_plan's (the flash forward kernel's o and lse, the delta
+    rule's inverses; the kernels' operands q, k, v where memory allows),
+    and recomputes the rest. So the block runs twice, ps_flash_fwd once,
+    and of an attention layer's second run what makes q, k and v is dead.
+    Where no value has those names (the jnp attention, the ring's hops, a
+    state-space block) this is jax.checkpoint(block)."""
+    names = remat_plan(kinds, params).names
+    return jax.checkpoint(block, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 _MIXER_LEAVES = ("ln1", "wqkv", "wo")
@@ -270,7 +300,7 @@ def apply_transformer(
         return transformer_block(cfg, x, blk, attend)
 
     if cfg.remat:
-        block = remat_block(block)
+        block = remat_block(block, saved_layers(cfg, b, t_loc), params)
     for blk in params["blocks"]:
         x = block(x, blk)
 

@@ -143,7 +143,9 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # "fused" or "split", and the fused backward's
                     # `dq_acc_bytes` in VMEM; what `remat` keeps
                     # of a layer, `saved_bytes_per_layer` under the names in
-                    # the string `remat_saves`) and `moe_route` (the
+                    # the string `remat_saves`, both of ops/flash_attention.
+                    # plan_remat_saves and in `kda_plan` too for a
+                    # delta-rule layer's) and `moe_route` (the
                     # dropless expert layers' rows, summed over layers;
                     # `<name>_per_layer` lists ride along); for a family
                     # with state-space layers `ssd_plan` (the scan's

@@ -63,6 +63,7 @@ oracle.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -81,6 +82,11 @@ NEG_INF = -1e30
 # without running ps_flash_fwd again; without such a policy the names
 # lower to nothing.
 FLASH_SAVED = ("ps_flash_o", "ps_flash_lse")
+# The kernels' operands, named where they already live: folded to [BH, T, D]
+# and padded to the block grid. Dear to make again (a layer's projections,
+# rotation and norms, the fold's transposes and pads) and bfloat16-small to
+# keep, so a policy saves them too wherever plan_remat_saves finds the room.
+FLASH_OPERANDS = ("ps_flash_q", "ps_flash_k", "ps_flash_v")
 
 # What one grid step may hold in VMEM by plan_flash's estimate: under the
 # 16 MiB a v5e kernel gets by default, with room for what the estimate
@@ -683,6 +689,101 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
     )
 
 
+# ------------------------------------------------------ what `remat` keeps
+
+# One chip's HBM as a v5e's memory_stats() states it (`bytes_limit`, 15.75
+# GiB less 2 MiB): what a device that states no limit (the CPU) is taken for.
+V5E_BYTES_LIMIT = 16_909_336_064
+# float32 weights, gradients and Adam's two moments
+STATE_BYTES_A_PARAMETER = 16
+# Operands are kept while the training state and everything the policy
+# saves stay under this share of the device's limit; the rest is for the
+# blocks' inputs and what one block's backward holds at a time.
+REMAT_SHARE = 0.8
+
+
+class SavedLayers(NamedTuple):
+    """What `count` layers of one kind name for a `remat` policy, each as
+    {name: jax.ShapeDtypeStruct}: the `residuals` only a kernel can make
+    (always kept) and the kernels' `operands` (kept where there is room)."""
+
+    count: int
+    residuals: dict
+    operands: dict
+
+
+class RematPlan(NamedTuple):
+    """What the blocks' policy saves. `kept` has one {name: bytes a layer,
+    as the program's values have them} for each kind of layer given."""
+
+    kept: tuple
+    operands_kept: bool
+    saved_bytes: int   # every layer's, as the chip stores them
+    state_bytes: int
+    bytes_limit: int
+
+    @property
+    def names(self) -> tuple:
+        return tuple(name for kind in self.kept for name in kind)
+
+
+def stored_bytes(shape, dtype) -> int:
+    """Bytes of one array as a TPU keeps it in HBM: the last dimension in
+    whole 128-lane tiles (a 192-wide head takes 256), the one before it in
+    whole tiles of 8 32-bit rows."""
+    itemsize = jnp.dtype(dtype).itemsize
+    dims = list(shape)
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        rows = 8 * max(4 // itemsize, 1)
+        dims[-2] = -(-dims[-2] // rows) * rows
+    return math.prod(dims) * itemsize
+
+
+def flash_saves(b: int, t: int, h: int, d: int, d_v: int, dtype, causal: bool,
+                layers: int) -> SavedLayers:
+    """What _flash_vjp_fwd names in `layers` attention layers that call
+    flash_attention with q, k [b, t, h, d] and v [b, t, h, d_v]."""
+    plan = plan_flash(t, t, d, dtype, causal, d_v=d_v)
+    sds, bh = jax.ShapeDtypeStruct, b * h
+    o_lse = (sds((bh, plan.tq_pad, d_v), dtype), sds((bh, plan.tq_pad), jnp.float32))
+    qkv = (sds((bh, plan.tq_pad, d), dtype), sds((bh, plan.tk_pad, d), dtype),
+           sds((bh, plan.tk_pad, d_v), dtype))
+    return SavedLayers(layers, dict(zip(FLASH_SAVED, o_lse)), dict(zip(FLASH_OPERANDS, qkv)))
+
+
+def device_bytes_limit() -> int:
+    """The first local device's memory limit; V5E_BYTES_LIMIT where it
+    states none."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit") or V5E_BYTES_LIMIT
+
+
+def plan_remat_saves(kinds, n_params: int, bytes_limit: int) -> RematPlan:
+    """The names a `remat` policy saves (models/transformer.remat_block),
+    from what the program can observe as it is traced. Pure. `kinds` are
+    the model's SavedLayers, `n_params` its parameters, `bytes_limit` the
+    device's memory.
+
+    The residuals are always kept. The operands are kept too, all of them,
+    where the training state at STATE_BYTES_A_PARAMETER and everything
+    saved, as the chip stores it (stored_bytes), come under REMAT_SHARE of
+    the limit; a longer sequence or a larger batch falls back to the
+    residuals alone."""
+    def stored(group):
+        return sum(kind.count * stored_bytes(a.shape, a.dtype)
+                   for kind in kinds for a in getattr(kind, group).values())
+
+    state = STATE_BYTES_A_PARAMETER * n_params
+    residuals, operands = stored("residuals"), stored("operands")
+    keep = state + residuals + operands <= REMAT_SHARE * bytes_limit
+    nbytes = lambda a: a.size * jnp.dtype(a.dtype).itemsize
+    kept = tuple({name: nbytes(a) for name, a in
+                  (*kind.residuals.items(), *(kind.operands.items() if keep else ()))}
+                 for kind in kinds)
+    return RematPlan(kept, keep, residuals + (operands if keep else 0), state, bytes_limit)
+
+
 # --------------------------------------------------------------- public API
 
 
@@ -707,6 +808,7 @@ def _flash_vjp_fwd(q3, k3, v3, scale, causal, block_q, block_k, k_len):
     o, lse = _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
                         k_len=k_len)
     o, lse = map(checkpoint_name, (o, lse), FLASH_SAVED)
+    q3, k3, v3 = map(checkpoint_name, (q3, k3, v3), FLASH_OPERANDS)
     return o, (q3, k3, v3, o, lse)
 
 

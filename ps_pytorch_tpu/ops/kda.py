@@ -74,6 +74,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.scopes import DELTA_RULE, scope
+from .flash_attention import SavedLayers
 from .pallas_mode import kernel_mode, pallas_mode
 
 # the one value of a chunk that `remat` is worth keeping (models/kda_hybrid.py
@@ -82,6 +83,12 @@ from .pallas_mode import kernel_mode, pallas_mode
 # head in float32, whose backward needs nothing else and whose forward is
 # ten products at "highest"
 KDA_SAVED = ("ps_kda_inverse",)
+# the op's q, k and v as models/kda_hybrid.kda_mixer's short branches leave
+# them (a product, a conv, silu and an L2 norm in float32 each; bfloat16
+# [B, T, H, d] to keep): kept too wherever ops/flash_attention.
+# plan_remat_saves finds the room. g is float32 and a low-rank pair and a
+# softplus to make again; beta is one number a head.
+KDA_OPERANDS = ("ps_kda_q", "ps_kda_k", "ps_kda_v")
 HI = lax.Precision.HIGHEST
 # a chunk whose SLOWEST-decaying channel keeps less than this of the state
 # it was given hands the next chunk nothing a float32 sum would notice
@@ -287,6 +294,18 @@ def padded_len(t: int, chunk: int, d_key: int, d_value: int) -> int:
     """T as `kda_chunked` pads it: whole chunks, and for the kernels, which
     take chunks in pairs, an even number of them."""
     return t + -t % (chunk if scan_path(chunk, d_key, d_value) == "xla" else 2 * chunk)
+
+
+def kda_saves(b: int, t: int, h: int, d: int, dtype, chunk: int, layers: int) -> SavedLayers:
+    """What `layers` delta-rule layers name for a `remat` policy at q, k, v
+    [b, t, h, d]: the triangular inverses as the form that runs shapes them,
+    and the op's q, k, v."""
+    sds = jax.ShapeDtypeStruct
+    nc = padded_len(t, chunk, d, d) // chunk
+    inverse = sds((b, nc, h, chunk * chunk) if scan_path(chunk, d, d) == "xla"
+                  else (b, nc // 2, h, chunk, 2 * chunk), jnp.float32)
+    return SavedLayers(layers, {KDA_SAVED[0]: inverse},
+                       {name: sds((b, t, h, d), dtype) for name in KDA_OPERANDS})
 
 
 def _dot(a, b, dims, precision=None):

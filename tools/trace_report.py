@@ -23,7 +23,9 @@ monotonic clock. This tool:
   step's time go: the spans opened directly under the trainers' `step`
   span — fetch vs dispatch vs window_close — or the serve tick's
   depth-0 spans), every instant with its attributes (train_lm's
-  `flash_plan`: the flash kernels' tile plan), and a nesting
+  `flash_plan`: the flash kernels' tile plan and, as `kda_plan` for a
+  delta-rule layer, what `remat` keeps of a layer: `remat_saves`,
+  `saved_bytes_per_layer`), and a nesting
   check (child spans must sit inside their parents — a violation means
   a tracer bug, not a workload property);
 - ``--require-phases a,b,c`` exits nonzero unless every named phase is
