@@ -30,6 +30,12 @@ CLIs expose, on synthetic data made from a seed:
             chunks of 64; k k k a k; 8 of 16 routed experts held), bf16,
             flash, remat, Adam; then the chunked delta rule on the chip
             against the token-by-token recurrence, past -88 a chunk too
+  lm_eva    cli.train_lm --lm-config on benchmark/configs/
+            evabyte_6b5_4layers.json itself (the dense EVA-attention
+            family at its published widths, four layers) at a short row of
+            4,096 bytes, two windows, so that the pass over chunk
+            summaries runs; bf16, flash, remat, Adam; then ops/eva.
+            eva_attention on the chip against its jnp twin
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -323,10 +329,11 @@ def jnp_twins():
         del os.environ["PS_TPU_DISABLE_PALLAS"]
 
 
-def library_lm_step(config_path, num_dp, num_sp, batch):
+def library_lm_step(config_path, num_dp, num_sp, batch, seq=None):
     """The step `cli.train_lm --lm-config <config_path>` + LM_CONFIG_ARGS
-    runs, built once more through the library and compiled: (cfg, the
-    compiled step, its (params, opt_state, tokens))."""
+    runs (at `seq` tokens a row where given), built once more through the
+    library and compiled: (cfg, the compiled step, its (params, opt_state,
+    tokens))."""
     import jax
     import jax.numpy as jnp
 
@@ -346,7 +353,7 @@ def library_lm_step(config_path, num_dp, num_sp, batch):
     tx = build_optimizer("adam", 3e-4)
     mesh = make_mesh_2d(num_dp, num_sp)
     params, opt_state = init_lm_state(cfg, tx, jax.random.key(1), mesh)
-    seq = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--seq-len") + 1])
+    seq = seq or int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--seq-len") + 1])
     if num_sp == 1:  # the ring's hops name nothing
         saves = remat_plan(lm_family(cfg).saved_layers(cfg, batch // num_dp, seq), params)
         print(f"[{os.path.basename(config_path)}] remat keeps {','.join(saves.names)}: "
@@ -768,6 +775,63 @@ def leg_lm_kda(workdir, devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_eva(devices, clog):
+    """The fifth LM family through `cli.train_lm --lm-config`, on the
+    benchmark's own file (published widths, four layers: 821M parameters,
+    9.2 GiB of float32 state a chip) at a row of two windows, data parallel
+    over the chips (a chunk's summary crosses no sequence shard): both
+    passes of the flash kernels must be Mosaic calls in the compiled step,
+    run once a layer under `remat`, and the counters must show the
+    summaries live. Then the op by itself against its jnp twin."""
+    import jax
+    import jax.numpy as jnp
+
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+    from ps_pytorch_tpu.ops import eva
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    leg = "lm_eva"
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "configs", "evabyte_6b5_4layers.json")
+    seq, batch = 4096, len(devices)     # one row a chip
+    out = train_lm_cli.main(
+        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
+        + LM_CONFIG_ARGS + ["--seq-len", str(seq), "--batch-size", str(batch)])
+    check_finite(leg, "loss", out["loss"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
+    cfg, step, (params, opt_state, tokens) = library_lm_step(path, len(devices), 1, batch, seq)
+    text = step.as_text()
+    check_kernels(leg, text, LM_KERNELS)
+    check_scopes(leg, text, remat="--remat" in LM_CONFIG_ARGS)
+    census = kernel_census(text)["mosaic"]
+    passes = 2 * cfg.num_hidden_layers      # over the windows, over the summaries
+    if census != {"ps_flash_fwd": passes, "ps_flash_dqkv": passes}:
+        raise AssertionError(
+            f"{leg}: wanted ps_flash_fwd and ps_flash_dqkv {passes} times each (two passes a "
+            f"layer, the forward not run again under remat); census {census}")
+    params, opt_state, loss, counters = step(params, opt_state, tokens)
+    check_finite(leg, "library step loss", jax.device_get(loss))
+    c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
+    if not 0.0 < c["eva_remote_mass"] < 1.0:
+        raise AssertionError(f"{leg}: no softmax mass on the summaries, or all of it: {c}")
+    print(f"[{leg}] counters: {c}", flush=True)
+    del step, params, opt_state
+
+    k = jax.random.split(jax.random.key(5), 5)
+    q, key, v = (jax.random.normal(kk, (1, seq, 4, 128), jnp.bfloat16) for kk in k[:3])
+    phi, mu = (jax.random.normal(kk, (4, 128), jnp.float32) for kk in k[3:])
+    attend = lambda impl: jax.jit(lambda *a: eva.eva_attention(
+        *a, cfg.window_size, cfg.chunk_size, impl=impl)[0])(q, key, v, phi, mu)
+    got, want = attend("flash"), attend("naive")
+    gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)))
+                / jnp.max(jnp.abs(want.astype(jnp.float32))))
+    if not gap < 0.02:      # bfloat16 operands and output, float32 sums: under 2% of the range
+        raise AssertionError(f"{leg}: the kernels are {gap:.4f} of the range off the jnp twin")
+    print(f"[{leg}] eva_attention, kernels vs jnp twin: {gap:.5f} of the range", flush=True)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -891,6 +955,7 @@ def main() -> int:
         run("lm_config", lambda clog: leg_lm_config(workdir, devices, clog))
         run("lm_ssm", lambda clog: leg_lm_ssm(workdir, devices, clog))
         run("lm_kda", lambda clog: leg_lm_kda(workdir, devices, clog))
+        run("lm_eva", lambda clog: leg_lm_eva(devices, clog))
 
     print(json.dumps({
         "versions": versions,

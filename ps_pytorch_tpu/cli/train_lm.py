@@ -35,7 +35,9 @@ and recorded as an instant: the expert layers' routing as `moe_route`, the
 state-space scan's cut-off chunks as `ssd_state` (after one `ssd_plan` at
 the start; that family runs --num-sp 1 only), the delta rule's as
 `kda_state` (after one `kda_plan`; `model_type: kimi_linear`, --num-sp 1
-only, and its expert layers' `moe_route` beside it).
+only, and its expert layers' `moe_route` beside it), EVA attention's
+softmax mass on chunk summaries as `eva_state` (after one `eva_plan`, which
+holds the kernels' tiles; `model_type: evabyte`, --num-sp 1 only).
 
   ... --lm-config benchmark/configs/kanana2_30b_a3b_ep8.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
@@ -45,6 +47,9 @@ only, and its expert layers' `moe_route` beside it).
       --attention-impl flash --optimizer adam --lr 3e-4
   ... --lm-config benchmark/configs/kimi_linear_48b_a3b_ep32.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
+      --attention-impl flash --optimizer adam --lr 3e-4
+  ... --lm-config benchmark/configs/evabyte_6b5_4layers.json --num-dp 1 \
+      --num-sp 1 --seq-len 16384 --batch-size 1 --dtype bfloat16 --remat \
       --attention-impl flash --optimizer adam --lr 3e-4
 """
 
@@ -513,7 +518,16 @@ def main(argv=None) -> dict:
         names = {n: b for kind in kept for n, b in kind.items() if n.startswith(kernels)}
         return dict(remat_saves=",".join(names), saved_bytes_per_layer=sum(names.values()))
 
-    if cfg.attention_impl == "flash":
+    if getattr(cfg, "eva_layers", 0):
+        # EVA attention runs the flash kernels twice a layer, over windows
+        # and over pooled keys: its own plan says both (models/eva_dense.py)
+        from ..models.eva_dense import eva_plan
+
+        plan = {**eva_plan(cfg, args.seq_len), "attention_impl": cfg.attention_impl,
+                **remat_fields("ps_eva_")}
+        logger.info("eva plan for T %d: %s (tiles per head)", args.seq_len, plan)
+        tr.instant("eva_plan", **plan)
+    elif cfg.attention_impl == "flash":
         # the kernels' tile plan is static: how often the skip engages is
         # known here, from the shapes every attention call will have, and
         # so is the path select_attention takes (the same function decides)
@@ -682,6 +696,17 @@ def main(argv=None) -> dict:
                         tr.instant("kda_state", **{
                             k[len("kda_"):]: v for k, v in c.items()
                             if k.startswith("kda_")})
+                    if "eva_remote_mass" in record:
+                        # EVA attention (models/eva_dense.eva_counters):
+                        # the share of its softmax a query past window 0
+                        # puts on chunk summaries
+                        logger.info(
+                            "EVA: %.4f of a far query's softmax on summaries, per layer %s",
+                            c["eva_remote_mass"], c["eva_remote_mass_per_layer"],
+                        )
+                        tr.instant("eva_state", **{
+                            k[len("eva_"):]: v for k, v in c.items()
+                            if k.startswith("eva_")})
                     if "moe_rows_here" in record:
                         # the expert layers' routing (parallel/moe.
                         # routing_counters)

@@ -5,7 +5,9 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
 
     init(cfg, key) -> params
     apply(cfg, params, tokens, seq_axis_name=None, pos_offset=None)
-        -> (logits, aux)      aux: a dict of integer arrays the step sums
+        -> (logits, aux)      logits [B, T, vocab], or [B, T, heads, vocab]
+                              where head p predicts the token at i + 1 + p;
+                              aux: a dict of arrays (counts) the step sums
                               over the mesh ({} where nothing is counted)
     counters(aux) -> dict     None where aux is {}: what the step returns
                               beside the loss, from the summed aux
@@ -15,7 +17,8 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
 
 `load_lm_config` builds a config from a published config.json-shaped dict
 by its `model_type` (`_PUBLISHED_FAMILIES`: deepseek_v3 -> models/mla_moe.py,
-granitemoehybrid -> models/ssm_hybrid.py, kimi_linear -> models/kda_hybrid.py);
+granitemoehybrid -> models/ssm_hybrid.py, kimi_linear -> models/kda_hybrid.py,
+evabyte -> models/eva_dense.py);
 TransformerConfig is built from sizes as before.
 """
 
@@ -62,6 +65,12 @@ def _kda_hybrid_family(cfg) -> LMFamily:
                     kda_counters if cfg.moe_layers or cfg.kda_layers else None, saved_layers)
 
 
+def _eva_dense_family(cfg) -> LMFamily:
+    from .eva_dense import apply_eva_dense, eva_counters, init_eva_dense, saved_layers
+
+    return LMFamily(init_eva_dense, apply_eva_dense, eva_counters, saved_layers)
+
+
 class _Published(NamedTuple):
     module: str          # under models/
     config: str          # its config class
@@ -84,6 +93,11 @@ _PUBLISHED_FAMILIES = {
         "a router activation other than sigmoid, expert groups, query compression, rope "
         "scaling, a tied head, next-token-prediction layers, a sequence axis of more than "
         "one member"),
+    "evabyte": _Published(
+        "eva_dense", "EvaByteConfig", _eva_dense_family,
+        "an attention_class other than eva, a fixed num_chunks, rope scaling, grouped "
+        "key/value heads, a tied head, a chunk_size that does not divide window_size, a "
+        "sequence axis of more than one member"),
 }
 
 

@@ -80,7 +80,8 @@ EVENT_KINDS: Dict[str, EventSpec] = {
         doc="LM trainer log window (cli/train_lm.py); the moe_* routing "
             "counters ride along for a family with dropless expert layers, "
             "ssd_chunks_cut_off for one with state-space layers, "
-            "kda_chunks_cut_off for one with delta-rule layers",
+            "kda_chunks_cut_off for one with delta-rule layers, "
+            "eva_remote_mass (a float) for one with EVA attention",
     ),
     "grad_skip": EventSpec(
         required=("step", "skipped_steps", "skip_streak"),
@@ -155,7 +156,11 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # one with delta-rule layers `kda_plan` (the chunk,
                     # the smallest block its scores and inverse are built
                     # from, the padded length: models/kda_hybrid.kda_plan)
-                    # and `kda_state` at log steps (`chunks_cut_off` too)
+                    # and `kda_state` at log steps (`chunks_cut_off` too);
+                    # for one with EVA attention `eva_plan` (windows and
+                    # summaries a row, both kernel passes' tiles a head:
+                    # models/eva_dense.eva_plan) and `eva_state` at log
+                    # steps (`remote_mass`, a float, whole and per layer)
                     "block_q", "block_k", "grid_steps", "tiles_run",
                     "tiles_total", "d_qk", "d_v", "seq_shards",
                     "dq_acc_bytes", "saved_bytes_per_layer", "rows_here",
@@ -163,6 +168,9 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "chunk", "n_chunks", "heads", "d_head", "d_state",
                     "groups", "mamba_layers", "attention_layers",
                     "chunks_cut_off", "sub_block", "padded_len", "kda_layers",
+                    "window", "windows", "summaries", "eva_layers",
+                    "tiles_local", "tiles_remote", "remote_block_q",
+                    "remote_block_k", "remote_grid_steps",
                     # `step_scopes`, once after the first step of a
                     # dp_sp run: the census of the compiled step (obs/
                     # scopes.step_scopes_instant; `phases` and `scopes`

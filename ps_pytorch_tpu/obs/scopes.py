@@ -41,6 +41,11 @@ MIXER_ATTENTION = "mixer/attention"
 MIXER_MLA = "mixer/mla"
 MIXER_SSD = "mixer/ssd"
 MIXER_KDA = "mixer/kda"
+MIXER_EVA = "mixer/eva"
+EVA_POOL = "pool"               # the four under MIXER_EVA (ops/eva.py)
+EVA_LOCAL = "local"
+EVA_REMOTE = "remote"
+EVA_MERGE = "merge"
 DELTA_RULE = "delta_rule"       # under MIXER_KDA
 SCAN = "scan"                   # under MIXER_SSD
 FLASH = "flash"                 # under any mixer
@@ -70,6 +75,11 @@ SCOPES: Tuple[Tuple[str, str], ...] = (
     ("mixer/ssd/scan", "ops/ssd.ssd_chunked: the chunked scan with the relayouts into and out of its chunks"),
     ("mixer/kda", "the delta-rule half of a block: norm, the three short convs, L2 norms, decay, beta, gated norm, W_o"),
     ("mixer/kda/delta_rule", "ops/kda.kda_chunked: chunk layout, cumulative sums, the ps_kda_* kernels, the scan across chunks"),
+    ("mixer/eva", "the EVA half of a block: norm, q/k/v, rotation, the folds into and out of ops/eva.eva_attention, W_o"),
+    ("mixer/eva/pool", "ops/eva.eva_pool: a chunk of keys and values into one learned summary of each"),
+    ("mixer/eva/local", "the causal ps_flash_* passes over the windows folded into the leading axis"),
+    ("mixer/eva/remote", "the ps_flash_* passes of every query over the pooled keys of the windows before its own"),
+    ("mixer/eva/merge", "the two passes' triples joined by (m, l) and normalized; delta; the two dq summed"),
     ("mixer/*/flash", "ops/flash_attention.flash_attention: fold, pad, the ps_flash_* kernels, unfold"),
     ("ffn", "the FFN half's own norm and residual"),
     ("ffn/mlp", "a dense (gated or GELU) MLP: a dense layer's, or the shared experts'"),

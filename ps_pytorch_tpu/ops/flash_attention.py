@@ -46,7 +46,10 @@ Causality is enforced by masking with global positions (_mask_scores),
 and a tile that the mask would blank entirely does no work (_tile_live:
 the same positions, read from SMEM at run time, so a ring hop whose
 visiting shard lies wholly in the future costs a grid walk and nothing
-else, and still comes out as m = NEG_INF, l = 0, zero gradients).
+else, and still comes out as m = NEG_INF, l = 0, zero gradients). Where
+`causal` is an EarlierWindows the same two functions hold the second mask
+kind: keys of the windows before the query's own (ops/eva.py's pass over
+pooled keys, through the partial-triple API below).
 
 Precision: p and ds are cast to the dtype of the operand they multiply,
 so bfloat16 inputs give the MXU bfloat16 operands in all seven products of
@@ -105,6 +108,25 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the last dim of both
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b: contract the first dim of both
 
 
+class EarlierWindows(NamedTuple):
+    """A mask kind, given where `causal` is: the query at position i sees
+    the key at position j iff j // k_window < i // q_window, every key of
+    the windows before the query's own and none of its own or a later one.
+    Queries and keys may count in different units (ops/eva.py: queries in
+    tokens, keys one a chunk of tokens, so q_window tokens hold k_window
+    keys). `causal` is False | True | an EarlierWindows wherever the
+    kernels, plan_flash and the partial-triple API take it."""
+
+    q_window: int
+    k_window: int
+
+
+def _window_of(pos, window: int):
+    """pos // window for a position (never negative): a Python int, or
+    traced int32 scalars and vectors alike."""
+    return pos // window if isinstance(pos, int) else jax.lax.div(pos, jnp.int32(window))
+
+
 def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
                  q_off=0, k_off=0):
     """Apply the causal and/or key-padding mask to one [block_k, block_q]
@@ -120,7 +142,12 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
     iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, scores.shape, axis)
     k_local = ki * block_k + iota(0)
     keep = None
-    if causal:
+    if isinstance(causal, EarlierWindows):
+        # the first key of each query's own window, one [1, block_q] row
+        q_row = q_off + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (1, scores.shape[1]), 1)
+        keep = (k_off + k_local) < _window_of(q_row, causal.q_window) * causal.k_window
+    elif causal:
         q_pos = q_off + qi * block_q + iota(1)
         keep = (k_off + k_local) <= q_pos
     if k_len is not None:
@@ -134,12 +161,16 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
 
 def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
     """False where _mask_scores would blank every score of tile (qi, ki):
-    its first key lies past its last query (causal), or past k_len. None
+    its first key lies past its last query (causal), or in no window
+    before its last query's (EarlierWindows), or past k_len. None
     when no tile can be blank. Plain arithmetic on the positions
     _mask_scores uses, so it serves the kernels (traced grid indices and
     SMEM offsets) and plan_flash's count (ints) alike."""
     live = None
-    if causal:
+    if isinstance(causal, EarlierWindows):
+        live = (_window_of(k_off + ki * block_k, causal.k_window)
+                < _window_of(q_off + qi * block_q + (block_q - 1), causal.q_window))
+    elif causal:
         live = k_off + ki * block_k <= q_off + qi * block_q + (block_q - 1)
     if k_len is not None:
         in_len = ki * block_k < k_len

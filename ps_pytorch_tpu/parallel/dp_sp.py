@@ -90,6 +90,8 @@ def lm_loss_local(
     n_sp = lax.axis_size(sp_axis)
     s = lax.axis_index(sp_axis)
     logits, aux = lm_family(cfg).apply(cfg, params, tokens, seq_axis_name=sp_axis)
+    if logits.ndim == 4:
+        return _offsets_loss(logits, tokens, n_sp), aux
     # target of my last token = next shard's first token (ring shift left);
     # with one member that shard is this one (the position is masked below)
     with scope(HEAD_LOSS):
@@ -106,6 +108,25 @@ def lm_loss_local(
         loss_sum = jnp.sum(nll * valid[None, :])
         count = jnp.float32(b_loc) * jnp.sum(valid)
         return loss_sum / lax.psum(count, sp_axis), aux
+
+
+def _offsets_loss(logits, tokens, n_sp: int):
+    """The loss of a family with several prediction heads: logits [b, t, P,
+    vocab], head p predicting the token at i + 1 + p; the plain mean of the
+    NLL over every head and every position whose target lies in the row (i
+    + 1 + p < t). One sequence shard only: such a family refuses more."""
+    if n_sp != 1:
+        raise NotImplementedError(
+            "several prediction heads read targets up to num_pred_heads tokens ahead, "
+            "which lm_loss_local fetches from this shard only: run --num-sp 1")
+    b, t, heads, _ = logits.shape
+    with scope(HEAD_LOSS):
+        ahead = jnp.pad(tokens, [(0, 0), (0, heads)])
+        tgt = jnp.stack([ahead[:, 1 + p:1 + p + t] for p in range(heads)], axis=-1)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        valid = (jnp.arange(t)[:, None] + 1 + jnp.arange(heads)[None, :] < t).astype(jnp.float32)
+        return jnp.sum(nll * valid[None]) / (jnp.float32(b) * jnp.sum(valid))
 
 
 def init_lm_state(

@@ -343,3 +343,31 @@ def test_every_kernel_name_is_read_by_flash_ms(flash_bwd):
         # XLA spells the instruction after the end of its op_name
         for spelt in (name, f"{name}.3", f"transpose_jvp_{name}_.1"):
             assert re.search(pattern, spelt), (pattern, spelt)
+
+
+# sha256 of the jaxpr (kernel bodies and all) of flash_attention's value and
+# gradient at a cell's shapes, as the commit before ops/eva.py and the mask
+# kind EarlierWindows lowered it (PR 40). A PR that means to change the dense
+# path's kernels prints the new digest with this test and says so.
+DENSE_JAXPR = {
+    "kanana_kimi": ((2, 8192, 32, 192, 128),
+                    "e87ef002de5305e267da4cfedbcf47c4eab246f9b50e58df386fd8634542591f"),
+    "gpt2m": ((8, 1024, 16, 64, 64),
+              "6fd32e26e116c47762353a167e856d3ff4dd886371ddf868009d627213d632c5"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DENSE_JAXPR))
+def test_the_dense_path_lowers_as_it_did_before_the_second_mask_kind(cell):
+    """`causal` False | True reaches _mask_scores and _tile_live through the
+    branches it always took: the kanana / kimi and gpt2m attention calls
+    trace to the text they traced to before EarlierWindows existed."""
+    import hashlib
+
+    (b, t, h, d, d_v), want = DENSE_JAXPR[cell]
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, t, h, d_v), jnp.bfloat16)
+    loss = lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True).astype(jnp.float32))
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, v))
+    assert text.count("ps_flash_fwd") == text.count("ps_flash_dqkv") == 1
+    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -7,6 +7,7 @@ grouped products at [16, 2048, 768]: what interpret mode cannot refuse.
 All such compiles live in THIS file: one process loads the TPU compiler,
 inside a fixture, after collection (see the on-chip-measurement guide)."""
 
+import math
 from functools import partial
 
 import jax
@@ -196,3 +197,48 @@ def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu
     assert layers == 4 and "ps_kda_within" not in census["jnp"]
     assert {k: v for k, v in census["mosaic"].items() if k.startswith("ps_kda_")} == {
         "ps_kda_inverse": layers, "ps_kda_within_fwd": 2 * layers, "ps_kda_within_bwd": layers}
+
+
+def test_an_eva_step_at_the_cells_row_holds_no_score_array(topo, as_on_a_tpu):
+    """benchmark/configs/evabyte_6b5_4layers.json at ONE layer (the compile's
+    time, not its shapes), 1 x 16,384 bytes, bfloat16, `remat`: both passes
+    of the flash kernels are Mosaic calls at [256, 2048, 128] and [32, 16384
+    x 1024, 128], each once forward (not again under `remat`) and once
+    backward; no array of the step is shaped like a window's or the
+    summaries' scores; its largest is an 11008-wide MLP tensor."""
+    import os
+    import re
+
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+    from ps_pytorch_tpu.parallel.dp_sp import SEQ_AXIS, WORKER_AXIS, make_lm_train_step, make_mesh_2d
+
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "evabyte_6b5_4layers.json")) as f:
+        published = {**json.load(f), "num_hidden_layers": 1}
+    cfg = load_lm_config(published, attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    tx = optax.adam(3e-4)
+    mesh = make_mesh_2d(1, 1, devices=[topo.devices[0]])
+    state = jax.eval_shape(lambda k: (lambda p: (p, tx.init(p)))(lm_family(cfg).init(cfg, k)),
+                           jax.random.key(0))
+    on = lambda spec: (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                      sharding=NamedSharding(mesh, spec)))
+    params, opt = jax.tree_util.tree_map(on(P()), state)
+    tokens = on(P(WORKER_AXIS, SEQ_AXIS))(jax.ShapeDtypeStruct((1, 16384), jnp.int32))
+    text = make_lm_train_step(cfg, tx, mesh).lower(params, opt, tokens).compile().as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {"ps_flash_fwd": 2, "ps_flash_dqkv": 2}}
+    sizes = {}
+    for dtype, dims in re.findall(r"= \(?(f32|bf16|s32|pred)\[([0-9,]+)\]", text):
+        shape = tuple(int(d) for d in dims.split(","))
+        sizes[shape] = max(sizes.get(shape, 0),
+                           math.prod(shape) * {"f32": 4, "bf16": 2, "s32": 4, "pred": 1}[dtype])
+    # a window's [2048, 2048], the summaries' [16384, 1024] (or a tile's worth of rows of them)
+    assert not [s for s in sizes if len(s) >= 2 and s[-2:] in (
+        (2048, 2048), (16384, 1024), (2048, 1024), (16384, 16384), (2048, 3072))]
+    assert max(sizes, key=sizes.get) == (16384, 11008)

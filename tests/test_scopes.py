@@ -355,6 +355,11 @@ def _lm_cfg(family):
     if family == "dense":
         return TransformerConfig(vocab_size=64, dim=32, depth=2, heads=4, max_seq_len=32,
                                  attention_impl="flash", remat=True)
+    if family == "eva_dense":      # windows of 16 over the 32 tokens: the summaries are seen
+        from ps_pytorch_tpu.models.eva_dense import EvaByteConfig
+
+        return EvaByteConfig(vocab_size=64, window_size=16, chunk_size=4, attention_impl="flash",
+                             remat=True)
     cls = {"mla_moe": MlaMoeConfig, "ssm_hybrid": SsmHybridConfig, "kda_hybrid": KdaHybridConfig}
     return cls[family](attention_impl="flash", remat=True)
 
@@ -392,7 +397,7 @@ def _ps_step():
 
 _STEPS = {"dense": lambda: _lm_step("dense"), "mla_moe": lambda: _lm_step("mla_moe"),
           "ssm_hybrid": lambda: _lm_step("ssm_hybrid"), "kda_hybrid": lambda: _lm_step("kda_hybrid"),
-          "ps": _ps_step}
+          "eva_dense": lambda: _lm_step("eva_dense"), "ps": _ps_step}
 _WANTED = {
     "dense": {"embed", "mixer/attention", "mixer/attention/flash", "ffn", "ffn/mlp", "head_loss",
               "grad_reduce", "update"},
@@ -402,6 +407,9 @@ _WANTED = {
                    "mixer/attention/flash", "ffn", "ffn/mlp", "head_loss", "update"},
     "kda_hybrid": {"embed", "mixer/kda", "mixer/kda/delta_rule", "mixer/mla", "mixer/mla/flash",
                    "ffn/mlp", "ffn/moe/dispatch", "ffn/moe/combine", "head_loss", "update"},
+    # off the chip the attention takes its jnp twin: the kernel passes' scopes
+    # (local, remote, merge) are held by tests/test_evabyte_family.py, interpreted
+    "eva_dense": {"embed", "mixer/eva", "mixer/eva/pool", "ffn", "ffn/mlp", "head_loss", "update"},
     "ps": {"augment", "model", "grad_reduce", "update"},
 }
 

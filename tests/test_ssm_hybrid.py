@@ -286,7 +286,7 @@ def test_a_sequence_axis_of_two_is_refused_and_the_messages_read_one_table():
     tokens = shard_tokens_2d(jnp.zeros((2, 32), jnp.int32), mesh)
     with pytest.raises(NotImplementedError, match="carried state.*sequence shard"):
         make_lm_train_step(cfg, tx, mesh)(params, opt, tokens)
-    with pytest.raises(ValueError, match=r"has no family here \(has: deepseek_v3, granitemoehybrid, kimi_linear\)"):
+    with pytest.raises(ValueError, match=r"has no family here \(has: deepseek_v3, granitemoehybrid, kimi_linear, evabyte\)"):
         load_lm_config({"model_type": "llama"})
     with pytest.raises(TypeError, match="TransformerConfig, MlaMoeConfig, SsmHybridConfig"):
         lm_family(object())
