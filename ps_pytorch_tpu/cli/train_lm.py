@@ -73,6 +73,7 @@ from ..parallel.dp_sp import (
     make_lm_train_step,
     make_mesh_2d,
     shard_tokens_2d,
+    update_plan,
 )
 from ..trainer import _shared_run_id, append_metrics_line
 from ..utils import (
@@ -511,6 +512,18 @@ def main(argv=None) -> dict:
             "(the kernels' operands %s)", saves.names, saves.saved_bytes / 2 ** 20,
             saves.state_bytes / 2 ** 20, saves.bytes_limit / 2 ** 20,
             "kept" if saves.operands_kept else "left to recompute: no room")
+
+    if args.parallelism == "dp_sp":
+        # where the update reads a materialised gradient and where XLA may
+        # fold it into the product that makes it: decided leaf by leaf as
+        # the step is traced, by this function (parallel/dp_sp.plan_update)
+        plan = update_plan(
+            params, args.batch_size // args.num_dp * (args.seq_len // num_sp))
+        logger.info(
+            "update stands apart for %d of %d leaves, %.1f%% of the parameters "
+            "(%d rows a step and chip)", plan["leaves_apart"], plan["leaves"],
+            100.0 * plan["params_apart"] / plan["params"], plan["rows"])
+        tr.instant("update_plan", **plan)
 
     def remat_fields(kernels):
         """The plan's share under one kernel family's names (`ps_flash_`,

@@ -160,7 +160,12 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # for one with EVA attention `eva_plan` (windows and
                     # summaries a row, both kernel passes' tiles a head:
                     # models/eva_dense.eva_plan) and `eva_state` at log
-                    # steps (`remote_mass`, a float, whole and per layer)
+                    # steps (`remote_mass`, a float, whole and per layer);
+                    # for a dp_sp run `update_plan` (for how many of the
+                    # parameters' `leaves` the update reads a materialised
+                    # gradient, `leaves_apart`, with their `params_apart`
+                    # of `params`, at `rows` a step and chip: parallel/
+                    # dp_sp.update_plan)
                     "block_q", "block_k", "grid_steps", "tiles_run",
                     "tiles_total", "d_qk", "d_v", "seq_shards",
                     "dq_acc_bytes", "saved_bytes_per_layer", "rows_here",
@@ -171,6 +176,7 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "window", "windows", "summaries", "eva_layers",
                     "tiles_local", "tiles_remote", "remote_block_q",
                     "remote_block_k", "remote_grid_steps",
+                    "rows", "leaves", "leaves_apart", "params", "params_apart",
                     # `step_scopes`, once after the first step of a
                     # dp_sp run: the census of the compiled step (obs/
                     # scopes.step_scopes_instant; `phases` and `scopes`

@@ -29,6 +29,7 @@ collective over sp moves anything.
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -143,6 +144,47 @@ def init_lm_state(
     return jax.device_put((params, tx.init(params)), replicated_sharding(mesh))
 
 
+# plan_update's cut, in elements of a product's smaller operand: 7/8 of a
+# v5e's 128 MiB of VMEM at the blocks' two bytes an element. Up to it the
+# TPU compiler keeps that operand of a weight-gradient product resident and
+# the product's cost does not depend on its output tile; past it the
+# contraction is walked in pieces, the output tile is what bounds the
+# operands' re-reads, and an epilogue of Adam's six float32 streams leaves
+# VMEM for a quarter of it (PERF.md, "where the update stands": compiled
+# for a described v5e the switch falls between 3,584 and 3,712 columns at
+# 16,384 rows, 7,168 and 7,424 at 8,192, 896 and 1,024 at 65,536)
+UPDATE_APART_OPERAND = 7 * 128 * 2 ** 20 // (8 * 2)
+
+
+def plan_update(leaf_shape, rows: int) -> bool:
+    """Whether a leaf's update stands apart from the product that makes its
+    gradient (the gradient is materialised and Adam reads it as an
+    elementwise pass of its own) or is left for XLA to fold into that
+    product as its epilogue. Pure: a leaf's shape and the rows a step
+    contracts over on one chip (b_loc * t_loc) are all it sees.
+
+    A matrix [m, n] is the result of ONE product [rows, m]^T x [rows, n].
+    Folding pays while the smaller operand, rows x min(m, n), stays in VMEM
+    (Adam's traffic hides under the MXUs: every block leaf of a 1024-wide
+    model at 8,192 rows); it costs twice the product's time where that
+    operand does not fit (4096-wide leaves at 16,384 rows). Anything else
+    is no product's result, or not one XLA makes: norm gains, biases and
+    conv taps; the experts' stacked matrices, whose gradient is a Pallas
+    kernel's and whose Adam stands alone already."""
+    return len(leaf_shape) == 2 and rows * min(leaf_shape) > UPDATE_APART_OPERAND
+
+
+def update_plan(params, rows: int) -> dict:
+    """What plan_update answers over a tree of parameters (arrays or their
+    shapes) at `rows` a step: how often the mechanism engages, for
+    `cli.train_lm`'s log line and its `update_plan` trace event."""
+    shapes = [tuple(leaf.shape) for leaf in jax.tree_util.tree_leaves(params)]
+    apart = [s for s in shapes if plan_update(s, rows)]
+    return {"rows": rows, "leaves": len(shapes), "leaves_apart": len(apart),
+            "params": sum(math.prod(s) for s in shapes),
+            "params_apart": sum(math.prod(s) for s in apart)}
+
+
 def make_lm_train_step(
     cfg,
     tx: optax.GradientTransformation,
@@ -167,6 +209,11 @@ def make_lm_train_step(
         with scope(GRAD_REDUCE):
             grads = lax.pmean(lax.psum(grads, sp_axis), dp_axis)
             loss = lax.pmean(lax.psum(loss_local, sp_axis), dp_axis)
+            # leaf by leaf, never the tree as one: a barrier over the tree
+            # would hold every float32 gradient live at once
+            grads = jax.tree_util.tree_map(
+                lambda g: lax.optimization_barrier(g)
+                if plan_update(g.shape, tokens.size) else g, grads)
         with scope(UPDATE):
             updates, new_opt = tx.update(grads, opt_state, params)
             new_params = optax.apply_updates(params, updates)

@@ -119,13 +119,17 @@ def test_grouped_products_compile_at_the_published_widths(shape, k, n):
 # ------------------------------------------------ the delta rule's kernels
 
 
+def _say_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("PS_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("PS_TPU_DISABLE_PALLAS", raising=False)
+
+
 @pytest.fixture()
 def as_on_a_tpu(monkeypatch):
     """ops/pallas_mode.py picks from what the process observes: say TPU, so
     the public entries take their compiled kernels."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("PS_TPU_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("PS_TPU_DISABLE_PALLAS", raising=False)
+    _say_tpu(monkeypatch)
 
 
 def _kda_grads(*args):
@@ -164,6 +168,26 @@ def test_kda_kernels_compile_at_the_cells_shapes_with_their_time_inside_kda_ms(s
         assert pattern.search(short_name(line)), short_name(line)
 
 
+def _lm_step_compiled(topo, cfg, batch, seq):
+    """make_lm_train_step for `cfg` on the described chip, compiled from
+    shapes alone, as `cli.train_lm` and the benchmark's drivers build it."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ps_pytorch_tpu.models.lm import lm_family
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel.dp_sp import SEQ_AXIS, WORKER_AXIS, make_lm_train_step, make_mesh_2d
+
+    tx = build_optimizer("adam", 3e-4, b1=0.9, b2=0.999, eps=1e-8)
+    mesh = make_mesh_2d(1, 1, devices=[topo.devices[0]])
+    state = jax.eval_shape(lambda k: (lambda p: (p, tx.init(p)))(lm_family(cfg).init(cfg, k)),
+                           jax.random.key(0))
+    on = lambda spec: (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                      sharding=NamedSharding(mesh, spec)))
+    params, opt = jax.tree_util.tree_map(on(P()), state)
+    tokens = on(P(WORKER_AXIS, SEQ_AXIS))(jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    return make_lm_train_step(cfg, tx, mesh).lower(params, opt, tokens).compile()
+
+
 def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu):
     """The small preset of chip_smoke.py's `lm_kda` leg (KDA heads of 128,
     chunks of 64; k k k a k) as `cli.train_lm` builds its step, `remat` on:
@@ -171,27 +195,15 @@ def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu
     `remat` runs again takes the kept inverse), the forward kernel eight
     times, the backward four; nothing of the XLA twin; and `kda_plan` says
     what ran."""
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     import chip_smoke
     from ps_pytorch_tpu.models import kda_hybrid
-    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.models.lm import load_lm_config
     from ps_pytorch_tpu.ops.pallas_mode import kernel_census
-    from ps_pytorch_tpu.parallel.dp_sp import SEQ_AXIS, WORKER_AXIS, make_lm_train_step, make_mesh_2d
 
     cfg = load_lm_config(dict(chip_smoke.LM_KDA_CONFIG), attention_impl="flash", remat=True,
                          compute_dtype=jnp.bfloat16)
     assert kda_hybrid.kda_plan(cfg, 512)["scan_path"] == "pallas_within+xla_scan"
-    tx = optax.adam(1e-3)
-    mesh = make_mesh_2d(1, 1, devices=[topo.devices[0]])
-    state = jax.eval_shape(lambda k: (lambda p: (p, tx.init(p)))(lm_family(cfg).init(cfg, k)),
-                           jax.random.key(0))
-    on = lambda spec: (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                      sharding=NamedSharding(mesh, spec)))
-    params, opt = jax.tree_util.tree_map(on(P()), state)
-    tokens = on(P(WORKER_AXIS, SEQ_AXIS))(jax.ShapeDtypeStruct((2, 512), jnp.int32))
-    text = make_lm_train_step(cfg, tx, mesh).lower(params, opt, tokens).compile().as_text()
+    text = _lm_step_compiled(topo, cfg, 2, 512).as_text()
     census = kernel_census(text)
     layers = len(cfg.kda_layers)
     assert layers == 4 and "ps_kda_within" not in census["jnp"]
@@ -199,39 +211,41 @@ def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu
         "ps_kda_inverse": layers, "ps_kda_within_fwd": 2 * layers, "ps_kda_within_bwd": layers}
 
 
-def test_an_eva_step_at_the_cells_row_holds_no_score_array(topo, as_on_a_tpu):
+def _eva_one_layer():
     """benchmark/configs/evabyte_6b5_4layers.json at ONE layer (the compile's
-    time, not its shapes), 1 x 16,384 bytes, bfloat16, `remat`: both passes
-    of the flash kernels are Mosaic calls at [256, 2048, 128] and [32, 16384
-    x 1024, 128], each once forward (not again under `remat`) and once
-    backward; no array of the step is shaped like a window's or the
-    summaries' scores; its largest is an 11008-wide MLP tensor."""
-    import os
-    import re
-
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
-    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
-    from ps_pytorch_tpu.parallel.dp_sp import SEQ_AXIS, WORKER_AXIS, make_lm_train_step, make_mesh_2d
-
+    time, not its shapes), bfloat16, `remat`."""
     import json
+    import os
+
+    from ps_pytorch_tpu.models.lm import load_lm_config
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", "evabyte_6b5_4layers.json")) as f:
         published = {**json.load(f), "num_hidden_layers": 1}
-    cfg = load_lm_config(published, attention_impl="flash", remat=True,
-                         compute_dtype=jnp.bfloat16)
-    tx = optax.adam(3e-4)
-    mesh = make_mesh_2d(1, 1, devices=[topo.devices[0]])
-    state = jax.eval_shape(lambda k: (lambda p: (p, tx.init(p)))(lm_family(cfg).init(cfg, k)),
-                           jax.random.key(0))
-    on = lambda spec: (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                      sharding=NamedSharding(mesh, spec)))
-    params, opt = jax.tree_util.tree_map(on(P()), state)
-    tokens = on(P(WORKER_AXIS, SEQ_AXIS))(jax.ShapeDtypeStruct((1, 16384), jnp.int32))
-    text = make_lm_train_step(cfg, tx, mesh).lower(params, opt, tokens).compile().as_text()
+    return load_lm_config(published, attention_impl="flash", remat=True,
+                          compute_dtype=jnp.bfloat16)
+
+
+@pytest.fixture(scope="module")
+def eva_step(topo):
+    """The one-layer evabyte step at the cell's row, 1 x 16,384 bytes,
+    compiled once for the tests that read it."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _say_tpu(monkeypatch)
+        return _lm_step_compiled(topo, _eva_one_layer(), 1, 16384)
+
+
+def test_an_eva_step_at_the_cells_row_holds_no_score_array(eva_step):
+    """The one-layer evabyte step at 1 x 16,384 bytes: both passes
+    of the flash kernels are Mosaic calls at [256, 2048, 128] and [32, 16384
+    x 1024, 128], each once forward (not again under `remat`) and once
+    backward; no array of the step is shaped like a window's or the
+    summaries' scores; its largest is an 11008-wide MLP tensor."""
+    import re
+
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    text = eva_step.as_text()
     assert kernel_census(text) == {"jnp": {}, "mosaic": {"ps_flash_fwd": 2, "ps_flash_dqkv": 2}}
     sizes = {}
     for dtype, dims in re.findall(r"= \(?(f32|bf16|s32|pred)\[([0-9,]+)\]", text):
@@ -242,3 +256,64 @@ def test_an_eva_step_at_the_cells_row_holds_no_score_array(topo, as_on_a_tpu):
     assert not [s for s in sizes if len(s) >= 2 and s[-2:] in (
         (2048, 2048), (16384, 1024), (2048, 1024), (16384, 16384), (2048, 3072))]
     assert max(sizes, key=sizes.get) == (16384, 11008)
+
+
+# ------------------------------------- where the update stands (PR 42)
+
+
+def _folded_updates(compiled):
+    """The leaves whose Adam rides their gradient's product: the shape of
+    every fused computation's result that holds both a `convolution` and
+    the update's `sqrt` (jax names it `.../update/sqrt`)."""
+    import re
+
+    folded, head, product, update = [], None, False, False
+    for line in compiled.as_text().splitlines():
+        if line.startswith("%") and line.rstrip().endswith("{"):
+            head, product, update = line, False, False
+        elif head is not None and line.startswith("}"):
+            if product and update:
+                dims = re.search(r"-> \(?f32\[([0-9,]+)\]", head).group(1)
+                folded.append(tuple(int(d) for d in dims.split(",")))
+            head = None
+        elif head is not None:
+            product |= " convolution(" in line
+            update |= " sqrt(" in line and "/update/sqrt" in line
+    return sorted(folded)
+
+
+def test_the_update_stands_apart_of_the_wide_products_and_rides_the_narrow_ones(
+        topo, as_on_a_tpu, eva_step, monkeypatch):
+    """parallel/dp_sp.plan_update against the compiler that would fold
+    through it, and the memory bound against a barrier over the tree. The
+    one-layer evabyte step at the cell's row (16,384): no fusion holds a
+    product and the update's sqrt for a leaf the plan names; with the plan
+    forced to none the same step folds all seven matrices of the layer
+    (what the chip ran before PR 42), and the step that keeps them apart
+    needs under two of the largest leaf's float32 bytes more in
+    temporaries (a barrier a leaf lets the scheduler run each update right
+    after its product; all gradients live at once would be seven leaves).
+    A layer at the GPT-2 cell's widths and rows (8 x 1,024) keeps its
+    folded fusions: there Adam hides under the MXUs as it should."""
+    from ps_pytorch_tpu.models.transformer import TransformerConfig
+    from ps_pytorch_tpu.parallel import dp_sp
+
+    apart = eva_step
+    layer = [(4096, 4096)] * 4 + [(4096, 11008)] * 2 + [(11008, 4096)]
+    assert all(dp_sp.plan_update(shape, 16384) for shape in layer)
+    assert not [shape for shape in _folded_updates(apart) if dp_sp.plan_update(shape, 16384)]
+    with monkeypatch.context() as forced:
+        forced.setattr(dp_sp, "plan_update", lambda shape, rows: False)
+        folded = _lm_step_compiled(topo, _eva_one_layer(), 1, 16384)
+    assert [s for s in _folded_updates(folded) if s in layer] == sorted(layer)
+    grown = (apart.memory_analysis().temp_size_in_bytes
+             - folded.memory_analysis().temp_size_in_bytes)
+    assert grown < 2 * 4 * 4096 * 11008, grown
+
+    gpt2 = TransformerConfig(vocab_size=50257, dim=1024, depth=1, heads=16, mlp_ratio=4,
+                             max_seq_len=1024, attention_impl="flash",
+                             compute_dtype=jnp.bfloat16)
+    block = [(1024, 1024), (1024, 3072), (1024, 4096), (4096, 1024)]
+    assert not any(dp_sp.plan_update(shape, 8 * 1024) for shape in block)
+    assert [s for s in _folded_updates(_lm_step_compiled(topo, gpt2, 8, 1024))
+            if s in block] == block
