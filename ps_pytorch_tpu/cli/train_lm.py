@@ -541,9 +541,10 @@ def main(argv=None) -> dict:
         logger.info("eva plan for T %d: %s (tiles per head)", args.seq_len, plan)
         tr.instant("eva_plan", **plan)
     elif cfg.attention_impl == "flash":
-        # the kernels' tile plan is static: how often the skip engages is
-        # known here, from the shapes every attention call will have, and
-        # so is the path select_attention takes (the same function decides)
+        # the kernels' tile plan is static: how many tiles of the rectangle
+        # the grids never enter (tiles_total - grid_steps) is known here,
+        # from the shapes every attention call will have, and so is the
+        # path select_attention takes (the same function decides)
         from ..ops.flash_attention import plan_flash
 
         t_att = args.seq_len // seq_shards if path == "ring" else args.seq_len
@@ -557,7 +558,7 @@ def main(argv=None) -> dict:
         logger.info(
             "flash plan for T %d x D %d: %s (per head%s)", t_att,
             d_qk, flash_plan,
-            "; ring hops decide from their offsets" if path == "ring" else "",
+            "; ring hops build their walk from their offsets" if path == "ring" else "",
         )
         tr.instant("flash_plan", **flash_plan)
     if getattr(cfg, "mamba_layers", 0):

@@ -239,7 +239,11 @@ def apply_eva_dense(
 def eva_plan(cfg: EvaByteConfig, seq_len: int) -> Dict:
     """What every call of the attention will look like, from the shapes
     alone (cli/train_lm.py logs it and records it as the `eva_plan`
-    instant): windows and summaries a row, the two passes' tiles a head."""
+    instant): windows and summaries a row, the two passes' tiles a head
+    (`tiles_local`, `tiles_remote`: the live ones; the local pass walks
+    exactly those, the remote pass `remote_grid_steps` of its rectangle's
+    `remote_tiles_total`: its live tiles and a dead entry for each q block
+    of window 0)."""
     plan = plan_eva(seq_len, cfg.head_dim, cfg.effective_compute_dtype,
                     cfg.window_size, cfg.chunk_size)
     local, remote = plan.tiles()
@@ -251,7 +255,8 @@ def eva_plan(cfg: EvaByteConfig, seq_len: int) -> Dict:
            "tiles_local": local, "tiles_remote": remote, "bwd": plan.local.bwd}
     if plan.remote:
         out.update(remote_block_q=plan.remote.block_q, remote_block_k=plan.remote.block_k,
-                   remote_grid_steps=plan.remote.grid_steps)
+                   remote_grid_steps=plan.remote.grid_steps,
+                   remote_tiles_total=plan.remote.tiles_total)
     return out
 
 

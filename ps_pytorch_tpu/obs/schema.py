@@ -138,7 +138,12 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "new_tokens", "weights_step", "from_step", "to_step",
                     "bytes", "block", "wall_ns", "err_ns",
                     # instants of cli/train_lm.py: `flash_plan` (the
-                    # kernels' tiles and widths, `seq_shards` and, a
+                    # kernels' tiles and widths: `grid_steps` the steps a
+                    # head's grid WALKS, the `tiles_run` that do work and
+                    # a dead entry for each q or k block none of them
+                    # touches, of the rectangle's `tiles_total`, whose
+                    # other tiles are never entered: ops/flash_attention.
+                    # _walk; `seq_shards` and, a
                     # string, the `attention_path` they run on:
                     # models/transformer.attention_path; `bwd`, a string,
                     # "fused" or "split", and the fused backward's
@@ -158,8 +163,10 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     # from, the padded length: models/kda_hybrid.kda_plan)
                     # and `kda_state` at log steps (`chunks_cut_off` too);
                     # for one with EVA attention `eva_plan` (windows and
-                    # summaries a row, both kernel passes' tiles a head:
-                    # models/eva_dense.eva_plan) and `eva_state` at log
+                    # summaries a row, both kernel passes' live tiles a
+                    # head and the remote pass's `remote_grid_steps` walked
+                    # of `remote_tiles_total`: models/eva_dense.eva_plan)
+                    # and `eva_state` at log
                     # steps (`remote_mass`, a float, whole and per layer);
                     # for a dp_sp run `update_plan` (for how many of the
                     # parameters' `leaves` the update reads a materialised
@@ -175,7 +182,7 @@ EVENT_KINDS: Dict[str, EventSpec] = {
                     "chunks_cut_off", "sub_block", "padded_len", "kda_layers",
                     "window", "windows", "summaries", "eva_layers",
                     "tiles_local", "tiles_remote", "remote_block_q",
-                    "remote_block_k", "remote_grid_steps",
+                    "remote_block_k", "remote_grid_steps", "remote_tiles_total",
                     "rows", "leaves", "leaves_apart", "params", "params_apart",
                     # `step_scopes`, once after the first step of a
                     # dp_sp run: the census of the compiled step (obs/

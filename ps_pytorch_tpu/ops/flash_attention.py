@@ -6,21 +6,46 @@ ACROSS chips; this kernel does the same WITHIN a chip: blockwise online
 softmax in VMEM, O(T) memory instead of O(T^2) HBM traffic, MXU-shaped
 [block_q, d] x [d, block_k] matmuls.
 
-Layout: inputs [B, T, H, D] are folded to [B*H, T, D]; the grid walks
-(batch*head, q_block, k_block) with the k axis innermost, accumulating
-(acc, running max m, running sum l) in VMEM scratch and writing the
-normalized output plus the logsumexp L = m + log(l) at the last k step.
-The backward pass recomputes p = exp(q k^T * scale - L) per block
-(flash-attention-2 style), seeded with delta = rowsum(do * o) computed in
-plain XLA, in ONE kernel (ps_flash_dqkv): each live tile's s, p, dp, ds
-are made once and feed all three gradients. Its grid walks (batch*head,
-k_block, q_block) with q innermost: dk and dv accumulate over q blocks
-in VMEM scratch as the forward's output does over k blocks; dq sums over
-k blocks, the OUTER axis, so the whole head's dq ([T_q, D] float32) stays
-in a VMEM scratch across the walk and is written out in the last k
-sweep. Where that scratch does not fit (plan_flash's cap: one chip at T
-well past 32k) the plan takes two kernels instead, ps_flash_dq over k
-blocks and ps_flash_dkv over q blocks, which compute every tile twice.
+Layout: inputs [B, T, H, D] are folded to [B*H, T, D]. A kernel's grid
+is (batch*head, step), and its steps are the entries of a table in SMEM
+(_walk, handed over by scalar prefetch as ops/grouped_matmul.py hands
+the experts' tiles): the (q block, k block) tiles of the [n_q, n_k]
+rectangle that do work, in the order the kernel accumulates them, each
+with flags that say what else the step does. A tile the mask blanks
+whole is no entry: it costs no grid step and fetches nothing (at T 8,192
+under `causal` 136 steps a head where the rectangle has 256). Every
+index_map reads the step's blocks from the tables.
+
+The forward walks q-major, k ascending within a q block, accumulating
+(acc, running max m, running sum l) in VMEM scratch from the q block's
+first entry and writing the normalized output plus the logsumexp
+L = m + log(l) at its last. The backward pass recomputes
+p = exp(q k^T * scale - L) per tile (flash-attention-2 style), seeded with
+delta = rowsum(do * o) computed in plain XLA, in ONE kernel
+(ps_flash_dqkv): each live tile's s, p, dp, ds are made once and feed all
+three gradients. It walks k-major, q ascending within a k block: dk and
+dv accumulate over a k block's entries in VMEM scratch as the forward's
+output does over a q block's; dq sums over k blocks, the OUTER axis, so
+the whole head's dq ([T_q, D] float32) stays in a VMEM scratch across the
+walk, and a q block's dq is written once, at the last entry that holds
+it (under `causal` its diagonal tile; the dq block resident in VMEM is
+the one being completed, Walk.in_block). Where that scratch does not fit
+(plan_flash's cap: one chip at T well past 32k) the plan takes two kernels
+instead, ps_flash_dq (q-major) and ps_flash_dkv (k-major), which compute
+every tile twice. Every sum runs over the same tiles in the same order as
+a walk of the whole rectangle would, so results are that walk's bit for
+bit.
+
+A block of an output that no live tile touches keeps one entry, dead
+(_kept): the queries of EVA's first window see no summary, a ring hop may
+lie wholly in the future, and their m = NEG_INF, l = 0 and zero gradients
+still have to be written. Where both mask offsets are Python ints (every
+call but a ring hop) the walk is a numpy constant and the grid is exactly
+its length (FlashPlan.grid_steps). A ring hop's offsets are traced: its
+walk is built in jnp from them before the call, the grid keeps the
+rectangle's length, and the steps past the last entry repeat its blocks
+with no flag set, so no index moves, nothing is fetched and nothing runs.
+One kernel body serves both.
 
 Every kernel works on the TRANSPOSED score tile k q^T, [block_k, block_q]
 with keys down the rows. The per-query statistics (m, l, lse, delta) are
@@ -31,25 +56,26 @@ rows, and the same shape in which they cross the kernel boundary
 equals the array's, as Mosaic wants). The forward and dq accumulators are
 held transposed too ([D, block_q]; the whole head's dq as [n_q, D,
 block_q], indexed by the q block on its leading dimension) and turned
-once, at the last k step.
+once, where they are written.
 Compiled calls need block sizes that are multiples of 128 or cover the
 whole (padded) sequence; plan_flash gives that.
 
 The tile plan (plan_flash) is what makes a grid step worth its fixed
 cost: tiles as large as the sequence and VMEM_BUDGET allow (512 x 512 at
-T = 1024, D = 64: 4 steps a head where 128-wide tiles walked 64), planned
+T = 1024, D = 64: 3 steps a head where 128-wide tiles walked 36), planned
 from what the call can observe: T_q, T_k, D, the operand dtype, causal.
 Any T works: it is padded up to the block grid and the padded keys are
 masked (k_len), so tiles stay MXU-shaped.
 
 Causality is enforced by masking with global positions (_mask_scores),
-and a tile that the mask would blank entirely does no work (_tile_live:
-the same positions, read from SMEM at run time, so a ring hop whose
-visiting shard lies wholly in the future costs a grid walk and nothing
-else, and still comes out as m = NEG_INF, l = 0, zero gradients). Where
-`causal` is an EarlierWindows the same two functions hold the second mask
-kind: keys of the windows before the query's own (ops/eva.py's pass over
-pooled keys, through the partial-triple API below).
+and a tile that the mask would blank entirely is left out of the walk
+(_tile_live: the same positions, so the mask and the skip cannot part;
+a ring hop whose visiting shard lies wholly in the future walks one dead
+entry an output block and comes out as m = NEG_INF, l = 0, zero
+gradients). Where `causal` is an EarlierWindows the same two functions
+hold the second mask kind: keys of the windows before the query's own
+(ops/eva.py's pass over pooled keys, through the partial-triple API
+below).
 
 Precision: p and ds are cast to the dtype of the operand they multiply,
 so bfloat16 inputs give the MXU bfloat16 operands in all seven products of
@@ -72,6 +98,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.scopes import FLASH, scope
@@ -122,9 +149,12 @@ class EarlierWindows(NamedTuple):
 
 
 def _window_of(pos, window: int):
-    """pos // window for a position (never negative): a Python int, or
-    traced int32 scalars and vectors alike."""
-    return pos // window if isinstance(pos, int) else jax.lax.div(pos, jnp.int32(window))
+    """pos // window for a position (never negative): a Python int or a
+    numpy array of them (the static walk), or traced int32 scalars and
+    vectors alike."""
+    if isinstance(pos, (int, np.ndarray)):
+        return pos // window
+    return jax.lax.div(pos, jnp.int32(window))
 
 
 def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
@@ -164,8 +194,8 @@ def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
     its first key lies past its last query (causal), or in no window
     before its last query's (EarlierWindows), or past k_len. None
     when no tile can be blank. Plain arithmetic on the positions
-    _mask_scores uses, so it serves the kernels (traced grid indices and
-    SMEM offsets) and plan_flash's count (ints) alike."""
+    _mask_scores uses, so it serves ints, numpy grids (the static walk
+    and plan_flash's counts) and traced offsets (a ring hop's walk) alike."""
     live = None
     if isinstance(causal, EarlierWindows):
         live = (_window_of(k_off + ki * block_k, causal.k_window)
@@ -178,16 +208,6 @@ def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
     return live
 
 
-def _when_live(live, tile):
-    """Run tile() where the tile is live (always, when none can be blank)."""
-    from jax.experimental import pallas as pl
-
-    if live is None:
-        tile()
-    else:
-        pl.when(live)(tile)
-
-
 def _guard_masked_rows(stat):
     """A query whose every key is masked has m (or lse) == NEG_INF, and
     exp(NEG_INF - NEG_INF) = 1 would count its masked scores. Put
@@ -196,10 +216,9 @@ def _guard_masked_rows(stat):
     return jnp.where(stat > NEG_INF / 2, stat, -NEG_INF)
 
 
-def _make_tile(scale, causal, block_q, block_k, k_len):
-    """(scores, live) of one tile geometry, shared by all three kernels:
-    scores(q, k, qi, ki, q_off, k_off) is the masked [block_k, block_q]
-    float32 tile k q^T * scale, live(qi, ki, q_off, k_off) is _tile_live."""
+def _make_scores(scale, causal, block_q, block_k, k_len):
+    """scores(q, k, qi, ki, q_off, k_off) of one tile geometry, shared by
+    all kernels: the masked [block_k, block_q] float32 tile k q^T * scale."""
     masked = causal or k_len is not None
 
     def scores(q, k, qi, ki, q_off, k_off):
@@ -211,37 +230,178 @@ def _make_tile(scale, causal, block_q, block_k, k_len):
                              q_off, k_off)
         return s
 
-    def live(qi, ki, q_off, k_off):
-        return _tile_live(qi, ki, block_q, block_k, causal, k_len,
-                          q_off, k_off)
+    return scores
 
-    return scores, live
+
+# ------------------------------------------------------------ the live walk
+# A kernel's grid is (batch*head, step) and the steps are the entries of a
+# table in SMEM (scalar prefetch, as ops/grouped_matmul.py walks the
+# experts' tiles): the tiles of the [n_q, n_k] rectangle that do work, in
+# the order the kernel accumulates them, so a tile the mask blanks costs no
+# step and no fetch.
+
+# What an entry does, as bits of its `flags`. A sweep is the run of entries
+# that share the walk's outer block (q for the forward and dq, k for dk and
+# dv); the fused backward also follows the inner one, q.
+LIVE = 1       # the tile does work (_tile_live)
+FIRST = 2      # first entry of its sweep: the sweep's accumulators start
+LAST = 4       # last entry of its sweep: they are written out
+FIRST_IN = 8   # first entry of its inner block in the whole walk
+LAST_IN = 16   # last one: the sum over the sweeps is complete
+
+
+class Walk(NamedTuple):
+    """The entries one kernel walks, one int32 table [steps] a column.
+    numpy where the call knows its offsets (`steps` entries, none idle);
+    traced where a ring hop's offsets are (the rectangle's length, with an
+    idle tail: flags 0 and the last entry's blocks, so no index moves)."""
+
+    qi: jax.Array
+    ki: jax.Array
+    flags: jax.Array
+    in_block: jax.Array   # the inner block whose sum is being completed
+
+    @property
+    def steps(self) -> int:
+        return self.qi.shape[0]
+
+
+def _live_tiles(n_q, n_k, block_q, block_k, causal, k_len, q_off=0, k_off=0):
+    """[n_q, n_k] booleans, _tile_live over the rectangle: a numpy array
+    where the mask reads no offset or both are Python ints, traced where
+    one is."""
+    static = not causal or (isinstance(q_off, int) and isinstance(k_off, int))
+    xp = np if static else jnp
+    qi = xp.arange(n_q, dtype=xp.int32)[:, None]
+    ki = xp.arange(n_k, dtype=xp.int32)[None, :]
+    live = _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off, k_off)
+    if live is None:
+        return np.ones((n_q, n_k), bool)
+    return xp.broadcast_to(live, (n_q, n_k))
+
+
+def _kept(live):
+    """The tiles a walk holds: the live ones and, for a q block or a k
+    block that has none, its first tile, dead. Every output block keeps
+    one step that way (its init and finalize run, the products do not: a
+    first window's queries see no summary, a last window's summaries are
+    seen by no query, a ring hop may lie wholly in the future), as every
+    expert owns a tile in ops/grouped_matmul.py."""
+    n_q, n_k = live.shape
+    first_k, first_q = np.arange(n_k)[None, :] == 0, np.arange(n_q)[:, None] == 0
+    return (live | (~live.any(axis=1, keepdims=True) & first_k)
+            | (~live.any(axis=0, keepdims=True) & first_q))
+
+
+def _walk(live, k_major: bool) -> Walk:
+    """The walk over _kept(live): q-major with k ascending (the forward,
+    ps_flash_dq), or k-major with q ascending (ps_flash_dkv, ps_flash_dqkv).
+    One body for numpy and traced `live`."""
+    static = isinstance(live, np.ndarray)
+    xp = np if static else jnp
+    keep = _kept(live)
+    if k_major:
+        live, keep = live.T, keep.T
+    (n_out, n_in), kept = keep.shape, keep.reshape(-1)
+    count = kept.sum()
+    step = xp.arange(kept.size)
+    walked = step < count
+    # the kept tiles first, in the rectangle's order; the idle tail stays
+    # on the last of them
+    order = xp.argsort(~kept, stable=True)
+    at = xp.where(walked, order, order[count - 1])
+    outer, inner = at // n_in, at % n_in
+    first = (step == 0) | (outer != xp.roll(outer, 1))
+    last = (step == count - 1) | (outer != xp.roll(outer, -1))
+    first_in = outer == xp.argmax(keep, axis=0)[inner]
+    last_in = outer == (n_out - 1 - xp.argmax(keep[::-1], axis=0))[inner]
+    flags = walked * (live.reshape(-1)[at] * LIVE + first * FIRST + last * LAST
+                      + first_in * FIRST_IN + last_in * LAST_IN)
+    # the inner block being completed: that of the latest entry to complete
+    # one (before the first of them, the first's), so each block is resident
+    # for one run of steps and written back once, whole
+    done = xp.where(walked & last_in, step, -1)
+    done = np.maximum.accumulate(done) if static else jax.lax.cummax(done)
+    in_block = inner[xp.where(done < 0, xp.argmax(done >= 0), done)]
+    qi, ki = (inner, outer) if k_major else (outer, inner)
+    tables = [x.astype(xp.int32) for x in (qi, ki, flags, in_block)]
+    if static:
+        tables = [x[:int(count)] for x in tables]
+    return Walk(*tables)
+
+
+def _entry(qi_ref, ki_ref, flag_ref, off_ref):
+    """(qi, ki, has, q_off, k_off) of this grid step: its tile's blocks,
+    has(bit) of its flags, and the mask's offsets."""
+    from jax.experimental import pallas as pl
+
+    step = pl.program_id(1)
+    flags = flag_ref[step]
+    return (qi_ref[step], ki_ref[step], lambda bit: (flags & bit) != 0,
+            off_ref[0], off_ref[1])
+
+
+# Where a grid step's blocks lie: every index_map reads the walk's tables
+# (after the grid indices, the scalar-prefetch refs: qi, ki, flags,
+# in_block, offsets).
+def _q_rows(b, s, qi, *_):
+    return b, qi[s], 0
+
+
+def _k_rows(b, s, qi, ki, *_):
+    return b, ki[s], 0
+
+
+def _q_stat(b, s, qi, *_):
+    return b, 0, qi[s]
+
+
+def _in_rows(b, s, qi, ki, flags, in_block, *_):
+    return b, in_block[s], 0
+
+
+def _walk_call(kernel, walk: Walk, offsets, bh, *, name, in_specs, out_specs,
+               out_shape, scratch_shapes, mode, **params):
+    """pallas_call over (bh, walk.steps) with the walk and the offsets in
+    SMEM ahead of the operands."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    call = pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(bh, walk.steps), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        **params, **mode,
+    )
+    return partial(call, *walk, jnp.stack([jnp.asarray(o, jnp.int32) for o in offsets]))
 
 
 # --------------------------------------------------------------- forward
 
 
-def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
-                     k_len=None):
+def _make_fwd_kernel(scale, causal, block_q, block_k, normalize, k_len=None):
     from jax.experimental import pallas as pl
 
-    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
+    scores = _make_scores(scale, causal, block_q, block_k, k_len)
 
-    def kernel(off_ref, q_ref, k_ref, v_ref, *out_and_scratch):
+    def kernel(qi_ref, ki_ref, flag_ref, _, off_ref, q_ref, k_ref, v_ref,
+               *out_and_scratch):
         if normalize:
             o_ref, lse_ref, acc_ref, m_ref, l_ref = out_and_scratch
         else:
             pv_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref = out_and_scratch
-        qi = pl.program_id(1)
-        ki = pl.program_id(2)
-        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
+        qi, ki, has, q_off, k_off = _entry(qi_ref, ki_ref, flag_ref, off_ref)
 
-        @pl.when(ki == 0)
+        @pl.when(has(FIRST))
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
 
+        @pl.when(has(LIVE))
         def _tile():
             v = v_ref[0]  # [Bk, D]
             s = scores(q_ref[0], k_ref[0], qi, ki, q_off, k_off)  # [Bk, Bq]
@@ -255,9 +415,7 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
             )  # (p v)^T, [D, Bq]
             m_ref[:] = m_new
 
-        _when_live(live(qi, ki, q_off, k_off), _tile)
-
-        @pl.when(ki == n_k - 1)
+        @pl.when(has(LAST))
         def _finalize():
             if normalize:
                 l = l_ref[:]
@@ -274,43 +432,26 @@ def _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
     return kernel
 
 
-def _offsets_arr(offsets):
-    """(q_off, k_off) traced/static scalars -> (1, 2) i32 SMEM operand."""
-    if offsets is None:
-        return jnp.zeros((1, 2), jnp.int32)
-    q_off, k_off = offsets
-    return jnp.stack(
-        [jnp.asarray(q_off, jnp.int32), jnp.asarray(k_off, jnp.int32)]
-    )[None]
-
-
-def _smem_spec():
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pl.BlockSpec(
-        (1, 2), lambda *_: (0, 0), memory_space=pltpu.SMEM
-    )
-
-
 def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
-               offsets=None, normalize=True, k_len=None):
+               offsets=(0, 0), normalize=True, k_len=None):
     """q3/k3: [BH, T, D], v3: [BH, T, Dv] -> (o [BH, T, Dv], lse [BH, T])
     when normalize, else the partial triple (pv f32 [BH, T, Dv], m f32
     [BH, T], l f32 [BH, T]) for ring-hop merging. `offsets` shifts the
     causal mask's global positions; static `k_len` masks zero-padded key
-    positions (see _mask_scores)."""
+    positions (see _mask_scores). The grid walks the live tiles q-major,
+    k ascending within a q block."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
     tk, dv = k3.shape[1], v3.shape[2]
-    n_q, n_k = t // block_q, tk // block_k
-    kernel = _make_fwd_kernel(scale, causal, block_q, block_k, n_k, normalize,
+    walk = _walk(_live_tiles(t // block_q, tk // block_k, block_q, block_k,
+                             causal, k_len, *offsets), k_major=False)
+    kernel = _make_fwd_kernel(scale, causal, block_q, block_k, normalize,
                               k_len=k_len)
-    row = pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi))
+    row = pl.BlockSpec((1, 1, block_q), _q_stat)
     row_shape = jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)
-    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0))]
+    out_specs = [pl.BlockSpec((1, block_q, dv), _q_rows)]
     if normalize:
         out_specs += [row]
         out_shape = [jax.ShapeDtypeStruct((bh, t, dv), q3.dtype), row_shape]
@@ -321,15 +462,12 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
             row_shape,
             row_shape,
         ]
-    out, *rows = pl.pallas_call(
-        kernel,
-        name="ps_flash_fwd",
-        grid=(bh, n_q, n_k),
+    out, *rows = _walk_call(
+        kernel, walk, offsets, bh, name="ps_flash_fwd",
         in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_q, d), _q_rows),
+            pl.BlockSpec((1, block_k, d), _k_rows),
+            pl.BlockSpec((1, block_k, dv), _k_rows),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -338,8 +476,8 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, mode,
             pltpu.VMEM((1, block_q), jnp.float32),
             pltpu.VMEM((1, block_q), jnp.float32),
         ],
-        **mode,
-    )(_offsets_arr(offsets), q3, k3, v3)
+        mode=mode,
+    )(q3, k3, v3)
     return (out, *(r.reshape(bh, t) for r in rows))
 
 
@@ -366,30 +504,30 @@ def _make_ds(scores):
     return tile
 
 
-def _make_dqkv_kernel(scale, causal, block_q, block_k, n_q, n_k, k_len=None):
-    """The fused backward: grid (bh, k_block, q_block), q innermost. dk and
-    dv are complete when a k block's q sweep ends; dq[qi] gains one term a
-    k block, in ascending ki, and is complete in the last sweep."""
+def _make_dqkv_kernel(scale, causal, block_q, block_k, k_len=None):
+    """The fused backward: k-major, q ascending within a k block. dk and dv
+    are complete when a k block's sweep ends; dq[qi] gains one term a k
+    block, in ascending ki, and is written at its q block's last entry
+    (under `causal` its diagonal tile, the first of a sweep)."""
     from jax.experimental import pallas as pl
 
-    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
-    ds_tile = _make_ds(scores)
+    ds_tile = _make_ds(_make_scores(scale, causal, block_q, block_k, k_len))
 
-    def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
-        ki = pl.program_id(1)
-        qi = pl.program_id(2)
-        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
+    def kernel(qi_ref, ki_ref, flag_ref, _, off_ref, q_ref, k_ref, v_ref,
+               do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+               dq_acc, dk_acc, dv_acc):
+        qi, ki, has, q_off, k_off = _entry(qi_ref, ki_ref, flag_ref, off_ref)
 
-        @pl.when(qi == 0)
+        @pl.when(has(FIRST))
         def _init_dkv():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
-        @pl.when(ki == 0)
+        @pl.when(has(FIRST_IN))
         def _init_dq():
             dq_acc[qi] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
 
+        @pl.when(has(LIVE))
         def _tile():
             p, ds, q, do = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, qi, ki, q_off, k_off)
@@ -403,36 +541,32 @@ def _make_dqkv_kernel(scale, causal, block_q, block_k, n_q, n_k, k_len=None):
                 k, ds, _TN, preferred_element_type=jnp.float32
             )  # (ds k)^T, [D, Bq]
 
-        _when_live(live(qi, ki, q_off, k_off), _tile)
-
-        @pl.when(qi == n_q - 1)
+        @pl.when(has(LAST))
         def _finalize_dkv():
             dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-        @pl.when(ki == n_k - 1)
+        @pl.when(has(LAST_IN))
         def _finalize_dq():
             dq_ref[0] = (dq_acc[qi] * scale).T.astype(dq_ref.dtype)
 
     return kernel
 
 
-def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
+def _make_dq_kernel(scale, causal, block_q, block_k, k_len=None):
     from jax.experimental import pallas as pl
 
-    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
-    ds_tile = _make_ds(scores)
+    ds_tile = _make_ds(_make_scores(scale, causal, block_q, block_k, k_len))
 
-    def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, acc_ref):
-        qi = pl.program_id(1)
-        ki = pl.program_id(2)
-        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
+    def kernel(qi_ref, ki_ref, flag_ref, _, off_ref, q_ref, k_ref, v_ref,
+               do_ref, lse_ref, delta_ref, dq_ref, acc_ref):
+        qi, ki, has, q_off, k_off = _entry(qi_ref, ki_ref, flag_ref, off_ref)
 
-        @pl.when(ki == 0)
+        @pl.when(has(FIRST))
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
 
+        @pl.when(has(LIVE))
         def _tile():
             _, ds, _, _ = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                   delta_ref, qi, ki, q_off, k_off)
@@ -441,32 +575,28 @@ def _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=None):
                 k, ds.astype(k.dtype), _TN, preferred_element_type=jnp.float32
             )  # (ds k)^T, [D, Bq]
 
-        _when_live(live(qi, ki, q_off, k_off), _tile)
-
-        @pl.when(ki == n_k - 1)
+        @pl.when(has(LAST))
         def _finalize():
             dq_ref[0] = (acc_ref[:] * scale).T.astype(dq_ref.dtype)
 
     return kernel
 
 
-def _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=None):
+def _make_dkv_kernel(scale, causal, block_q, block_k, k_len=None):
     from jax.experimental import pallas as pl
 
-    scores, live = _make_tile(scale, causal, block_q, block_k, k_len)
-    ds_tile = _make_ds(scores)
+    ds_tile = _make_ds(_make_scores(scale, causal, block_q, block_k, k_len))
 
-    def kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dk_ref, dv_ref, dk_acc, dv_acc):
-        ki = pl.program_id(1)
-        qi = pl.program_id(2)
-        q_off, k_off = off_ref[0, 0], off_ref[0, 1]
+    def kernel(qi_ref, ki_ref, flag_ref, _, off_ref, q_ref, k_ref, v_ref,
+               do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc):
+        qi, ki, has, q_off, k_off = _entry(qi_ref, ki_ref, flag_ref, off_ref)
 
-        @pl.when(qi == 0)
+        @pl.when(has(FIRST))
         def _init():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
+        @pl.when(has(LIVE))
         def _tile():
             p, ds, q, do = ds_tile(q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    delta_ref, qi, ki, q_off, k_off)
@@ -477,9 +607,7 @@ def _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=None):
                 ds.astype(q.dtype), q, preferred_element_type=jnp.float32
             )
 
-        _when_live(live(qi, ki, q_off, k_off), _tile)
-
-        @pl.when(qi == n_q - 1)
+        @pl.when(has(LAST))
         def _finalize():
             dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -488,7 +616,7 @@ def _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=None):
 
 
 def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
-               mode, offsets=None, out_dtype=None, k_len=None):
+               mode, offsets=(0, 0), out_dtype=None, k_len=None):
     """Blockwise gradients. `lse`/`delta` are the FINAL (post-merge)
     softmax stats — single-chip they come straight from the forward; on a
     ring every hop reuses the globally-merged values, which is what makes
@@ -497,90 +625,76 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
     `out_dtype` overrides the gradients' dtype (the ring passes f32 so
     per-hop pieces accumulate without a per-hop rounding). v3 and do3 are
     Dv wide where q3 and k3 are D wide. One kernel or two is plan_bwd's
-    choice, from the shapes here."""
+    choice, from the shapes here; each walks the live tiles (_walk)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q3.shape
     tk, dv = k3.shape[1], v3.shape[2]
-    n_q, n_k = t // block_q, tk // block_k
-    off = _offsets_arr(offsets)
+    live = _live_tiles(t // block_q, tk // block_k, block_q, block_k, causal,
+                       k_len, *offsets)
+    by_k = _walk(live, k_major=True)
     lse, delta = lse.reshape(bh, 1, t), delta.reshape(bh, 1, t)
     dq_shape = jax.ShapeDtypeStruct((bh, t, d), out_dtype or q3.dtype)
     dkv_shape = [
         jax.ShapeDtypeStruct((bh, tk, d), out_dtype or k3.dtype),
         jax.ShapeDtypeStruct((bh, tk, dv), out_dtype or v3.dtype),
     ]
-    by_k_then_q = [
-        _smem_spec(),
-        pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
-        pl.BlockSpec((1, block_q, dv), lambda b, ki, qi: (b, qi, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
-        pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), _q_rows),
+        pl.BlockSpec((1, block_k, d), _k_rows),
+        pl.BlockSpec((1, block_k, dv), _k_rows),
+        pl.BlockSpec((1, block_q, dv), _q_rows),
+        pl.BlockSpec((1, 1, block_q), _q_stat),
+        pl.BlockSpec((1, 1, block_q), _q_stat),
     ]
     dkv_specs = [
-        pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        pl.BlockSpec((1, block_k, dv), lambda b, ki, qi: (b, ki, 0)),
+        pl.BlockSpec((1, block_k, d), _k_rows),
+        pl.BlockSpec((1, block_k, dv), _k_rows),
     ]
     dkv_scratch = [
         pltpu.VMEM((block_k, d), jnp.float32),
         pltpu.VMEM((block_k, dv), jnp.float32),
     ]
-    args = (off, q3, k3, v3, do3, lse, delta)
+    args = (q3, k3, v3, do3, lse, delta)
     bwd, _, vmem_bytes = plan_bwd(block_q, block_k, t, d, dv,
                                   q3.dtype.itemsize)
 
     if bwd == "fused":
-        # the dq block stays at (b, 0) until the last k sweep, the only one
-        # that writes it, and then follows qi: Pallas writes a block back
-        # when its index moves on, so each is written once, complete
-        return pl.pallas_call(
-            _make_dqkv_kernel(scale, causal, block_q, block_k, n_q, n_k,
-                              k_len=k_len),
-            name="ps_flash_dqkv",
-            grid=(bh, n_k, n_q),
-            in_specs=by_k_then_q,
-            out_specs=[pl.BlockSpec(
-                (1, block_q, d),
-                lambda b, ki, qi: (b, jnp.where(ki == n_k - 1, qi, 0), 0),
-            )] + dkv_specs,
+        # the dq block in VMEM is the one being completed (Walk.in_block):
+        # Pallas writes a block back when its index moves on, so each is
+        # written once, whole
+        return _walk_call(
+            _make_dqkv_kernel(scale, causal, block_q, block_k, k_len=k_len),
+            by_k, offsets, bh, name="ps_flash_dqkv",
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, block_q, d), _in_rows)] + dkv_specs,
             out_shape=[dq_shape] + dkv_shape,
-            scratch_shapes=[pltpu.VMEM((n_q, d, block_q), jnp.float32)]
+            scratch_shapes=[pltpu.VMEM((t // block_q, d, block_q), jnp.float32)]
             + dkv_scratch,
+            mode=mode,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_limit(vmem_bytes)),
-            **mode,
         )(*args)
 
-    dq = pl.pallas_call(
-        _make_dq_kernel(scale, causal, block_q, block_k, n_k, k_len=k_len),
-        name="ps_flash_dq",
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            _smem_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+    by_q = _walk(live, k_major=False)
+    dq = _walk_call(
+        _make_dq_kernel(scale, causal, block_q, block_k, k_len=k_len),
+        by_q, offsets, bh, name="ps_flash_dq",
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_q, d), _q_rows),
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
-        **mode,
+        mode=mode,
     )(*args)
-    dk, dv = pl.pallas_call(
-        _make_dkv_kernel(scale, causal, block_q, block_k, n_q, k_len=k_len),
-        name="ps_flash_dkv",
-        grid=(bh, n_k, n_q),
-        in_specs=by_k_then_q,
+    dk, dv = _walk_call(
+        _make_dkv_kernel(scale, causal, block_q, block_k, k_len=k_len),
+        by_k, offsets, bh, name="ps_flash_dkv",
+        in_specs=in_specs,
         out_specs=dkv_specs,
         out_shape=dkv_shape,
         scratch_shapes=dkv_scratch,
-        **mode,
+        mode=mode,
     )(*args)
     return dq, dk, dv
 
@@ -590,23 +704,25 @@ def _flash_bwd(q3, k3, v3, lse, delta, do3, scale, causal, block_q, block_k,
 
 class FlashPlan(NamedTuple):
     """How one call tiles its [T_q, T_k] score square. The counts are per
-    head, with both offsets 0 (what the call can know before it runs: on a
-    ring hop the kernels decide from the hop's offsets)."""
+    head, with both offsets 0 (what the call can know before it runs: a
+    ring hop builds its walk from the hop's offsets, _walk)."""
 
     block_q: int
     block_k: int
     tq_pad: int       # T_q and T_k padded up to their blocks
     tk_pad: int
     k_len: Optional[int]  # T_k where the kernels must mask a padded tail
-    grid_steps: int   # tiles walked: (tq_pad / block_q) * (tk_pad / block_k)
-    tiles_run: int    # ... of which do work (_tile_live)
+    grid_steps: int   # steps the kernels' grids walk (_kept): tiles_run and
+    #                   one for each q or k block that no live tile touches
+    tiles_run: int    # tiles that do work (_tile_live)
     vmem_bytes: int   # _vmem_bytes of the backward that runs
     bwd: str          # "fused": ps_flash_dqkv; "split": ps_flash_dq + _dkv
     dq_acc_bytes: int  # the fused kernel's dq accumulator; 0 where split
 
     @property
     def tiles_total(self) -> int:
-        return self.grid_steps
+        """The rectangle: tiles_total - grid_steps are never entered."""
+        return (self.tq_pad // self.block_q) * (self.tk_pad // self.block_k)
 
 
 def _ceil_pow2(x: int) -> int:
@@ -707,15 +823,12 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
 
     bq, bk = block(t_q, block_q), block(t_k, block_k)
     tq_pad, tk_pad = -(-t_q // bq) * bq, -(-t_k // bk) * bk
-    n_q, n_k = tq_pad // bq, tk_pad // bk
     k_len = t_k if tk_pad != t_k else None
-    live = [_tile_live(qi, ki, bq, bk, causal, k_len)
-            for qi in range(n_q) for ki in range(n_k)]
+    live = _live_tiles(tq_pad // bq, tk_pad // bk, bq, bk, causal, k_len)
     bwd, dq_acc_bytes, vmem_bytes = plan_bwd(bq, bk, tq_pad, d, d_v, itemsize)
     return FlashPlan(
         block_q=bq, block_k=bk, tq_pad=tq_pad, tk_pad=tk_pad, k_len=k_len,
-        grid_steps=n_q * n_k,
-        tiles_run=sum(x is None or bool(x) for x in live),
+        grid_steps=int(_kept(live).sum()), tiles_run=int(live.sum()),
         vmem_bytes=vmem_bytes, bwd=bwd, dq_acc_bytes=dq_acc_bytes,
     )
 
@@ -903,8 +1016,10 @@ def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
     """One hop's UNNORMALIZED contribution: [BH, Tq, D] queries against a
     visiting K [BH, Tk, D] / V [BH, Tk, Dv] shard -> (pv f32 [BH, Tq, Dv],
     m f32 [BH, Tq], l f32 [BH, Tq]). q_off/k_off are the shards' global sequence offsets
-    (traced scalars are fine — they ride in SMEM, one compiled kernel
-    serves every hop, and decides there which tiles the hop runs). The
+    (traced scalars are fine: the walk over the hop's live tiles is built
+    from them in jnp and rides in SMEM with them, so one compiled kernel
+    serves every hop; Python ints give a constant walk and a grid of
+    exactly its length). The
     caller merges triples across hops with the usual online-softmax
     rescale and normalizes once at the end.
 

@@ -192,6 +192,31 @@ def test_remat_is_bitwise_the_plain_backward(kernels, policy):
     assert text.count("ps_flash_fwd") == text.count("ps_flash_dqkv") == 2  # local, remote
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_both_passes_walk_live_tiles_to_the_rectangles_bits(kernels, flash_bwd, dtype, monkeypatch):
+    """A part-filled last window (868 of 4 x 256 positions, chunks of 4) at
+    blocks capped at 128, so the local pass is 2 x 2 tiles a window (three
+    live) and the remote pass 8 x 2 (eight live, and a dead entry for each
+    of the two q blocks of window 0): o and the five gradients from the walk
+    over live tiles equal, to the bit, those of the same kernels handed
+    every tile of the rectangle (the walk before PR 43)."""
+    monkeypatch.setattr(fa, "MAX_BLOCK", 128)
+    t, window, chunk = 868, 256, 4
+    plan = eva.plan_eva(t, D, dtype, window, chunk)
+    assert (plan.local.tiles_run, plan.local.grid_steps, plan.local.tiles_total) == (3, 3, 4)
+    assert (plan.remote.tiles_run, plan.remote.grid_steps, plan.remote.tiles_total) == (8, 10, 16)
+    args = _inputs(t, seed=13, dtype=dtype)
+    both = lambda: _with_grads(lambda *a: eva.eva_attention(*a, window, chunk))(*args)
+    (o, _), grads = both()
+    with monkeypatch.context() as whole:
+        whole.setattr(fa, "_kept", lambda live: np.ones(live.shape, bool))
+        assert eva.plan_eva(t, D, dtype, window, chunk).remote.grid_steps == 16
+        (o_rect, _), grads_rect = both()
+    for name, a, b in zip("o q k v phi mu".split(), (o, *grads), (o_rect, *grads_rect)):
+        assert np.array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32)), name
+    assert float(jnp.max(jnp.abs(grads[3]))) > 0   # the summaries were seen
+
+
 # ------------------------------------------------------------ the mask kind
 
 
@@ -224,7 +249,8 @@ def test_a_tile_is_skipped_iff_every_score_in_it_is_masked(mask):
                     assert kept[kk, qq] == (j // k_window < i // q_window and j < t_k)
             assert bool(fa._tile_live(qi, ki, bq, bk, kind, plan.k_len)) == bool(kept.any())
             live += bool(kept.any())
-    assert plan.tiles_run == live < plan.grid_steps
+    assert plan.tiles_run == live < plan.tiles_total == n_q * n_k
+    assert live <= plan.grid_steps <= plan.tiles_total   # live, and a q or k block's dead entry
 
 
 def test_the_partial_kernels_under_the_mask_kind_match_dense_scores(kernels):
@@ -269,7 +295,9 @@ def test_the_plan_counts_what_the_cell_runs():
     assert (plan.windows, plan.t_pad, plan.summaries, plan.per_window) == (8, 16384, 1024, 128)
     assert (plan.local.block_q, plan.local.block_k, plan.local.tiles_run) == (512, 512, 10)
     assert (plan.remote.block_q, plan.remote.block_k) == (512, 512)
-    assert (plan.remote.grid_steps, plan.remote.tiles_run) == (64, 40)
+    # the four q blocks of window 0 keep a dead entry each
+    assert (plan.remote.tiles_total, plan.remote.grid_steps, plan.remote.tiles_run) == (64, 44, 40)
+    assert (plan.local.tiles_total, plan.local.grid_steps) == (16, 10)
     assert plan.tiles() == (80, 40) and plan.local.bwd == plan.remote.bwd == "fused"
     # before tile rounding: 8 x 2048^2 / 2 local and 2048 x 128 x 28 remote score entries
     assert 80 * 512 * 512 >= 8 * 2048 * 2048 // 2 and 40 * 512 * 512 >= 2048 * 128 * 28

@@ -221,6 +221,79 @@ def test_planned_path_matches_full(t, causal, dtype, flash_bwd):
     assert all(e < bound for e in errs.values()), errs
 
 
+WALK_MASKS = {"bidir": False, "causal": True, "earlier_windows": (256, 256)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("mask", sorted(WALK_MASKS))
+@pytest.mark.parametrize("t", [96, 1000, 1024, 2048])
+def test_the_live_walk_is_the_rectangular_walk_bitwise(t, mask, dtype, flash_bwd, monkeypatch):
+    """o, lse, dq, dk, dv of the kernels as they walk the live tiles, and
+    of the SAME kernels handed the whole rectangle as their table (every
+    tile an entry, the dead ones skipped by their flag: the walk before
+    PR 43): equal to the bit, since each accumulator sums the same tiles
+    in the same order. T = 96 is one tile (wholly dead under the windows),
+    1000 pads and masks a tail, 2048 has runs of dead tiles."""
+    from ps_pytorch_tpu.ops import flash_attention as fa
+    from ps_pytorch_tpu.ops.pallas_mode import INTERPRET
+
+    causal = WALK_MASKS[mask]
+    if isinstance(causal, tuple):
+        causal = fa.EarlierWindows(*causal)
+    plan = fa.plan_flash(t, t, 64, dtype, causal)
+    rng = np.random.RandomState(t)
+    q, k, v, do = (fa._pad_t(jnp.asarray(rng.randn(1, t, 64) * 0.5, dtype), plan.tq_pad)
+                   for _ in range(4))
+
+    def run():
+        args = (0.125, causal, plan.block_q, plan.block_k, INTERPRET)
+        o, lse = fa._flash_fwd(q, k, v, *args, k_len=plan.k_len)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+        return (o, lse) + tuple(fa._flash_bwd(q, k, v, lse, delta, do, *args, k_len=plan.k_len))
+
+    live = run()
+    with monkeypatch.context() as whole:
+        whole.setattr(fa, "_kept", lambda live: np.ones(live.shape, bool))
+        assert fa.plan_flash(t, t, 64, dtype, causal).grid_steps == plan.tiles_total
+        rectangle = run()
+    assert plan.grid_steps < plan.tiles_total or mask == "bidir" or t < 1000
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), live, rectangle):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32)), name
+
+
+@pytest.mark.parametrize("k_major", [False, True], ids=["q_major", "k_major"])
+def test_a_traced_walk_is_the_static_walk_and_an_idle_tail(k_major):
+    """Offsets known only at run time (a ring hop): the tables are built in
+    jnp at the rectangle's length; their first entries are the static
+    walk's for the same offsets, and the tail repeats the last entry's
+    blocks with no flag set, so no block index moves and nothing runs."""
+    from ps_pytorch_tpu.ops import flash_attention as fa
+
+    n_q, n_k, bq, bk = 3, 5, 32, 32
+
+    @jax.jit
+    def traced(q_off, k_off):
+        return tuple(fa._walk(fa._live_tiles(n_q, n_k, bq, bk, True, 150, q_off, k_off), k_major))
+
+    for q_off, k_off in [(64, 0), (0, 64), (40, 250), (0, 0), (1000, 0)]:
+        static = fa._walk(fa._live_tiles(n_q, n_k, bq, bk, True, 150, q_off, k_off), k_major)
+        assert isinstance(static.qi, np.ndarray) and static.steps <= n_q * n_k
+        got = [np.asarray(x) for x in traced(jnp.int32(q_off), jnp.int32(k_off))]
+        assert all(x.shape == (n_q * n_k,) and x.dtype == np.int32 for x in got)
+        for name, g, s in zip(fa.Walk._fields, got, static):
+            assert np.array_equal(g[:static.steps], s), (name, q_off, k_off)
+            tail = g[static.steps:]
+            assert np.all(tail == (0 if name == "flags" else s[-1])), (name, q_off, k_off)
+        # every output block is written, in exactly one run of steps
+        qi, ki, flags, in_block = static
+        swept, summed = ((ki, qi) if k_major else (qi, ki))
+        assert sorted(swept[flags & fa.LAST != 0]) == list(range(n_k if k_major else n_q))
+        assert sorted(summed[flags & fa.LAST_IN != 0]) == list(range(n_q if k_major else n_k))
+        runs = in_block[np.r_[True, in_block[1:] != in_block[:-1]]]
+        assert len(set(runs)) == len(runs)
+
+
 def test_plan_flash():
     """The tile plan is a pure function of what a call can observe."""
     from ps_pytorch_tpu.ops.flash_attention import (
@@ -229,7 +302,8 @@ def test_plan_flash():
     # cell 3's call: 512-wide tiles, the one above the diagonal skipped
     plan = plan_flash(1024, 1024, 64, jnp.bfloat16, True)
     assert plan[:4] == (512, 512, 1024, 1024)
-    assert (plan.tiles_run, plan.tiles_total, plan.grid_steps) == (3, 4, 4)
+    # the grid walks the live tiles alone: three steps of the rectangle's four
+    assert (plan.tiles_run, plan.tiles_total, plan.grid_steps) == (3, 4, 3)
     assert plan_flash(1024, 1024, 64, jnp.bfloat16, False).tiles_run == 4
     # the old 128-wide plan, as the tests can still ask for it
     old = plan_flash(1024, 1024, 64, jnp.bfloat16, True, 128, 128)
@@ -257,9 +331,14 @@ def test_plan_flash():
             assert p.vmem_bytes == _vmem_bytes(
                 p.block_q, p.block_k, d, itemsize, None, p.dq_acc_bytes)
             assert p.dq_acc_bytes < p.vmem_bytes <= FUSED_BWD_CAP
-            assert p.tiles_total == p.grid_steps == (
+            assert p.tiles_total == (
                 (p.tq_pad // p.block_q) * (p.tk_pad // p.block_k))
-            assert 1 <= p.tiles_run <= p.tiles_total
+            # a square, causal or not: every q block meets its own keys and
+            # every k block its own queries, so the walk holds no dead entry
+            # (512 queries against 4,096 keys: one live tile, and an entry
+            # for each of the seven k blocks that lie in the future)
+            assert 1 <= p.tiles_run <= p.grid_steps <= p.tiles_total
+            assert t_q != t_k or p.tiles_run == p.grid_steps
             assert causal or p.tiles_run == p.tiles_total
     # padding stays small: T=520 takes 128-wide blocks over 640, T=1000
     # 512-wide ones over 1024
@@ -267,9 +346,44 @@ def test_plan_flash():
         128, 128, 640, 640)
     assert plan_flash(1000, 1000, 64, jnp.bfloat16, True)[:4] == (
         512, 512, 1024, 1024)
-    # a visiting shard of another length is tiled on its own axis
-    assert plan_flash(96, 1024, 64, jnp.bfloat16, True)[:4] == (
-        128, 512, 128, 1024)
+    # a visiting shard of another length is tiled on its own axis; its
+    # second k block lies past every query and keeps one dead entry, in
+    # which dk and dv are written as zeros
+    shard = plan_flash(96, 1024, 64, jnp.bfloat16, True)
+    assert shard[:4] == (128, 512, 128, 1024)
+    assert (shard.tiles_run, shard.grid_steps, shard.tiles_total) == (1, 2, 2)
+    for cell, (_, t, d, d_v) in CELL_SHAPES.items():
+        p = plan_flash(t, t, d, jnp.bfloat16, True, d_v=d_v)
+        assert (p.grid_steps, p.tiles_run, p.tiles_total) == (
+            (136, 136, 256) if t == 8192 else (3, 3, 4)), cell
+
+
+def test_plan_eva_remote_keeps_an_entry_for_each_block_no_live_tile_touches():
+    """Under EarlierWindows the queries of window 0 see no summary and (at
+    narrow blocks) the last window's summaries are seen by no query: each
+    such q block and k block keeps ONE dead entry of the walk, which writes
+    the parent's outputs there (m = NEG_INF, l = 0, pv = 0; zero
+    gradients), and nothing else above the live tiles is walked."""
+    from ps_pytorch_tpu.ops import eva
+    from ps_pytorch_tpu.ops import flash_attention as fa
+
+    remote = eva.plan_eva(16384, 128, jnp.bfloat16, 2048, 16).remote
+    # 4 q blocks of window 0; both k blocks hold a window some query sees
+    assert (remote.tiles_run, remote.grid_steps, remote.tiles_total) == (40, 44, 64)
+    kind = fa.EarlierWindows(64, 8)
+    plan = fa.plan_flash(256, 32, 16, jnp.float32, kind, 32, 8)
+    live = fa._live_tiles(8, 4, 32, 8, kind, None)
+    no_key = int((~live.any(axis=1)).sum())    # q blocks of window 0
+    no_query = int((~live.any(axis=0)).sum())  # k blocks of the last window
+    assert (no_key, no_query) == (2, 1)
+    assert plan.tiles_run == int(live.sum()) == 12
+    assert plan.tiles_run < plan.grid_steps == 12 + no_key + no_query < plan.tiles_total == 32
+    for k_major in (False, True):
+        walk = fa._walk(live, k_major)
+        dead = walk.flags & fa.LIVE == 0
+        # the dead entries: q blocks 0 and 1 at k block 0, k block 3 at q block 0
+        assert sorted(zip(walk.qi[dead], walk.ki[dead])) == [(0, 0), (0, 3), (1, 0)]
+        assert walk.steps == plan.grid_steps
 
 
 # [B * H, T, D_qk, D_v] of an attention layer in the benchmark's four LM
@@ -346,14 +460,16 @@ def test_every_kernel_name_is_read_by_flash_ms(flash_bwd):
 
 
 # sha256 of the jaxpr (kernel bodies and all) of flash_attention's value and
-# gradient at a cell's shapes, as the commit before ops/eva.py and the mask
-# kind EarlierWindows lowered it (PR 40). A PR that means to change the dense
-# path's kernels prints the new digest with this test and says so.
+# gradient at a cell's shapes. PR 41 held them to the commit before ops/eva.py
+# and the mask kind EarlierWindows (PR 40); PR 43 meant to change the dense
+# path's kernels (their grids walk the live tiles from tables in SMEM) and
+# these are its digests. A PR that means to change them again prints the new
+# digest with this test and says so.
 DENSE_JAXPR = {
     "kanana_kimi": ((2, 8192, 32, 192, 128),
-                    "e87ef002de5305e267da4cfedbcf47c4eab246f9b50e58df386fd8634542591f"),
+                    "f6a7cbeb3f41e9012d5052c7f5c5e844e2670bd8817adedc9582cedb4137b74c"),
     "gpt2m": ((8, 1024, 16, 64, 64),
-              "6fd32e26e116c47762353a167e856d3ff4dd886371ddf868009d627213d632c5"),
+              "f4fca00e1c578ef06ac67fac2156ef31b447eaba995bebd93c062f3b4f03be7e"),
 }
 
 
@@ -361,7 +477,9 @@ DENSE_JAXPR = {
 def test_the_dense_path_lowers_as_it_did_before_the_second_mask_kind(cell):
     """`causal` False | True reaches _mask_scores and _tile_live through the
     branches it always took: the kanana / kimi and gpt2m attention calls
-    trace to the text they traced to before EarlierWindows existed."""
+    trace to one held text (since PR 43 the live walk's: grid (bh, 136) and
+    (bh, 3)), so a change to another mask kind cannot move the dense path
+    unseen."""
     import hashlib
 
     (b, t, h, d, d_v), want = DENSE_JAXPR[cell]
@@ -370,4 +488,6 @@ def test_the_dense_path_lowers_as_it_did_before_the_second_mask_kind(cell):
     loss = lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True).astype(jnp.float32))
     text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, v))
     assert text.count("ps_flash_fwd") == text.count("ps_flash_dqkv") == 1
+    steps = 136 if t == 8192 else 3
+    assert text.count(f"grid=({b * h}, {steps})") == 2
     assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -160,7 +160,7 @@ def test_plan_at_the_published_widths():
     """PR 25's plan for its cell stays (512 x 512 at T 1024, D 64: three of
     four tiles), and MLA at T 8192 takes 512 x 512 tiles, 136 of 256 run."""
     small = plan_flash(1024, 1024, 64, jnp.bfloat16, True)
-    assert (small.block_q, small.block_k, small.tiles_run, small.grid_steps) == (512, 512, 3, 4)
+    assert (small.block_q, small.block_k, small.tiles_run, small.tiles_total) == (512, 512, 3, 4)
     tiles = 2 * (512 + 512) * 128 * 2 + 2 * 2 * 512 * 4 \
         + 3 * 512 * 128 * 4 + 4 * 512 * 512 * 4 + 2 * 512 * 512 * 2
     assert _vmem_bytes(512, 512, 64, 2) == tiles
@@ -168,7 +168,8 @@ def test_plan_at_the_published_widths():
     assert (small.bwd, small.dq_acc_bytes) == ("fused", 1024 * 64 * 4)
     assert small.vmem_bytes == tiles + 1024 * 64 * 4 + 2 * 512 * 64 * 4
     mla = plan_flash(8192, 8192, 192, jnp.bfloat16, True, d_v=128)
-    assert (mla.block_q, mla.block_k, mla.tiles_run, mla.grid_steps) == (512, 512, 136, 256)
+    assert (mla.block_q, mla.block_k, mla.tiles_run, mla.tiles_total) == (512, 512, 136, 256)
+    assert (small.grid_steps, mla.grid_steps) == (3, 136)   # the grids walk the live tiles alone
     assert mla.k_len is None and _vmem_bytes(512, 512, 192, 2, 128) < 12 * 2 ** 20
     assert (mla.bwd, mla.dq_acc_bytes) == ("fused", 6 * 2 ** 20)
     assert mla.vmem_bytes == _vmem_bytes(512, 512, 192, 2, 128) + 6 * 2 ** 20 + 2 * 512 * 192 * 4

@@ -63,10 +63,11 @@ FLASH_SHAPES = dict(CELL_SHAPES, t65536=(1, 65536, 192, 128))
 @pytest.mark.parametrize("bwd", ["fused", "split"])
 @pytest.mark.parametrize("cell", sorted(FLASH_SHAPES))
 def test_flash_kernels_compile_at_the_cells_shapes(shape, monkeypatch, cell, bwd):
-    """Forward and backward as a ring hop drives them (traced offsets in
-    SMEM, float32 results) and the backward as the custom VJP does (results
-    in the operands' dtype). The fused backward is ONE Mosaic call, given
-    the VMEM limit the plan asks; past the cap (put at 0 here) the pair."""
+    """Forward and backward as a ring hop drives them (traced offsets and a
+    walk built from them in SMEM, float32 results) and the backward as the
+    custom VJP does (a constant walk, results in the operands' dtype). The
+    fused backward is ONE Mosaic call, given the VMEM limit the plan asks;
+    past the cap (put at 0 here) the pair."""
     import re
 
     bh, t, d_qk, d_v = FLASH_SHAPES[cell]
@@ -89,12 +90,18 @@ def test_flash_kernels_compile_at_the_cells_shapes(shape, monkeypatch, cell, bwd
 
     assert _mosaic_calls(jax.jit(fwd).lower(q, k, v, off, off).compile()) == 1
     names = {"fused": ["ps_flash_dqkv"], "split": ["ps_flash_dkv", "ps_flash_dq"]}[bwd]
-    for compiled in (jax.jit(hop).lower(q, k, v, do, row, row, off, off).compile(),
-                     jax.jit(local).lower(q, k, v, do, row, row).compile()):
+    # the walk rides ahead of the operands as four int32 tables (scalar
+    # prefetch): the rectangle's length where the hop's offsets are traced,
+    # the live tiles' alone where they are known
+    walks = [(jax.jit(hop).lower(q, k, v, do, row, row, off, off).compile(), plan.tiles_total),
+             (jax.jit(local).lower(q, k, v, do, row, row).compile(), plan.grid_steps)]
+    assert plan.grid_steps == plan.tiles_run < plan.tiles_total
+    for compiled, steps in walks:
         calls = _mosaic_lines(compiled)
         assert sorted(re.search(r"%(ps_flash_[a-z]+)", c).group(1) for c in calls) == names
+        assert all(c.count(f"s32[{steps}]{{0}}") == 4 for c in calls)
         if bwd == "fused":
-            (limit,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"', calls[0])
+            (limit,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"', calls[0])
             assert int(limit) == fa.vmem_limit(plan.vmem_bytes) > 16 * 2 ** 20
 
 

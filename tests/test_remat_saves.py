@@ -294,6 +294,8 @@ def _residual_bytes(cfg, params, tokens, capsys):
     jax.ad_checkpoint.print_saved_residuals(loss, params)
     total = 0
     for line in capsys.readouterr().out.splitlines():
+        if line.endswith("from a constant"):
+            continue    # the flash kernels' walk (int32 tables): the program's, not a layer's
         dtype, dims = re.match(r"(\w+)\[([\d,]*)\]", line).groups()
         total += int(np.prod([int(d) for d in dims.split(",") if d])) * np.dtype(
             {"f32": "float32", "i32": "int32", "bf16": "uint16", "bool": "bool"}[dtype]).itemsize

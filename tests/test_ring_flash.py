@@ -280,7 +280,10 @@ def test_ring_hop_of_several_blocks_each_way(q_off, k_off, dtype, flash_bwd):
 
     bh, tq, tk, d, dv, scale = 2, 96, 150, 16, 8, 0.25
     plan = plan_flash(tq, tk, d, dtype, True, 32, 32, d_v=dv)
-    assert (plan.grid_steps, plan.k_len, plan.bwd) == (15, 150, flash_bwd)
+    assert (plan.tiles_total, plan.k_len, plan.bwd) == (15, 150, flash_bwd)
+    # at offsets 0 (all the plan can know): six live tiles and an entry for
+    # each of the two k blocks past every query; the hop's own offsets decide
+    assert (plan.tiles_run, plan.grid_steps) == (6, 8)
     rng = np.random.RandomState(13)
     mk = lambda t, w: jnp.asarray(rng.randn(bh, t, w), dtype)
     q3, do3, k3, v3 = mk(tq, d), mk(tq, dv), mk(tk, d), mk(tk, dv)
@@ -311,6 +314,49 @@ def test_ring_hop_of_several_blocks_each_way(q_off, k_off, dtype, flash_bwd):
         err = float(jnp.max(jnp.abs(g - w))) / max(1.0, float(jnp.max(jnp.abs(w))))
         assert err < bound, (name, err)
         assert bool(jnp.any(g)) == (k_off < 200), name
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_ring_hops_walk_a_list_built_at_run_time(shards, flash_bwd):
+    """T = 256 over a ring of 1, 2 and 4 with 32-wide blocks asked for, so
+    a hop's rectangle is 8 x 8, 4 x 4 or 2 x 2 tiles: the walk is built in
+    jnp from the hop's TRACED offsets (rectangle-long tables, the live
+    tiles first, an idle tail after them) and the diagonal hop, the hops
+    wholly in the past and the hops wholly in the future all go through
+    the one kernel. Value and gradients against full_attention."""
+    import re
+
+    from ps_pytorch_tpu.ops.flash_attention import plan_flash
+
+    mesh = make_seq_mesh(shards)
+    rng = np.random.RandomState(20 + shards)
+    mk = lambda: jnp.asarray(rng.randn(1, 256, 2, 16).astype(np.float32))
+    q, k, v = mk(), mk(), mk()
+
+    def ring_loss(q, k, v):
+        out = jax.shard_map(
+            lambda a, b, c: ring_flash_attention(a, b, c, SEQ_AXIS, True, None, 32, 32),
+            mesh=mesh, in_specs=(P(None, SEQ_AXIS),) * 3, out_specs=P(None, SEQ_AXIS),
+            check_vma=False,
+        )(q, k, v)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    def full_loss(q, k, v):
+        out = full_attention(q, k, v, causal=True)
+        return jnp.sum(out * jnp.cos(out)), out
+
+    grad = jax.jit(jax.value_and_grad(ring_loss, argnums=(0, 1, 2), has_aux=True))
+    (_, out), got = grad(q, k, v)
+    (_, want_out), want = jax.value_and_grad(full_loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(jax.device_get(out), jax.device_get(want_out), rtol=2e-5, atol=2e-5)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(jax.device_get(g), jax.device_get(w), rtol=5e-4, atol=5e-5)
+    # every kernel's grid is the hop's whole rectangle: what is not live is the tail
+    t_loc = 256 // shards
+    plan = plan_flash(t_loc, t_loc, 16, jnp.float32, True, 32, 32)
+    grids = set(re.findall(r"grid=\((\d+), (\d+)\)", str(jax.make_jaxpr(grad)(q, k, v))))
+    assert grids == {("2", str(plan.tiles_total))} and plan.tiles_total == (8 // shards) ** 2
+    assert plan.grid_steps == plan.tiles_run < plan.tiles_total  # with offsets known: no tail
 
 
 def test_sp_transformer_flash_matches_single_device(seq_mesh):
