@@ -632,6 +632,39 @@ def leg_lm(train_dir, devices, clog):
     return {"step_programs": programs}
 
 
+def family_leg(leg, config, workdir, devices, clog, kernels, over_sequence=False,
+               batch=None, seq=None):
+    """What every family's leg opens with. `cli.train_lm --lm-config` on
+    `config` (a published dict, written under `workdir`, or a file's path)
+    + LM_CONFIG_ARGS, the chips a data axis (`over_sequence`: a sequence
+    axis), ends on a finite loss with one step program compiled; the same
+    step through the library holds `kernels` as Mosaic calls and the
+    scopes. -> (step programs, cfg, the compiled step, its arguments)."""
+    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
+
+    path = config
+    if isinstance(config, dict):
+        path = os.path.join(workdir, f"{leg}_small.json")
+        with open(path, "w") as f:
+            json.dump(config, f)
+    n = len(devices)
+    num_dp, num_sp = (1, n) if over_sequence else (n, 1)
+    argv = ["--lm-config", path, "--num-dp", str(num_dp), "--num-sp", str(num_sp)] + LM_CONFIG_ARGS
+    if batch is not None:       # a flag given again overrides LM_CONFIG_ARGS'
+        argv += ["--batch-size", str(batch)]
+    if seq is not None:
+        argv += ["--seq-len", str(seq)]
+    out = train_lm_cli.main(argv)
+    check_finite(leg, "loss", out["loss"])
+    programs = clog.check_steps(leg, [step_program("worker_fn")])
+    batch = batch or int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--batch-size") + 1])
+    cfg, step, state = library_lm_step(path, num_dp, num_sp, batch, seq)
+    text = step.as_text()
+    check_kernels(leg, text, kernels)
+    check_scopes(leg, text, remat="--remat" in LM_CONFIG_ARGS)
+    return programs, cfg, step, state
+
+
 def leg_lm_config(workdir, devices, clog):
     """The second LM family through `cli.train_lm --lm-config`: the flash
     kernels at a 192-wide query beside a 128-wide value and the dropless
@@ -639,23 +672,9 @@ def leg_lm_config(workdir, devices, clog):
     and the routing counters must account for every token."""
     import jax
 
-    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
-
     leg = "lm_config"
-    path = os.path.join(workdir, "lm_config_small.json")
-    with open(path, "w") as f:
-        json.dump(LM_CONFIG, f)
-    out = train_lm_cli.main(
-        ["--lm-config", path, "--num-dp", "1", "--num-sp", str(len(devices))]
-        + LM_CONFIG_ARGS
-    )
-    check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, [step_program("worker_fn")])
-
-    batch = int(LM_CONFIG_ARGS[LM_CONFIG_ARGS.index("--batch-size") + 1])
-    cfg, step, (params, opt_state, tokens) = library_lm_step(path, 1, len(devices), batch)
-    check_kernels(leg, step.as_text(), LM_CONFIG_KERNELS)
-    check_scopes(leg, step.as_text(), remat="--remat" in LM_CONFIG_ARGS)
+    programs, cfg, step, (params, opt_state, tokens) = family_leg(
+        leg, LM_CONFIG, workdir, devices, clog, LM_CONFIG_KERNELS, over_sequence=True)
     params, opt_state, loss, counters = step(params, opt_state, tokens)
     check_finite(leg, "library step loss", jax.device_get(loss))
     c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
@@ -680,22 +699,13 @@ def leg_lm_ssm(workdir, devices, clog):
     import jax.numpy as jnp
     import numpy as np
 
-    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
     from ps_pytorch_tpu.ops import ssd
 
     leg = "lm_ssm"
-    path = os.path.join(workdir, "lm_ssm_small.json")
-    with open(path, "w") as f:
-        json.dump(LM_SSM_CONFIG, f)
-    out = train_lm_cli.main(
-        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
-        + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
-    check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, [step_program("worker_fn")])
-    _, step, _ = library_lm_step(path, len(devices), 1, 2 * len(devices))
-    check_kernels(leg, step.as_text(), LM_KERNELS)
-    check_scopes(leg, step.as_text(), remat="--remat" in LM_CONFIG_ARGS)
-    del step
+    programs, _, step, state = family_leg(
+        leg, LM_SSM_CONFIG, workdir, devices, clog, LM_KERNELS,
+        batch=2 * len(devices))  # two rows a chip
+    del step, state
 
     k = jax.random.split(jax.random.key(3), 6)
     t, h, p, n = 1024, 8, 64, 128
@@ -725,27 +735,17 @@ def leg_lm_kda(workdir, devices, clog):
     import jax.numpy as jnp
     import numpy as np
 
-    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
     from ps_pytorch_tpu.ops import kda
     from ps_pytorch_tpu.ops.pallas_mode import kernel_census
 
     leg = "lm_kda"
-    path = os.path.join(workdir, "lm_kda_small.json")
-    with open(path, "w") as f:
-        json.dump(LM_KDA_CONFIG, f)
-    out = train_lm_cli.main(
-        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
-        + LM_CONFIG_ARGS + ["--batch-size", str(2 * len(devices))])  # two rows a chip
-    check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, [step_program("worker_fn")])
+    programs, cfg, step, state = family_leg(
+        leg, LM_KDA_CONFIG, workdir, devices, clog, LM_KDA_KERNELS,
+        batch=2 * len(devices))  # two rows a chip
     # what the trainer's step holds: the chunk's own part as Mosaic kernels
     # (the system solved once a KDA layer, `remat` or not), no XLA twin
-    cfg, step, _ = library_lm_step(path, len(devices), 1, 2 * len(devices))
-    text = step.as_text()
-    del step
-    check_kernels(leg, text, LM_KDA_KERNELS)
-    check_scopes(leg, text, remat="--remat" in LM_CONFIG_ARGS)
-    census = kernel_census(text)
+    census = kernel_census(step.as_text())
+    del step, state
     if census["jnp"].get("ps_kda_within") or census["mosaic"]["ps_kda_inverse"] != len(cfg.kda_layers):
         raise AssertionError(
             f"{leg}: wanted ps_kda_inverse once a KDA layer ({len(cfg.kda_layers)}) and no "
@@ -786,24 +786,16 @@ def leg_lm_eva(devices, clog):
     import jax
     import jax.numpy as jnp
 
-    from ps_pytorch_tpu.cli import train_lm as train_lm_cli
     from ps_pytorch_tpu.ops import eva
     from ps_pytorch_tpu.ops.pallas_mode import kernel_census
 
     leg = "lm_eva"
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "benchmark", "configs", "evabyte_6b5_4layers.json")
-    seq, batch = 4096, len(devices)     # one row a chip
-    out = train_lm_cli.main(
-        ["--lm-config", path, "--num-dp", str(len(devices)), "--num-sp", "1"]
-        + LM_CONFIG_ARGS + ["--seq-len", str(seq), "--batch-size", str(batch)])
-    check_finite(leg, "loss", out["loss"])
-    programs = clog.check_steps(leg, [step_program("worker_fn")])
-    cfg, step, (params, opt_state, tokens) = library_lm_step(path, len(devices), 1, batch, seq)
-    text = step.as_text()
-    check_kernels(leg, text, LM_KERNELS)
-    check_scopes(leg, text, remat="--remat" in LM_CONFIG_ARGS)
-    census = kernel_census(text)["mosaic"]
+    seq = 4096
+    programs, cfg, step, (params, opt_state, tokens) = family_leg(
+        leg, path, None, devices, clog, LM_KERNELS, batch=len(devices), seq=seq)  # one row a chip
+    census = kernel_census(step.as_text())["mosaic"]
     passes = 2 * cfg.num_hidden_layers      # over the windows, over the summaries
     if census != {"ps_flash_fwd": passes, "ps_flash_dqkv": passes}:
         raise AssertionError(

@@ -27,17 +27,18 @@ data/datasets.make_synthetic.
 
 A published architecture is named by its config.json, not by --dim/--depth/
 --heads: `--lm-config <json>` (the published keys plus the chip's share,
-e.g. `model_type: deepseek_v3` with `experts_held`, or `model_type:
-granitemoehybrid` without routed experts; models/lm.load_lm_config builds
-the family, the same call the benchmark's driver makes). Such a model
-trains through --parallelism dp_sp; what it counts is logged at log steps
-and recorded as an instant: the expert layers' routing as `moe_route`, the
-state-space scan's cut-off chunks as `ssd_state` (after one `ssd_plan` at
-the start; that family runs --num-sp 1 only), the delta rule's as
-`kda_state` (after one `kda_plan`; `model_type: kimi_linear`, --num-sp 1
-only, and its expert layers' `moe_route` beside it), EVA attention's
-softmax mass on chunk summaries as `eva_state` (after one `eva_plan`, which
-holds the kernels' tiles; `model_type: evabyte`, --num-sp 1 only).
+e.g. `model_type: deepseek_v3` with `experts_held`; models/lm.load_lm_config
+builds the family's config, the same call the benchmark's driver makes).
+Such a model trains through --parallelism dp_sp. This file names no family:
+what a run's kernels will look like is logged once and recorded as the
+instants the family lists (models/lm.LMFamily.plans; `flash_plan` is the
+one most share), and what the family counts is logged at log steps and
+recorded as the instants it names for its groups of counters
+(LMFamily.states; the expert layers' routing is `moe_route`). A new
+published family costs its models/<family>.py (and an ops/ module where it
+has a mechanism of its own), ONE row in models/lm.py, its rows in
+obs/scopes.SCOPES, one leg in chip_smoke.py, its tests and its benchmark
+data files; not this file, not obs/schema.py, not another family's tests.
 
   ... --lm-config benchmark/configs/kanana2_30b_a3b_ep8.json --num-dp 1 \
       --num-sp 1 --seq-len 8192 --batch-size 2 --dtype bfloat16 --remat \
@@ -228,13 +229,11 @@ def main(argv=None) -> dict:
         args.vocab_size = cfg.vocab_size  # the corpus draws from the slice held
         args.depth, args.dim, args.heads = (
             cfg.num_hidden_layers, cfg.hidden_size, cfg.num_attention_heads)
-        d_qk, d_v = cfg.qk_head_dim, cfg.v_head_dim
     else:
         cfg = TransformerConfig(
             vocab_size=args.vocab_size, dim=args.dim, depth=args.depth,
             heads=args.heads, max_seq_len=args.seq_len, **run_opts,
         )
-        d_qk = d_v = cfg.head_dim
     if args.lr_schedule == "cosine":
         lr = optax.warmup_cosine_decay_schedule(
             init_value=0.0,
@@ -500,11 +499,11 @@ def main(argv=None) -> dict:
     seq_shards = num_sp if args.parallelism in ("dp_sp", "ep_sp") else 1
     path = attention_path(cfg, seq_shards)
     kept = ()  # one {name: bytes a layer} a kind of layer
+    family = lm_family(cfg)
     if cfg.remat and args.parallelism == "dp_sp" and path == "local":
         from ..models.transformer import remat_plan
 
-        kinds = lm_family(cfg).saved_layers(
-            cfg, args.batch_size // args.num_dp, args.seq_len)
+        kinds = family.saved_layers(cfg, args.batch_size // args.num_dp, args.seq_len)
         saves = remat_plan(kinds, params)
         kept = saves.kept
         logger.info(
@@ -525,56 +524,16 @@ def main(argv=None) -> dict:
             100.0 * plan["params_apart"] / plan["params"], plan["rows"])
         tr.instant("update_plan", **plan)
 
-    def remat_fields(kernels):
-        """The plan's share under one kernel family's names (`ps_flash_`,
-        `ps_kda_`)."""
-        names = {n: b for kind in kept for n, b in kind.items() if n.startswith(kernels)}
-        return dict(remat_saves=",".join(names), saved_bytes_per_layer=sum(names.values()))
-
-    if getattr(cfg, "eva_layers", 0):
-        # EVA attention runs the flash kernels twice a layer, over windows
-        # and over pooled keys: its own plan says both (models/eva_dense.py)
-        from ..models.eva_dense import eva_plan
-
-        plan = {**eva_plan(cfg, args.seq_len), "attention_impl": cfg.attention_impl,
-                **remat_fields("ps_eva_")}
-        logger.info("eva plan for T %d: %s (tiles per head)", args.seq_len, plan)
-        tr.instant("eva_plan", **plan)
-    elif cfg.attention_impl == "flash":
-        # the kernels' tile plan is static: how many tiles of the rectangle
-        # the grids never enter (tiles_total - grid_steps) is known here,
-        # from the shapes every attention call will have, and so is the
-        # path select_attention takes (the same function decides)
-        from ..ops.flash_attention import plan_flash
-
-        t_att = args.seq_len // seq_shards if path == "ring" else args.seq_len
-        plan = plan_flash(t_att, t_att, d_qk,
-                          cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
-        flash_plan = {f: getattr(plan, f) for f in (
-            "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total",
-            "bwd", "dq_acc_bytes")}
-        flash_plan.update(d_qk=d_qk, d_v=d_v, attention_path=path,
-                          seq_shards=seq_shards, **remat_fields("ps_flash_"))
-        logger.info(
-            "flash plan for T %d x D %d: %s (per head%s)", t_att,
-            d_qk, flash_plan,
-            "; ring hops build their walk from their offsets" if path == "ring" else "",
-        )
-        tr.instant("flash_plan", **flash_plan)
-    if getattr(cfg, "mamba_layers", 0):
-        # the state-space scan's shapes are static too
-        from ..models.ssm_hybrid import ssd_plan
-
-        plan = ssd_plan(cfg, args.seq_len)
-        logger.info("ssd plan for T %d: %s (per row)", args.seq_len, plan)
-        tr.instant("ssd_plan", **plan)
-    if getattr(cfg, "kda_layers", ()):
-        # and so are the delta rule's
-        from ..models.kda_hybrid import kda_plan
-
-        plan = {**kda_plan(cfg, args.seq_len), **remat_fields("ps_kda_")}
-        logger.info("kda plan for T %d: %s (per row)", args.seq_len, plan)
-        tr.instant("kda_plan", **plan)
+    # what every call of the family's kernels will look like, from the
+    # shapes alone (models/lm.LMFamily.plans), each with `remat`'s share
+    # under its kernels' names
+    for name, kernels, plan in family.plans(cfg, args.seq_len, seq_shards):
+        if kernels is not None:
+            names = {n: b for kind in kept for n, b in kind.items() if n.startswith(kernels)}
+            plan = {**plan, "remat_saves": ",".join(names),
+                    "saved_bytes_per_layer": sum(names.values())}
+        logger.info("%s for T %d: %s", name, args.seq_len, plan)
+        tr.instant(name, **plan)
 
     def save_lm_checkpoint(step_no):
         if args.train_dir is None:
@@ -686,54 +645,13 @@ def main(argv=None) -> dict:
                              jax.device_get(counters_box["last"]).items()}
                         record.update({k: v for k, v in c.items()
                                        if not k.endswith("_per_layer")})
-                    if "ssd_chunks_cut_off" in record:
-                        # the state-space layers' scan (models/ssm_hybrid.
-                        # ssd_counters): where a whole chunk's decay is
-                        # zero in float32, the carried state does no work
-                        logger.info(
-                            "SSD scan: %d (row, chunk, head) cut off from the "
-                            "chunk before, per layer %s", c["ssd_chunks_cut_off"],
-                            c["ssd_chunks_cut_off_per_layer"],
-                        )
-                        tr.instant("ssd_state", **{
-                            k[len("ssd_"):]: v for k, v in c.items()})
-                    if "kda_chunks_cut_off" in record:
-                        # the delta rule's chunks (models/kda_hybrid.
-                        # kda_counters): where even the slowest channel's
-                        # decay over a chunk is under 2^-24, the carried
-                        # state does no work
-                        logger.info(
-                            "KDA: %d (row, chunk, head) cut off from the "
-                            "chunk before, per layer %s", c["kda_chunks_cut_off"],
-                            c["kda_chunks_cut_off_per_layer"],
-                        )
-                        tr.instant("kda_state", **{
-                            k[len("kda_"):]: v for k, v in c.items()
-                            if k.startswith("kda_")})
-                    if "eva_remote_mass" in record:
-                        # EVA attention (models/eva_dense.eva_counters):
-                        # the share of its softmax a query past window 0
-                        # puts on chunk summaries
-                        logger.info(
-                            "EVA: %.4f of a far query's softmax on summaries, per layer %s",
-                            c["eva_remote_mass"], c["eva_remote_mass_per_layer"],
-                        )
-                        tr.instant("eva_state", **{
-                            k[len("eva_"):]: v for k, v in c.items()
-                            if k.startswith("eva_")})
-                    if "moe_rows_here" in record:
-                        # the expert layers' routing (parallel/moe.
-                        # routing_counters)
-                        logger.info(
-                            "MoE routing: %d rows here, fullest expert %d, "
-                            "emptiest %d, %d tokens with no expert here, "
-                            "max over mean %.3f", c["moe_rows_here"],
-                            c["moe_max_expert_rows"], c["moe_min_expert_rows"],
-                            c["moe_tokens_unserved"], c["moe_rows_max_over_mean"],
-                        )
-                        tr.instant("moe_route", **{
-                            k[len("moe_"):]: v for k, v in c.items()
-                            if k.startswith("moe_")})
+                        # and each group of them as the family's instant
+                        for name, prefix in family.states:
+                            state = {k[len(prefix):]: v for k, v in c.items()
+                                     if k.startswith(prefix)}
+                            if state:
+                                logger.info("%s: %s", name, state)
+                                tr.instant(name, **state)
                     if step_no == 1 and args.trace and scoped_step is not None:
                         # the census of the executable that just ran, once
                         # (obs/scopes.ScopedStep.scopes; the device is idle

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_EVA, MLP, scope
 from ..ops.eva import eva_attention, eva_saves, plan_eva
+from .lm import LMFamily
 from .mla_moe import _gated_mlp, _rms32
 from .transformer import remat_block
 
@@ -49,6 +50,11 @@ _PUBLISHED = (
     "intermediate_size", "window_size", "chunk_size", "num_pred_heads", "rope_theta",
     "rms_norm_eps",
 )
+# what from_published turns down, for models/lm.require_dense's message
+REFUSES = (
+    "an attention_class other than eva, a fixed num_chunks, rope scaling, grouped "
+    "key/value heads, a tied head, a chunk_size that does not divide window_size, a "
+    "sequence axis of more than one member")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,12 +124,8 @@ class EvaByteConfig:
 
     @property
     def eva_layers(self) -> int:
-        """Layers whose mixer is EVA attention: every one (cli/train_lm.py
-        records `eva_plan` for a config that has any)."""
+        """Layers whose mixer is EVA attention: every one."""
         return self.num_hidden_layers
-
-    # the widths cli/train_lm.py plans the flash kernels with
-    qk_head_dim = v_head_dim = head_dim
 
 
 def init_eva_dense(cfg: EvaByteConfig, key: jax.Array) -> Dict:
@@ -268,3 +270,19 @@ def eva_counters(aux) -> Dict:
     queries = jnp.maximum(aux["eva_mass_queries"], 1.0)
     return {"eva_remote_mass": jnp.sum(aux["eva_mass_sum"]) / jnp.sum(queries),
             "eva_remote_mass_per_layer": aux["eva_mass_sum"] / queries}
+
+
+def plans(cfg: EvaByteConfig, seq_len: int, seq_shards: int):
+    """EVA attention runs the flash kernels twice a layer, over windows and
+    over pooled keys: its own plan says both, in place of a `flash_plan`."""
+    del seq_shards  # one member only (apply_eva_dense)
+    return [("eva_plan", "ps_eva_",
+             {**eva_plan(cfg, seq_len), "attention_impl": cfg.attention_impl})]
+
+
+CONFIG = EvaByteConfig
+
+
+def family(cfg: EvaByteConfig) -> LMFamily:
+    return LMFamily(init_eva_dense, apply_eva_dense, eva_counters, saved_layers, plans,
+                    (("eva_state", "eva_"),))

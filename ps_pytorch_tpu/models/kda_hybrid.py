@@ -52,9 +52,10 @@ from jax.ad_checkpoint import checkpoint_name
 from ..obs.scopes import EMBED, HEAD_LOSS, MIXER_KDA, scope
 from ..ops.kda import KDA_OPERANDS, kda_chunked, kda_saves, l2_normalize
 from ..parallel.moe import DroplessSpec, routing_counters
+from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half, mla_mixer_half
 from .ssm_hybrid import _causal_conv
-from .transformer import flash_layers, remat_block, select_attention
+from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
 # config.json keys this family reads (`linear_attn_config` is a group);
 # every other key is carried by the benchmark's file and ignored here
@@ -66,6 +67,11 @@ _PUBLISHED = (
     "routed_scaling_factor", "moe_renormalize", "mla_use_nope", "rope_theta",
     "rms_norm_eps",
 )
+# what from_published turns down, for models/lm.require_dense's message
+REFUSES = (
+    "a router activation other than sigmoid, expert groups, query compression, rope "
+    "scaling, a tied head, next-token-prediction layers, a sequence axis of more than "
+    "one member")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -387,3 +393,19 @@ def kda_counters(aux) -> Dict:
         out.update(kda_chunks_cut_off=jnp.sum(aux["kda_cut_off"]),
                    kda_chunks_cut_off_per_layer=aux["kda_cut_off"])
     return out
+
+
+def plans(cfg: KdaHybridConfig, seq_len: int, seq_shards: int):
+    out = flash_plans(cfg, seq_len, seq_shards, cfg.qk_head_dim, cfg.v_head_dim)
+    if cfg.kda_layers:
+        out.append(("kda_plan", "ps_kda_", kda_plan(cfg, seq_len)))
+    return out
+
+
+CONFIG = KdaHybridConfig
+
+
+def family(cfg: KdaHybridConfig) -> LMFamily:
+    return LMFamily(init_kda_hybrid, apply_kda_hybrid,
+                    kda_counters if cfg.moe_layers or cfg.kda_layers else None,
+                    saved_layers, plans, (("kda_state", "kda_"), ("moe_route", "moe_")))

@@ -1,7 +1,7 @@
 """The LM families and the one protocol the training path needs of them.
 
-parallel/dp_sp.py (and cli/train_lm.py, the benchmark's driver) take a
-family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
+parallel/dp_sp.py, cli/train_lm.py and the benchmark's driver take a family
+from its CONFIG: `lm_family(cfg)`, and know nothing else of it. A family is
 
     init(cfg, key) -> params
     apply(cfg, params, tokens, seq_axis_name=None, pos_offset=None)
@@ -14,21 +14,34 @@ family's init and apply from its CONFIG: `lm_family(cfg)`. A family is
     saved_layers(cfg, batch, seq_len) -> [ops/flash_attention.SavedLayers]
                               what its blocks name for `remat`'s policy
                               (models/transformer.remat_block)
+    plans(cfg, seq_len, seq_shards) -> [(instant, kernels, fields)]
+                              what every call of its kernels will look like,
+                              from the shapes alone: cli/train_lm.py logs
+                              each once and records it as the instant of
+                              that name; `kernels` is the prefix under which
+                              `remat`'s kept names belong to the plan
+                              ("ps_flash_"; None where it names none), whose
+                              names and bytes a layer ride in the instant
+    states                    ((instant, prefix), ...): the groups of what
+                              `counters` returns. At a log step the counters
+                              whose key starts with `prefix` are one instant
+                              of that name, the prefix cut off
 
-`load_lm_config` builds a config from a published config.json-shaped dict
-by its `model_type` (`_PUBLISHED_FAMILIES`: deepseek_v3 -> models/mla_moe.py,
-granitemoehybrid -> models/ssm_hybrid.py, kimi_linear -> models/kda_hybrid.py,
-evabyte -> models/eva_dense.py);
-TransformerConfig is built from sizes as before.
+A published family is ONE module under models/, named by its row of
+`_PUBLISHED_FAMILIES`, that exports `CONFIG` (its config class, with
+`from_published`), `REFUSES` (what `from_published` turns down, for
+`require_dense`'s message) and `family(cfg)`. `load_lm_config` builds a
+config from a published config.json-shaped dict by its `model_type`;
+TransformerConfig (models/transformer.py) is built from sizes as before.
 """
 
 from __future__ import annotations
 
 import importlib
 import json
-from typing import Callable, Dict, NamedTuple, Optional, Union
+from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
-from .transformer import TransformerConfig, apply_transformer, init_transformer, saved_layers
+from . import transformer
 
 
 class LMFamily(NamedTuple):
@@ -36,96 +49,49 @@ class LMFamily(NamedTuple):
     apply: Callable
     counters: Optional[Callable]
     saved_layers: Callable
+    plans: Callable = lambda cfg, seq_len, seq_shards: []
+    states: Tuple[Tuple[str, str], ...] = ()
 
 
-def _apply_dense(cfg, params, tokens, seq_axis_name=None, pos_offset=None):
-    return apply_transformer(cfg, params, tokens, seq_axis_name, pos_offset), {}
-
-
-def _mla_moe_family(cfg) -> LMFamily:
-    from ..parallel.moe import routing_counters
-    from .mla_moe import apply_mla_moe, init_mla_moe, saved_layers
-
-    counters = (lambda aux: routing_counters(aux["counts"], aux["unserved"])) \
-        if cfg.moe_layers else None
-    return LMFamily(init_mla_moe, apply_mla_moe, counters, saved_layers)
-
-
-def _ssm_hybrid_family(cfg) -> LMFamily:
-    from .ssm_hybrid import apply_ssm_hybrid, init_ssm_hybrid, saved_layers, ssd_counters
-
-    return LMFamily(init_ssm_hybrid, apply_ssm_hybrid,
-                    ssd_counters if cfg.mamba_layers else None, saved_layers)
-
-
-def _kda_hybrid_family(cfg) -> LMFamily:
-    from .kda_hybrid import apply_kda_hybrid, init_kda_hybrid, kda_counters, saved_layers
-
-    return LMFamily(init_kda_hybrid, apply_kda_hybrid,
-                    kda_counters if cfg.moe_layers or cfg.kda_layers else None, saved_layers)
-
-
-def _eva_dense_family(cfg) -> LMFamily:
-    from .eva_dense import apply_eva_dense, eva_counters, init_eva_dense, saved_layers
-
-    return LMFamily(init_eva_dense, apply_eva_dense, eva_counters, saved_layers)
-
-
-class _Published(NamedTuple):
-    module: str          # under models/
-    config: str          # its config class
-    family: Callable     # the LMFamily of such a config
-    refuses: str         # what from_published turns down, for require_dense's message
-
-
-# The families built from a published config.json, by its `model_type`. The
-# ONE table: load_lm_config, lm_family and require_dense read it, and so do
-# their messages.
+# The families built from a published config.json: `model_type` -> the
+# module under models/ that holds it. The ONE table: load_lm_config,
+# lm_family and require_dense read it, and so do their messages.
 _PUBLISHED_FAMILIES = {
-    "deepseek_v3": _Published(
-        "mla_moe", "MlaMoeConfig", _mla_moe_family,
-        "query compression, rope scaling, grouped routing, a tied head"),
-    "granitemoehybrid": _Published(
-        "ssm_hybrid", "SsmHybridConfig", _ssm_hybrid_family,
-        "routed experts, a positional term, a sequence axis of more than one member"),
-    "kimi_linear": _Published(
-        "kda_hybrid", "KdaHybridConfig", _kda_hybrid_family,
-        "a router activation other than sigmoid, expert groups, query compression, rope "
-        "scaling, a tied head, next-token-prediction layers, a sequence axis of more than "
-        "one member"),
-    "evabyte": _Published(
-        "eva_dense", "EvaByteConfig", _eva_dense_family,
-        "an attention_class other than eva, a fixed num_chunks, rope scaling, grouped "
-        "key/value heads, a tied head, a chunk_size that does not divide window_size, a "
-        "sequence axis of more than one member"),
+    "deepseek_v3": "mla_moe",
+    "granitemoehybrid": "ssm_hybrid",
+    "kimi_linear": "kda_hybrid",
+    "evabyte": "eva_dense",
 }
 
 
-def _config_class(model_type: str):
-    entry = _PUBLISHED_FAMILIES[model_type]
-    return getattr(importlib.import_module(f".{entry.module}", __package__), entry.config)
+def _module(model_type: str):
+    return importlib.import_module(f".{_PUBLISHED_FAMILIES[model_type]}", __package__)
+
+
+def _modules():
+    """The dense family's module, then the published ones' in the table's
+    order, each imported as it is reached."""
+    yield transformer
+    yield from map(_module, _PUBLISHED_FAMILIES)
 
 
 def lm_family(cfg) -> LMFamily:
-    if isinstance(cfg, TransformerConfig):
-        return LMFamily(init_transformer, _apply_dense, None, saved_layers)
-    for model_type, entry in _PUBLISHED_FAMILIES.items():
-        if isinstance(cfg, _config_class(model_type)):
-            return entry.family(cfg)
-    raise TypeError(
-        f"no LM family for a {type(cfg).__name__} (has: TransformerConfig, "
-        + ", ".join(entry.config for entry in _PUBLISHED_FAMILIES.values()) + ")")
+    for module in _modules():
+        if isinstance(cfg, module.CONFIG):
+            return module.family(cfg)
+    raise TypeError(f"no LM family for a {type(cfg).__name__} (has: "
+                    + ", ".join(module.CONFIG.__name__ for module in _modules()) + ")")
 
 
 def require_dense(cfg, where: str) -> None:
     """The schemes that restate the dense block's math (ROADMAP D6) take
     TransformerConfig only."""
-    if not isinstance(cfg, TransformerConfig):
+    if not isinstance(cfg, transformer.TransformerConfig):
         raise NotImplementedError(
             f"{where} runs the dense TransformerConfig family only; a "
             f"{type(cfg).__name__} model trains through --parallelism dp_sp (ROADMAP D6), "
             "where each family refuses by name what it cannot express ("
-            + "; ".join(f"{kind}: {entry.refuses}" for kind, entry in _PUBLISHED_FAMILIES.items())
+            + "; ".join(f"{kind}: {_module(kind).REFUSES}" for kind in _PUBLISHED_FAMILIES)
             + ")")
 
 
@@ -140,4 +106,4 @@ def load_lm_config(published: Union[str, Dict], **run):
     if kind not in _PUBLISHED_FAMILIES:
         raise ValueError(f"model_type {kind!r} has no family here "
                          f"(has: {', '.join(_PUBLISHED_FAMILIES)})")
-    return _config_class(kind).from_published(published, **run)
+    return _module(kind).CONFIG.from_published(published, **run)

@@ -44,8 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_MLA, MLP, MOE, scope
-from ..parallel.moe import DroplessSpec, moe_dropless_local
-from .transformer import flash_layers, remat_block, select_attention
+from ..parallel.moe import DroplessSpec, moe_dropless_local, routing_counters
+from .lm import LMFamily
+from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -56,6 +57,8 @@ _PUBLISHED = (
     "n_shared_experts", "num_experts_per_tok", "first_k_dense_replace",
     "routed_scaling_factor", "norm_topk_prob", "rope_theta", "rms_norm_eps",
 )
+# what from_published turns down, for models/lm.require_dense's message
+REFUSES = "query compression, rope scaling, grouped routing, a tied head"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,3 +316,21 @@ def apply_mla_moe(
     routing = {"counts": jnp.stack(counts), "unserved": jnp.stack(unserved)} if counts else {}
     with scope(HEAD_LOSS):
         return n @ params["head"].astype(cd), routing
+
+
+def plans(cfg: MlaMoeConfig, seq_len: int, seq_shards: int):
+    return flash_plans(cfg, seq_len, seq_shards, cfg.qk_head_dim, cfg.v_head_dim)
+
+
+def moe_counters(aux) -> Dict:
+    return routing_counters(aux["counts"], aux["unserved"])
+
+
+CONFIG = MlaMoeConfig
+
+
+def family(cfg: MlaMoeConfig) -> LMFamily:
+    """models/lm.LMFamily of such a config: the expert layers' routing
+    (parallel/moe.routing_counters) is its one group of counters."""
+    return LMFamily(init_mla_moe, apply_mla_moe, moe_counters if cfg.moe_layers else None,
+                    saved_layers, plans, (("moe_route", "moe_"),))

@@ -46,9 +46,10 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_ATTENTION, MIXER_SSD, MLP, scope
-from ..ops.ssd import ssd_chunked
+from ..ops.ssd import SCAN_PATH, ssd_chunked
+from .lm import LMFamily
 from .mla_moe import _rms32
-from .transformer import flash_layers, remat_block, select_attention
+from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
 # config.json keys this family reads; every other key is carried by the
 # benchmark's file and ignored here
@@ -59,6 +60,8 @@ _PUBLISHED = (
     "mamba_d_conv", "mamba_chunk_size", "attention_multiplier",
     "embedding_multiplier", "residual_multiplier", "logits_scaling", "rms_norm_eps",
 )
+# what from_published turns down, for models/lm.require_dense's message
+REFUSES = "routed experts, a positional term, a sequence axis of more than one member"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,9 +145,6 @@ class SsmHybridConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
-
-    # the widths cli/train_lm.py plans the flash kernels with
-    qk_head_dim = v_head_dim = head_dim
 
     @property
     def d_inner(self) -> int:
@@ -312,9 +312,7 @@ def apply_ssm_hybrid(
 
 def ssd_plan(cfg: SsmHybridConfig, seq_len: int) -> Dict:
     """What every call of the scan will look like, from the shapes alone
-    (cli/train_lm.py logs it and records it as the `ssd_plan` instant)."""
-    from ..ops.ssd import SCAN_PATH
-
+    (the `ssd_plan` instant, through `plans`); `scan_path` is a constant."""
     return {"chunk": cfg.mamba_chunk_size, "n_chunks": -(-seq_len // cfg.mamba_chunk_size),
             "heads": cfg.mamba_n_heads, "d_head": cfg.mamba_d_head,
             "d_state": cfg.mamba_d_state, "groups": cfg.mamba_n_groups,
@@ -328,3 +326,18 @@ def ssd_counters(aux) -> Dict:
     mesh: `ssd_chunks_cut_off` over all state-space layers, and per layer."""
     return {"ssd_chunks_cut_off": jnp.sum(aux["ssd_cut_off"]),
             "ssd_chunks_cut_off_per_layer": aux["ssd_cut_off"]}
+
+
+def plans(cfg: SsmHybridConfig, seq_len: int, seq_shards: int):
+    out = flash_plans(cfg, seq_len, seq_shards, cfg.head_dim, cfg.head_dim)
+    if cfg.mamba_layers:
+        out.append(("ssd_plan", None, ssd_plan(cfg, seq_len)))
+    return out
+
+
+CONFIG = SsmHybridConfig
+
+
+def family(cfg: SsmHybridConfig) -> LMFamily:
+    return LMFamily(init_ssm_hybrid, apply_ssm_hybrid, ssd_counters if cfg.mamba_layers else None,
+                    saved_layers, plans, (("ssd_state", "ssd_"),))

@@ -198,6 +198,28 @@ def flash_layers(cfg, batch: int, seq_len: int, heads: int, d: int, d_v: int, la
                         cfg.causal, layers)]
 
 
+def flash_plans(cfg, seq_len: int, seq_shards: int, d_qk: int, d_v: int):
+    """The `flash_plan` entry of a family's plans (models/lm.LMFamily.plans)
+    under any family's `cfg`: the kernels' tile plan is static, so how many
+    tiles of the rectangle the grids never enter (tiles_total - grid_steps)
+    is known from the shapes every attention call will have (ops/
+    flash_attention.plan_flash says what each field means), and so is the
+    path select_attention takes (attention_path decides both; a ring's hops
+    attend a shard's length and build their walk from their offsets). []
+    where the flash kernels do not run."""
+    from ..ops.flash_attention import plan_flash
+
+    if cfg.attention_impl != "flash":
+        return []
+    path = attention_path(cfg, seq_shards)
+    t_att = seq_len // seq_shards if path == "ring" else seq_len
+    plan = plan_flash(t_att, t_att, d_qk, cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
+    fields = {f: getattr(plan, f) for f in (
+        "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total", "bwd", "dq_acc_bytes")}
+    fields.update(d_qk=d_qk, d_v=d_v, attention_path=path, seq_shards=seq_shards)
+    return [("flash_plan", "ps_flash_", fields)]
+
+
 def saved_layers(cfg: TransformerConfig, batch: int, seq_len: int):
     """What this family's blocks name for `remat` at tokens [batch,
     seq_len] (ops/flash_attention.SavedLayers, one a kind of layer): the
@@ -308,6 +330,26 @@ def apply_transformer(
     with scope(HEAD_LOSS):
         xf = _rms_norm(x.astype(cd), params["out_norm"].astype(cd))
         return xf @ params["embed"].T.astype(cd)
+
+
+def apply_dense(cfg, params, tokens, seq_axis_name=None, pos_offset=None):
+    """apply_transformer as models/lm.LMFamily.apply: nothing is counted."""
+    return apply_transformer(cfg, params, tokens, seq_axis_name, pos_offset), {}
+
+
+def plans(cfg: TransformerConfig, seq_len: int, seq_shards: int):
+    return flash_plans(cfg, seq_len, seq_shards, cfg.head_dim, cfg.head_dim)
+
+
+# what models/lm.py reads of a family's module; this one is built from
+# sizes, so it has no published config to refuse
+CONFIG = TransformerConfig
+
+
+def family(cfg: TransformerConfig):
+    from .lm import LMFamily  # models/lm.py imports this module
+
+    return LMFamily(init_transformer, apply_dense, None, saved_layers, plans)
 
 
 def make_sp_forward(

@@ -136,62 +136,13 @@ EVENT_KINDS: Dict[str, EventSpec] = {
         required=("name", "t", "dur"),
         int_fields=("depth", "step", "tick", "slot", "rid",
                     "new_tokens", "weights_step", "from_step", "to_step",
-                    "bytes", "block", "wall_ns", "err_ns",
-                    # instants of cli/train_lm.py: `flash_plan` (the
-                    # kernels' tiles and widths: `grid_steps` the steps a
-                    # head's grid WALKS, the `tiles_run` that do work and
-                    # a dead entry for each q or k block none of them
-                    # touches, of the rectangle's `tiles_total`, whose
-                    # other tiles are never entered: ops/flash_attention.
-                    # _walk; `seq_shards` and, a
-                    # string, the `attention_path` they run on:
-                    # models/transformer.attention_path; `bwd`, a string,
-                    # "fused" or "split", and the fused backward's
-                    # `dq_acc_bytes` in VMEM; what `remat` keeps
-                    # of a layer, `saved_bytes_per_layer` under the names in
-                    # the string `remat_saves`, both of ops/flash_attention.
-                    # plan_remat_saves and in `kda_plan` too for a
-                    # delta-rule layer's) and `moe_route` (the
-                    # dropless expert layers' rows, summed over layers;
-                    # `<name>_per_layer` lists ride along); for a family
-                    # with state-space layers `ssd_plan` (the scan's
-                    # shapes and, a string, its `scan_path`: models/
-                    # ssm_hybrid.ssd_plan) and `ssd_state` at log steps
-                    # (`chunks_cut_off`, with its `_per_layer` list); for
-                    # one with delta-rule layers `kda_plan` (the chunk,
-                    # the smallest block its scores and inverse are built
-                    # from, the padded length: models/kda_hybrid.kda_plan)
-                    # and `kda_state` at log steps (`chunks_cut_off` too);
-                    # for one with EVA attention `eva_plan` (windows and
-                    # summaries a row, both kernel passes' live tiles a
-                    # head and the remote pass's `remote_grid_steps` walked
-                    # of `remote_tiles_total`: models/eva_dense.eva_plan)
-                    # and `eva_state` at log
-                    # steps (`remote_mass`, a float, whole and per layer);
-                    # for a dp_sp run `update_plan` (for how many of the
-                    # parameters' `leaves` the update reads a materialised
-                    # gradient, `leaves_apart`, with their `params_apart`
-                    # of `params`, at `rows` a step and chip: parallel/
-                    # dp_sp.update_plan)
-                    "block_q", "block_k", "grid_steps", "tiles_run",
-                    "tiles_total", "d_qk", "d_v", "seq_shards",
-                    "dq_acc_bytes", "saved_bytes_per_layer", "rows_here",
-                    "max_expert_rows", "min_expert_rows", "tokens_unserved",
-                    "chunk", "n_chunks", "heads", "d_head", "d_state",
-                    "groups", "mamba_layers", "attention_layers",
-                    "chunks_cut_off", "sub_block", "padded_len", "kda_layers",
-                    "window", "windows", "summaries", "eva_layers",
-                    "tiles_local", "tiles_remote", "remote_block_q",
-                    "remote_block_k", "remote_grid_steps", "remote_tiles_total",
-                    "rows", "leaves", "leaves_apart", "params", "params_apart",
-                    # `step_scopes`, once after the first step of a
-                    # dp_sp run: the census of the compiled step (obs/
-                    # scopes.step_scopes_instant; `phases` and `scopes`
-                    # are comma-joined strings)
-                    "instructions", "mixed_instructions", "mosaic_calls"),
+                    "bytes", "block", "wall_ns", "err_ns"),
         doc="one traced host-side phase: t/dur are seconds on the "
             "stream header's monotonic clock; a clock_sync span pairs "
-            "that clock with the wall clock (wall_ns +- err_ns at t)",
+            "that clock with the wall clock (wall_ns +- err_ns at t). An "
+            "instant's other attributes (a plan's, a state's, a census's) "
+            "ride along as their emitter made them; the function that "
+            "returns them says what they mean",
     ),
     # ---- serving request lifecycle (ARCHITECTURE §7i): every submitted
     # request terminates in EXACTLY one of request_done | request_shed |

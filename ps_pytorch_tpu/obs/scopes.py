@@ -309,8 +309,9 @@ class ScopedStep:
 
 
 def step_scopes_instant(step: ScopedStep) -> dict:
-    """The `step_scopes` instant of cli/train_lm.py (obs/schema.py lists its
-    integer fields): the census in one record, and what reading it cost."""
+    """The `step_scopes` instant of cli/train_lm.py: the census in one
+    record (`instructions`, those under more than one scope, the Mosaic
+    calls; `phases` and `scopes` comma-joined), and what reading it cost."""
     t0 = time.perf_counter()
     census = step.scopes()
     rows = census["by_place"]

@@ -37,6 +37,10 @@ from jax import lax
 
 from ..obs.scopes import SCAN, scope
 
+# A constant, not a selection: there is one scan, in plain XLA, and nothing
+# branches on this. models/ssm_hybrid.ssd_plan reports it so that its
+# instant reads like kda_plan's, whose `scan_path` (ops/kda.scan_path) IS
+# chosen from the shapes.
 SCAN_PATH = "xla"
 # float32 exp(x) is 0 below this: a chunk whose whole log-decay is under it
 # hands nothing of the state it was given to the next chunk
