@@ -36,6 +36,13 @@ CLIs expose, on synthetic data made from a seed:
             4,096 bytes, two windows, so that the pass over chunk
             summaries runs; bf16, flash, remat, Adam; then ops/eva.
             eva_attention on the chip against its jnp twin
+  lm_swa    cli.train_lm --lm-config on a small preset of the sliding-
+            window / global grouped-query attention expert family (heads of
+            128, 9 and 6 query heads over 2 key/value heads, a window of
+            512 under a row of 2,048, YaRN on half a global head, a gate a
+            head; g s s s g; 8 of 16 routed experts held), bf16, flash,
+            remat, Adam; then flash_attention under the window on the chip
+            against its jnp twin
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -153,6 +160,30 @@ LM_KDA_CONFIG = {
     "linear_attn_config": {"kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
                            "num_heads": 4, "head_dim": 128,
                            "short_conv_kernel_size": 4},
+    "experts_held": 8, "expert_offset": 0,
+}
+# a small preset of the sliding-window / global grouped-query attention
+# expert family at the published head width, window and head ratios (128-wide
+# heads, 9 and 6 query heads a key/value head, a window of 512, YaRN on the
+# first half of a global head over 512 original positions)
+LM_SWA_CONFIG = {
+    "model_type": "laguna", "vocab_size": 1024, "hidden_size": 512, "intermediate_size": 1024,
+    "num_hidden_layers": 5, "num_attention_heads": 12, "num_key_value_heads": 2,
+    "head_dim": 128, "rms_norm_eps": 1e-6, "num_experts": 16, "num_experts_per_tok": 5,
+    "moe_intermediate_size": 256, "shared_expert_intermediate_size": 256,
+    "norm_topk_prob": True, "moe_routed_scaling_factor": 2.5, "sliding_window": 512,
+    "gating": "per-head", "tie_word_embeddings": False, "moe_router_logit_softcapping": 0,
+    "rope_parameters": {
+        "full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 16,
+                           "original_max_position_embeddings": 512, "beta_slow": 1,
+                           "beta_fast": 32, "attention_factor": 1.28,
+                           "partial_rotary_factor": 0.5},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                              "partial_rotary_factor": 1}},
+    "layer_types": ["full_attention", "sliding_attention", "sliding_attention",
+                    "sliding_attention", "full_attention"],
+    "num_attention_heads_per_layer": [12, 18, 18, 18, 12],
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"],
     "experts_held": 8, "expert_offset": 0,
 }
 LM_CONFIG_ARGS = [
@@ -824,6 +855,61 @@ def leg_lm_eva(devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_swa(workdir, devices, clog):
+    """The sixth LM family through `cli.train_lm --lm-config` (data parallel
+    over the chips: the ring does not know the window): the flash kernels
+    under both masks and the experts' grouped products must be Mosaic calls
+    in the compiled step, once a layer under `remat`, with the new scopes in
+    its census; the gate reads about a half. Then flash_attention under the
+    window by itself, compiled, against its jnp twin."""
+    import jax
+    import jax.numpy as jnp
+
+    from ps_pytorch_tpu.obs.hlo import census as read
+    from ps_pytorch_tpu.ops.flash_attention import SlidingWindow, flash_attention
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+    from ps_pytorch_tpu.parallel.ring_attention import full_attention
+
+    leg = "lm_swa"
+    programs, cfg, step, (params, opt_state, tokens) = family_leg(
+        leg, LM_SWA_CONFIG, workdir, devices, clog, LM_CONFIG_KERNELS,
+        batch=len(devices), seq=2048)  # one row a chip, four windows long
+    text = step.as_text()
+    census = kernel_census(text)["mosaic"]
+    layers = cfg.num_hidden_layers
+    if (census["ps_flash_fwd"], census["ps_flash_dqkv"]) != (layers, layers):
+        raise AssertionError(
+            f"{leg}: wanted ps_flash_fwd and ps_flash_dqkv once a layer ({layers}), the "
+            f"forward not run again under remat; census {census}")
+    scopes = {row["scope"] for row in read(text)["by_place"]}
+    want = {f"mixer/{m}{part}" for m in ("swa", "attention")
+            for part in ("", "/rope", "/gate", "/kv_repeat", "/flash")}
+    if not want <= scopes:
+        raise AssertionError(f"{leg}: the step's census lacks {sorted(want - scopes)}")
+    params, opt_state, loss, counters = step(params, opt_state, tokens)
+    check_finite(leg, "library step loss", jax.device_get(loss))
+    c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
+    if not (0.4 < c["attn_gate_open"] < 0.6 and c["moe_rows_here"] > 0):
+        raise AssertionError(f"{leg}: counters out of range: {c}")
+    print(f"[{leg}] counters: {c}", flush=True)
+    del step, params, opt_state
+
+    k = jax.random.split(jax.random.key(6), 3)
+    q, key, v = (jax.random.normal(kk, (1, 2048, 4, 128), jnp.bfloat16) for kk in k)
+    mask = SlidingWindow(cfg.sliding_window)
+    got = jax.jit(lambda *a: flash_attention(*a, causal=mask))(q, key, v)
+    with jnp_twins():
+        want_o = jax.jit(lambda *a: full_attention(*a, causal=mask))(
+            *(x.astype(jnp.float32) for x in (q, key, v)))
+    gap = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want_o)) / jnp.max(jnp.abs(want_o)))
+    if not gap < 0.02:      # bfloat16 operands and output, float32 sums: under 2% of the range
+        raise AssertionError(f"{leg}: the kernels are {gap:.4f} of the range off the jnp twin")
+    print(f"[{leg}] flash_attention under a window of {cfg.sliding_window}, kernels vs jnp "
+          f"twin: {gap:.5f} of the range", flush=True)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -948,6 +1034,7 @@ def main() -> int:
         run("lm_ssm", lambda clog: leg_lm_ssm(workdir, devices, clog))
         run("lm_kda", lambda clog: leg_lm_kda(workdir, devices, clog))
         run("lm_eva", lambda clog: leg_lm_eva(devices, clog))
+        run("lm_swa", lambda clog: leg_lm_swa(workdir, devices, clog))
 
     print(json.dumps({
         "versions": versions,

@@ -529,7 +529,8 @@ def main(argv=None) -> dict:
     # under its kernels' names
     for name, kernels, plan in family.plans(cfg, args.seq_len, seq_shards):
         if kernels is not None:
-            names = {n: b for kind in kept for n, b in kind.items() if n.startswith(kernels)}
+            names = {n: b for i, kind in enumerate(kept) if plan.get("layer_kind", i) == i
+                     for n, b in kind.items() if n.startswith(kernels)}
             plan = {**plan, "remat_saves": ",".join(names),
                     "saved_bytes_per_layer": sum(names.values())}
         logger.info("%s for T %d: %s", name, args.seq_len, plan)

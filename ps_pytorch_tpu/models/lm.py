@@ -22,6 +22,8 @@ from its CONFIG: `lm_family(cfg)`, and know nothing else of it. A family is
                               `remat`'s kept names belong to the plan
                               ("ps_flash_"; None where it names none), whose
                               names and bytes a layer ride in the instant
+                              (where the fields hold `layer_kind`, those of
+                              that entry of saved_layers alone)
     states                    ((instant, prefix), ...): the groups of what
                               `counters` returns. At a log step the counters
                               whose key starts with `prefix` are one instant
@@ -61,6 +63,7 @@ _PUBLISHED_FAMILIES = {
     "granitemoehybrid": "ssm_hybrid",
     "kimi_linear": "kda_hybrid",
     "evabyte": "eva_dense",
+    "laguna": "swa_moe",
 }
 
 

@@ -186,19 +186,21 @@ def select_attention(cfg: TransformerConfig, seq_axis_name: Optional[str] = None
     )
 
 
-def flash_layers(cfg, batch: int, seq_len: int, heads: int, d: int, d_v: int, layers: int):
+def flash_layers(cfg, batch: int, seq_len: int, heads: int, d: int, d_v: int, layers: int,
+                 causal=None):
     """[ops/flash_attention.flash_saves] of `layers` attention layers that
     attend with q, k [batch, seq_len, heads, d] and v [..., d_v] under any
-    family's `cfg`; [] where the flash kernels do not run."""
+    family's `cfg` and the mask kind `causal` (cfg.causal where None); []
+    where the flash kernels do not run."""
     from ..ops.flash_attention import flash_saves
 
     if cfg.attention_impl != "flash":
         return []
     return [flash_saves(batch, seq_len, heads, d, d_v, cfg.effective_compute_dtype,
-                        cfg.causal, layers)]
+                        cfg.causal if causal is None else causal, layers)]
 
 
-def flash_plans(cfg, seq_len: int, seq_shards: int, d_qk: int, d_v: int):
+def flash_plans(cfg, seq_len: int, seq_shards: int, d_qk: int, d_v: int, causal=None):
     """The `flash_plan` entry of a family's plans (models/lm.LMFamily.plans)
     under any family's `cfg`: the kernels' tile plan is static, so how many
     tiles of the rectangle the grids never enter (tiles_total - grid_steps)
@@ -206,17 +208,25 @@ def flash_plans(cfg, seq_len: int, seq_shards: int, d_qk: int, d_v: int):
     flash_attention.plan_flash says what each field means), and so is the
     path select_attention takes (attention_path decides both; a ring's hops
     attend a shard's length and build their walk from their offsets). []
-    where the flash kernels do not run."""
-    from ..ops.flash_attention import plan_flash
+    where the flash kernels do not run. A family whose layers differ in
+    mask gives each kind's as `causal` and gets `mask` (ops/flash_attention.
+    mask_name), `window` and `tile_fill` (mask_fill: the share of the live
+    tiles' entries the mask keeps) beside the fields every plan has."""
+    from ..ops.flash_attention import SlidingWindow, mask_fill, mask_name, plan_flash
 
     if cfg.attention_impl != "flash":
         return []
     path = attention_path(cfg, seq_shards)
     t_att = seq_len // seq_shards if path == "ring" else seq_len
-    plan = plan_flash(t_att, t_att, d_qk, cfg.effective_compute_dtype, cfg.causal, d_v=d_v)
+    own = causal is not None
+    causal = causal if own else cfg.causal
+    plan = plan_flash(t_att, t_att, d_qk, cfg.effective_compute_dtype, causal, d_v=d_v)
     fields = {f: getattr(plan, f) for f in (
         "block_q", "block_k", "grid_steps", "tiles_run", "tiles_total", "bwd", "dq_acc_bytes")}
     fields.update(d_qk=d_qk, d_v=d_v, attention_path=path, seq_shards=seq_shards)
+    if own:
+        fields.update(mask=mask_name(causal), tile_fill=mask_fill(plan, t_att, t_att, causal),
+                      window=causal.window if isinstance(causal, SlidingWindow) else 0)
     return [("flash_plan", "ps_flash_", fields)]
 
 

@@ -42,6 +42,7 @@ MIXER_MLA = "mixer/mla"
 MIXER_SSD = "mixer/ssd"
 MIXER_KDA = "mixer/kda"
 MIXER_EVA = "mixer/eva"
+MIXER_SWA = "mixer/swa"
 EVA_POOL = "pool"               # the four under MIXER_EVA (ops/eva.py)
 EVA_LOCAL = "local"
 EVA_REMOTE = "remote"
@@ -49,6 +50,9 @@ EVA_MERGE = "merge"
 DELTA_RULE = "delta_rule"       # under MIXER_KDA
 SCAN = "scan"                   # under MIXER_SSD
 FLASH = "flash"                 # under any mixer
+ROPE = "rope"                   # the three under MIXER_SWA / MIXER_ATTENTION
+ATTN_GATE = "gate"              # (models/swa_moe.py)
+KV_REPEAT = "kv_repeat"
 FFN = "ffn"
 MLP = "mlp"                     # under FFN
 MOE = "moe"                     # under FFN
@@ -80,7 +84,11 @@ SCOPES: Tuple[Tuple[str, str], ...] = (
     ("mixer/eva/local", "the causal ps_flash_* passes over the windows folded into the leading axis"),
     ("mixer/eva/remote", "the ps_flash_* passes of every query over the pooled keys of the windows before its own"),
     ("mixer/eva/merge", "the two passes' triples joined by (m, l) and normalized; delta; the two dq summed"),
+    ("mixer/swa", "the sliding-window half of a block: norm, q/k/v over grouped heads, W_o (models/swa_moe.py; its global layers are mixer/attention)"),
     ("mixer/*/flash", "ops/flash_attention.flash_attention: fold, pad, the ps_flash_* kernels, unfold"),
+    ("mixer/*/rope", "the rotation of q and k: plain, or YaRN frequencies on the leading part of a head"),
+    ("mixer/*/kv_repeat", "keys and values repeated to the query heads' width before the attention call"),
+    ("mixer/*/gate", "the sigmoid gate a head and token on the attention output, before W_o"),
     ("ffn", "the FFN half's own norm and residual"),
     ("ffn/mlp", "a dense (gated or GELU) MLP: a dense layer's, or the shared experts'"),
     ("ffn/moe", "parallel/moe.moe_dropless_local outside its four parts (the counters)"),

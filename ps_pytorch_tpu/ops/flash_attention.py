@@ -75,7 +75,11 @@ entry an output block and comes out as m = NEG_INF, l = 0, zero
 gradients). Where `causal` is an EarlierWindows the same two functions
 hold the second mask kind: keys of the windows before the query's own
 (ops/eva.py's pass over pooled keys, through the partial-triple API
-below).
+below); where it is a SlidingWindow, the third: the `window` latest keys
+up to the query's own, a band along the diagonal, so a tile is blank above
+the diagonal AND below the band and the walk holds the band's tiles only
+(31 of 256 at T 8,192, window 512, 512-wide tiles; flash_attention takes
+it where it takes True).
 
 Precision: p and ds are cast to the dtype of the operand they multiply,
 so bfloat16 inputs give the MXU bfloat16 operands in all seven products of
@@ -148,6 +152,39 @@ class EarlierWindows(NamedTuple):
     k_window: int
 
 
+class SlidingWindow(NamedTuple):
+    """A mask kind, given where `causal` is: the query at position i sees
+    the key at position j iff i - window < j <= i, its own position and the
+    `window` - 1 before it. flash_attention, plan_flash and the jnp twin
+    (parallel/ring_attention.full_attention) take it; a ring hop does not
+    (flash_partial refuses it by name: ROADMAP M5)."""
+
+    window: int
+
+
+def mask_name(causal) -> str:
+    """How a plan's instant spells a mask kind."""
+    if isinstance(causal, SlidingWindow):
+        return "sliding_window"
+    if isinstance(causal, EarlierWindows):
+        return "earlier_windows"
+    return "causal" if causal else "none"
+
+
+def dense_mask(causal, t_q: int, t_k: int):
+    """The [t_q, t_k] booleans a mask kind keeps, both offsets 0: what
+    _mask_scores computes tile by tile, for the jnp twins and the tests.
+    None where nothing is masked."""
+    if not causal:
+        return None
+    i, j = jnp.arange(t_q)[:, None], jnp.arange(t_k)[None, :]
+    if isinstance(causal, EarlierWindows):
+        return j // causal.k_window < i // causal.q_window
+    if isinstance(causal, SlidingWindow):
+        return (j <= i) & (j > i - causal.window)
+    return j <= i
+
+
 def _window_of(pos, window: int):
     """pos // window for a position (never negative): a Python int or a
     numpy array of them (the static walk), or traced int32 scalars and
@@ -177,6 +214,10 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
         q_row = q_off + qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (1, scores.shape[1]), 1)
         keep = (k_off + k_local) < _window_of(q_row, causal.q_window) * causal.k_window
+    elif isinstance(causal, SlidingWindow):
+        q_pos = q_off + qi * block_q + iota(1)
+        k_pos = k_off + k_local
+        keep = (k_pos <= q_pos) & (k_pos > q_pos - causal.window)
     elif causal:
         q_pos = q_off + qi * block_q + iota(1)
         keep = (k_off + k_local) <= q_pos
@@ -192,7 +233,9 @@ def _mask_scores(scores, qi, ki, block_q, block_k, causal, k_len,
 def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
     """False where _mask_scores would blank every score of tile (qi, ki):
     its first key lies past its last query (causal), or in no window
-    before its last query's (EarlierWindows), or past k_len. None
+    before its last query's (EarlierWindows), or past its last query or
+    its last key a window or more before its first query (SlidingWindow),
+    or past k_len. None
     when no tile can be blank. Plain arithmetic on the positions
     _mask_scores uses, so it serves ints, numpy grids (the static walk
     and plan_flash's counts) and traced offsets (a ring hop's walk) alike."""
@@ -200,6 +243,10 @@ def _tile_live(qi, ki, block_q, block_k, causal, k_len, q_off=0, k_off=0):
     if isinstance(causal, EarlierWindows):
         live = (_window_of(k_off + ki * block_k, causal.k_window)
                 < _window_of(q_off + qi * block_q + (block_q - 1), causal.q_window))
+    elif isinstance(causal, SlidingWindow):
+        q_first, k_first = q_off + qi * block_q, k_off + ki * block_k
+        live = ((k_first <= q_first + (block_q - 1))
+                & (k_first + (block_k - 1) > q_first - causal.window))
     elif causal:
         live = k_off + ki * block_k <= q_off + qi * block_q + (block_q - 1)
     if k_len is not None:
@@ -797,6 +844,32 @@ def _fit_block(t: int, cap: int) -> int:
     return b
 
 
+# What a grid step costs beside its tile's entries, in entries. Read on the
+# chip at [72, 8192, 128] under a window of 512 (PERF.md section 6, PR 45):
+# forward and backward together take 4.21 us a step at 512 x 512 tiles and
+# 1.92 us at 256 x 256, so a step costs 1.15 us beside 11.7 ps an entry.
+STEP_ENTRIES = 3 * 2 ** 15
+
+
+def _band_blocks(t_q: int, t_k: int, bq: int, bk: int, causal: SlidingWindow):
+    """The blocks for a band: from the square plan_flash would take, halved
+    together down to 128, those whose live tiles cost least at tile entries
+    plus STEP_ENTRIES a step. A band fills a tile as wide as its window by
+    half, so smaller tiles waste fewer entries and walk more steps; at
+    T 8,192 and window 512 the 512-wide tiles win (31 steps against 93 of a
+    quarter the entries: 9.4 against 12.8 ms a layer of 72 heads on the
+    chip), at window 128 the 256-wide ones."""
+    best = None
+    while True:
+        live = _live_tiles(-(-t_q // bq), -(-t_k // bk), bq, bk, causal, None)
+        cost = int(live.sum()) * (bq * bk + STEP_ENTRIES)
+        if best is None or cost < best[0]:
+            best = (cost, bq, bk)
+        if min(bq, bk) <= 128:
+            return best[1:]
+        bq, bk = bq // 2, bk // 2
+
+
 def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
                block_q: Optional[int] = None,
                block_k: Optional[int] = None,
@@ -822,6 +895,8 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
         return min(_floor_pow2(want), max(8, _ceil_pow2(t)))
 
     bq, bk = block(t_q, block_q), block(t_k, block_k)
+    if isinstance(causal, SlidingWindow) and block_q is None and block_k is None:
+        bq, bk = _band_blocks(t_q, t_k, bq, bk, causal)
     tq_pad, tk_pad = -(-t_q // bq) * bq, -(-t_k // bk) * bk
     k_len = t_k if tk_pad != t_k else None
     live = _live_tiles(tq_pad // bq, tk_pad // bk, bq, bk, causal, k_len)
@@ -831,6 +906,23 @@ def plan_flash(t_q: int, t_k: int, d: int, dtype, causal: bool,
         grid_steps=int(_kept(live).sum()), tiles_run=int(live.sum()),
         vmem_bytes=vmem_bytes, bwd=bwd, dq_acc_bytes=dq_acc_bytes,
     )
+
+
+def mask_fill(plan: FlashPlan, t_q: int, t_k: int, causal) -> float:
+    """The share of the live tiles' score entries that the mask keeps (both
+    offsets 0): 1 where nothing is masked, about a half under `causal` at a
+    few tiles a side and under a SlidingWindow as wide as its tiles. What
+    the tiles' shape wastes, for the `flash_plan` instant."""
+    i = np.arange(t_q, dtype=np.int64)
+    if isinstance(causal, EarlierWindows):
+        kept = np.minimum(i // causal.q_window * causal.k_window, t_k)
+    elif isinstance(causal, SlidingWindow):
+        kept = np.maximum(np.minimum(i, t_k - 1) - np.maximum(i - causal.window + 1, 0) + 1, 0)
+    elif causal:
+        kept = np.minimum(i + 1, t_k)
+    else:
+        kept = np.full_like(i, t_k)
+    return float(kept.sum()) / max(plan.tiles_run * plan.block_q * plan.block_k, 1)
 
 
 # ------------------------------------------------------ what `remat` keeps
@@ -1000,8 +1092,8 @@ def flash_attention(
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
     q3 = _pad_t(fold(q), plan.tq_pad)
     k3, v3 = _pad_t(fold(k), plan.tk_pad), _pad_t(fold(v), plan.tk_pad)
-    o3 = _flash(q3, k3, v3, float(scale), bool(causal), plan.block_q,
-                plan.block_k, plan.k_len)
+    mask = causal if isinstance(causal, SlidingWindow) else bool(causal)
+    o3 = _flash(q3, k3, v3, float(scale), mask, plan.block_q, plan.block_k, plan.k_len)
     o3 = o3[:, :t]
     return o3.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
@@ -1009,6 +1101,14 @@ def flash_attention(
 # ------------------------------------------- ring-hop partial-triple API
 # (consumed by parallel/ring_attention.ring_flash_attention: flash WITHIN
 # each ring hop, so a sequence shard never materializes [T_loc, T_loc])
+
+
+def _no_band(causal, where: str) -> None:
+    if isinstance(causal, SlidingWindow):
+        raise NotImplementedError(
+            f"{where}: a SlidingWindow over a ring's hops is not built (a hop a window or "
+            "more behind the queries would be sent for nothing: ROADMAP M5); "
+            "flash_attention takes it on one chip")
 
 
 def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
@@ -1026,6 +1126,7 @@ def flash_partial(q3, k3, v3, scale, causal, q_off, k_off,
     Shard lengths need not be block multiples: like flash_attention, odd
     lengths are padded up to the block grid (padded keys masked via
     k_len, padded query rows sliced off) so tiles stay MXU-shaped."""
+    _no_band(causal, "flash_partial")
     tq, tk = q3.shape[1], k3.shape[1]
     plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k,
                       d_v=v3.shape[2])
@@ -1048,6 +1149,7 @@ def flash_grads_partial(q3, k3, v3, do3, lse, delta, scale, causal,
     accumulation never rounds per hop, even under bf16 inputs). Odd shard
     lengths pad-and-mask exactly like flash_partial (padded q rows carry
     zero do/delta, so they contribute nothing to dk/dv)."""
+    _no_band(causal, "flash_grads_partial")
     tq, tk = q3.shape[1], k3.shape[1]
     plan = plan_flash(tq, tk, q3.shape[2], q3.dtype, causal, block_q, block_k,
                       d_v=v3.shape[2])

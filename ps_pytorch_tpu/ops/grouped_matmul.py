@@ -43,6 +43,13 @@ from .pallas_mode import COMPILED, INTERPRET, pallas_mode
 
 TILE_M = 256      # rows a tile: padding is half a tile an expert on average
 TILE_N_GRAD = 256  # columns of a weight-gradient block (float32 in VMEM)
+# ps_moe_gmm keeps one expert's whole matrix resident, double-buffered beside
+# a row tile in and out. Up to here that fits the 16 MiB a v5e kernel gets
+# by default with room for Mosaic's own temporaries (12.2 MiB at 2304 x 1024,
+# the widest of the accepted cells); past it (16 MiB at 3072 x 1024) the call
+# asks for what it holds plus that room.
+GMM_VMEM_DEFAULT = 14 * 2 ** 20
+GMM_VMEM_ROOM = 8 * 2 ** 20
 
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -85,6 +92,12 @@ def group_layout(counts: jax.Array, rows: int, tile_m: int = TILE_M) -> GroupLay
 # ---------------------------------------------------------------- kernels
 
 
+def gmm_vmem_bytes(tile_m: int, k: int, n: int, itemsize: int) -> int:
+    """What a grid step of ps_moe_gmm holds: a row tile in, one expert's
+    matrix and a row tile out, each double-buffered by the pipeline."""
+    return 2 * (tile_m * k + k * n + tile_m * n) * itemsize
+
+
 def _gmm(x, w, layout: GroupLayout, tile_m: int, transpose_rhs: bool, mode: dict):
     """x [M, K] @ w[e] ([E, K, N], or [E, N, K] with transpose_rhs) -> [M, N]
     in x's dtype, float32 accumulation; one row tile a grid step, the whole
@@ -104,6 +117,9 @@ def _gmm(x, w, layout: GroupLayout, tile_m: int, transpose_rhs: bool, mode: dict
             ).astype(o_ref.dtype)
 
     last = lambda i, nl: jnp.minimum(i, nl[0] - 1)
+    held = gmm_vmem_bytes(tile_m, k, n, x.dtype.itemsize)
+    limit = {} if held <= GMM_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=held + GMM_VMEM_ROOM)}
     return pl.pallas_call(
         kernel,
         name="ps_moe_gmm",
@@ -118,7 +134,7 @@ def _gmm(x, w, layout: GroupLayout, tile_m: int, transpose_rhs: bool, mode: dict
             out_specs=pl.BlockSpec((tile_m, n), lambda i, te, nl: (last(i, nl), 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        **mode,
+        **limit, **mode,
     )(layout.tile_expert, layout.n_live, x, w)
 
 

@@ -457,7 +457,9 @@ def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """Single-device reference: exact softmax attention, [B, T, H, D]."""
+    """Single-device reference: exact softmax attention, [B, T, H, D].
+    `causal` is False | True | an ops/flash_attention.SlidingWindow (the
+    mask kinds flash_attention takes, whose jnp twin this is)."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -466,7 +468,12 @@ def full_attention(
         jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
         * scale
     )
-    if causal:
+    if isinstance(causal, tuple):  # a mask kind of ops/flash_attention.py
+        from ..ops.flash_attention import dense_mask
+
+        mask = dense_mask(causal, q.shape[1], k.shape[1])
+        scores = jnp.where(mask[None, None], scores, _NEG_BIG)
+    elif causal:
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
         scores = jnp.where(mask[None, None], scores, _NEG_BIG)

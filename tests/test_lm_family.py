@@ -26,6 +26,7 @@ from .test_attention_path import MLA
 from .test_evabyte_family import PUBLISHED as EVA
 from .test_kda_hybrid import PUBLISHED as KDA
 from .test_ssm_hybrid import PUBLISHED as HYBRID
+from .test_swa_moe import PUBLISHED as SWA
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,7 +128,7 @@ def test_the_messages_and_the_loaders_read_the_row(toy):
         load_lm_config({**toy, "tied_head": True})
     with pytest.raises(ValueError, match=r"\(has: " + ", ".join(lm._PUBLISHED_FAMILIES) + r"\)"):
         load_lm_config({"model_type": "llama"})
-    with pytest.raises(TypeError, match="EvaByteConfig, ToyConfig"):
+    with pytest.raises(TypeError, match="SwaMoeConfig, ToyConfig"):
         lm_family(object())
     with pytest.raises(NotImplementedError, match="a ToyConfig model.*toy: a tied_head"):
         lm.require_dense(cfg, "tensor parallelism")
@@ -144,6 +145,9 @@ FAMILIES = {
     "kimi_linear": (KDA, ["flash_plan", "kda_plan"], ["kda_plan"],
                     (("kda_state", "kda_"), ("moe_route", "moe_"))),
     "evabyte": (EVA, ["eva_plan"], ["eva_plan"], (("eva_state", "eva_"),)),
+    # one plan a kind of attention layer: the sliding layers', the global ones'
+    "laguna": (SWA, ["flash_plan", "flash_plan"], [],
+               (("moe_route", "moe_"), ("attn_state", "attn_"))),
 }
 
 

@@ -123,6 +123,77 @@ def test_grouped_products_compile_at_the_published_widths(shape, k, n):
     assert _mosaic_calls(jax.jit(bwd).lower(x, w, layout, dy).compile()) == 2
 
 
+@pytest.mark.parametrize("bh, mask, steps, read", [
+    (72, fa.SlidingWindow(512), 31, True), (48, True, 136, False)], ids=["sliding", "global"])
+def test_the_band_kernels_compile_at_the_cells_shapes_and_the_metric_tells_them_apart(
+        shape, bh, mask, steps, read):
+    """ps_flash_fwd and the fused ps_flash_dqkv under SlidingWindow(512) at
+    [72, 8192, 128], the sliding layers of the laguna cell: the walk rides as
+    four tables of 31 entries (the band's tiles of the rectangle's 256), and
+    `swa_flash_ms` / `swa_flash_roofline` go by the 72 that leads the largest
+    result in an op's short name: they take these kernels and leave the
+    global layers' (48 heads, causal) to `flash_ms`."""
+    import json
+    import os
+    import re
+
+    from benchmark.reducers.trace import short_name
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    patterns = set()
+    for metric in ("swa_flash_ms", "swa_flash_roofline"):
+        with open(os.path.join(root, "benchmark", "layer_metrics", f"{metric}.json")) as f:
+            patterns.add(json.load(f)["args"]["pattern"])
+    (pattern,) = patterns
+    t, d = 8192, 128
+    plan = fa.plan_flash(t, t, d, jnp.bfloat16, mask)
+    assert (plan.block_q, plan.block_k, plan.grid_steps, plan.bwd) == (512, 512, steps, "fused")
+    q, row = shape((bh, t, d)), shape((bh, t), jnp.float32)
+
+    def fwd(q, k, v):
+        return fa._flash_fwd(q, k, v, d ** -0.5, mask, plan.block_q, plan.block_k, {})
+
+    def bwd(q, k, v, do, lse, delta):
+        return fa._flash_bwd(q, k, v, lse, delta, do, d ** -0.5, mask, plan.block_q,
+                             plan.block_k, {})
+
+    compiled = {"ps_flash_fwd": jax.jit(fwd).lower(q, q, q).compile(),
+                "ps_flash_dqkv": jax.jit(bwd).lower(q, q, q, q, row, row).compile()}
+    for name, program in compiled.items():
+        (call,) = _mosaic_lines(program)
+        assert call.count(f"s32[{steps}]{{0}}") == 4
+        short = short_name(call.strip().removeprefix("ROOT "))
+        assert short.startswith(name) and (re.search(pattern, short) is not None) == read, short
+
+
+@pytest.mark.parametrize("k, n, own_limit", [(3072, 1024, True), (1024, 3072, True),
+                                             (2304, 1024, False)],
+                         ids=["laguna_gate_up", "laguna_down", "kimi_gate_up"])
+def test_the_grouped_products_take_their_own_vmem_limit_past_the_default(shape, k, n, own_limit):
+    """ps_moe_gmm keeps one expert's matrix resident: at 3072 x 1024 (the
+    laguna cell's experts, 8 held, a worst-case buffer of 10 x 8,192 rows)
+    that passes the 16 MiB a kernel gets by default, so the call states its
+    own limit; at the accepted cells' widths it states none, as before."""
+    experts, tm = 8, gm.TILE_M
+    m = gm.buffer_rows(10 * 8192, experts, tm)
+    layout = gm.GroupLayout(shape((m // tm,), jnp.int32), shape((1,), jnp.int32),
+                            shape((experts,), jnp.int32), shape((experts,), jnp.int32))
+    assert (gm.gmm_vmem_bytes(tm, k, n, 2) > gm.GMM_VMEM_DEFAULT) == own_limit
+    assert gm.gmm_vmem_bytes(tm, 2048, 768, 2) < gm.GMM_VMEM_DEFAULT     # the kanana cell's
+    x, w, dy = shape((m, k)), shape((experts, k, n), jnp.float32), shape((m, n))
+
+    def fwd(x, w, lay):
+        return gm._grouped(x, w, lay, tm, False)
+
+    def bwd(x, w, lay, dy):
+        return jax.vjp(partial(fwd, lay=lay), x, w)[1](dy)
+
+    (call,) = _mosaic_lines(jax.jit(fwd).lower(x, w, layout).compile())
+    assert ('"scoped_memory_configs":[{' in call) == own_limit
+    assert ('"scoped_memory_configs":[]' in call) != own_limit
+    assert _mosaic_calls(jax.jit(bwd).lower(x, w, layout, dy).compile()) == 2
+
+
 # ------------------------------------------------ the delta rule's kernels
 
 
