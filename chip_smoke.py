@@ -399,6 +399,61 @@ def library_lm_step(config_path, num_dp, num_sp, batch, seq=None):
     return cfg, step, (params, opt_state, tokens)
 
 
+def check_passes(leg, cfg, params, tokens):
+    """The dropless layer's passes on the chip (parallel/moe.py): the
+    family's loss and gradient norm, bfloat16 under `remat`, with a pass a
+    quarter of the worst case beside the same call at the worst-case size
+    (one pass, whatever the routing), as the weights route and with every
+    token sent to experts held here. The small presets hold half their
+    experts, so the layer's own pass IS the worst case and the step above
+    ran one pass a layer; a quarter takes several."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+    import optax
+
+    from ps_pytorch_tpu.models.lm import lm_family
+    from ps_pytorch_tpu.ops.grouped_matmul import TILE_M, buffer_rows
+    from ps_pytorch_tpu.ops.metrics import next_token_nll
+    from ps_pytorch_tpu.parallel import moe
+
+    family, spec = lm_family(cfg), cfg.routing
+    params = jax.device_get(params)
+    tokens = np.asarray(jax.device_get(tokens))[:2]
+    worst = buffer_rows(tokens.size * spec.top_k, spec.experts_held)
+    quarter = worst // 4 // TILE_M * TILE_M
+    chosen = np.arange(spec.num_experts) - spec.expert_offset
+    bias = np.where((chosen >= 0) & (chosen < spec.top_k), 10.0, 0.0).astype(np.float32)
+    forced = {**params, "blocks": [{**blk, "router_bias": bias} if "router_bias" in blk else blk
+                                   for blk in params["blocks"]]}
+
+    def run(p, rows):
+        def loss_fn(p):
+            logits, aux = family.apply(cfg, p, tokens)
+            return next_token_nll(logits, tokens), family.counters(aux)
+
+        with mock.patch.object(moe, "pass_rows", lambda n, spec: rows):
+            (loss, c), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(p)
+        return float(loss), float(optax.global_norm(grads)), jax.device_get(c)
+
+    for name, p in (("as the weights route", params), ("every token to experts held here", forced)):
+        loss, norm, c = run(p, quarter)
+        want_loss, want_norm, want_c = run(p, worst)
+        passes = c["moe_passes_per_layer"].tolist()
+        print(f"[{leg}] passes of {quarter} rows of {worst}, {name}: moe_passes_per_layer "
+              f"{passes}, rows here {c['moe_rows_here_per_layer'].tolist()}, loss {loss:.6f} | "
+              f"{want_loss:.6f} in one pass, gradient norm {norm:.6f} | {want_norm:.6f}", flush=True)
+        # the first expert layer sees the same input either way; behind it a
+        # bfloat16 rounding may tip a near-tie in the routing
+        same = all(c[k][0] == want_c[k][0]
+                   for k in ("moe_rows_here_per_layer", "moe_tokens_unserved_per_layer"))
+        if (min(passes) < 2 or set(want_c["moe_passes_per_layer"].tolist()) != {1} or not same
+                or abs(loss - want_loss) > 5e-3 * abs(want_loss)
+                or abs(norm - want_norm) > 5e-2 * want_norm):
+            raise AssertionError(f"{leg}: the layer in passes is not the layer in one ({name})")
+
+
 # ------------------------------------------------------------------ legs
 
 
@@ -716,6 +771,7 @@ def leg_lm_config(workdir, devices, clog):
             and c["moe_min_expert_rows"] <= c["moe_max_expert_rows"]):
         raise AssertionError(f"{leg}: routing counters out of range: {c}")
     print(f"[{leg}] routing: {c}", flush=True)
+    check_passes(leg, cfg, params, tokens)
     check_on_all_devices(leg, "params", params, devices)
     check_memory_in_use(leg, devices)
     return {"step_programs": programs}
@@ -776,6 +832,7 @@ def leg_lm_kda(workdir, devices, clog):
     # what the trainer's step holds: the chunk's own part as Mosaic kernels
     # (the system solved once a KDA layer, `remat` or not), no XLA twin
     census = kernel_census(step.as_text())
+    check_passes(leg, cfg, state[0], state[2])
     del step, state
     if census["jnp"].get("ps_kda_within") or census["mosaic"]["ps_kda_inverse"] != len(cfg.kda_layers):
         raise AssertionError(
@@ -892,6 +949,7 @@ def leg_lm_swa(workdir, devices, clog):
     if not (0.4 < c["attn_gate_open"] < 0.6 and c["moe_rows_here"] > 0):
         raise AssertionError(f"{leg}: counters out of range: {c}")
     print(f"[{leg}] counters: {c}", flush=True)
+    check_passes(leg, cfg, params, tokens)
     del step, params, opt_state
 
     k = jax.random.split(jax.random.key(6), 3)

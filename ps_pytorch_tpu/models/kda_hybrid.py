@@ -51,7 +51,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.scopes import EMBED, HEAD_LOSS, MIXER_KDA, scope
 from ..ops.kda import KDA_OPERANDS, kda_chunked, kda_saves, l2_normalize
-from ..parallel.moe import DroplessSpec, routing_counters
+from ..parallel.moe import DroplessSpec, routing_counters, stack_layers
 from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half, mla_mixer_half
 from .ssm_hybrid import _causal_conv
@@ -336,20 +336,19 @@ def apply_kda_hybrid(
         mixer, ffn = remat_block(mixer, kinds, params), remat_block(ffn, kinds, params)
     with scope(EMBED):
         x = params["embed"][tokens].astype(cd)
-    counts, unserved, cut_off = [], [], []
+    routed, cut_off = [], []
     for blk in params["blocks"]:
         x, cut = mixer(x, blk)
-        x, c, u = ffn(x, blk)
+        x, stats = ffn(x, blk)
         if "mlp" not in blk:
-            counts.append(c)
-            unserved.append(u)
+            routed.append(stats)
         if "a_log" in blk:
             cut_off.append(cut)
     with scope(HEAD_LOSS):
         n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
     aux = {}
-    if counts:
-        aux.update(counts=jnp.stack(counts), unserved=jnp.stack(unserved))
+    if routed:
+        aux.update(stack_layers(routed))
     if cut_off:
         aux["kda_cut_off"] = jnp.stack(cut_off)
     with scope(HEAD_LOSS):
@@ -388,7 +387,7 @@ def kda_counters(aux) -> Dict:
     layer."""
     out = {}
     if "counts" in aux:
-        out.update(routing_counters(aux["counts"], aux["unserved"]))
+        out.update(routing_counters(aux))
     if "kda_cut_off" in aux:
         out.update(kda_chunks_cut_off=jnp.sum(aux["kda_cut_off"]),
                    kda_chunks_cut_off_per_layer=aux["kda_cut_off"])

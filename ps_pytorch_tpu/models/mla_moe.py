@@ -44,7 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_MLA, MLP, MOE, scope
-from ..parallel.moe import DroplessSpec, moe_dropless_local, routing_counters
+from ..parallel.moe import (DroplessSpec, moe_dropless_local, no_routing, routing_counters,
+                            stack_layers)
 from .lm import LMFamily
 from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
@@ -244,28 +245,29 @@ def mla_mixer_half(cfg, x, blk, attend, pos):
 
 
 def ffn_half(cfg, x, blk):
-    """x [B, T, D] in the compute dtype -> (x + FFN(norm(x)), counts int32
-    [held], unserved int32): the dense MLP where the block holds `mlp`
-    (zero counters), else the routed experts held here plus the shared
-    expert. The half every block of this family and of models/kda_hybrid.py
-    ends in, whatever its mixer."""
+    """x [B, T, D] in the compute dtype -> (x + FFN(norm(x)), the layer's
+    routing counters as parallel/moe.moe_dropless_local gives them): the
+    dense MLP where the block holds `mlp` (zero counters), else the routed
+    experts held here plus the shared expert. The half every block of this
+    family, of models/kda_hybrid.py and of models/swa_moe.py ends in,
+    whatever its mixer."""
     cd = x.dtype
     with scope(FFN):
         n32 = _rms32(x, blk["ln2"], cfg.rms_norm_eps)
         if "mlp" in blk:
             with scope(MLP):
                 y = x + _gated_mlp(n32.astype(cd), blk["mlp"], cd)
-            return y, jnp.zeros((cfg.experts_held,), jnp.int32), jnp.int32(0)
+            return y, no_routing(cfg.experts_held)
         with scope(MOE):
-            routed, counts, unserved = moe_dropless_local(n32, blk, cfg.routing, cd)
+            routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd)
         y = x + routed.astype(cd)
         with scope(MLP):
-            return y + _gated_mlp(n32.astype(cd), blk["shared"], cd), counts, unserved
+            return y + _gated_mlp(n32.astype(cd), blk["shared"], cd), stats
 
 
 def mla_moe_block(cfg: MlaMoeConfig, x, blk, attend, pos):
-    """One block -> (x, counts int32 [held], unserved int32); the counters
-    are zeros for a dense layer."""
+    """One block -> (x, the layer's routing counters); the counters are
+    zeros for a dense layer."""
     x = mla_mixer_half(cfg, x.astype(cfg.effective_compute_dtype), blk, attend, pos)
     return ffn_half(cfg, x, blk)
 
@@ -287,9 +289,11 @@ def apply_mla_moe(
     """Forward -> (logits [B, T_local, vocab], routing): routing["counts"]
     int32 [expert layers, held] are the rows each expert held here got from
     these tokens, routing["unserved"] int32 [expert layers] the tokens none
-    of whose experts is held. Under shard_map pass seq_axis_name, as for
-    apply_transformer: attention runs over the axis and the rotary angles
-    take GLOBAL positions."""
+    of whose experts is held, routing["passes"] and ["buffer_rows"] the
+    passes each layer ran and the rows a pass holds (parallel/moe.
+    moe_dropless_local's counters, stacked). Under shard_map pass
+    seq_axis_name, as for apply_transformer: attention runs over the axis
+    and the rotary angles take GLOBAL positions."""
     b, t_loc = tokens.shape
     shard = jax.lax.axis_index(seq_axis_name) * t_loc if seq_axis_name is not None else 0
     if pos_offset is not None:
@@ -305,15 +309,14 @@ def apply_mla_moe(
         block = remat_block(block, saved_layers(cfg, b, t_loc), params)
     with scope(EMBED):
         x = params["embed"][tokens].astype(cd)
-    counts, unserved = [], []
+    routed = []
     for blk in params["blocks"]:
-        x, c, u = block(x, blk)
+        x, stats = block(x, blk)
         if "mlp" not in blk:
-            counts.append(c)
-            unserved.append(u)
+            routed.append(stats)
     with scope(HEAD_LOSS):
         n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
-    routing = {"counts": jnp.stack(counts), "unserved": jnp.stack(unserved)} if counts else {}
+    routing = stack_layers(routed) if routed else {}
     with scope(HEAD_LOSS):
         return n @ params["head"].astype(cd), routing
 
@@ -323,7 +326,7 @@ def plans(cfg: MlaMoeConfig, seq_len: int, seq_shards: int):
 
 
 def moe_counters(aux) -> Dict:
-    return routing_counters(aux["counts"], aux["unserved"])
+    return routing_counters(aux)
 
 
 CONFIG = MlaMoeConfig

@@ -62,7 +62,7 @@ import numpy as np
 from ..obs.scopes import (ATTN_GATE, EMBED, HEAD_LOSS, KV_REPEAT, MIXER_ATTENTION, MIXER_SWA,
                           ROPE, scope)
 from ..ops.flash_attention import SlidingWindow
-from ..parallel.moe import DroplessSpec, routing_counters
+from ..parallel.moe import DroplessSpec, routing_counters, stack_layers
 from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half
 from .transformer import flash_layers, flash_plans, remat_block, select_attention
@@ -321,9 +321,9 @@ def gqa_attention(cfg: SwaMoeConfig, n, blk, attend, pos, rope: Rope):
 
 
 def swa_moe_block(cfg: SwaMoeConfig, kind: str, x, blk, attend, pos):
-    """One block of the layer kind `kind` -> (x, the gate's sum, counts
-    int32 [held], unserved int32); the routing counters are zeros for a
-    dense layer."""
+    """One block of the layer kind `kind` -> (x, the layer's routing
+    counters, the gate's sum); the routing counters are zeros for a dense
+    layer."""
     cd = cfg.effective_compute_dtype
     with scope(MIXER_SWA if kind == SLIDING else MIXER_ATTENTION):
         x = x.astype(cd)
@@ -378,19 +378,18 @@ def apply_swa_moe(
     blocks = {kind: block_of(kind) for kind in attends}
     with scope(EMBED):
         x = params["embed"][tokens].astype(cd)
-    counts, unserved, opened = [], [], []
+    routed, opened = [], []
     for kind, blk in zip(cfg.layer_types, params["blocks"]):
-        x, c, u, g = blocks[kind](x, blk)
+        x, stats, g = blocks[kind](x, blk)
         opened.append(g)
         if "mlp" not in blk:
-            counts.append(c)
-            unserved.append(u)
+            routed.append(stats)
     with scope(HEAD_LOSS):
         n = _rms32(x, params["out_norm"], cfg.rms_norm_eps).astype(cd)
     entries = jnp.asarray([b * t * h for h in cfg.num_attention_heads_per_layer], jnp.float32)
     aux = {"attn_gate_sum": jnp.stack(opened), "attn_gate_count": entries}
-    if counts:
-        aux.update(counts=jnp.stack(counts), unserved=jnp.stack(unserved))
+    if routed:
+        aux.update(stack_layers(routed))
     with scope(HEAD_LOSS):
         return n @ params["head"].astype(cd), aux
 
@@ -404,7 +403,7 @@ def swa_counters(aux) -> Dict:
     out = {"attn_gate_open": jnp.sum(aux["attn_gate_sum"]) / jnp.sum(aux["attn_gate_count"]),
            "attn_gate_open_per_layer": aux["attn_gate_sum"] / aux["attn_gate_count"]}
     if "counts" in aux:
-        out.update(routing_counters(aux["counts"], aux["unserved"]))
+        out.update(routing_counters(aux))
     return out
 
 

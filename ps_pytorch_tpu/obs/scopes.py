@@ -93,7 +93,7 @@ SCOPES: Tuple[Tuple[str, str], ...] = (
     ("ffn/mlp", "a dense (gated or GELU) MLP: a dense layer's, or the shared experts'"),
     ("ffn/moe", "parallel/moe.moe_dropless_local outside its four parts (the counters)"),
     ("ffn/moe/route", "the float32 router: scores, top-k, weights"),
-    ("ffn/moe/dispatch", "rows by expert: sorts, masks, the gather into the worst-case buffer"),
+    ("ffn/moe/dispatch", "rows by expert: sorts, masks, a pass's gather into its buffer"),
     ("ffn/moe/experts", "the grouped products ps_moe_gmm / ps_moe_tgmm and the gate between them"),
     ("ffn/moe/combine", "the rows' weights and the gather back to tokens"),
     ("head_loss", "final norm, head, log_softmax, the loss"),

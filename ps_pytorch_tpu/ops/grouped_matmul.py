@@ -2,9 +2,11 @@
 
 An expert layer that drops no token sorts its (token, expert) assignments
 by expert and multiplies each expert's rows by that expert's weights. The
-row buffer is sized for the worst case (every assignment routed here), so
-most of it is empty on a chip that holds a share of the experts; what the
-products cost has to follow the rows that are really there.
+rows are laid out for the worst case (every assignment routed here), so
+most of that layout is empty on a chip that holds a share of the experts;
+what the products cost has to follow the rows that are really there. The
+caller (parallel/moe.py) walks the layout in passes of a buffer sized to
+the load: `layout_pass` gives one pass's part of it as a layout of its own.
 
 Layout (`GroupLayout`, built by `group_layout` from the per-expert counts):
 each expert's rows start on a row-tile boundary, so a `tile_m`-row tile
@@ -13,10 +15,13 @@ the tiles that hold rows; both ride in SMEM (scalar prefetch). A tile past
 `n_live` does no work and moves no data: its block indices are those of the
 last live tile, so the pipeline fetches and writes nothing new (the same
 idea as flash_attention._tile_live, decided from SMEM at run time). Every
-expert owns at least one tile, also with no rows, so `n_live >= 1` and the
-weight-gradient kernel zeroes every expert's block. The caller keeps
-rows that hold no assignment ZERO (parallel/moe.py does); rows past the
-last live tile are never written and hold garbage that nothing may read.
+expert owns at least one tile, also with no rows, so `n_live >= 1`. The
+weight-gradient kernel zeroes the block of every expert that owns a live
+tile and leaves the others' unwritten: a whole layout names every expert,
+one pass of it only some, so `grouped_matmul`'s backward reads the kernel's
+result through `experts_live`. The caller keeps rows that hold no
+assignment ZERO (parallel/moe.py does); rows past the last live tile are
+never written and hold garbage that nothing may read.
 
 Three products make a layer's forward and backward:
 
@@ -87,6 +92,29 @@ def group_layout(counts: jax.Array, rows: int, tile_m: int = TILE_M) -> GroupLay
     return GroupLayout(tile_expert=tile_expert, n_live=ends[-1:].astype(jnp.int32),
                        starts=((ends - tiles) * tile_m).astype(jnp.int32),
                        sizes=(tiles * tile_m).astype(jnp.int32))
+
+
+def layout_pass(layout: GroupLayout, p, rows: int, tile_m: int = TILE_M) -> GroupLayout:
+    """Rows p * rows .. (p + 1) * rows - 1 of `layout` as the layout of a
+    [rows, ...] buffer of their own (p may be traced; `layout.tile_expert`
+    reaches to the end of that pass): its tiles' experts, the live tiles
+    among them (at least one: a pass past the last live tile is not to be
+    run) and each expert's rows INSIDE the pass, none for most. An expert
+    whose rows straddle the boundary has a part in either pass."""
+    tiles = rows // tile_m
+    ends = jnp.clip(layout.starts + layout.sizes - p * rows, 0, rows)
+    starts = jnp.clip(layout.starts - p * rows, 0, rows)
+    return GroupLayout(
+        tile_expert=jax.lax.dynamic_slice(layout.tile_expert, (p * tiles,), (tiles,)),
+        n_live=jnp.clip(layout.n_live - p * tiles, 1, tiles).astype(jnp.int32),
+        starts=starts.astype(jnp.int32), sizes=(ends - starts).astype(jnp.int32))
+
+
+def experts_live(layout: GroupLayout, num_experts: int) -> jax.Array:
+    """bool [E]: the experts that own a live tile of `layout`."""
+    tile = jnp.arange(layout.tile_expert.shape[0], dtype=jnp.int32)
+    owns = layout.tile_expert[None] == jnp.arange(num_experts, dtype=jnp.int32)[:, None]
+    return jnp.any(owns & (tile < layout.n_live[0])[None], axis=1)
 
 
 # ---------------------------------------------------------------- kernels
@@ -209,7 +237,9 @@ def _grouped_bwd(tile_m, interpret, res, dy):
     x, w, layout = res
     mode = _mode(interpret)
     dx = _gmm(dy, w.astype(dy.dtype), layout, tile_m, True, mode)
-    dw = _tgmm(x, dy, layout, tile_m, w.shape[0], mode).astype(w.dtype)
+    # the kernel leaves the block of an expert with no live tile unwritten
+    dw = jnp.where(experts_live(layout, w.shape[0])[:, None, None],
+                   _tgmm(x, dy, layout, tile_m, w.shape[0], mode), 0).astype(w.dtype)
     return dx, dw, None
 
 

@@ -23,9 +23,10 @@ collectives:
 Beside that capacity layer stands the DROPLESS layer of the sigmoid-routed
 top-k-of-many families (`DroplessSpec`, `moe_dropless_local`): no
 capacity, no token dropped at any imbalance, the (token, expert)
-assignments sorted by expert into a buffer sized for the worst case and
-multiplied by ops/grouped_matmul.py, whose cost follows the rows really
-routed here. The layer is TOLD which experts it holds (`experts_held`,
+assignments sorted by expert, laid out for the worst case and walked in
+passes of a buffer sized to twice the uniform load (`pass_rows`), as many
+as the rows really routed here need, each multiplied by
+ops/grouped_matmul.py. The layer is TOLD which experts it holds (`experts_held`,
 `expert_offset`): it routes over all of them and computes the part of the
 result its own experts give, which is what one chip of an expert-parallel
 deployment does. With axis_name=None it runs without its exchange; nothing
@@ -40,7 +41,8 @@ all_to_all, which is exact under this convention).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Sequence, TYPE_CHECKING
+from functools import partial
+from typing import Dict, NamedTuple, Optional, Sequence, TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +52,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.scopes import MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTE, scope
+from ..ops.grouped_matmul import (TILE_M, GroupLayout, buffer_rows, group_layout,
+                                   grouped_matmul, layout_pass)
 from ..ops.metrics import next_token_nll
 from .tp import opt_state_specs
 
@@ -282,9 +286,10 @@ def dropless_route(n32, router, router_bias, spec: DroplessSpec):
 
 # The dropless layer moves rows three ways, each a gather whose transpose
 # is written as the inverse gather (a scatter of 98,304 rows is what the
-# device is worst at). `route` = (row_assign, row_live, pos, held): row r
-# of the buffer holds assignment row_assign[r] = n * k + j when
-# row_live[r]; assignment (n, j) lies in row pos[n, j] when held[n, j].
+# device is worst at). `route` = (row_assign, row_live, pos, held) of ONE
+# PASS (`_pass_route`): row r of the pass's buffer holds assignment
+# row_assign[r] = n * k + j when row_live[r]; assignment (n, j) lies in row
+# pos[n, j] of it when held[n, j] (held HERE and in THIS pass).
 
 
 def _gather_rows(v, route, per_token: int):
@@ -293,7 +298,9 @@ def _gather_rows(v, route, per_token: int):
 
 
 def _gather_assignments(v, route):
-    pos, held = route[2], route[3]
+    """[M, ...] -> [k, N, ...]: k leads, so a [N, D] slab keeps whole tiles
+    (as [N, k, D] the chip pads k = 10 to 16 and relays 480 MiB out as 768)."""
+    pos, held = route[2].T, route[3].T
     return jnp.where(held[..., None], v[pos], 0)
 
 
@@ -306,7 +313,7 @@ def _rows_from_tokens(x, route):
 @jax.custom_vjp
 def _tokens_from_rows(y, route):
     """[M, D] -> [N, D]: each token the sum of its held assignments' rows."""
-    return jnp.sum(_gather_assignments(y, route), axis=1)
+    return jnp.sum(_gather_assignments(y, route), axis=0)
 
 
 @jax.custom_vjp
@@ -323,66 +330,89 @@ _tokens_from_rows.defvjp(
     lambda route, g: (_rows_from_tokens(g, route), None))
 _rows_from_assignments.defvjp(
     lambda w, route: (_rows_from_assignments(w, route), route),
-    lambda route, g: (_gather_assignments(g[:, None], route)[..., 0], None))
+    lambda route, g: (_gather_assignments(g[:, None], route)[..., 0].T, None))
+
+
+def pass_rows(n: int, spec: DroplessSpec) -> int:
+    """Rows of the buffer one pass of the dropless layer fills, from n
+    tokens: twice what uniform routing sends to the experts held here (and
+    a tile an expert), never more than the worst case, which is one pass."""
+    uniform = -(-n * spec.top_k * spec.experts_held // spec.num_experts)
+    return min(buffer_rows(2 * uniform, spec.experts_held),
+               buffer_rows(n * spec.top_k, spec.experts_held))
 
 
 def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
-                       axis_name: Optional[str] = None):
+                       axis_name: Optional[str] = None, rows: Optional[int] = None):
     """The routed experts' part of a dropless layer on local rows.
 
     n32 [B, T, D]: the float32 normed hidden. blk: "router" [D, E_all],
     "router_bias" [E_all], "experts": {"w_gate", "w_up" [held, D, F],
     "w_down" [held, F, D]} (gated SiLU experts). Returns (y [B, T, D] in
-    compute_dtype, counts int32 [held], unserved int32): the weighted sum
-    over the chosen experts HELD HERE, the rows each of them got, and the
-    tokens none of whose experts is held (they get zeros: the caller adds
-    what every chip computes alike, such as a shared expert).
+    compute_dtype, stats): the weighted sum over the chosen experts HELD
+    HERE, and int32 counters of this call: "counts" [held], the rows each
+    of them got; "unserved", the tokens none of whose experts is held (they
+    get zeros: the caller adds what every chip computes alike, such as a
+    shared expert); "passes" and "buffer_rows", below.
 
-    Every assignment held here gets a row: the buffer holds the worst case,
-    ops/grouped_matmul skips what is empty. axis_name is the expert axis of
-    a deployment whose exchange this repo does not build yet: only None
-    (this chip's share, no exchange) is accepted."""
+    Every assignment held here gets a row. The rows are laid out for the
+    worst case and WALKED IN PASSES of a `rows`-row buffer (`pass_rows`:
+    twice the uniform load; any multiple of a tile can be asked for, and
+    the worst case is the layer in one pass whatever the routing), as many
+    as the live tiles need, counted on the device: one compiled body, so a
+    layer past its buffer pays for one more pass and nothing else.
+    ops/grouped_matmul skips what is empty inside a pass. axis_name is the
+    expert axis of a deployment whose exchange this repo does not build
+    yet: only None (this chip's share, no exchange) is accepted."""
     if axis_name is not None:
         raise NotImplementedError(
             "the dropless layer runs one chip's share without its exchange; "
             "an all_to_all over an expert axis is not built (ROADMAP M3)")
-    from ..ops.grouped_matmul import grouped_matmul
-
     b, t, d = n32.shape
     n = b * t
+    rows = pass_rows(n, spec) if rows is None else rows
     x32 = n32.reshape(n, d)
     with scope(MOE_ROUTE):
         idx, w = dropless_route(x32, blk["router"], blk["router_bias"], spec)
     with scope(MOE_DISPATCH):
-        route, counts, layout = _dispatch_plan(idx, spec, n)
-        xs = _rows_from_tokens(x32.astype(compute_dtype), route)
-    ex = blk["experts"]
-    with scope(MOE_EXPERTS):
-        gate = grouped_matmul(xs, ex["w_gate"], layout)
-        up = grouped_matmul(xs, ex["w_up"], layout)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, ex["w_down"], layout)
-    with scope(MOE_COMBINE):
-        # weighted on the row side, so no [N, k, D] tensor exists in either pass
-        ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
-        y = _tokens_from_rows(ys, route)
-    unserved = jnp.sum(~jnp.any(route[3], axis=-1), dtype=jnp.int32)
-    return y.reshape(b, t, d), counts, unserved
+        plan = _dispatch_plan(idx, spec, n, rows)
+    y = _routed(x32.astype(compute_dtype), w, blk["experts"], plan, rows)
+    stats = {"counts": plan.counts,
+             "unserved": jnp.sum(~jnp.any(plan.held, axis=-1), dtype=jnp.int32),
+             "passes": _passes(plan, rows), "buffer_rows": jnp.int32(rows)}
+    return y.reshape(b, t, d), stats
 
 
-def _dispatch_plan(idx, spec: DroplessSpec, n: int):
-    """(route, counts, layout) from the chosen experts idx [N, k]: which
-    buffer row holds which assignment (`route`, see above), the rows each
-    held expert got, and the grouped products' layout."""
-    from ..ops.grouped_matmul import TILE_M, buffer_rows, group_layout
+def no_routing(held: int) -> Dict:
+    """moe_dropless_local's counters for a layer that routes nothing."""
+    return {"counts": jnp.zeros((held,), jnp.int32), "unserved": jnp.int32(0),
+            "passes": jnp.int32(0), "buffer_rows": jnp.int32(0)}
 
+
+class _Plan(NamedTuple):
+    """Where the N x k assignments lie in the worst case's rows: sorted by
+    expert, each expert on tile boundaries, live rows packed from row 0.
+    Nothing here is sized by the worst case but `layout.tile_expert`."""
+
+    counts: jax.Array   # int32 [held]: the rows each held expert got
+    first: jax.Array    # int32 [held]: its first slot in `order`
+    order: jax.Array    # int32 [N * k]: sorted slot -> assignment n * k + j
+    pos: jax.Array      # int32 [N, k]: an assignment's row, where `held`
+    held: jax.Array     # bool [N, k]: its expert lives here
+    layout: GroupLayout
+
+
+def _dispatch_plan(idx, spec: DroplessSpec, n: int, rows: int) -> _Plan:
+    """The plan from the chosen experts idx [N, k]; `layout.tile_expert`
+    reaches to the end of the last `rows`-row pass the worst case needs."""
     k, held_n = spec.top_k, spec.experts_held
     local = idx - spec.expert_offset
     held = (local >= 0) & (local < held_n)                       # [N, k]
     key = jnp.where(held, local, held_n).reshape(-1)             # [A]
     counts = jnp.sum(key[None] == jnp.arange(held_n, dtype=jnp.int32)[:, None],
                      axis=1, dtype=jnp.int32)                    # [held]
-    rows = buffer_rows(n * k, held_n, TILE_M)
-    layout = group_layout(counts, rows, TILE_M)
+    worst = buffer_rows(n * k, held_n, TILE_M)
+    layout = group_layout(counts, -(-worst // rows) * rows, TILE_M)
     # one stable sort by expert orders the assignments; both maps are read
     # off it: `order` (sorted slot -> assignment) and its inverse `slot`
     a = jnp.arange(n * k, dtype=jnp.int32)
@@ -393,24 +423,98 @@ def _dispatch_plan(idx, spec: DroplessSpec, n: int):
     # expert's assignments
     e_a = jnp.minimum(key, held_n - 1)
     pos = jnp.where(held.reshape(-1), layout.starts[e_a] + slot - first[e_a], 0).reshape(n, k)
-    # a row's assignment: the sorted slot of the same rank
-    r = jnp.arange(rows, dtype=jnp.int32)
-    e_r = layout.tile_expert[r // TILE_M]
-    in_e = r - layout.starts[e_r]
-    row_live = (in_e < counts[e_r]) & (r // TILE_M < layout.n_live[0])
-    row_assign = jnp.where(row_live, order[jnp.minimum(first[e_r] + in_e, n * k - 1)], 0)
-    return (row_assign, row_live, pos, held), counts, layout
+    return _Plan(counts, first, order, pos, held, layout)
 
 
-def routing_counters(counts, unserved):
-    """The step's routing counters from per-layer per-expert rows
-    counts [L, held] and unserved [L] (already summed over the mesh):
-    rows_here, max_expert_rows, min_expert_rows and tokens_unserved, each
-    summed over layers under `moe_<name>` and per
-    layer under `moe_<name>_per_layer`; and `moe_rows_max_over_mean`: the
-    fullest expert's rows over the mean expert's, layers summed."""
+def _passes(plan: _Plan, rows: int):
+    """int32: the passes of `rows` rows that hold the live tiles; at least 1."""
+    return -(-plan.layout.n_live[0] * TILE_M // rows)
+
+
+def _pass_route(plan: _Plan, p, rows: int):
+    """`route` (see above) of pass p, the layout's rows p * rows .. (p + 1)
+    * rows - 1: a row's assignment is the sorted slot of the same rank."""
+    lay = plan.layout
+    r = p * rows + jnp.arange(rows, dtype=jnp.int32)
+    e_r = lay.tile_expert[r // TILE_M]
+    in_e = r - lay.starts[e_r]
+    row_live = (in_e < plan.counts[e_r]) & (r // TILE_M < lay.n_live[0])
+    last = plan.order.shape[0] - 1
+    row_assign = jnp.where(row_live, plan.order[jnp.minimum(plan.first[e_r] + in_e, last)], 0)
+    here = plan.held & (plan.pos >= p * rows) & (plan.pos < (p + 1) * rows)
+    return row_assign, row_live, jnp.where(here, plan.pos - p * rows, 0), here
+
+
+def _pass(x, w, experts, plan: _Plan, p, rows: int):
+    """What the rows of pass p add to y [N, D]: their tokens gathered into
+    a [rows, D] buffer, the three grouped products over the pass's part of
+    the layout, the rows' weights, the gather back over N x k."""
+    with scope(MOE_DISPATCH):
+        route = _pass_route(plan, p, rows)
+        layout = layout_pass(plan.layout, p, rows)
+        xs = _rows_from_tokens(x, route)
+    with scope(MOE_EXPERTS):
+        gate = grouped_matmul(xs, experts["w_gate"], layout)
+        up = grouped_matmul(xs, experts["w_up"], layout)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, experts["w_down"], layout)
+    with scope(MOE_COMBINE):
+        # weighted on the row side, so no [N, k, D] tensor exists in either pass
+        ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
+        return _tokens_from_rows(ys, route)
+
+
+def _sum_over_passes(plan: _Plan, rows: int, like, term):
+    """sum over the passes p of term(p), a tree shaped as `like`, in
+    float32: a `while` whose count the device decides (no reverse rule of
+    its own, hence `_routed`'s). One pass adds its term to zeros: the bits
+    of the term."""
+    zero = jax.tree.map(lambda v: jnp.zeros(v.shape, jnp.float32), like)
+    add = lambda acc, v: acc + v.astype(jnp.float32)
+    total = lax.fori_loop(0, _passes(plan, rows),
+                          lambda p, acc: jax.tree.map(add, acc, term(p)), zero)
+    return jax.tree.map(lambda s, v: s.astype(v.dtype), total, like)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _routed(x, w, experts, plan: _Plan, rows: int):
+    """x [N, D], the router's weights w [N, k] and the held experts' stacked
+    matrices -> y [N, D]: `_pass` summed over the passes. The backward is a
+    second loop of the same count that runs a pass again and takes its
+    `jax.vjp`, so a pass's rows live for one turn of one loop (under `remat`
+    the half-block is run again anyway; without it the re-run is the
+    layer's forward a second time)."""
+    return _sum_over_passes(plan, rows, x, lambda p: _pass(x, w, experts, plan, p, rows))
+
+
+def _routed_fwd(x, w, experts, plan, rows):
+    return _routed(x, w, experts, plan, rows), (x, w, experts, plan)
+
+
+def _routed_bwd(rows, res, g):
+    x, w, experts, plan = res
+
+    def grads(p):
+        return jax.vjp(lambda *a: _pass(*a, plan, p, rows), x, w, experts)[1](g)
+
+    return (*_sum_over_passes(plan, rows, (x, w, experts), grads), None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def routing_counters(stats):
+    """The step's routing counters from the expert layers' stacked
+    `moe_dropless_local` counters (already summed over the mesh): counts
+    [L, held], unserved, passes, buffer_rows [L]. rows_here,
+    max_expert_rows, min_expert_rows, tokens_unserved, passes (L when every
+    layer fits one pass) and buffer_rows (what a pass holds), each summed
+    over layers under `moe_<name>` and per layer under
+    `moe_<name>_per_layer`; and `moe_rows_max_over_mean`: the fullest
+    expert's rows over the mean expert's, layers summed."""
+    counts = stats["counts"]
     per = {"rows_here": jnp.sum(counts, axis=1), "max_expert_rows": jnp.max(counts, axis=1),
-           "min_expert_rows": jnp.min(counts, axis=1), "tokens_unserved": unserved}
+           "min_expert_rows": jnp.min(counts, axis=1), "tokens_unserved": stats["unserved"],
+           "passes": stats["passes"], "buffer_rows": stats["buffer_rows"]}
     out = {}
     for name, v in per.items():
         out[f"moe_{name}"] = jnp.sum(v)
@@ -418,6 +522,11 @@ def routing_counters(counts, unserved):
     mean = jnp.maximum(out["moe_rows_here"], 1).astype(jnp.float32) / counts.shape[1]
     out["moe_rows_max_over_mean"] = out["moe_max_expert_rows"].astype(jnp.float32) / mean
     return out
+
+
+def stack_layers(stats):
+    """The expert layers' counters, one dict a layer -> one dict of [L, ...]."""
+    return jax.tree.map(lambda *v: jnp.stack(v), *stats)
 
 
 def apply_moe_transformer(

@@ -31,7 +31,7 @@ from ps_pytorch_tpu.models.kda_hybrid import apply_kda_hybrid
 from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
 from ps_pytorch_tpu.parallel.dp_sp import (
     init_lm_state, make_lm_train_step, make_mesh_2d, shard_tokens_2d)
-from ps_pytorch_tpu.parallel.moe import moe_dropless_local
+from ps_pytorch_tpu.parallel.moe import moe_dropless_local, no_routing
 
 PUBLISHED = {
     "model_type": "kimi_linear", "vocab_size": 101, "hidden_size": 64, "num_hidden_layers": 5,
@@ -114,9 +114,9 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     for off in (0, 4, 8, 12):
         cfg = load_lm_config({**PUBLISHED, "experts_held": 4, "expert_offset": off})
         share = {**whole, "experts": jax.tree_util.tree_map(lambda a: a[off:off + 4], whole["experts"])}
-        y, counts, u = moe_dropless_local(n[None], share, cfg.routing, jnp.float32)
-        routed, rows = routed + y[0], rows + int(jnp.sum(counts))
-        unserved.append(int(u))
+        y, stats = moe_dropless_local(n[None], share, cfg.routing, jnp.float32)
+        routed, rows = routed + y[0], rows + int(jnp.sum(stats["counts"]))
+        unserved.append(int(stats["unserved"]))
     assert rows == T * 3 and max(unserved) < T         # every assignment lands on one share
     got = routed + mla_moe._gated_mlp(n, whole["shared"], jnp.float32)
     np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.max(jnp.abs(want))))
@@ -260,11 +260,9 @@ def _welded_block(cfg, x, blk, attend, pos):
                                   blk, attend, pos)
     n32 = mla_moe._rms32(x, blk["ln2"], cfg.rms_norm_eps)
     if "mlp" in blk:
-        return (x + mla_moe._gated_mlp(n32.astype(cd), blk["mlp"], cd),
-                jnp.zeros((cfg.experts_held,), jnp.int32), jnp.int32(0))
-    routed, counts, unserved = moe_dropless_local(n32, blk, cfg.routing, cd)
-    return (x + routed.astype(cd) + mla_moe._gated_mlp(n32.astype(cd), blk["shared"], cd),
-            counts, unserved)
+        return x + mla_moe._gated_mlp(n32.astype(cd), blk["mlp"], cd), no_routing(cfg.experts_held)
+    routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd)
+    return x + routed.astype(cd) + mla_moe._gated_mlp(n32.astype(cd), blk["shared"], cd), stats
 
 
 def _renumbered(text):
@@ -323,6 +321,9 @@ def test_train_lm_traces_the_plan_once_and_the_state_at_log_steps(tmp_path):
     assert not any(k.startswith(("rows_", "tokens_")) for s in states for k in s)
     routes = [s for s in spans if s.get("name") == "moe_route"]
     assert routes and not any("chunks_cut_off" in s for s in routes)
+    # the dropless layer's passes ride the same instant: one a layer here
+    assert all(s["passes_per_layer"] == [1] * 4 and s["passes"] == 4 for s in routes)
+    assert all(s["buffer_rows_per_layer"] == [s["buffer_rows"] // 4] * 4 for s in routes)
     logged = 0
     for rec in map(json.loads, open(tmp_path / "metrics.jsonl")):
         schema.validate_event(rec)
@@ -330,4 +331,5 @@ def test_train_lm_traces_the_plan_once_and_the_state_at_log_steps(tmp_path):
             logged += 1
             assert isinstance(rec["kda_chunks_cut_off"], int)
             assert isinstance(rec["moe_rows_here"], int)
+            assert rec["moe_passes"] == 4 and isinstance(rec["moe_buffer_rows"], int)
     assert len(states) == len(routes) == logged == 3   # steps 1, 2 and 4: one instant a log step

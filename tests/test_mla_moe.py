@@ -124,8 +124,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
                "experts": stacked({"experts": plain["experts"][2 * share:2 * share + 2]})["experts"]}
         sp = moe.DroplessSpec(num_experts=16, top_k=3, experts_held=2, expert_offset=2 * share,
                               routed_scale=2.448)
-        y, counts, _ = moe.moe_dropless_local(n, blk, sp, jnp.float32)
-        total, rows = total + y[0], rows + int(jnp.sum(counts))
+        y, stats = moe.moe_dropless_local(n, blk, sp, jnp.float32)
+        total, rows = total + y[0], rows + int(jnp.sum(stats["counts"]))
     assert rows == 40 * 3                       # every assignment lives on exactly one share
     np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
     # and a share left out is seen
@@ -143,9 +143,9 @@ def test_no_token_is_dropped_when_all_route_to_the_same_experts(interpret, monke
     blk_plain["router_bias"] = jnp.zeros((16,)).at[jnp.array([4, 5, 6])].set(10.0)
     n = jax.random.normal(jax.random.key(1), (2, 300, 64))
     blk = stacked({"b": [blk_plain]})["b"][0]
-    y, counts, unserved = jax.jit(partial(
+    y, stats = jax.jit(partial(
         moe.moe_dropless_local, spec=cfg.routing, compute_dtype=jnp.float32))(n, blk)
-    assert counts.tolist() == [600, 600, 600, 0] and int(unserved) == 0
+    assert stats["counts"].tolist() == [600, 600, 600, 0] and int(stats["unserved"]) == 0
     mm = ref._mm(None)
     for row in range(2):
         want = _layer(pub, n[row], blk_plain) - ref._gated(n[row], blk_plain["shared"], mm)
@@ -204,8 +204,11 @@ def test_sequence_parallel_step_matches_one_device():
         out[dp] = step(params, opt, shard_tokens_2d(jnp.asarray(tokens), mesh))
     (p1, _, l1, c1), (p2, _, l2, c2) = out[1], out[2]
     np.testing.assert_allclose(l1, l2, rtol=1e-5)
-    assert {k: np.asarray(v).tolist() for k, v in c1.items() if "mean" not in k} == {
-        k: np.asarray(v).tolist() for k, v in c2.items() if "mean" not in k}
+    # what a shard counts of itself (its passes, its buffer) adds up over the mesh
+    own = lambda k: "mean" in k or "passes" in k or "buffer_rows" in k
+    assert {k: np.asarray(v).tolist() for k, v in c1.items() if not own(k)} == {
+        k: np.asarray(v).tolist() for k, v in c2.items() if not own(k)}
+    assert c1["moe_passes_per_layer"].tolist() == [1, 1] and c2["moe_passes_per_layer"].tolist() == [4, 4]
     assert int(c1["moe_rows_here"]) + 0 == int(np.sum(c1["moe_rows_here_per_layer"]))
     for a, b in zip(jax.tree_util.tree_leaves(p1), jax.tree_util.tree_leaves(p2)):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4)

@@ -398,3 +398,84 @@ def test_the_update_stands_apart_of_the_wide_products_and_rides_the_narrow_ones(
     assert not any(dp_sp.plan_update(shape, 8 * 1024) for shape in block)
     assert [s for s in _folded_updates(_lm_step_compiled(topo, gpt2, 8, 1024))
             if s in block] == block
+
+
+# ------------------------------------------------ the dropless layer's passes
+
+
+def _moe_calls(text):
+    """{kernel: [(the call's line, the computation that holds it)]} for the
+    grouped products' Mosaic calls in a compiled program's text, and the
+    name of every `while`'s body."""
+    import io
+    import re
+
+    from ps_pytorch_tpu.obs import hlo
+
+    comps, _ = hlo._computations(io.StringIO(text))
+    line_of = {m.group(1): line for line in text.splitlines()
+               if hlo.MOSAIC_TARGET in line and (m := hlo._INSTR.match(line))}
+    calls = {"ps_moe_gmm": [], "ps_moe_tgmm": []}
+    for comp, body in comps.items():
+        for ins in body:
+            kernel = (hlo._KERNEL.findall(ins.op_name) or [""])[-1]
+            if ins.mosaic and kernel in calls:
+                calls[kernel].append((line_of[ins.name], comp))
+    return calls, set(re.findall(r"\bbody=%?([\w.\-]+)", text))
+
+
+def test_an_expert_step_under_remat_holds_the_layer_once_inside_its_loops(topo, as_on_a_tpu):
+    """The small preset of chip_smoke.py's `lm_config` leg (one dense and one
+    expert layer) as `cli.train_lm` builds its step, `remat` on. The step of
+    the tree before the passes held `ps_moe_gmm` 9 times and `ps_moe_tgmm` 3
+    (an expert layer's three products forward, again under `remat`, their
+    three transposes and three weight gradients; counted at that tree with
+    this function). The layer in passes holds no more: ONE body a loop, the
+    forward's three products in the forward's `while`, the re-run, the
+    transposes and the weight gradients in the backward's, and the
+    recomputed half-block runs no loop of its own. A second size of the
+    layer behind a `cond` would hold every one of them twice."""
+    import chip_smoke
+    from ps_pytorch_tpu.models.lm import load_lm_config
+
+    cfg = load_lm_config(dict(chip_smoke.LM_CONFIG), attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    calls, bodies = _moe_calls(_lm_step_compiled(topo, cfg, 2, 256).as_text())
+    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (9, 3)
+    held_in = {comp for found in calls.values() for _, comp in found}
+    assert held_in <= bodies and len(held_in) == 2, held_in      # forward's loop, backward's
+    per_loop = sorted(sum(comp == b for found in calls.values() for _, comp in found)
+                      for b in held_in)
+    assert per_loop == [3, 9]
+
+
+def test_the_layer_in_passes_compiles_at_the_laguna_cells_shapes_with_its_vmem_statement(
+        shape, as_on_a_tpu):
+    """parallel/moe.moe_dropless_local alone, forward and backward under
+    `jax.checkpoint`, at 8,192 tokens, top 10 of 256, 8 held, 3072 x 1024
+    experts: a pass of 7,168 rows where the worst case is 83,968. The
+    gradient's program holds what the layer before the passes held (6 and 3
+    calls: the forward's own loop is dead once only gradients are asked
+    for), all inside ONE `while` body, and every `ps_moe_gmm` still states
+    its own VMEM limit (ops/grouped_matmul.GMM_VMEM_DEFAULT)."""
+    from ps_pytorch_tpu.parallel import moe
+
+    spec = moe.DroplessSpec(num_experts=256, top_k=10, experts_held=8, routed_scale=2.5)
+    n, d, f = 8192, 3072, 1024
+    assert moe.pass_rows(n, spec) == 7168 and gm.buffer_rows(n * 10, 8) == 83968
+    f32 = partial(shape, dtype=jnp.float32)
+    blk = {"router": f32((d, 256)), "router_bias": f32((256,)),
+           "experts": {"w_gate": f32((8, d, f)), "w_up": f32((8, d, f)), "w_down": f32((8, f, d))}}
+
+    def loss(x, blk):
+        layer = jax.checkpoint(lambda x, blk: moe.moe_dropless_local(x, blk, spec, jnp.bfloat16)[0])
+        return jnp.sum(layer(x, blk).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(f32((1, n, d)), blk).compile().as_text()
+    calls, bodies = _moe_calls(text)
+    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (6, 3)
+    held_in = {comp for found in calls.values() for _, comp in found}
+    assert len(held_in) == 1 and held_in <= bodies
+    for line, _ in calls["ps_moe_gmm"]:
+        assert '"scoped_memory_configs":[{' in line
+        assert "7168" in line and "83968" not in line

@@ -186,9 +186,9 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     for off in (0, 8, 16, 24):
         cfg = load_lm_config({**PUBLISHED, "experts_held": 8, "expert_offset": off})
         share = {**whole, "experts": jax.tree_util.tree_map(lambda a: a[off:off + 8], whole["experts"])}
-        y, counts, u = _program_share(n, share, cfg.routing)
-        routed, rows = routed + y[0], rows + int(jnp.sum(counts))
-        unserved.append(int(u))
+        y, stats = _program_share(n, share, cfg.routing)
+        routed, rows = routed + y[0], rows + int(jnp.sum(stats["counts"]))
+        unserved.append(int(stats["unserved"]))
         # and each share is the reference's share
         part = {**blk, "experts": blk["experts"][off:off + 8]}
         np.testing.assert_allclose(y[0], _reference_share(n, part, 8, off, False), atol=2e-5)
