@@ -23,13 +23,15 @@ CLIs expose, on synthetic data made from a seed:
   lm_ssm    cli.train_lm --lm-config on a small preset of the hybrid
             state-space / attention family (Mamba-2 heads of 64, state 128,
             chunks of 256; 4 query over 2 key/value heads; m m a m), bf16,
-            flash, remat, Adam; then the chunked scan on the chip against
-            the token-by-token recurrence
+            flash, remat, Adam, the short conv as `ps_causal_conv_*` in
+            the step and its loss beside PS_TPU_DISABLE_PALLAS's; then the
+            chunked scan on the chip against the token-by-token recurrence
   lm_kda    cli.train_lm --lm-config on a small preset of the hybrid
             delta-rule / latent-attention expert family (KDA heads of 128,
             chunks of 64; k k k a k; 8 of 16 routed experts held), bf16,
-            flash, remat, Adam; then the chunked delta rule on the chip
-            against the token-by-token recurrence, past -88 a chunk too
+            flash, remat, Adam, the short convs as `ps_causal_conv_*`;
+            then the chunked delta rule on the chip against the
+            token-by-token recurrence, past -88 a chunk too
   lm_eva    cli.train_lm --lm-config on benchmark/configs/
             evabyte_6b5_4layers.json itself (the dense EVA-attention
             family at its published widths, four layers) at a short row of
@@ -192,7 +194,10 @@ LM_CONFIG_ARGS = [
     "--max-steps", "4", "--log-interval", "2", "--remat",
 ]
 LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm")
-LM_KDA_KERNELS = LM_CONFIG_KERNELS + ("ps_kda_inverse", "ps_kda_within_fwd", "ps_kda_within_bwd")
+# the short conv of the state-space and the delta-rule mixers (ops/causal_conv.py)
+CONV_KERNELS = ("ps_causal_conv_fwd", "ps_causal_conv_bwd")
+LM_KDA_KERNELS = LM_CONFIG_KERNELS + CONV_KERNELS + (
+    "ps_kda_inverse", "ps_kda_within_fwd", "ps_kda_within_bwd")
 FLASH_SHAPE = (8, 1024, 8, 64)  # B, T, H, D: the LM leg's attention
 BUCKET_ELEMS = (4 << 20) // 4   # one 4 MiB f32 gradient bucket
 
@@ -452,6 +457,40 @@ def check_passes(leg, cfg, params, tokens):
                 or abs(loss - want_loss) > 5e-3 * abs(want_loss)
                 or abs(norm - want_norm) > 5e-2 * want_norm):
             raise AssertionError(f"{leg}: the layer in passes is not the layer in one ({name})")
+
+
+def check_conv(leg, cfg, params, tokens):
+    """The short conv's kernels inside the family's loss: loss and gradient
+    norm of two rows, bfloat16, as this process runs them beside the same
+    call under PS_TPU_DISABLE_PALLAS (every entry's jnp twin, the plain
+    conv among them)."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+    import optax
+
+    from ps_pytorch_tpu.models.lm import lm_family
+    from ps_pytorch_tpu.ops.metrics import next_token_nll
+
+    family = lm_family(cfg)
+    tokens = np.asarray(jax.device_get(tokens))[:2]
+
+    def run(env):
+        def loss_fn(p):
+            return next_token_nll(family.apply(cfg, p, tokens)[0], tokens)
+
+        jax.clear_caches()      # a mixer's cached `jax.checkpoint` trace keeps the form it took
+        with mock.patch.dict(os.environ, env):
+            loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params)
+        return float(loss), float(optax.global_norm(grads))
+
+    loss, norm = run({})
+    want_loss, want_norm = run({"PS_TPU_DISABLE_PALLAS": "1"})
+    print(f"[{leg}] conv kernels in the loss: loss {loss:.6f} | {want_loss:.6f} by the jnp twins, "
+          f"gradient norm {norm:.6f} | {want_norm:.6f}", flush=True)
+    if abs(loss - want_loss) > 5e-3 * abs(want_loss) or abs(norm - want_norm) > 5e-2 * want_norm:
+        raise AssertionError(f"{leg}: the step by the kernels is not the step by their jnp twins")
 
 
 # ------------------------------------------------------------------ legs
@@ -789,9 +828,10 @@ def leg_lm_ssm(workdir, devices, clog):
     from ps_pytorch_tpu.ops import ssd
 
     leg = "lm_ssm"
-    programs, _, step, state = family_leg(
-        leg, LM_SSM_CONFIG, workdir, devices, clog, LM_KERNELS,
+    programs, cfg, step, state = family_leg(
+        leg, LM_SSM_CONFIG, workdir, devices, clog, LM_KERNELS + CONV_KERNELS,
         batch=2 * len(devices))  # two rows a chip
+    check_conv(leg, cfg, state[0], state[2])
     del step, state
 
     k = jax.random.split(jax.random.key(3), 6)
@@ -833,6 +873,7 @@ def leg_lm_kda(workdir, devices, clog):
     # (the system solved once a KDA layer, `remat` or not), no XLA twin
     census = kernel_census(step.as_text())
     check_passes(leg, cfg, state[0], state[2])
+    check_conv(leg, cfg, state[0], state[2])
     del step, state
     if census["jnp"].get("ps_kda_within") or census["mosaic"]["ps_kda_inverse"] != len(cfg.kda_layers):
         raise AssertionError(

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.scopes import EMBED, HEAD_LOSS, MIXER_KDA, scope
-from ..ops.kda import KDA_OPERANDS, kda_chunked, kda_saves, l2_normalize
+from ..ops.causal_conv import causal_conv_silu, conv_path
+from ..ops.kda import KDA_OPERANDS, kda_chunked, kda_saves
 from ..parallel.moe import DroplessSpec, routing_counters, stack_layers
 from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half, mla_mixer_half
@@ -239,10 +240,10 @@ def _into32(a, w):
 def _short_branch(n, w, taps, heads: int, scale: Optional[float]):
     """silu(conv(n W)) a head, L2-normalised times `scale` where one is
     given: [B, T, H, d] in n's dtype; the conv, its silu and the norm in
-    float32."""
-    x = jax.nn.silu(_causal_conv(_into32(n, w), taps.astype(jnp.float32), 0.0))
-    x = x.reshape(x.shape[:2] + (heads, -1))
-    return (x if scale is None else l2_normalize(x, scale)).astype(n.dtype)
+    float32 (ops/causal_conv.py)."""
+    x = causal_conv_silu(_into32(n, w), taps, None, n.dtype, _causal_conv,
+                         heads=None if scale is None else heads, head_scale=scale)
+    return x.reshape(x.shape[:2] + (heads, -1))
 
 
 def _log_decay(n, blk, heads: int):
@@ -377,7 +378,8 @@ def kda_plan(cfg: KdaHybridConfig, seq_len: int) -> Dict:
     return {"chunk": chunk, "sub_block": 1, "n_chunks": padded // chunk,
             "padded_len": padded, "heads": cfg.kda_heads,
             "d_head": cfg.kda_head_dim, "kda_layers": len(cfg.kda_layers),
-            "attention_layers": len(cfg.full_attn_layers), "scan_path": scan_path(chunk, d, d)}
+            "attention_layers": len(cfg.full_attn_layers), "scan_path": scan_path(chunk, d, d),
+            "conv_path": conv_path(cfg.kda_inner, cfg.short_conv_kernel_size)}
 
 
 def kda_counters(aux) -> Dict:

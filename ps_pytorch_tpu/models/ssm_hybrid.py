@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_ATTENTION, MIXER_SSD, MLP, scope
+from ..ops.causal_conv import causal_conv_silu, conv_path
 from ..ops.ssd import SCAN_PATH, ssd_chunked
 from .lm import LMFamily
 from .mla_moe import _rms32
@@ -201,7 +202,9 @@ def init_ssm_hybrid(cfg: SsmHybridConfig, key: jax.Array) -> Dict:
 
 def _causal_conv(x, w, bias):
     """Depthwise causal conv over time: x [B, T, C] float32, w [K, C] (tap
-    K-1 meets the current token), bias [C]."""
+    K-1 meets the current token), bias [C]. The plain form of
+    ops/causal_conv.py's kernels, which `causal_conv_silu` takes wherever
+    `conv_path` says "xla"."""
     k, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x, [(0, 0), (k - 1, 0), (0, 0)])
     return sum(padded[:, i:i + t] * w[i] for i in range(k)) + bias
@@ -215,8 +218,7 @@ def mamba_mixer(cfg: SsmHybridConfig, n, blk):
     h, p, g, s = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups, cfg.mamba_d_state
     proj = n @ blk["in_proj"].astype(cd)
     z, xbc, dt = jnp.split(proj, [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
-    xbc = jax.nn.silu(_causal_conv(
-        xbc.astype(f32), blk["conv_w"].astype(f32), blk["conv_b"].astype(f32))).astype(cd)
+    xbc = causal_conv_silu(xbc, blk["conv_w"], blk["conv_b"], cd, _causal_conv)
     x, bmat, cmat = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + g * s], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + blk["dt_bias"].astype(f32))
     y, cut_off = ssd_chunked(
@@ -312,13 +314,14 @@ def apply_ssm_hybrid(
 
 def ssd_plan(cfg: SsmHybridConfig, seq_len: int) -> Dict:
     """What every call of the scan will look like, from the shapes alone
-    (the `ssd_plan` instant, through `plans`); `scan_path` is a constant."""
+    (the `ssd_plan` instant, through `plans`); `scan_path` is a constant,
+    `conv_path` the form the short conv takes in this process."""
     return {"chunk": cfg.mamba_chunk_size, "n_chunks": -(-seq_len // cfg.mamba_chunk_size),
             "heads": cfg.mamba_n_heads, "d_head": cfg.mamba_d_head,
             "d_state": cfg.mamba_d_state, "groups": cfg.mamba_n_groups,
             "mamba_layers": cfg.mamba_layers,
             "attention_layers": cfg.num_hidden_layers - cfg.mamba_layers,
-            "scan_path": SCAN_PATH}
+            "scan_path": SCAN_PATH, "conv_path": conv_path(cfg.conv_dim, cfg.mamba_d_conv)}
 
 
 def ssd_counters(aux) -> Dict:

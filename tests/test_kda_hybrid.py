@@ -314,7 +314,7 @@ def test_train_lm_traces_the_plan_once_and_the_state_at_log_steps(tmp_path):
     assert len(plans) == 1
     assert {k: plans[0][k] for k in kda_hybrid.kda_plan(load_lm_config(PUBLISHED), 40)} == {
         "chunk": 16, "sub_block": 1, "n_chunks": 3, "padded_len": 48, "heads": 4, "d_head": 16,
-        "kda_layers": 4, "attention_layers": 1, "scan_path": "xla"}
+        "kda_layers": 4, "attention_layers": 1, "scan_path": "xla", "conv_path": "xla"}
     states = [s for s in spans if s.get("name") == "kda_state"]
     assert all(set(s) >= {"chunks_cut_off", "chunks_cut_off_per_layer"} for s in states)
     assert all(len(s["chunks_cut_off_per_layer"]) == 4 for s in states)

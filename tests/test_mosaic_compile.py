@@ -292,6 +292,91 @@ def test_a_kda_step_under_remat_solves_the_system_once_a_layer(topo, as_on_a_tpu
         "ps_kda_inverse": layers, "ps_kda_within_fwd": 2 * layers, "ps_kda_within_bwd": layers}
 
 
+# ------------------------------------------------ the short conv's kernels
+
+
+def _pattern(metric: str):
+    import json
+    import os
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "layer_metrics", metric + ".json")) as f:
+        return re.compile(json.load(f)["args"]["pattern"])
+
+
+@pytest.mark.parametrize("dims, dtype, out_dtype, bias, heads", [
+    ((1, 8192, 4352), jnp.bfloat16, jnp.bfloat16, True, None),     # granite: x, B and C of a Mamba-2 layer
+    ((2, 8192, 4096), jnp.float32, jnp.bfloat16, False, 32),       # kimi: q and k, L2-normalised a head
+    ((2, 8192, 4096), jnp.float32, jnp.bfloat16, False, None),     # kimi: v
+], ids=["granite", "kimi_qk", "kimi_v"])
+def test_conv_kernels_compile_at_the_cells_shapes_with_their_time_in_short_conv_ms_alone(
+        shape, as_on_a_tpu, dims, dtype, out_dtype, bias, heads):
+    """`ps_causal_conv_fwd` / `_bwd` at the two cells' shapes, each ONE
+    Mosaic call whose tiles twice over stay inside the VMEM it asks for.
+    `short_conv_ms` reads them by name; `kda_ms` and `ssd_ms` go by the shape
+    at the end of an op's short name and must NOT take them in, or
+    `kda_roofline` and `ssd_roofline` would divide the work they count by
+    more time than before."""
+    from benchmark.reducers.trace import short_name
+    from ps_pytorch_tpu.models.ssm_hybrid import _causal_conv
+    from ps_pytorch_tpu.ops import causal_conv as cc
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    c = dims[-1]
+    assert cc.conv_path(c) == "pallas"
+    plan = cc.plan_conv(dims[1], c, dtype)
+    tile = plan.block_t * plan.block_c
+    assert 2 * tile * (2 * jnp.dtype(dtype).itemsize + jnp.dtype(out_dtype).itemsize) <= cc.VMEM_LIMIT // 2
+    assert cc.VMEM_LIMIT <= 64 << 20                 # half of a v5e core's 128 MiB
+
+    def both(x, w, b, dy):
+        y, vjp = jax.vjp(lambda x, w, b: cc.causal_conv_silu(
+            x, w, b, out_dtype, _causal_conv, heads=heads, head_scale=128 ** -0.5), x, w, b)
+        return y, vjp(dy)
+
+    text = jax.jit(both).lower(
+        shape(dims, dtype), shape((4, c), jnp.float32), shape((c,), jnp.float32) if bias else None,
+        shape(dims, out_dtype)).compile().as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {
+        "ps_causal_conv_fwd": 1, "ps_causal_conv_bwd": 1}}
+    calls = [short_name(line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    for name in calls:
+        assert _pattern("short_conv_ms").search(name), name
+        assert not _pattern("kda_ms").search(name) and not _pattern("ssd_ms").search(name), name
+
+
+@pytest.mark.parametrize("leg, fwd_a_layer, bwd_a_layer", [("lm_ssm", 2, 1), ("lm_kda", 3, 3)])
+def test_a_step_under_remat_holds_the_conv_kernels_its_plan_says(
+        topo, as_on_a_tpu, leg, fwd_a_layer, bwd_a_layer):
+    """The small presets of chip_smoke.py's `lm_ssm` and `lm_kda` legs as
+    `cli.train_lm` builds their steps, `remat` on. A state-space layer runs
+    its conv forward twice (the forward, and `remat`'s) and backward once. A
+    delta-rule layer's three branches run it forward once each and backward
+    once each: the backward kernel makes all it needs from x, the L2 norm's
+    gradient included, so what the branch's own `jax.checkpoint` runs again
+    is the product alone (and the blocks' policy keeps q, k, v, so `remat`
+    runs no branch again). Nothing of the plain conv, and the plan's
+    `conv_path` says so."""
+    import chip_smoke
+    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    published = {"lm_ssm": chip_smoke.LM_SSM_CONFIG, "lm_kda": chip_smoke.LM_KDA_CONFIG}[leg]
+    cfg = load_lm_config(dict(published), attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    (name, _, plan), = [p for p in lm_family(cfg).plans(cfg, 128, 1) if p[0] in ("ssd_plan", "kda_plan")]
+    layers = plan["mamba_layers"] if name == "ssd_plan" else plan["kda_layers"]
+    assert plan["conv_path"] == "pallas" and layers >= 3
+    census = kernel_census(_lm_step_compiled(topo, cfg, 2, 128).as_text())
+    assert "ps_causal_conv" not in census["jnp"]
+    assert {k: v for k, v in census["mosaic"].items() if k.startswith("ps_causal_conv")} == {
+        "ps_causal_conv_fwd": fwd_a_layer * layers,
+        "ps_causal_conv_bwd": bwd_a_layer * layers}
+
+
 def _eva_one_layer():
     """benchmark/configs/evabyte_6b5_4layers.json at ONE layer (the compile's
     time, not its shapes), bfloat16, `remat`."""

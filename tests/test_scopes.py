@@ -433,8 +433,25 @@ def test_a_step_compiles_with_nothing_outside_the_vocabulary_above_2_pct_of_resu
     assert scopes.last_step(program) is step
 
 
+@pytest.fixture()
+def no_compile_cache():
+    """The persistent compile cache leaves metadata out of its key, so a
+    program found there carries the scopes of whoever compiled it first; a
+    CLI test that ran earlier on this worker turns the cache on for the
+    process (`utils.enable_persistent_compile_cache`)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
 @pytest.mark.parametrize("which", sorted(_STEPS))
-def test_a_scope_is_metadata_the_compiled_code_is_the_same_without_it(which, monkeypatch):
+def test_a_scope_is_metadata_the_compiled_code_is_the_same_without_it(
+        which, monkeypatch, no_compile_cache):
     def text():
         step, args = _STEPS[which]()
         return step.lower(*args).compile().as_text()

@@ -310,7 +310,7 @@ def test_train_lm_traces_the_plan_once_and_the_state_at_log_steps(tmp_path):
     assert len(plans) == 1
     assert {k: plans[0][k] for k in ssm_hybrid.ssd_plan(load_lm_config(PUBLISHED), 32)} == {
         "chunk": 16, "n_chunks": 2, "heads": 4, "d_head": 16, "d_state": 16, "groups": 1,
-        "mamba_layers": 3, "attention_layers": 1, "scan_path": "xla"}
+        "mamba_layers": 3, "attention_layers": 1, "scan_path": "xla", "conv_path": "xla"}
     states = [s for s in spans if s.get("name") == "ssd_state"]
     assert all(len(s["chunks_cut_off_per_layer"]) == 3 for s in states)
     logged = 0
