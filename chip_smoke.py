@@ -45,6 +45,13 @@ CLIs expose, on synthetic data made from a seed:
             head; g s s s g; 8 of 16 routed experts held), bf16, flash,
             remat, Adam; then flash_attention under the window on the chip
             against its jnp twin
+  lm_pre    cli.train_lm --lm-config on a small preset of the family whose
+            experts are routed from the attention's input (heads of 128, 14
+            query heads over 2 key/value heads: the seven-fold repeat; a
+            window of 512 with rotary beside a global layer without
+            positions, g s s s; softmax over the 6 chosen logits of 16, 8
+            held, ReLU-gated experts, no shared one), bf16, flash, remat,
+            Adam: the plans it lists and `moe_gate_active` about a half
 
 While each ``main`` runs, jax's own compile log is read: no step program
 may compile twice for the same argument shapes. After each trainer leg the
@@ -187,6 +194,19 @@ LM_SWA_CONFIG = {
     "num_attention_heads_per_layer": [12, 18, 18, 18, 12],
     "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"],
     "experts_held": 8, "expert_offset": 0,
+}
+# a small preset of the family routed from the attention's input, at the
+# published head width and head ratio (128-wide heads, seven query heads a
+# key/value head), a window of 512 and the published 0, 1, 1, 1 layouts
+LM_PRE_CONFIG = {
+    "model_type": "smallthinker", "vocab_size": 1024, "hidden_size": 512,
+    "num_hidden_layers": 4, "num_attention_heads": 14, "num_key_value_heads": 2,
+    "head_dim": 128, "rms_norm_eps": 1e-6, "moe_num_primary_experts": 16,
+    "moe_num_active_primary_experts": 6, "moe_ffn_hidden_size": 256,
+    "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+    "sliding_window_size": 512, "sliding_window_layout": [0, 1, 1, 1],
+    "rope_layout": [0, 1, 1, 1], "rope_theta": 1500000, "rope_scaling": None,
+    "tie_word_embeddings": False, "experts_held": 8, "expert_offset": 0,
 }
 LM_CONFIG_ARGS = [
     "--seq-len", "1024", "--batch-size", "2", "--dtype", "bfloat16",
@@ -1009,6 +1029,60 @@ def leg_lm_swa(workdir, devices, clog):
     return {"step_programs": programs}
 
 
+def leg_lm_pre(workdir, devices, clog):
+    """The seventh LM family through `cli.train_lm --lm-config` (data
+    parallel over the chips: the ring does not know the window): the flash
+    kernels under both masks and the experts' grouped products must be Mosaic
+    calls in the compiled step, once a layer under `remat`; its census holds
+    the router's scope and the sliding layers' rotation, and no rotation in
+    the global layer, no gate and no dense MLP anywhere; the family lists a
+    `flash_plan` a layer kind and its `moe_plan`; the ReLU gate is open for
+    about half its entries."""
+    import jax
+
+    from ps_pytorch_tpu.models.lm import lm_family
+    from ps_pytorch_tpu.obs.hlo import census as read
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    leg = "lm_pre"
+    programs, cfg, step, (params, opt_state, tokens) = family_leg(
+        leg, LM_PRE_CONFIG, workdir, devices, clog, LM_CONFIG_KERNELS,
+        batch=len(devices), seq=2048)  # one row a chip, four windows long
+    text = step.as_text()
+    census = kernel_census(text)["mosaic"]
+    layers = cfg.num_hidden_layers
+    if (census["ps_flash_fwd"], census["ps_flash_dqkv"]) != (layers, layers):
+        raise AssertionError(
+            f"{leg}: wanted ps_flash_fwd and ps_flash_dqkv once a layer ({layers}), the "
+            f"forward not run again under remat; census {census}")
+    scopes = {row["scope"] for row in read(text)["by_place"]}
+    want = {"mixer/swa", "mixer/swa/rope", "mixer/swa/kv_repeat", "mixer/swa/flash",
+            "mixer/attention", "mixer/attention/kv_repeat", "mixer/attention/flash",
+            "ffn/moe/route", "ffn/moe/dispatch", "ffn/moe/experts", "ffn/moe/combine"}
+    never = {"mixer/attention/rope", "mixer/swa/gate", "mixer/attention/gate", "ffn/mlp"}
+    if not want <= scopes or never & scopes:
+        raise AssertionError(f"{leg}: the step's census lacks {sorted(want - scopes)} or "
+                             f"holds {sorted(never & scopes)}")
+    plans = lm_family(cfg).plans(cfg, 2048, 1)
+    said = [(name, fields.get("rotary"), fields.get("mask")) for name, _, fields in plans]
+    moe_plan = plans[-1][2]
+    if (said != [("flash_plan", "default", "sliding_window"), ("flash_plan", "none", "causal"),
+                 ("moe_plan", None, None)]
+            or (moe_plan["scores"], moe_plan["router_input"], moe_plan["activation"],
+                moe_plan["shared_expert"]) != ("softmax_topk", "attention_norm", "relu", False)):
+        raise AssertionError(f"{leg}: the family's plans are {plans}")
+    params, opt_state, loss, counters = step(params, opt_state, tokens)
+    check_finite(leg, "library step loss", jax.device_get(loss))
+    c = {k: v.tolist() for k, v in jax.device_get(counters).items()}
+    if not (0.4 < c["moe_gate_active"] < 0.6 and c["moe_rows_here"] > 0
+            and set(c["moe_passes_per_layer"]) == {1}):
+        raise AssertionError(f"{leg}: counters out of range: {c}")
+    print(f"[{leg}] counters: {c}", flush=True)
+    check_on_all_devices(leg, "params", params, devices)
+    check_memory_in_use(leg, devices)
+    return {"step_programs": programs}
+
+
 def leg_serve(lm_dir, devices, clog):
     from ps_pytorch_tpu.cli import serve as serve_cli
 
@@ -1134,6 +1208,7 @@ def main() -> int:
         run("lm_kda", lambda clog: leg_lm_kda(workdir, devices, clog))
         run("lm_eva", lambda clog: leg_lm_eva(devices, clog))
         run("lm_swa", lambda clog: leg_lm_swa(workdir, devices, clog))
+        run("lm_pre", lambda clog: leg_lm_pre(workdir, devices, clog))
 
     print(json.dumps({
         "versions": versions,

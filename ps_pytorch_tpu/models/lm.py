@@ -64,6 +64,7 @@ _PUBLISHED_FAMILIES = {
     "kimi_linear": "kda_hybrid",
     "evabyte": "eva_dense",
     "laguna": "swa_moe",
+    "smallthinker": "prerouted_moe",
 }
 
 

@@ -244,12 +244,15 @@ def mla_mixer_half(cfg, x, blk, attend, pos):
                                  blk, attend, pos)
 
 
-def ffn_half(cfg, x, blk):
+def ffn_half(cfg, x, blk, route=None):
     """x [B, T, D] in the compute dtype -> (x + FFN(norm(x)), the layer's
     routing counters as parallel/moe.moe_dropless_local gives them): the
     dense MLP where the block holds `mlp` (zero counters), else the routed
-    experts held here plus the shared expert. The half every block of this
-    family, of models/kda_hybrid.py and of models/swa_moe.py ends in,
+    experts held here plus the shared expert where the block holds one
+    (`shared`). `route`: parallel/moe.route_tokens' pair where the router
+    read the block's first norm and not this half's (`cfg.routing.
+    router_input`). The half every block of this family, of models/
+    kda_hybrid.py, models/swa_moe.py and models/prerouted_moe.py ends in,
     whatever its mixer."""
     cd = x.dtype
     with scope(FFN):
@@ -259,8 +262,10 @@ def ffn_half(cfg, x, blk):
                 y = x + _gated_mlp(n32.astype(cd), blk["mlp"], cd)
             return y, no_routing(cfg.experts_held)
         with scope(MOE):
-            routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd)
+            routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd, route=route)
         y = x + routed.astype(cd)
+        if "shared" not in blk:
+            return y, stats
         with scope(MLP):
             return y + _gated_mlp(n32.astype(cd), blk["shared"], cd), stats
 

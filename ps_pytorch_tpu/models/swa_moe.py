@@ -298,25 +298,32 @@ def _rope_leading(x, pos, rope: Rope):
         [a * cos - b * sin, b * cos + a * sin, x32[..., r:]], axis=-1).astype(x.dtype)
 
 
-def gqa_attention(cfg: SwaMoeConfig, n, blk, attend, pos, rope: Rope):
-    """n [B, T, D] in the compute dtype -> (the gated attention branch [B,
-    T, D], the sum of the gate over tokens and heads)."""
+def gqa_attention(cfg, n, blk, attend, pos, rope: Optional[Rope]):
+    """n [B, T, D] in the compute dtype -> (the attention branch [B, T, D],
+    the sum of its output gate over tokens and heads). `cfg` gives
+    `num_key_value_heads` and `head_dim`, the block its query heads (W_q's
+    width). `rope` None: nothing rotates and no pass is run (a layer without
+    positions). A block without `wg` has no gate, and the sum is None.
+    Shared with models/prerouted_moe.py."""
     cd = n.dtype
     b, t, _ = n.shape
     kv, hd = cfg.num_key_value_heads, cfg.head_dim
-    heads = blk["wg"].shape[1]
+    heads = blk["wq"].shape[1] // hd
     q = (n @ blk["wq"].astype(cd)).reshape(b, t, heads, hd)
     k = (n @ blk["wk"].astype(cd)).reshape(b, t, kv, hd)
     v = (n @ blk["wv"].astype(cd)).reshape(b, t, kv, hd)
-    with scope(ROPE):
-        q, k = _rope_leading(q, pos, rope), _rope_leading(k, pos, rope)
+    if rope is not None:
+        with scope(ROPE):
+            q, k = _rope_leading(q, pos, rope), _rope_leading(k, pos, rope)
     with scope(KV_REPEAT):
         k, v = (jnp.repeat(a, heads // kv, axis=2) for a in (k, v))
     o = attend(q, k, v)                                                  # [B, T, H, hd]
-    with scope(ATTN_GATE):
-        gate = jax.nn.sigmoid((n @ blk["wg"].astype(cd)).astype(jnp.float32))   # [B, T, H]
-        o = o * gate[..., None].astype(cd)
-        opened = jnp.sum(gate)
+    opened = None
+    if "wg" in blk:
+        with scope(ATTN_GATE):
+            gate = jax.nn.sigmoid((n @ blk["wg"].astype(cd)).astype(jnp.float32))   # [B, T, H]
+            o = o * gate[..., None].astype(cd)
+            opened = jnp.sum(gate)
     return o.reshape(b, t, heads * hd) @ blk["wo"].astype(cd), opened
 
 

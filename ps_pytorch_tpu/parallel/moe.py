@@ -20,8 +20,10 @@ collectives:
 - a Switch-style load-balance auxiliary loss (E * sum f_e p_e) is returned
   alongside the task loss.
 
-Beside that capacity layer stands the DROPLESS layer of the sigmoid-routed
-top-k-of-many families (`DroplessSpec`, `moe_dropless_local`): no
+Beside that capacity layer stands the DROPLESS layer of the
+top-k-of-many families (`DroplessSpec`, `moe_dropless_local`; the spec
+says how the router scores, whose input it reads and what the experts'
+gate is): no
 capacity, no token dropped at any imbalance, the (token, expert)
 assignments sorted by expert, laid out for the worst case and walked in
 passes of a buffer sized to twice the uniform load (`pass_rows`), as many
@@ -79,18 +81,35 @@ class MoEConfig:
             raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
 
 
+SCORES = ("sigmoid", "softmax_topk")
+ROUTER_INPUTS = ("ffn_norm", "attention_norm")
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @dataclasses.dataclass(frozen=True)
 class DroplessSpec:
-    """A sigmoid-routed dropless expert layer and this chip's share of it."""
+    """A dropless expert layer and this chip's share of it. Three static
+    choices say which layer: how the router scores (`dropless_route`),
+    whose rows it reads, and the experts' gate."""
 
     num_experts: int            # the router's outputs, all of them
     top_k: int
     experts_held: int           # experts whose weights live here ...
     expert_offset: int = 0      # ... ids offset .. offset + held - 1
-    routed_scale: float = 1.0
+    routed_scale: float = 1.0   # `sigmoid` scores only
     norm_topk_prob: bool = True
+    scores: str = "sigmoid"
+    # `ffn_norm`: the router reads the rows the experts read, inside
+    # moe_dropless_local. `attention_norm`: it read the block's first norm
+    # where the block began (`route_tokens`) and the layer is handed the route
+    router_input: str = "ffn_norm"
+    activation: str = "silu"    # down(activation(gate) * up)
 
     def __post_init__(self):
+        for name, known in (("scores", SCORES), ("router_input", ROUTER_INPUTS),
+                            ("activation", tuple(ACTIVATIONS))):
+            if getattr(self, name) not in known:
+                raise ValueError(f"{name}={getattr(self, name)!r}: one of {' | '.join(known)}")
         if not 0 < self.top_k <= self.num_experts:
             raise ValueError(f"top_k {self.top_k} of {self.num_experts} experts")
         if not (0 <= self.expert_offset
@@ -269,14 +288,21 @@ def moe_mlp_local(h, blk, moe: MoEConfig, axis_name: Optional[str]):
 
 
 def dropless_route(n32, router, router_bias, spec: DroplessSpec):
-    """Sigmoid top-k routing of float32 rows n32 [N, D] over ALL experts:
-    (idx int32 [N, k], weights float32 [N, k]). The choice is by score plus
+    """Top-k routing of float32 rows n32 [N, D] over ALL experts: (idx int32
+    [N, k], weights float32 [N, k]). `sigmoid`: the choice is by score plus
     `router_bias` (the aux-loss-free correction: a buffer, no gradient),
     the weights are the scores themselves, normalised over the k chosen,
-    held here or not, and scaled. The products are float32 (`highest`): a
-    bfloat16 router picks other experts."""
-    s = jax.nn.sigmoid(jnp.dot(n32.astype(jnp.float32), router.astype(jnp.float32),
-                               precision=lax.Precision.HIGHEST))
+    held here or not, and scaled. `softmax_topk`: the k largest LOGITS, the
+    weights a softmax over those k alone (a softmax over all of them
+    renormalised over the chosen is the same number); no bias, no scale.
+    The products are float32 (`highest`): a bfloat16 router picks other
+    experts."""
+    logits = jnp.dot(n32.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if spec.scores == "softmax_topk":
+        top, idx = lax.top_k(logits, spec.top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    s = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(s + lax.stop_gradient(router_bias.astype(jnp.float32)), spec.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if spec.norm_topk_prob:
@@ -342,18 +368,34 @@ def pass_rows(n: int, spec: DroplessSpec) -> int:
                buffer_rows(n * spec.top_k, spec.experts_held))
 
 
+def route_tokens(n32, blk, spec: DroplessSpec):
+    """`dropless_route` of n32 [B, T, D] under the router's scope: the
+    (idx, weights) [B * T, k] that moe_dropless_local is handed where the
+    router reads other rows than the experts (`spec.router_input`); from
+    its own rows it computes the same itself."""
+    with scope(MOE_ROUTE):
+        return dropless_route(n32.reshape(-1, n32.shape[-1]), blk["router"],
+                              blk.get("router_bias"), spec)
+
+
 def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
-                       axis_name: Optional[str] = None, rows: Optional[int] = None):
+                       axis_name: Optional[str] = None, rows: Optional[int] = None,
+                       route=None):
     """The routed experts' part of a dropless layer on local rows.
 
     n32 [B, T, D]: the float32 normed hidden. blk: "router" [D, E_all],
-    "router_bias" [E_all], "experts": {"w_gate", "w_up" [held, D, F],
-    "w_down" [held, F, D]} (gated SiLU experts). Returns (y [B, T, D] in
+    "router_bias" [E_all] (`sigmoid` scores), "experts": {"w_gate", "w_up"
+    [held, D, F], "w_down" [held, F, D]} (experts gated by
+    `spec.activation`). `route`: `route_tokens`' pair where
+    `spec.router_input` is not the layer's own rows, None otherwise.
+    Returns (y [B, T, D] in
     compute_dtype, stats): the weighted sum over the chosen experts HELD
     HERE, and int32 counters of this call: "counts" [held], the rows each
     of them got; "unserved", the tokens none of whose experts is held (they
     get zeros: the caller adds what every chip computes alike, such as a
-    shared expert); "passes" and "buffer_rows", below.
+    shared expert); "passes" and "buffer_rows", below; under a `relu` gate
+    also "gate_active", the gate's pre-activations above zero over the rows
+    routed here, and "gate_entries", how many those are.
 
     Every assignment held here gets a row. The rows are laid out for the
     worst case and WALKED IN PASSES of a `rows`-row buffer (`pass_rows`:
@@ -368,18 +410,27 @@ def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
         raise NotImplementedError(
             "the dropless layer runs one chip's share without its exchange; "
             "an all_to_all over an expert axis is not built (ROADMAP M3)")
+    if (route is None) != (spec.router_input == "ffn_norm"):
+        raise ValueError(f"router_input={spec.router_input!r}: the layer routes its own rows "
+                         "(route=None) or is handed route_tokens' pair, as the spec says")
     b, t, d = n32.shape
     n = b * t
     rows = pass_rows(n, spec) if rows is None else rows
     x32 = n32.reshape(n, d)
-    with scope(MOE_ROUTE):
-        idx, w = dropless_route(x32, blk["router"], blk["router_bias"], spec)
+    if route is None:
+        with scope(MOE_ROUTE):
+            route = dropless_route(x32, blk["router"], blk.get("router_bias"), spec)
+    idx, w = route
     with scope(MOE_DISPATCH):
         plan = _dispatch_plan(idx, spec, n, rows)
-    y = _routed(x32.astype(compute_dtype), w, blk["experts"], plan, rows)
+    y, active = _routed(x32.astype(compute_dtype), w, blk["experts"], plan, rows,
+                        spec.activation)
     stats = {"counts": plan.counts,
              "unserved": jnp.sum(~jnp.any(plan.held, axis=-1), dtype=jnp.int32),
              "passes": _passes(plan, rows), "buffer_rows": jnp.int32(rows)}
+    if active is not None:
+        width = blk["experts"]["w_gate"].shape[-1]
+        stats.update(gate_active=active, gate_entries=jnp.sum(plan.counts) * width)
     return y.reshape(b, t, d), stats
 
 
@@ -445,10 +496,12 @@ def _pass_route(plan: _Plan, p, rows: int):
     return row_assign, row_live, jnp.where(here, plan.pos - p * rows, 0), here
 
 
-def _pass(x, w, experts, plan: _Plan, p, rows: int):
+def _pass(x, w, experts, plan: _Plan, p, rows: int, activation: str):
     """What the rows of pass p add to y [N, D]: their tokens gathered into
     a [rows, D] buffer, the three grouped products over the pass's part of
-    the layout, the rows' weights, the gather back over N x k."""
+    the layout, the rows' weights, the gather back over N x k. Beside it,
+    under a `relu` gate, int32: the gate's pre-activations above zero in
+    the pass's live rows (None under `silu`, whose step counts nothing)."""
     with scope(MOE_DISPATCH):
         route = _pass_route(plan, p, rows)
         layout = layout_pass(plan.layout, p, rows)
@@ -456,45 +509,52 @@ def _pass(x, w, experts, plan: _Plan, p, rows: int):
     with scope(MOE_EXPERTS):
         gate = grouped_matmul(xs, experts["w_gate"], layout)
         up = grouped_matmul(xs, experts["w_up"], layout)
-        ys = grouped_matmul(jax.nn.silu(gate) * up, experts["w_down"], layout)
+        ys = grouped_matmul(ACTIVATIONS[activation](gate) * up, experts["w_down"], layout)
+        active = (jnp.sum((gate > 0) & route[1][:, None], dtype=jnp.int32)
+                  if activation == "relu" else None)
     with scope(MOE_COMBINE):
         # weighted on the row side, so no [N, k, D] tensor exists in either pass
         ys = ys * _rows_from_assignments(w, route)[:, None].astype(ys.dtype)
-        return _tokens_from_rows(ys, route)
+        return _tokens_from_rows(ys, route), active
 
 
 def _sum_over_passes(plan: _Plan, rows: int, like, term):
     """sum over the passes p of term(p), a tree shaped as `like`, in
-    float32: a `while` whose count the device decides (no reverse rule of
-    its own, hence `_routed`'s). One pass adds its term to zeros: the bits
-    of the term."""
-    zero = jax.tree.map(lambda v: jnp.zeros(v.shape, jnp.float32), like)
-    add = lambda acc, v: acc + v.astype(jnp.float32)
+    float32 (a count in its own integers): a `while` whose count the device
+    decides (no reverse rule of its own, hence `_routed`'s). One pass adds
+    its term to zeros: the bits of the term."""
+    wide = lambda v: jnp.float32 if jnp.issubdtype(v.dtype, jnp.floating) else v.dtype
+    zero = jax.tree.map(lambda v: jnp.zeros(v.shape, wide(v)), like)
+    add = lambda acc, v: acc + v.astype(acc.dtype)
     total = lax.fori_loop(0, _passes(plan, rows),
                           lambda p, acc: jax.tree.map(add, acc, term(p)), zero)
     return jax.tree.map(lambda s, v: s.astype(v.dtype), total, like)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _routed(x, w, experts, plan: _Plan, rows: int):
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _routed(x, w, experts, plan: _Plan, rows: int, activation: str):
     """x [N, D], the router's weights w [N, k] and the held experts' stacked
-    matrices -> y [N, D]: `_pass` summed over the passes. The backward is a
+    matrices -> (y [N, D], `_pass`'s count or None): `_pass` summed over the
+    passes. The backward is a
     second loop of the same count that runs a pass again and takes its
     `jax.vjp`, so a pass's rows live for one turn of one loop (under `remat`
     the half-block is run again anyway; without it the re-run is the
     layer's forward a second time)."""
-    return _sum_over_passes(plan, rows, x, lambda p: _pass(x, w, experts, plan, p, rows))
+    count = jax.ShapeDtypeStruct((), jnp.int32) if activation == "relu" else None
+    return _sum_over_passes(plan, rows, (x, count),
+                            lambda p: _pass(x, w, experts, plan, p, rows, activation))
 
 
-def _routed_fwd(x, w, experts, plan, rows):
-    return _routed(x, w, experts, plan, rows), (x, w, experts, plan)
+def _routed_fwd(x, w, experts, plan, rows, activation):
+    return _routed(x, w, experts, plan, rows, activation), (x, w, experts, plan)
 
 
-def _routed_bwd(rows, res, g):
+def _routed_bwd(rows, activation, res, g):
     x, w, experts, plan = res
 
     def grads(p):
-        return jax.vjp(lambda *a: _pass(*a, plan, p, rows), x, w, experts)[1](g)
+        y_of = lambda *a: _pass(*a, plan, p, rows, activation)[0]
+        return jax.vjp(y_of, x, w, experts)[1](g[0])
 
     return (*_sum_over_passes(plan, rows, (x, w, experts), grads), None)
 
@@ -510,7 +570,11 @@ def routing_counters(stats):
     layer fits one pass) and buffer_rows (what a pass holds), each summed
     over layers under `moe_<name>` and per layer under
     `moe_<name>_per_layer`; and `moe_rows_max_over_mean`: the fullest
-    expert's rows over the mean expert's, layers summed."""
+    expert's rows over the mean expert's, layers summed. Where the layers
+    count their `relu` gate (gate_active, gate_entries [L]) also
+    `moe_gate_active`, the share of the gate's pre-activations above zero
+    over the rows routed here (a half at fresh weights; a SiLU in its place
+    has no such share), and `moe_gate_active_per_layer`."""
     counts = stats["counts"]
     per = {"rows_here": jnp.sum(counts, axis=1), "max_expert_rows": jnp.max(counts, axis=1),
            "min_expert_rows": jnp.min(counts, axis=1), "tokens_unserved": stats["unserved"],
@@ -521,6 +585,11 @@ def routing_counters(stats):
         out[f"moe_{name}_per_layer"] = v
     mean = jnp.maximum(out["moe_rows_here"], 1).astype(jnp.float32) / counts.shape[1]
     out["moe_rows_max_over_mean"] = out["moe_max_expert_rows"].astype(jnp.float32) / mean
+    if "gate_active" in stats:
+        share = lambda a, n: a.astype(jnp.float32) / jnp.maximum(n, 1).astype(jnp.float32)
+        out["moe_gate_active"] = share(jnp.sum(stats["gate_active"]),
+                                       jnp.sum(stats["gate_entries"]))
+        out["moe_gate_active_per_layer"] = share(stats["gate_active"], stats["gate_entries"])
     return out
 
 

@@ -25,6 +25,7 @@ from ps_pytorch_tpu.obs import schema
 from .test_attention_path import MLA
 from .test_evabyte_family import PUBLISHED as EVA
 from .test_kda_hybrid import PUBLISHED as KDA
+from .test_prerouted_moe import PUBLISHED as PREROUTED
 from .test_ssm_hybrid import PUBLISHED as HYBRID
 from .test_swa_moe import PUBLISHED as SWA
 
@@ -128,7 +129,7 @@ def test_the_messages_and_the_loaders_read_the_row(toy):
         load_lm_config({**toy, "tied_head": True})
     with pytest.raises(ValueError, match=r"\(has: " + ", ".join(lm._PUBLISHED_FAMILIES) + r"\)"):
         load_lm_config({"model_type": "llama"})
-    with pytest.raises(TypeError, match="SwaMoeConfig, ToyConfig"):
+    with pytest.raises(TypeError, match="SwaMoeConfig, PreroutedMoeConfig, ToyConfig"):
         lm_family(object())
     with pytest.raises(NotImplementedError, match="a ToyConfig model.*toy: a tied_head"):
         lm.require_dense(cfg, "tensor parallelism")
@@ -148,6 +149,9 @@ FAMILIES = {
     # one plan a kind of attention layer: the sliding layers', the global ones'
     "laguna": (SWA, ["flash_plan", "flash_plan"], [],
                (("moe_route", "moe_"), ("attn_state", "attn_"))),
+    # a plan a kind of attention layer, then which dropless layer every block runs
+    "smallthinker": (PREROUTED, ["flash_plan", "flash_plan", "moe_plan"], ["moe_plan"],
+                     (("moe_route", "moe_"),)),
 }
 
 

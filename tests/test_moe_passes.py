@@ -73,7 +73,8 @@ def _leaves(tree):
 @pytest.mark.parametrize("cell, n, k, held, of, rows, worst, passes", [
     ("laguna", 8192, 10, 8, 256, 7168, 83968, 12),
     ("kimi", 16384, 8, 8, 256, 10240, 133120, 13),
-    ("kanana", 16384, 6, 16, 128, 28672, 102400, 4)])
+    ("kanana", 16384, 6, 16, 128, 28672, 102400, 4),
+    ("smallthinker", 16384, 6, 16, 64, 53248, 102400, 2)])
 def test_a_pass_holds_twice_the_uniform_load_at_the_cells_shapes(cell, n, k, held, of, rows, worst, passes):
     spec = moe.DroplessSpec(num_experts=of, top_k=k, experts_held=held)
     assert moe.pass_rows(n, spec) == rows and gm.buffer_rows(n * k, held) == worst

@@ -564,3 +564,127 @@ def test_the_layer_in_passes_compiles_at_the_laguna_cells_shapes_with_its_vmem_s
     for line, _ in calls["ps_moe_gmm"]:
         assert '"scoped_memory_configs":[{' in line
         assert "7168" in line and "83968" not in line
+
+
+# ------------------- the family routed from the attention's input (PR 49)
+
+
+def _cell_config(name, **cut):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        return {**json.load(f), **cut}
+
+
+@pytest.fixture(scope="module")
+def prerouted_step(topo):
+    """The smallthinker cell's own step, 1 x 16,384 tokens over all four
+    layers, bfloat16, `remat`, compiled once for the tests that read it."""
+    from ps_pytorch_tpu.models.lm import load_lm_config
+
+    cfg = load_lm_config(_cell_config("smallthinker_21b_a3b_ep4"), attention_impl="flash",
+                         remat=True, compute_dtype=jnp.bfloat16)
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _say_tpu(monkeypatch)
+        return cfg, _lm_step_compiled(topo, cfg, 1, 16384)
+
+
+def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_shapes(
+        prerouted_step):
+    """The step of `smallthinker_train_b1s16384_ep4share` for a described
+    v5e: state and temporaries under the 15.75 GiB a v5e gives, with
+    `plan_remat_saves` keeping the kernels' operands; the flash kernels once
+    a layer each way at `[28, 16384, 128]`, three walking the band's 252
+    live tiles and one the causal 528; the grouped products 9 + 3 a layer at
+    2560 x 768 over 16 experts and a pass of `pass_rows(16384, spec)` rows,
+    never the worst case's; no rotation pass in the global layer."""
+    import re
+
+    from ps_pytorch_tpu.models.lm import lm_family
+    from ps_pytorch_tpu.obs import hlo
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+    from ps_pytorch_tpu.parallel import moe
+
+    cfg, compiled = prerouted_step
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    assert 12.0 < held / 2 ** 30 < 14.0 < fa.V5E_BYTES_LIMIT / 2 ** 30, held / 2 ** 30
+    text = compiled.as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {
+        "ps_flash_fwd": 4, "ps_flash_dqkv": 4, "ps_moe_gmm": 36, "ps_moe_tgmm": 12}}
+    spec = cfg.routing
+    rows, worst = moe.pass_rows(16384, spec), gm.buffer_rows(16384 * 6, 16)
+    assert (rows, worst) == (53248, 102400)
+    walks = {"ps_flash_fwd": [], "ps_flash_dqkv": []}
+    for line in _mosaic_lines(compiled):
+        kernel = re.search(r"%(ps_[a-z_]+)", line).group(1)
+        if kernel in walks:
+            assert "bf16[28,16384,128]" in line
+            walks[kernel].append(int(re.search(r"s32\[(\d+)\]\{0\}", line).group(1)))
+        else:
+            assert f"[{rows}," in line and str(worst) not in line, line
+            assert re.search(r"\[16,(2560,768|768,2560)\]", line), line
+    sliding, glob = fa.plan_flash(16384, 16384, 128, jnp.bfloat16, fa.SlidingWindow(4096)), \
+        fa.plan_flash(16384, 16384, 128, jnp.bfloat16, True)
+    assert (sliding.grid_steps, glob.grid_steps, sliding.bwd) == (252, 528, "fused")
+    assert sorted(walks["ps_flash_fwd"]) == sorted(walks["ps_flash_dqkv"]) == [252, 252, 252, 528]
+    kinds = [fields for name, _, fields in lm_family(cfg).plans(cfg, 16384, 1)
+             if name == "flash_plan"]
+    assert [(k["grid_steps"], k["layers"], k["rotary"]) for k in kinds] == [
+        (252, 3, "default"), (528, 1, "none")]
+    places = {row["scope"] for row in hlo.census(text)["by_place"]}
+    assert {"mixer/swa/rope", "ffn/moe/route"} <= places and "mixer/attention/rope" not in places
+
+
+# cell's configuration -> (its depth cut to the leading dense layer and ONE
+# expert layer with the per-layer lists that go with it, rows, the pass's
+# rows, experts held, an expert's [D, F]): the kernels' shapes are the
+# parent's, read off the tree before PR 49 with the same function
+EXPERT_CELLS = {
+    "kanana2_30b_a3b_ep8": ({"num_hidden_layers": 2}, 2, 28672, 16, (2048, 768)),
+    "kimi_linear_48b_a3b_ep32": (
+        {"num_hidden_layers": 2, "linear_attn_config": {
+            "full_attn_layers": [], "head_dim": 128, "kda_layers": [1, 2], "num_heads": 32,
+            "short_conv_kernel_size": 4}}, 2, 10240, 8, (2304, 1024)),
+    "laguna_s_2_1_ep32": (
+        {"num_hidden_layers": 2, "layer_types": ["full_attention", "sliding_attention"],
+         "num_attention_heads_per_layer": [48, 72], "mlp_layer_types": ["dense", "sparse"],
+         "gating_types": ["per_head"] * 2}, 1, 7168, 8, (3072, 1024)),
+}
+
+
+@pytest.mark.parametrize("name", list(EXPERT_CELLS))
+def test_the_accepted_expert_cells_still_hold_the_layer_once_inside_its_loops(
+        topo, as_on_a_tpu, name):
+    """The sigmoid-routed, SiLU-gated layer that routes its own rows is the
+    parent's program: at the kanana, kimi and laguna cells' rows (depth cut
+    to one expert layer) the step holds `ps_moe_gmm` 9 times and
+    `ps_moe_tgmm` 3, in the forward's loop and the backward's, on operands
+    shaped as before this family came: a pass's rows, the held experts'
+    matrices, and nothing the ReLU gate's count would add."""
+    import re
+
+    from ps_pytorch_tpu.models.lm import load_lm_config
+
+    cut, batch, rows, held, (d, f) = EXPERT_CELLS[name]
+    cfg = load_lm_config(_cell_config(name, **cut), attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    assert (cfg.routing.scores, cfg.routing.router_input, cfg.routing.activation) == (
+        "sigmoid", "ffn_norm", "silu")
+    calls, bodies = _moe_calls(_lm_step_compiled(topo, cfg, batch, 8192).as_text())
+    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (9, 3)
+    held_in = {comp for found in calls.values() for _, comp in found}
+    assert held_in <= bodies and len(held_in) == 2, held_in
+    shapes = {kernel: {tuple(re.findall(r"(?:bf16|f32|s32)\[[0-9,]+\]", line))
+                       for line, _ in found} for kernel, found in calls.items()}
+    walk = (f"s32[{rows // gm.TILE_M}]", "s32[1]")
+    wide, narrow = f"bf16[{rows},{d}]", f"bf16[{rows},{f}]"
+    up, down = f"bf16[{held},{d},{f}]", f"bf16[{held},{f},{d}]"
+    assert shapes["ps_moe_gmm"] == {
+        (narrow, *walk, wide, up), (wide, *walk, narrow, down),      # gate / up, down
+        (wide, *walk, narrow, up), (narrow, *walk, wide, down)}      # their transposes
+    assert shapes["ps_moe_tgmm"] == {
+        (f"f32[{held},{d},{f}]", *walk, wide, narrow), (f"f32[{held},{f},{d}]", *walk, narrow, wide)}

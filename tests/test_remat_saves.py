@@ -233,7 +233,11 @@ CELLS = {
     # one latent layer as kanana's; four delta-rule layers of q, k, v
     # [2, 8192, 32, 128] at 128 each and 128 of float32 inverses
     "kimi_linear_48b_a3b_ep32": (2, 640 + 4 * 384, 770 + 4 * 512),
+    # one row of 16,384: q3, k3, v3, o [28, 16384, 128] bfloat16 at 112 each
+    # (keys and values at the query heads' width), lse 2, in each of four layers
+    "smallthinker_21b_a3b_ep4": (1, 4 * 336, 4 * 450),
 }
+SEQ_LEN = {"smallthinker_21b_a3b_ep4": 16_384}
 
 
 def _cell(name, rows, seq_len=8192, limit=flash_attention.V5E_BYTES_LIMIT):
@@ -254,8 +258,9 @@ def _cell(name, rows, seq_len=8192, limit=flash_attention.V5E_BYTES_LIMIT):
 def test_the_cells_keep_their_operands_on_a_described_v5e(name, monkeypatch):
     monkeypatch.delenv("PS_TPU_PALLAS_INTERPRET")  # the shapes of the chip's kernels or the twin's: the same bytes
     rows, operands, saved = CELLS[name]
-    plan, pub = _cell(name, rows)
-    alone, _ = _cell(name, rows, limit=1)
+    seq_len = SEQ_LEN.get(name, 8192)
+    plan, pub = _cell(name, rows, seq_len)
+    alone, _ = _cell(name, rows, seq_len, limit=1)
     assert plan.operands_kept and not alone.operands_kept
     assert set(alone.names) < set(plan.names)
     assert (plan.saved_bytes - alone.saved_bytes, plan.saved_bytes) == (operands * MIB, saved * MIB)

@@ -360,6 +360,10 @@ def _lm_cfg(family):
 
         return EvaByteConfig(vocab_size=64, window_size=16, chunk_size=4, attention_impl="flash",
                              remat=True)
+    if family == "prerouted_moe":  # a window of 8 under the 32 tokens; a global layer, three sliding
+        from ps_pytorch_tpu.models.prerouted_moe import PreroutedMoeConfig
+
+        return PreroutedMoeConfig(attention_impl="flash", remat=True)
     cls = {"mla_moe": MlaMoeConfig, "ssm_hybrid": SsmHybridConfig, "kda_hybrid": KdaHybridConfig}
     return cls[family](attention_impl="flash", remat=True)
 
@@ -397,7 +401,8 @@ def _ps_step():
 
 _STEPS = {"dense": lambda: _lm_step("dense"), "mla_moe": lambda: _lm_step("mla_moe"),
           "ssm_hybrid": lambda: _lm_step("ssm_hybrid"), "kda_hybrid": lambda: _lm_step("kda_hybrid"),
-          "eva_dense": lambda: _lm_step("eva_dense"), "ps": _ps_step}
+          "eva_dense": lambda: _lm_step("eva_dense"),
+          "prerouted_moe": lambda: _lm_step("prerouted_moe"), "ps": _ps_step}
 _WANTED = {
     "dense": {"embed", "mixer/attention", "mixer/attention/flash", "ffn", "ffn/mlp", "head_loss",
               "grad_reduce", "update"},
@@ -410,6 +415,12 @@ _WANTED = {
     # off the chip the attention takes its jnp twin: the kernel passes' scopes
     # (local, remote, merge) are held by tests/test_evabyte_family.py, interpreted
     "eva_dense": {"embed", "mixer/eva", "mixer/eva/pool", "ffn", "ffn/mlp", "head_loss", "update"},
+    # the route stands at the block's entry under the scope it has everywhere; no
+    # dense MLP, no shared expert, and nothing rotates in the global layer
+    "prerouted_moe": {"embed", "mixer/swa", "mixer/swa/rope", "mixer/swa/kv_repeat",
+                      "mixer/swa/flash", "mixer/attention", "mixer/attention/kv_repeat",
+                      "mixer/attention/flash", "ffn", "ffn/moe/route", "ffn/moe/dispatch",
+                      "ffn/moe/experts", "ffn/moe/combine", "head_loss", "update"},
     "ps": {"augment", "model", "grad_reduce", "update"},
 }
 
