@@ -214,6 +214,8 @@ LM_CONFIG_ARGS = [
     "--max-steps", "4", "--log-interval", "2", "--remat",
 ]
 LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm")
+# the grouped-query families' rotation of q and k (ops/rope.py)
+LM_GQA_KERNELS = LM_CONFIG_KERNELS + ("ps_rope",)
 # the short conv of the state-space and the delta-rule mixers (ops/causal_conv.py)
 CONV_KERNELS = ("ps_causal_conv_fwd", "ps_causal_conv_bwd")
 LM_KDA_KERNELS = LM_CONFIG_KERNELS + CONV_KERNELS + (
@@ -479,7 +481,7 @@ def check_passes(leg, cfg, params, tokens):
             raise AssertionError(f"{leg}: the layer in passes is not the layer in one ({name})")
 
 
-def check_conv(leg, cfg, params, tokens):
+def check_conv(leg, cfg, params, tokens, what="conv kernels"):
     """The short conv's kernels inside the family's loss: loss and gradient
     norm of two rows, bfloat16, as this process runs them beside the same
     call under PS_TPU_DISABLE_PALLAS (every entry's jnp twin, the plain
@@ -507,10 +509,16 @@ def check_conv(leg, cfg, params, tokens):
 
     loss, norm = run({})
     want_loss, want_norm = run({"PS_TPU_DISABLE_PALLAS": "1"})
-    print(f"[{leg}] conv kernels in the loss: loss {loss:.6f} | {want_loss:.6f} by the jnp twins, "
+    print(f"[{leg}] {what} in the loss: loss {loss:.6f} | {want_loss:.6f} by the jnp twins, "
           f"gradient norm {norm:.6f} | {want_norm:.6f}", flush=True)
     if abs(loss - want_loss) > 5e-3 * abs(want_loss) or abs(norm - want_norm) > 5e-2 * want_norm:
         raise AssertionError(f"{leg}: the step by the kernels is not the step by their jnp twins")
+
+
+def check_rope(leg, cfg, params, tokens):
+    """`ps_rope` inside the family's loss beside the plain rotation (and
+    every other entry's jnp twin), as `check_conv` holds the conv's."""
+    check_conv(leg, cfg, params, tokens, what="the rotation's kernel")
 
 
 # ------------------------------------------------------------------ legs
@@ -978,8 +986,9 @@ def leg_lm_swa(workdir, devices, clog):
     over the chips: the ring does not know the window): the flash kernels
     under both masks and the experts' grouped products must be Mosaic calls
     in the compiled step, once a layer under `remat`, with the new scopes in
-    its census; the gate reads about a half. Then flash_attention under the
-    window by itself, compiled, against its jnp twin."""
+    its census; `ps_rope` rotates q and k in both layer kinds; the gate reads
+    about a half. Then flash_attention under the window by itself, compiled,
+    against its jnp twin."""
     import jax
     import jax.numpy as jnp
 
@@ -990,7 +999,7 @@ def leg_lm_swa(workdir, devices, clog):
 
     leg = "lm_swa"
     programs, cfg, step, (params, opt_state, tokens) = family_leg(
-        leg, LM_SWA_CONFIG, workdir, devices, clog, LM_CONFIG_KERNELS,
+        leg, LM_SWA_CONFIG, workdir, devices, clog, LM_GQA_KERNELS,
         batch=len(devices), seq=2048)  # one row a chip, four windows long
     text = step.as_text()
     census = kernel_census(text)["mosaic"]
@@ -1011,6 +1020,7 @@ def leg_lm_swa(workdir, devices, clog):
         raise AssertionError(f"{leg}: counters out of range: {c}")
     print(f"[{leg}] counters: {c}", flush=True)
     check_passes(leg, cfg, params, tokens)
+    check_rope(leg, cfg, params, tokens)
     del step, params, opt_state
 
     k = jax.random.split(jax.random.key(6), 3)
@@ -1036,8 +1046,9 @@ def leg_lm_pre(workdir, devices, clog):
     calls in the compiled step, once a layer under `remat`; its census holds
     the router's scope and the sliding layers' rotation, and no rotation in
     the global layer, no gate and no dense MLP anywhere; the family lists a
-    `flash_plan` a layer kind and its `moe_plan`; the ReLU gate is open for
-    about half its entries."""
+    `flash_plan` a layer kind (the sliding kind's says `ps_rope` rotates its
+    q and k) and its `moe_plan`; the ReLU gate is open for about half its
+    entries; the loss by the kernels is the loss by their jnp twins."""
     import jax
 
     from ps_pytorch_tpu.models.lm import lm_family
@@ -1046,7 +1057,7 @@ def leg_lm_pre(workdir, devices, clog):
 
     leg = "lm_pre"
     programs, cfg, step, (params, opt_state, tokens) = family_leg(
-        leg, LM_PRE_CONFIG, workdir, devices, clog, LM_CONFIG_KERNELS,
+        leg, LM_PRE_CONFIG, workdir, devices, clog, LM_GQA_KERNELS,
         batch=len(devices), seq=2048)  # one row a chip, four windows long
     text = step.as_text()
     census = kernel_census(text)["mosaic"]
@@ -1064,10 +1075,11 @@ def leg_lm_pre(workdir, devices, clog):
         raise AssertionError(f"{leg}: the step's census lacks {sorted(want - scopes)} or "
                              f"holds {sorted(never & scopes)}")
     plans = lm_family(cfg).plans(cfg, 2048, 1)
-    said = [(name, fields.get("rotary"), fields.get("mask")) for name, _, fields in plans]
+    said = [(name, fields.get("rotary"), fields.get("mask"), fields.get("rope_path"))
+            for name, _, fields in plans]
     moe_plan = plans[-1][2]
-    if (said != [("flash_plan", "default", "sliding_window"), ("flash_plan", "none", "causal"),
-                 ("moe_plan", None, None)]
+    if (said != [("flash_plan", "default", "sliding_window", "pallas"),
+                 ("flash_plan", "none", "causal", "none"), ("moe_plan", None, None, None)]
             or (moe_plan["scores"], moe_plan["router_input"], moe_plan["activation"],
                 moe_plan["shared_expert"]) != ("softmax_topk", "attention_norm", "relu", False)):
         raise AssertionError(f"{leg}: the family's plans are {plans}")
@@ -1079,6 +1091,7 @@ def leg_lm_pre(workdir, devices, clog):
         raise AssertionError(f"{leg}: counters out of range: {c}")
     print(f"[{leg}] counters: {c}", flush=True)
     check_on_all_devices(leg, "params", params, devices)
+    check_rope(leg, cfg, params, tokens)
     check_memory_in_use(leg, devices)
     return {"step_programs": programs}
 

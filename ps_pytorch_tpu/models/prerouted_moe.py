@@ -49,7 +49,7 @@ from ..ops.flash_attention import SlidingWindow
 from ..parallel.moe import DroplessSpec, pass_rows, route_tokens, routing_counters, stack_layers
 from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half
-from .swa_moe import Rope, gqa_attention
+from .swa_moe import Rope, gqa_attention, rope_fields
 from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
 # config keys this family reads; every other key is carried by the
@@ -256,10 +256,10 @@ def apply_prerouted_moe(
 def plans(cfg: PreroutedMoeConfig, seq_len: int, seq_shards: int):
     """One `flash_plan` a kind of attention layer (models/transformer.
     flash_plans' fields under that kind's mask, with `layer_kind` its place
-    in saved_layers, `heads`, `kv_heads`, `layers` and `rotary`: `default` |
-    `none`), then `moe_plan`: which dropless layer every block runs
-    (parallel/moe.DroplessSpec's choices; `pass_rows` at one row of seq_len
-    tokens a chip)."""
+    in saved_layers, `heads`, `kv_heads`, `layers`, `rotary`: `default` |
+    `none`, and models/swa_moe.rope_fields), then `moe_plan`: which dropless
+    layer every block runs (parallel/moe.DroplessSpec's choices; `pass_rows`
+    at one row of seq_len tokens a chip)."""
     out = []
     for i, (sliding, rotary, layers) in enumerate(cfg.layer_kinds()):
         for name, kernels, fields in flash_plans(cfg, seq_len, seq_shards, cfg.head_dim,
@@ -267,7 +267,9 @@ def plans(cfg: PreroutedMoeConfig, seq_len: int, seq_shards: int):
             out.append((name, kernels, {
                 **fields, "layer_kind": i, "heads": cfg.num_attention_heads,
                 "kv_heads": cfg.num_key_value_heads, "layers": layers,
-                "rotary": "default" if rotary else "none"}))
+                "rotary": "default" if rotary else "none",
+                **rope_fields(cfg, cfg.num_attention_heads, cfg.rope if rotary else None,
+                              seq_len // seq_shards)}))
     spec = cfg.routing
     out.append(("moe_plan", None, {
         "scores": spec.scores, "router_input": spec.router_input, "activation": spec.activation,

@@ -62,6 +62,7 @@ import numpy as np
 from ..obs.scopes import (ATTN_GATE, EMBED, HEAD_LOSS, KV_REPEAT, MIXER_ATTENTION, MIXER_SWA,
                           ROPE, scope)
 from ..ops.flash_attention import SlidingWindow
+from ..ops.rope import plan_rope, rope_path, rotate_leading
 from ..parallel.moe import DroplessSpec, routing_counters, stack_layers
 from .lm import LMFamily
 from .mla_moe import _gated_init, _rms32, ffn_half
@@ -298,6 +299,23 @@ def _rope_leading(x, pos, rope: Rope):
         [a * cos - b * sin, b * cos + a * sin, x32[..., r:]], axis=-1).astype(x.dtype)
 
 
+def rope_fields(cfg, heads: int, rope: Optional[Rope], t: int) -> Dict:
+    """What a layer kind's `flash_plan` says of its rotation at rows of t
+    tokens: `rope_path` (ops/rope.rope_path; "none" for a layer without
+    positions), `rope_dims` (r) and, where the kernel runs, `ps_rope`'s tile
+    for q and the block of channels for k. Shared with
+    models/prerouted_moe.py."""
+    if rope is None:
+        return {"rope_path": "none"}
+    hd, dt = cfg.head_dim, cfg.effective_compute_dtype
+    r = 2 * len(rope.frequencies(hd)[0])
+    out = {"rope_path": rope_path(hd, r), "rope_dims": r}
+    if out["rope_path"] == "pallas":
+        q, k = plan_rope(t, heads * hd, dt), plan_rope(t, cfg.num_key_value_heads * hd, dt)
+        out.update(rope_block_t=q.block_t, rope_block_c=q.block_c, rope_block_c_kv=k.block_c)
+    return out
+
+
 def gqa_attention(cfg, n, blk, attend, pos, rope: Optional[Rope]):
     """n [B, T, D] in the compute dtype -> (the attention branch [B, T, D],
     the sum of its output gate over tokens and heads). `cfg` gives
@@ -314,7 +332,8 @@ def gqa_attention(cfg, n, blk, attend, pos, rope: Optional[Rope]):
     v = (n @ blk["wv"].astype(cd)).reshape(b, t, kv, hd)
     if rope is not None:
         with scope(ROPE):
-            q, k = _rope_leading(q, pos, rope), _rope_leading(k, pos, rope)
+            q, k = rotate_leading((q, k), pos, *rope.frequencies(hd),
+                                  twin=lambda x: _rope_leading(x, pos, rope))
     with scope(KV_REPEAT):
         k, v = (jnp.repeat(a, heads // kv, axis=2) for a in (k, v))
     o = attend(q, k, v)                                                  # [B, T, H, hd]
@@ -417,14 +436,16 @@ def swa_counters(aux) -> Dict:
 def plans(cfg: SwaMoeConfig, seq_len: int, seq_shards: int):
     """One `flash_plan` a kind of attention layer: models/transformer.
     flash_plans' fields under that kind's mask, with `layer_kind` its place
-    in saved_layers, `layer_type`, `heads`, `kv_heads` and `layers`."""
+    in saved_layers, `layer_type`, `heads`, `kv_heads`, `layers` and how the
+    kind's q and k are rotated (`rope_fields`)."""
     out = []
     for i, (kind, heads, layers) in enumerate(cfg.layer_kinds()):
         for name, kernels, fields in flash_plans(cfg, seq_len, seq_shards, cfg.head_dim,
                                                  cfg.head_dim, causal=cfg.mask(kind)):
             out.append((name, kernels, {
                 **fields, "layer_kind": i, "layer_type": kind, "heads": heads,
-                "kv_heads": cfg.num_key_value_heads, "layers": layers}))
+                "kv_heads": cfg.num_key_value_heads, "layers": layers,
+                **rope_fields(cfg, heads, cfg.rope(kind), seq_len // seq_shards)}))
     return out
 
 

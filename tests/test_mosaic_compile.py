@@ -377,6 +377,86 @@ def test_a_step_under_remat_holds_the_conv_kernels_its_plan_says(
         "ps_causal_conv_bwd": bwd_a_layer * layers}
 
 
+# ------------------------------------------------ the rotation's kernel
+
+
+# the metrics that go by an op's name or by the shape at its end, and must
+# not take `ps_rope` in
+OTHER_KERNEL_METRICS = ("flash_ms", "swa_flash_ms", "moe_buffer_ms", "moe_routed_ms", "kda_ms",
+                        "ssd_ms", "short_conv_ms")
+
+
+@pytest.mark.parametrize("dims, rope", [
+    ((1, 8192, 72, 128), dict(rope_theta=10000.0)),                # laguna: a sliding layer's q
+    ((1, 8192, 48, 128), dict(                                     # its global layers': YaRN, half a head
+        rope_type="yarn", rope_theta=500000.0, factor=32.0, original_max_position_embeddings=4096,
+        attention_factor=1.35, partial_rotary_factor=0.5)),
+    ((1, 8192, 8, 128), dict(rope_theta=10000.0)),                 # laguna: k
+    ((1, 16384, 28, 128), dict(rope_theta=1500000.0)),             # smallthinker: q
+], ids=["sliding_q", "global_q_yarn_half", "k", "smallthinker_q"])
+def test_the_rotation_compiles_at_the_cells_shapes_with_its_time_in_rope_ms_alone(
+        shape, as_on_a_tpu, dims, rope):
+    """`ps_rope` at the two cells' shapes, bfloat16, forward and backward
+    each ONE Mosaic call on `[B, T, H * 128]`, tiles inside the VMEM it asks
+    for. `rope_ms` reads them by name; no other kernel's metric takes them
+    in, by name or by the shape at the end of an op's short name."""
+    from benchmark.reducers.trace import short_name
+    from ps_pytorch_tpu.models.swa_moe import Rope, _rope_leading
+    from ps_pytorch_tpu.ops import rope as rp
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    rope = Rope(**rope)
+    b, t, heads, d = dims
+    freqs, scale = rope.frequencies(d)
+    assert rp.rope_path(d, 2 * len(freqs)) == "pallas"
+    plan = rp.plan_rope(t, heads * d, jnp.bfloat16)
+    assert plan.vmem_bytes(jnp.bfloat16) <= rp.VMEM_LIMIT // 2 and rp.VMEM_LIMIT <= 64 << 20
+
+    def both(x, dy):
+        pos = jnp.arange(t)
+        y, vjp = jax.vjp(lambda a: rp.rotate_leading(
+            (a,), pos, freqs, scale, twin=lambda a: _rope_leading(a, pos, rope))[0], x)
+        return y, vjp(dy)
+
+    text = jax.jit(both).lower(shape(dims), shape(dims)).compile().as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {"ps_rope": 2}}
+    lines = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(lines) == 2
+    for line in lines:
+        assert f"bf16[{b},{t},{heads * d}]" in line
+        name = short_name(line.strip().removeprefix("ROOT "))
+        assert _pattern("rope_ms").search(name), name
+        for other in OTHER_KERNEL_METRICS:
+            assert not _pattern(other).search(name), (other, name)
+
+
+@pytest.mark.parametrize("leg", ["lm_swa", "lm_pre"])
+def test_a_step_under_remat_holds_the_rotations_its_plans_say(topo, as_on_a_tpu, leg):
+    """The small presets of chip_smoke.py's `lm_swa` and `lm_pre` legs as
+    `cli.train_lm` builds their steps, `remat` on, heads of 128: every layer
+    kind whose plan says `rope_path: pallas` runs `ps_rope` on q and on k,
+    forward and backward (the blocks' policy keeps the rotated q and k, so
+    `remat` runs no rotation again); a layer without positions runs none;
+    nothing of the plain rotation is left."""
+    import chip_smoke
+    from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    published = {"lm_swa": chip_smoke.LM_SWA_CONFIG, "lm_pre": chip_smoke.LM_PRE_CONFIG}[leg]
+    cfg = load_lm_config(dict(published), attention_impl="flash", remat=True,
+                         compute_dtype=jnp.bfloat16)
+    plans = [f for name, _, f in lm_family(cfg).plans(cfg, 256, 1) if name == "flash_plan"]
+    assert [p["rope_path"] for p in plans] == {"lm_swa": ["pallas", "pallas"],
+                                               "lm_pre": ["pallas", "none"]}[leg]
+    for p in plans:
+        if p["rope_path"] == "pallas":
+            assert (p["rope_block_t"], p["rope_block_c_kv"]) == (256, 256)
+            assert p["heads"] * 128 % p["rope_block_c"] == 0
+    rotary = sum(p["layers"] for p in plans if p["rope_path"] == "pallas")
+    census = kernel_census(_lm_step_compiled(topo, cfg, 1, 256).as_text())
+    assert "ps_rope" not in census["jnp"] and census["mosaic"]["ps_rope"] == 2 * 2 * rotary
+
+
 def _eva_one_layer():
     """benchmark/configs/evabyte_6b5_4layers.json at ONE layer (the compile's
     time, not its shapes), bfloat16, `remat`."""
@@ -599,7 +679,9 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
     a layer each way at `[28, 16384, 128]`, three walking the band's 252
     live tiles and one the causal 528; the grouped products 9 + 3 a layer at
     2560 x 768 over 16 experts and a pass of `pass_rows(16384, spec)` rows,
-    never the worst case's; no rotation pass in the global layer."""
+    never the worst case's; no rotation pass in the global layer, and in
+    the others `ps_rope` on q and k each way (the operands are kept, so
+    `remat` runs no rotation again)."""
     import re
 
     from ps_pytorch_tpu.models.lm import lm_family
@@ -614,7 +696,8 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
     assert 12.0 < held / 2 ** 30 < 14.0 < fa.V5E_BYTES_LIMIT / 2 ** 30, held / 2 ** 30
     text = compiled.as_text()
     assert kernel_census(text) == {"jnp": {}, "mosaic": {
-        "ps_flash_fwd": 4, "ps_flash_dqkv": 4, "ps_moe_gmm": 36, "ps_moe_tgmm": 12}}
+        "ps_flash_fwd": 4, "ps_flash_dqkv": 4, "ps_moe_gmm": 36, "ps_moe_tgmm": 12,
+        "ps_rope": 3 * 2 * 2}}      # q and k, forward and backward, in the three rotary layers
     spec = cfg.routing
     rows, worst = moe.pass_rows(16384, spec), gm.buffer_rows(16384 * 6, 16)
     assert (rows, worst) == (53248, 102400)
@@ -624,6 +707,8 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
         if kernel in walks:
             assert "bf16[28,16384,128]" in line
             walks[kernel].append(int(re.search(r"s32\[(\d+)\]\{0\}", line).group(1)))
+        elif kernel == "ps_rope":
+            assert re.search(r"bf16\[1,16384,(3584|512)\]", line), line
         else:
             assert f"[{rows}," in line and str(worst) not in line, line
             assert re.search(r"\[16,(2560,768|768,2560)\]", line), line

@@ -423,6 +423,8 @@ def test_train_lm_traces_a_plan_a_layer_kind_the_moe_plan_and_the_gate_at_log_st
             sliding["layers"], sliding["rotary"]) == ("sliding_window", 24, 6, 2, 3, "default")
     assert (glob["mask"], glob["window"], glob["heads"], glob["layers"], glob["rotary"]) == (
         "causal", 0, 6, 1, "none")
+    # heads of 16 here: the plain rotation; the layer without positions runs none
+    assert (sliding["rope_path"], sliding["rope_dims"], glob["rope_path"]) == ("xla", 16, "none")
     for plan in (sliding, glob):
         assert plan["tiles_run"] <= plan["tiles_total"] and 0 < plan["tile_fill"] <= 1
         assert plan["remat_saves"].startswith("ps_flash_o,ps_flash_lse")

@@ -344,6 +344,9 @@ def test_train_lm_traces_a_plan_a_layer_kind_and_the_gate_at_log_steps(tmp_path,
     assert (sliding["mask"], sliding["window"], sliding["heads"], sliding["layers"],
             sliding["layer_type"]) == ("sliding_window", 24, 6, 3, SLIDING)
     assert (glob["mask"], glob["window"], glob["heads"], glob["layers"]) == ("causal", 0, 4, 2)
+    # heads of 16 here: the plain rotation, and no tile of `ps_rope` to name
+    assert all(plan["rope_path"] == "xla" and "rope_block_t" not in plan for plan in (sliding, glob))
+    assert (sliding["rope_dims"], glob["rope_dims"]) == (16, 8)      # a whole head, and YaRN on half
     for plan in (sliding, glob):
         assert plan["tiles_run"] <= plan["tiles_total"] and 0 < plan["tile_fill"] <= 1
         assert plan["remat_saves"].startswith("ps_flash_o,ps_flash_lse")
