@@ -20,6 +20,7 @@ import sys
 
 import jax
 
+from ..obs import setup_tracer
 from ..parallel import initialize_multihost
 from ..trainer import Trainer
 from ..utils import enable_persistent_compile_cache, get_logger
@@ -54,12 +55,15 @@ def main(argv=None) -> dict:
     )
     args = parser.parse_args(argv)
 
-    initialize_multihost(
-        coordinator_address=args.coordinator_address,
-        num_processes=args.num_processes,
-        process_id=args.process_id,
-    )
-    num_workers = args.num_workers or len(jax.devices())
+    # the process's set-up record opens here; the Trainer's first log step
+    # prints where the time to the first step went (obs/trace.setup_summary)
+    with setup_tracer().span("setup.devices"):
+        initialize_multihost(
+            coordinator_address=args.coordinator_address,
+            num_processes=args.num_processes,
+            process_id=args.process_id,
+        )
+        num_workers = args.num_workers or len(jax.devices())
     tcfg = train_config_from(args)
     pcfg = ps_config_from(args, num_workers)
     trainer = Trainer(tcfg, pcfg)

@@ -198,6 +198,26 @@ def main(argv=None) -> dict:
     parser.add_argument("--eval-freq", type=int, default=0,
                         help="checkpoint every N steps (0 = only at the end)")
     args = parser.parse_args(argv)
+    from ..obs import (
+        NULL_TRACER, Tracer, run_header, setup_line_once, setup_tracer)
+
+    # the process's set-up record opens here; the first log step prints
+    # where the time to the first step went. The run's own span stream
+    # (--trace) comes first too, so that its first flush writes the set-up
+    # of this run's life; its header's geometry is filled in once known.
+    with setup_tracer().span("setup.devices"):
+        n_dev = len(jax.devices())
+        run_id = _shared_run_id()  # one id for the metrics and the spans, on every host
+    tr = NULL_TRACER
+    if args.trace:
+        tr = Tracer(
+            "train_lm",
+            path=os.path.join(
+                args.trace, f"trace_train_lm_p{jax.process_index()}.jsonl"
+            ),
+            run_id=run_id, pid=jax.process_index(), annotate=True,
+            with_setup=True,
+        )
 
     if args.shard_vocab and args.parallelism not in ("tp", "dp_tp"):
         raise ValueError(
@@ -255,7 +275,6 @@ def main(argv=None) -> dict:
         args.optimizer, lr, momentum=args.momentum,
         weight_decay=args.weight_decay,
     )
-    n_dev = len(jax.devices())
     n_shards = args.num_shards or n_dev
     key = jax.random.key(args.seed)
 
@@ -465,11 +484,8 @@ def main(argv=None) -> dict:
         "LM %dx d%d h%d (%d params), seq %d, %s",
         args.depth, args.dim, args.heads, n_params, args.seq_len, layout,
     )
-    from ..obs import NULL_TRACER, Tracer, run_header
     from ..obs.scopes import step_scopes_instant, write_step_scopes
 
-    # one id for the metrics and the span stream, on every host
-    run_id = _shared_run_id()
     geometry = {
         "parallelism": args.parallelism,
         "dim": args.dim, "depth": args.depth,
@@ -482,16 +498,8 @@ def main(argv=None) -> dict:
         args.metrics_file,
         run_header("train_lm", run_id=run_id, geometry=geometry),
     )
-    tr = NULL_TRACER
-    if args.trace:
-        tr = Tracer(
-            "train_lm",
-            path=os.path.join(
-                args.trace, f"trace_train_lm_p{jax.process_index()}.jsonl"
-            ),
-            run_id=run_id, pid=jax.process_index(), annotate=True,
-            geometry=geometry,
-        )
+    if tr.enabled:
+        tr.header["geometry"] = geometry
 
     # what `remat` keeps beside each block's input, by the function the
     # blocks' policy comes from (models/transformer.remat_plan), where the
@@ -660,6 +668,11 @@ def main(argv=None) -> dict:
                         tr.instant("step_scopes", **step_scopes_instant(scoped_step))
                     with tr.span("metrics_write"):
                         append_metrics_line(args.metrics_file, record)
+                    # set-up is over: the process's time to its first
+                    # step, by phase, once (after --resume too)
+                    line = setup_line_once()
+                    if line:
+                        logger.info(line)
                     flush_due = True
                 if profiling and step_no >= profile_stop:
                     host_sync(params)  # trace must contain retired work

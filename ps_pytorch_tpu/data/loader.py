@@ -146,6 +146,9 @@ def shard_for_worker(
     raise ValueError(f"unknown shard mode {mode!r}")
 
 
+_first_loader = True  # no loader has started in this process yet
+
+
 def prefetch_to_device(
     iterator: Iterator[dict], size: int = 2, device=None, tracer=None
 ) -> Iterator[dict]:
@@ -164,10 +167,18 @@ def prefetch_to_device(
     ``tracer`` (obs/trace.py) wraps each device_put dispatch in an
     ``h2d`` span carrying the ``bytes`` uploaded — dispatch walltime,
     not transfer completion: the transfer itself overlaps compute,
-    which is the point of prefetching."""
+    which is the point of prefetching. The process's FIRST loader is also
+    one ``setup.first_batch`` span of its set-up record (obs/trace.py),
+    from the loader's start to its first batches queued; no later loader
+    (the next epoch's, the next ``train()`` call's) records there."""
+    global _first_loader
+    from ..obs import NULL_TRACER, setup_tracer
+
     queue = collections.deque()
     if tracer is None:
-        from ..obs import NULL_TRACER as tracer  # noqa: N811 - constant
+        tracer = NULL_TRACER
+    setup = setup_tracer() if _first_loader else NULL_TRACER
+    _first_loader = False
 
     def enqueue(n):
         for _ in range(n):
@@ -183,7 +194,8 @@ def prefetch_to_device(
             with tracer.span("h2d", **attrs):
                 queue.append(jax.device_put(batch, device))
 
-    enqueue(size)
+    with setup.span("setup.first_batch"):
+        enqueue(size)
     while queue:
         yield queue.popleft()
         enqueue(1)

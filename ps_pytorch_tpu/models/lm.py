@@ -43,6 +43,7 @@ import importlib
 import json
 from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 
+from ..obs.trace import setup_span
 from . import transformer
 
 
@@ -99,6 +100,7 @@ def require_dense(cfg, where: str) -> None:
             + ")")
 
 
+@setup_span("setup.lm_config")  # the family's module loads here
 def load_lm_config(published: Union[str, Dict], **run):
     """A family's config from a published config.json (a path or its
     dict) by `model_type`; `run` are the run options every family has

@@ -5,8 +5,9 @@ Five layers (ARCHITECTURE §7g; the last two: PERF.md section 3):
 - ``obs.schema`` — the unified JSONL event registry (kind -> required
   fields + int contract), ``run_header`` records, run ids;
 - ``obs.trace`` — the host-side span tracer (ring-buffered, flushed
-  once per window while the device is busy, Chrome-trace exportable)
-  and NULL_TRACER, the zero-cost off switch;
+  once per window while the device is busy, Chrome-trace exportable),
+  NULL_TRACER, the zero-cost off switch, and the process's set-up
+  record (``setup_tracer``: always on, nothing in a loop records there);
 - ``obs.profiler`` — bounded ``jax.profiler`` capture windows for
   ``--profile-dir``;
 - ``obs.scopes`` — the names a step program writes INSIDE itself
@@ -34,6 +35,11 @@ from .trace import (
     NullTracer,
     Tracer,
     chrome_trace_events,
+    format_setup_summary,
+    setup_line_once,
+    setup_span,
+    setup_summary,
+    setup_tracer,
     summarize_spans,
 )
 
@@ -45,8 +51,13 @@ __all__ = [
     "SCHEMA_VERSION",
     "Tracer",
     "chrome_trace_events",
+    "format_setup_summary",
     "new_run_id",
     "run_header",
+    "setup_line_once",
+    "setup_span",
+    "setup_summary",
+    "setup_tracer",
     "summarize_spans",
     "validate_event",
 ]

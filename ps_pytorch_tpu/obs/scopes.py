@@ -35,6 +35,8 @@ import time
 import weakref
 from typing import Dict, Optional, Tuple
 
+from .trace import setup_tracer
+
 # what `scope()` is given (the relative names that models import) ...
 EMBED = "embed"
 MIXER_ATTENTION = "mixer/attention"
@@ -270,11 +272,14 @@ class ScopedStep:
     """A jitted step that can give the census of its own executable.
 
     Calling it calls the jitted function. The first call also notes the
-    arguments' abstract shapes, and a call made while a profiler capture
-    runs marks the step as profiled: two tests a call (`is None`, `is not
-    None`) on the way to the jitted function. `scopes()` reads the census
-    of the executable the calls ran. Everything else (`lower`, `trace`,
-    `eval_shape`, ...) is the jitted function's."""
+    arguments' abstract shapes and is the `setup.first_call` span of the
+    process's set-up record (obs/trace.setup_tracer: trace, lowering,
+    compile or cache load and the first dispatch, with jax's own spans
+    inside it), and a call made while a profiler capture runs marks the
+    step as profiled: two tests a call (`is None`, `is not None`) on the
+    way to the jitted function, and no record after the first. `scopes()`
+    reads the census of the executable the calls ran. Everything else
+    (`lower`, `trace`, `eval_shape`, ...) is the jitted function's."""
 
     def __init__(self, program: str, jitted):
         self.program = program
@@ -287,17 +292,20 @@ class ScopedStep:
         weakref.finalize(self, _released, jitted, ran).atexit = False
 
     def __call__(self, *args):
-        if self._avals is None or self._capture.profile_session is not None:
-            self._note(args)
-        return self._jitted(*args)
-
-    def _note(self, args) -> None:
         if self._avals is None:
-            import jax
-
-            self._avals = self._ran.avals = jax.tree_util.tree_map(_abstract, args)
+            return self._first_call(args)
         if self._capture.profile_session is not None:
             self._ran.profiled = True
+        return self._jitted(*args)
+
+    def _first_call(self, args):
+        import jax
+
+        self._avals = self._ran.avals = jax.tree_util.tree_map(_abstract, args)
+        if self._capture.profile_session is not None:
+            self._ran.profiled = True
+        with setup_tracer().span("setup.first_call", program=self.program):
+            return self._jitted(*args)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)

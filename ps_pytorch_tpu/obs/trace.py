@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import functools
 import json
 import os
 import time
@@ -156,6 +157,7 @@ class Tracer:
         annotate: bool = False,
         geometry: Optional[dict] = None,
         pid: int = 0,
+        with_setup: bool = False,
     ):
         self.component = component
         self.path = path
@@ -174,6 +176,12 @@ class Tracer:
         self.dropped = 0  # ring overflow count (oldest spans evicted)
         self._dropped_reported = 0  # watermark already flushed as a marker
         self._header_written = False
+        # a run's own stream (the Trainer's, cli.train_lm's): its first
+        # flush also writes what the process's set-up record holds of
+        # this stream's life (setup_tracer, below). Asked for, not the
+        # rule for every stream with a file: the serve loop's and a bench
+        # leg's share the process with the record and are not its run
+        self._with_setup = with_setup
         self._ann_cls = None
         if annotate:
             try:
@@ -244,6 +252,11 @@ class Tracer:
         self._buf.clear()
         return out
 
+    def snapshot(self) -> List[dict]:
+        """Every buffered span record, left where it is: a reader of the
+        set-up record does not take the spans from the next reader."""
+        return list(self._buf)
+
     def flush(self) -> int:
         """Append drained spans (validated) to the trace file, closed by
         one ``clock_sync`` record; writes the run_header (and the
@@ -280,10 +293,228 @@ class Tracer:
             if not self._header_written:
                 f.write(json.dumps(validate_event(dict(self.header))) + "\n")
                 self._header_written = True
-                spans.insert(0, self._sync0)
+                spans[:0] = [self._sync0] + (
+                    _setup_records_since(self._base) if self._with_setup else [])
             for rec in spans:
                 f.write(json.dumps(validate_event(rec)) + "\n")
         return n
+
+
+# ------------------------------------------------------- the set-up record
+#
+# ONE pathless Tracer a process, on whether or not --trace is given: what
+# the program does between the process's birth and its first steady step
+# happens once, so recording it costs nothing a step (nothing in a loop body
+# may record here). Who records what, and which metric or log line reads
+# it: PERF.md section 3.
+
+SETUP_RING = 2048
+_SETUP_TID = 1  # the set-up record's lane in a stream's Chrome trace
+# the three intervals jax itself times around every program it makes
+# (jax/_src/dispatch.py), by the span each becomes here
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_JAX_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+# setup_summary's parts that are read from spans, in the order they take a
+# moment two of them cover: a cache load inside a first call is a cache load
+SETUP_PARTS = (
+    ("cache_load_s", ("jax.cache_load",)),
+    ("trace_lower_s", ("jax.trace", "jax.lower")),
+    ("first_call_s", ("setup.first_call",)),
+    ("build_s", ("build", "setup.devices", "setup.lm_config", "setup.init_state",
+                 "setup.make_step", "setup.shard_state", "setup.first_batch")),
+)
+
+_SETUP: Optional[Tracer] = None
+_listening = False
+_jax_open = 0  # a jit traced inside another's trace or lowering leaves no span of its own
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since this process was started: /proc/self/stat's start time
+    against the clock it is counted on. None where there is no /proc."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        born = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - born
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def setup_tracer() -> Tracer:
+    """The process's set-up record, made at first use. It opens with the
+    `process_start` instant (`age_s`: how old the process was then: the
+    interpreter, the imports and, where the caller started the device
+    first, the runtime's bring-up) and from then on holds jax's own
+    intervals by program name beside the program's set-up spans."""
+    global _SETUP
+    if _SETUP is None:
+        _SETUP = Tracer("setup", path=None, ring=SETUP_RING)
+        age = process_age_s()
+        if age is not None:
+            _SETUP.instant("process_start", age_s=round(age, 6))
+        _listen_to_jax()
+    return _SETUP
+
+
+def setup_span(name: str):
+    """Decorator for a function of the program's set-up: each call is one
+    span `name` of the set-up record (never on a function a loop calls)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with setup_tracer().span(name):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap
+
+
+def _listen_to_jax() -> None:
+    """Registers the listeners once a process. They fire where jax traces,
+    lowers, compiles or loads a program, never at a call that finds its
+    executable: a steady loop compiles nothing and records nothing."""
+    global _listening
+    if _listening:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:  # a host-only use of the tracer: nothing to listen to
+        return
+    monitoring.register_scalar_listener(_on_jax_start)
+    monitoring.register_event_time_span_listener(_on_jax_span)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _listening = True
+
+
+def _on_jax_start(event, value, **_):
+    global _jax_open
+    if event in _JAX_SPANS:
+        _jax_open += 1
+
+
+def _on_jax_span(event, start, end, fun_name="", **_):
+    """The outermost intervals only: the functions jax traces while it
+    traces or lowers another (a rule written in jnp: a few hundred a step
+    program) are that program's time. jax's events carry wall-clock ends;
+    the span's start is this clock's now less the duration, so the record
+    stays on the one clock."""
+    global _jax_open
+    name = _JAX_SPANS.get(event)
+    if name is None:
+        return
+    _jax_open = max(_jax_open - 1, 0)
+    if _jax_open or _SETUP is None:
+        return
+    dur = end - start
+    _SETUP.add(name, _SETUP.now() - dur, dur, program=str(fun_name))
+
+
+def _on_jax_duration(event, secs, **_):
+    if event == _JAX_CACHE_LOAD and _SETUP is not None:
+        _SETUP.add("jax.cache_load", _SETUP.now() - secs, secs)
+
+
+def _setup_records_since(base: float) -> List[dict]:
+    """The set-up record as a run's own stream writes it: the records of
+    that stream's life (from its clock base on) and the process's birth, on
+    the stream's clock, in a category and a lane of their own (they come
+    from another span stack, so `async`)."""
+    if _SETUP is None:
+        return []
+    shift = _SETUP._base - base
+    return [{**rec, "t": round(rec["t"] + shift, 6), "cat": "setup", "async": True}
+            for rec in _SETUP.snapshot()
+            if rec["t"] + shift >= 0.0 or rec["name"] == "process_start"]
+
+
+def _covered_s(intervals) -> float:
+    """Seconds the (start, end) intervals cover together."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+def setup_summary(until: Optional[float] = None, records: Optional[List[dict]] = None,
+                  base: float = 0.0) -> dict:
+    """Where the time from the process's birth to `until` went (`until` on
+    `time.perf_counter()`, now if None), read from the set-up record's
+    spans that had ended by then (`records` on the clock base `base`: the
+    process's own where None).
+
+    Seven parts in seconds that sum to `stretch_s`: `before_program_s` (the
+    process's age at the record's first moment), then `cache_load_s`,
+    `trace_lower_s`, `first_call_s`, `build_s` as SETUP_PARTS lists them,
+    each without what an earlier one covers; `warm_s`, from the end of the
+    last step program's first call to `until` without what the four cover
+    there (the first steps' remainder, warm-up; None where no step was
+    called); `unplaced_s`, the rest (from the record's first moment to that
+    call's end, under none of the above). A part with no record of its
+    kind reads 0.0 (None only where the record holds nothing at all, and
+    `before_program_s` where there is no `process_start`). Beside them,
+    overlapping: `programs` and `compile_s` (jax's backend-compile step,
+    cache loads included: inside a first call or a build) and `cache_hits`.
+    benchmark/reducers/setup_spans.py makes the same numbers from the same
+    record by its own rules (tests/test_setup_record.py holds them equal)."""
+    if records is None:
+        tracer = setup_tracer()
+        records, base = tracer.snapshot(), tracer._base
+    end = (time.perf_counter() if until is None else until) - base
+    records = [r for r in records if r["t"] + r["dur"] <= end + 1e-6]
+    born = next((r for r in records if r["name"] == "process_start"), None)
+    first = born["t"] if born else min((r["t"] for r in records), default=end)
+    spans = lambda names: [(max(r["t"], first), r["t"] + r["dur"])
+                           for r in records if r["name"] in names]
+    out = {"before_program_s": born["age_s"] if born else None}
+    taken: list = []
+    for part, names in SETUP_PARTS:
+        own = spans(names)
+        # no record of its kind in a record that holds others: 0 s (a cold
+        # cache loads nothing); only an empty record says nothing
+        out[part] = _covered_s(own + taken) - _covered_s(taken) if records else None
+        taken += own
+    called = max((b for _, b in spans(("setup.first_call",))), default=None)
+    split = end if called is None else called
+    after = _covered_s([(max(a, split), b) for a, b in taken if b > split])
+    out["warm_s"] = None if called is None else (end - split) - after
+    out["unplaced_s"] = (split - first) - (_covered_s(taken) - after)
+    out["stretch_s"] = (out["before_program_s"] or 0.0) + end - first
+    compiles = spans(("jax.compile",))
+    out.update(programs=len(compiles), compile_s=_covered_s(compiles),
+               cache_hits=len(spans(("jax.cache_load",))))
+    return out
+
+
+def format_setup_summary(s: dict) -> str:
+    """The one log line at the end of set-up (cli.train, cli.train_lm)."""
+    sec = lambda key: "%.1f" % (s[key] or 0.0)
+    return (
+        f"set-up {sec('stretch_s')} s: before the program {sec('before_program_s')}, "
+        f"build {sec('build_s')}, trace+lower {sec('trace_lower_s')}, "
+        f"cache load {sec('cache_load_s')}, first call {sec('first_call_s')}, "
+        f"first steps and warm-up {sec('warm_s')}, unplaced {sec('unplaced_s')} "
+        f"({s['programs']} programs in {sec('compile_s')} s of compile or load, "
+        f"{s['cache_hits']} from the cache)")
+
+
+_line_given = False
+
+
+def setup_line_once() -> Optional[str]:
+    """`format_setup_summary` of the set-up so far, the first time a
+    process asks; None ever after (a run's first log step asks: the second
+    `train()` call of a process, or a second Trainer, is not its set-up)."""
+    global _line_given
+    if _line_given:
+        return None
+    _line_given = True
+    return format_setup_summary(setup_summary())
 
 
 # ------------------------------------------------------------------ reports
@@ -353,6 +584,7 @@ def chrome_trace_events(
             },
         }
     ]
+    lane_named = False
     for s in spans:
         if s.get("kind") != "span":
             continue
@@ -361,7 +593,15 @@ def chrome_trace_events(
         # each track properly nested (one slot serves one request at a
         # time, so a slot's lane never self-overlaps)
         tid = 0
-        if s.get("async"):
+        if s.get("cat") == "setup":
+            # the process's set-up record, written into this stream by
+            # its first flush: a lane of its own, named once
+            tid = _SETUP_TID
+            if not lane_named:
+                lane_named = True
+                out.append({"name": "thread_name", "ph": "M", "pid": p,
+                            "tid": tid, "args": {"name": "set-up"}})
+        elif s.get("async"):
             tid = 10 + int(s.get("slot", -1)) + 1
         base = header_base
         if syncs:

@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.lm import lm_family
 from ..obs.scopes import GRAD_REDUCE, HEAD_LOSS, UPDATE, ScopedStep, scope, stamped
+from ..obs.trace import setup_span
 from .mesh import WORKER_AXIS, replicated_sharding
 from .ring_attention import SEQ_AXIS
 
@@ -130,6 +131,7 @@ def _offsets_loss(logits, tokens, n_sp: int):
         return jnp.sum(nll * valid[None]) / (jnp.float32(b) * jnp.sum(valid))
 
 
+@setup_span("setup.init_state")
 def init_lm_state(
     cfg,
     tx: optax.GradientTransformation,
@@ -185,6 +187,7 @@ def update_plan(params, rows: int) -> dict:
             "params_apart": sum(math.prod(s) for s in apart)}
 
 
+@setup_span("setup.make_step")
 def make_lm_train_step(
     cfg,
     tx: optax.GradientTransformation,

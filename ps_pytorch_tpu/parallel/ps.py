@@ -46,6 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import apply_model
 from ..obs.scopes import AUGMENT, GRAD_REDUCE, MODEL, UPDATE, ScopedStep, scope, stamped
+from ..obs.trace import setup_span
 from ..ops.metrics import accuracy, cross_entropy_loss
 from ..ops.quantize import accum_dtype, dequantize_int8, quantize_int8
 from ..resilience.guard import (
@@ -515,6 +516,7 @@ def state_specs(cfg: PSConfig):
     )
 
 
+@setup_span("setup.shard_state")
 def shard_state(state: PSTrainState, mesh: Mesh, cfg: PSConfig) -> PSTrainState:
     """Place a host-built state onto the mesh with the right shardings."""
     specs = state_specs(cfg)
@@ -885,6 +887,7 @@ def _sharded_ps_update_pipelined(params, opt_state, grads, tx, cfg, layout,
     return new_params, new_opt_state, new_err
 
 
+@setup_span("setup.make_step")
 def make_ps_train_step(
     model,
     tx: optax.GradientTransformation,

@@ -53,6 +53,8 @@ from .obs import (
     Tracer,
     new_run_id,
     run_header,
+    setup_line_once,
+    setup_tracer,
     validate_event,
 )
 from .resilience import AdaptiveMaskController, resolve_fault_plan
@@ -213,6 +215,9 @@ class Trainer:
                 # scopes, so a --profile-dir capture shows the named
                 # phases on the profiler timeline too
                 annotate=True,
+                # its first flush also writes the set-up record of this
+                # trainer's life: `build*`, jax's intervals, the first call
+                with_setup=True,
             )
         # straggler watchdog event counter (observable --mode action)
         self.straggler_steps = 0
@@ -253,8 +258,9 @@ class Trainer:
             logger.warning("fault injection ACTIVE: %s", self.faults)
         # set-up the program owns, by part: data, model (mesh, network,
         # optimizer), state (initialised and sharded: the many one-op
-        # programs) and step (the jitted train and eval steps)
-        tr = self.tracer
+        # programs) and step (the jitted train and eval steps); into the
+        # process's set-up record, so the spans exist without --trace
+        tr = setup_tracer()
         with tr.span("build"):
             with tr.span("build.data"):
                 self.dataset = dataset or prepare_data(
@@ -267,8 +273,8 @@ class Trainer:
                 n_params = self._build_state(tcfg, pcfg)
             with tr.span("build.step"):
                 self._build_step(tcfg, pcfg)
-        if tr.enabled:
-            tr.header["geometry"] = self._geometry()
+        if self.tracer.enabled:
+            self.tracer.header["geometry"] = self._geometry()
         logger.info(
             "model %s (%d params), dataset %s%s, %d workers",
             tcfg.network,
@@ -995,6 +1001,11 @@ class Trainer:
                                 # so an aborting window is still in the JSONL
                                 with tr.span("guard", step=step_no):
                                     self._guard_check(metrics, step_no)
+                            # set-up is over: the process's time to its
+                            # first step, by phase, once
+                            line = setup_line_once()
+                            if line:
+                                logger.info(line)
                             # span I/O waits for the next dispatch (above):
                             # here the device is idle
                             flush_due = True

@@ -18,7 +18,10 @@ monotonic clock. This tool:
 - overlays metrics-JSONL events (``--metrics``: grad_skip, straggler
   storms, mask_adapt, resume_reshape, checkpoint quarantine/failure) as
   instant markers via their ``t_wall`` stamps;
-- prints a summary: per-phase count and p50/p99/total duration,
+- prints a summary: per-phase count and p50/p99/total duration (under
+  `setup`, apart: the phases of the set-up record a trainer's first flush
+  writes into its stream, and where each stream's time from the process's
+  birth to its first `step` went),
   per-component fraction of loop walltime by phase (where does a
   step's time go: the spans opened directly under the trainers' `step`
   span — fetch vs dispatch vs window_close — or the serve tick's
@@ -72,6 +75,7 @@ sys.path.insert(0, os.path.join(REPO, "tools"))
 
 from ps_pytorch_tpu.obs import (  # noqa: E402
     chrome_trace_events,
+    setup_summary,
     summarize_spans,
 )
 
@@ -214,7 +218,24 @@ def merge(
         })
 
     all_spans = [s for _, _, spans in streams for s in spans]
-    phases = summarize_spans(all_spans)
+    # the set-up record a trainer's first flush wrote into its stream
+    # (cat "setup": `build*`, `setup.*`, jax's own intervals by program,
+    # `process_start`) stands under its own heading: its phases, and for
+    # each stream where the time from the process's birth to the first
+    # `step` went (obs/trace.setup_summary, the set-up log line's numbers)
+    is_setup = lambda s: s.get("cat") == "setup"
+    phases = summarize_spans([s for s in all_spans if not is_setup(s)])
+    setup = {"phases": summarize_spans([s for s in all_spans if is_setup(s)]),
+             "time_to_first_step": []}
+    for _, header, spans in streams:
+        records = [s for s in spans if is_setup(s)]
+        if records:
+            first = min((float(s["t"]) for s in spans if s["name"] == "step"),
+                        default=max(float(s["t"]) + float(s["dur"]) for s in records))
+            setup["time_to_first_step"].append({
+                "component": header.get("component"), "pid": header.get("pid", 0),
+                **{k: v if v is None else round(v, 6)
+                   for k, v in setup_summary(first, records, base=0.0).items()}})
     # fraction of loop walltime by TOP-LEVEL phase, per component (a
     # nested span — h2d under fetch — must not double-count, and async
     # intervals overlap the loop phases so they must not either).
@@ -268,6 +289,7 @@ def merge(
         ],
         "n_overlay_events": len(overlays),
         "phases": phases,
+        "setup": setup,
         "instants": instants,
         "fraction_of_loop_walltime": fractions,
         "nesting_violations": nest_bad,
@@ -411,7 +433,7 @@ def main(argv=None) -> int:
             json.dump(summary, f, indent=2)
     if args.require_phases:
         need = {s for s in args.require_phases.split(",") if s}
-        missing = sorted(need - set(summary["phases"]))
+        missing = sorted(need - set(summary["phases"]) - set(summary["setup"]["phases"]))
         if missing:
             print(f"missing required phases: {missing}", file=sys.stderr)
             return 1
