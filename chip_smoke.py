@@ -213,7 +213,9 @@ LM_CONFIG_ARGS = [
     "--attention-impl", "flash", "--optimizer", "adam", "--lr", "0.0003",
     "--max-steps", "4", "--log-interval", "2", "--remat",
 ]
-LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm")
+# the dropless layer's grouped products and its way back to the tokens
+# (ops/grouped_matmul.py, ops/moe_rows_sum.py)
+LM_CONFIG_KERNELS = LM_KERNELS + ("ps_moe_gmm", "ps_moe_tgmm", "ps_moe_rows_sum")
 # the grouped-query families' rotation of q and k (ops/rope.py)
 LM_GQA_KERNELS = LM_CONFIG_KERNELS + ("ps_rope",)
 # the short conv of the state-space and the delta-rule mixers (ops/causal_conv.py)
@@ -433,7 +435,10 @@ def check_passes(leg, cfg, params, tokens):
     (one pass, whatever the routing), as the weights route and with every
     token sent to experts held here. The small presets hold half their
     experts, so the layer's own pass IS the worst case and the step above
-    ran one pass a layer; a quarter takes several."""
+    ran one pass a layer; a quarter takes several. Then the same call in
+    one pass with the way back to the tokens (the forward's combine and the
+    tokens' gradient) through ops/moe_rows_sum's compiled kernel beside the
+    one through its jnp twin, and the counter that says which of them ran."""
     from unittest import mock
 
     import jax
@@ -479,6 +484,19 @@ def check_passes(leg, cfg, params, tokens):
                 or abs(loss - want_loss) > 5e-3 * abs(want_loss)
                 or abs(norm - want_norm) > 5e-2 * want_norm):
             raise AssertionError(f"{leg}: the layer in passes is not the layer in one ({name})")
+    path = moe.rows_sum_path(cfg.hidden_size, "bfloat16")
+    loss, norm, c = run(params, worst)
+    with mock.patch.object(moe, "rows_sum", lambda ys, pos, held, twin: twin(ys)), \
+            mock.patch.object(moe, "rows_sum_path", lambda d, dtype: "xla"):
+        twin_loss, twin_norm, twin_c = run(params, worst)
+    read, here = int(c["moe_combine_rows_read"]), int(c["moe_rows_here"])
+    every = tokens.size * spec.top_k * int(twin_c["moe_passes"])
+    print(f"[{leg}] the way back to the tokens through {path} | its jnp twin: loss {loss:.6f} | "
+          f"{twin_loss:.6f}, gradient norm {norm:.6f} | {twin_norm:.6f}, moe_combine_rows_read "
+          f"{read} (rows here {here}) | {int(twin_c['moe_combine_rows_read'])}", flush=True)
+    if (path != "pallas" or read != here or int(twin_c["moe_combine_rows_read"]) != every
+            or abs(loss - twin_loss) > 1e-5 * abs(twin_loss) or abs(norm - twin_norm) > 1e-4 * twin_norm):
+        raise AssertionError(f"{leg}: the layer through ps_moe_rows_sum is not the layer through its twin")
 
 
 def check_conv(leg, cfg, params, tokens, what="conv kernels"):

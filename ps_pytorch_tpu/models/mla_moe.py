@@ -44,8 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.scopes import EMBED, FFN, HEAD_LOSS, MIXER_MLA, MLP, MOE, scope
-from ..parallel.moe import (DroplessSpec, moe_dropless_local, no_routing, routing_counters,
-                            stack_layers)
+from ..parallel.moe import (DroplessSpec, combine_rows_read, moe_dropless_local, no_routing,
+                            routing_counters, stack_layers)
 from .lm import LMFamily
 from .transformer import flash_layers, flash_plans, remat_block, select_attention
 
@@ -263,6 +263,8 @@ def ffn_half(cfg, x, blk, route=None):
             return y, no_routing(cfg.experts_held)
         with scope(MOE):
             routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd, route=route)
+            stats["combine_rows_read"] = combine_rows_read(
+                stats, n32.shape[0] * n32.shape[1] * cfg.routing.top_k, n32.shape[2], cd)
         y = x + routed.astype(cd)
         if "shared" not in blk:
             return y, stats

@@ -76,7 +76,7 @@ EVENT_KINDS: Dict[str, EventSpec] = {
         required=("step", "loss", "time_cost"),
         int_fields=("step", "moe_rows_here", "moe_max_expert_rows",
                     "moe_min_expert_rows", "moe_tokens_unserved",
-                    "moe_passes", "moe_buffer_rows",
+                    "moe_passes", "moe_buffer_rows", "moe_combine_rows_read",
                     "ssd_chunks_cut_off", "kda_chunks_cut_off"),
         doc="LM trainer log window (cli/train_lm.py); the moe_* routing "
             "counters ride along for a family with dropless expert layers, "

@@ -57,6 +57,7 @@ from ..obs.scopes import MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTE, scop
 from ..ops.grouped_matmul import (TILE_M, GroupLayout, buffer_rows, group_layout,
                                    grouped_matmul, layout_pass)
 from ..ops.metrics import next_token_nll
+from ..ops.moe_rows_sum import rows_sum, rows_sum_path
 from .tp import opt_state_specs
 
 if TYPE_CHECKING:  # pragma: no cover
@@ -338,8 +339,12 @@ def _rows_from_tokens(x, route):
 
 @jax.custom_vjp
 def _tokens_from_rows(y, route):
-    """[M, D] -> [N, D]: each token the sum of its held assignments' rows."""
-    return jnp.sum(_gather_assignments(y, route), axis=0)
+    """[M, D] -> [N, D]: each token the sum of its held assignments' rows:
+    ONE kernel that fetches the held rows alone where `rows_sum_path` says
+    so (the forward's combine and, as `_rows_from_tokens`' transpose, the
+    tokens' gradient), the gather over all N x k and its sum elsewhere."""
+    return rows_sum(y, route[2], route[3],
+                    lambda v: jnp.sum(_gather_assignments(v, route), axis=0))
 
 
 @jax.custom_vjp
@@ -434,10 +439,24 @@ def moe_dropless_local(n32, blk, spec: DroplessSpec, compute_dtype,
     return y.reshape(b, t, d), stats
 
 
+def combine_rows_read(stats: Dict, assignments: int, d: int, dtype):
+    """int32: the rows of [*, d] `dtype` that `_tokens_from_rows` reads as the
+    forward's combine of the layer whose counters `stats` are, over its
+    passes. The kernel fetches an assignment's row in the one pass that holds
+    it: the rows held here. The plain form reads a row for each of the
+    `assignments` (N x k) in every pass. The layer's caller adds it to the
+    counters under `combine_rows_read` (models/mla_moe.ffn_half)."""
+    if rows_sum_path(d, dtype) == "pallas":
+        return jnp.sum(stats["counts"], dtype=jnp.int32)
+    return stats["passes"] * jnp.int32(assignments)
+
+
 def no_routing(held: int) -> Dict:
-    """moe_dropless_local's counters for a layer that routes nothing."""
+    """A layer that routes nothing: moe_dropless_local's counters and its
+    caller's `combine_rows_read`."""
     return {"counts": jnp.zeros((held,), jnp.int32), "unserved": jnp.int32(0),
-            "passes": jnp.int32(0), "buffer_rows": jnp.int32(0)}
+            "passes": jnp.int32(0), "buffer_rows": jnp.int32(0),
+            "combine_rows_read": jnp.int32(0)}
 
 
 class _Plan(NamedTuple):
@@ -570,7 +589,10 @@ def routing_counters(stats):
     layer fits one pass) and buffer_rows (what a pass holds), each summed
     over layers under `moe_<name>` and per layer under
     `moe_<name>_per_layer`; and `moe_rows_max_over_mean`: the fullest
-    expert's rows over the mean expert's, layers summed. Where the layers
+    expert's rows over the mean expert's, layers summed. Where the layers'
+    caller counted it (`combine_rows_read` [L]) also `moe_combine_rows_read`,
+    the rows the forward's combine fetched: rows_here through the kernel of
+    ops/moe_rows_sum.py, N x k x passes through the plain form. Where the layers
     count their `relu` gate (gate_active, gate_entries [L]) also
     `moe_gate_active`, the share of the gate's pre-activations above zero
     over the rows routed here (a half at fresh weights; a SiLU in its place
@@ -579,6 +601,8 @@ def routing_counters(stats):
     per = {"rows_here": jnp.sum(counts, axis=1), "max_expert_rows": jnp.max(counts, axis=1),
            "min_expert_rows": jnp.min(counts, axis=1), "tokens_unserved": stats["unserved"],
            "passes": stats["passes"], "buffer_rows": stats["buffer_rows"]}
+    if "combine_rows_read" in stats:
+        per["combine_rows_read"] = stats["combine_rows_read"]
     out = {}
     for name, v in per.items():
         out[f"moe_{name}"] = jnp.sum(v)

@@ -31,7 +31,7 @@ from ps_pytorch_tpu.models.kda_hybrid import apply_kda_hybrid
 from ps_pytorch_tpu.models.lm import lm_family, load_lm_config
 from ps_pytorch_tpu.parallel.dp_sp import (
     init_lm_state, make_lm_train_step, make_mesh_2d, shard_tokens_2d)
-from ps_pytorch_tpu.parallel.moe import moe_dropless_local, no_routing
+from ps_pytorch_tpu.parallel.moe import combine_rows_read, moe_dropless_local, no_routing
 
 PUBLISHED = {
     "model_type": "kimi_linear", "vocab_size": 101, "hidden_size": 64, "num_hidden_layers": 5,
@@ -262,6 +262,9 @@ def _welded_block(cfg, x, blk, attend, pos):
     if "mlp" in blk:
         return x + mla_moe._gated_mlp(n32.astype(cd), blk["mlp"], cd), no_routing(cfg.experts_held)
     routed, stats = moe_dropless_local(n32, blk, cfg.routing, cd)
+    # the one line younger than PR 32's text: the half counts what its combine read (PR 53)
+    stats["combine_rows_read"] = combine_rows_read(
+        stats, n32.shape[0] * n32.shape[1] * cfg.routing.top_k, n32.shape[2], cd)
     return x + routed.astype(cd) + mla_moe._gated_mlp(n32.astype(cd), blk["shared"], cd), stats
 
 

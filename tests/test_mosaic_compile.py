@@ -430,6 +430,60 @@ def test_the_rotation_compiles_at_the_cells_shapes_with_its_time_in_rope_ms_alon
             assert not _pattern(other).search(name), (other, name)
 
 
+# the dropless layer's way back to the tokens at the four expert cells'
+# [N, k] over a pass's [M, D] (M = parallel/moe.pass_rows)
+ROWS_SUM_SHAPES = {"kanana": (16384, 6, 2048, 28672), "smallthinker": (16384, 6, 2560, 53248),
+                   "kimi": (16384, 8, 2304, 10240), "laguna": (8192, 10, 3072, 7168)}
+
+
+def _weighted_rows_sum(ys, w, pos, held):
+    from ps_pytorch_tpu.ops import moe_rows_sum as mr
+
+    def refuse(v):
+        pytest.fail("the kernel's path took the twin")
+    return mr.rows_sum(ys * w[:, None], pos, held, refuse)
+
+
+@pytest.mark.parametrize("cell", sorted(ROWS_SUM_SHAPES))
+def test_the_rows_sum_compiles_at_the_cells_shapes_with_its_time_in_moe_rows_sum_ms_alone(
+        shape, as_on_a_tpu, cell):
+    """`ps_moe_rows_sum` (ops/moe_rows_sum.py) behind the product that makes
+    its operand, bfloat16: ONE Mosaic call whose ys is handed over as a
+    BITCAST of what that product wrote (the tile order, pairs of rows a word,
+    costs no pass: a relayout there would cost more than the gather the
+    kernel replaces, and so would a pack), the landing buffers inside the
+    VMEM the call asks for. `moe_rows_sum_ms` reads it by name; no other
+    kernel's metric takes it in, `moe_routed_ms` (`ps_moe_t?gmm`) and
+    `moe_buffer_ms` (the N x k in an op's name) among them."""
+    import re
+
+    from benchmark.reducers.trace import short_name
+    from ps_pytorch_tpu.ops import moe_rows_sum as mr
+    from ps_pytorch_tpu.ops.pallas_mode import kernel_census
+
+    n, k, d, m = ROWS_SUM_SHAPES[cell]
+    assert mr.rows_sum_path(d, jnp.bfloat16) == "pallas"
+    plan = mr.plan_rows(n, k, d, jnp.bfloat16)
+    assert n % plan.tile == 0 and plan.vmem_bytes(k) <= mr.BUFFER_BYTES
+    text = jax.jit(_weighted_rows_sum).lower(
+        shape((m, d)), shape((m,)), shape((n, k), jnp.int32), shape((n, k), jnp.bool_)
+    ).compile().as_text()
+    assert kernel_census(text) == {"jnp": {}, "mosaic": {"ps_moe_rows_sum": 1}}
+    (line,) = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    pairs = f"bf16[{m // 8},{d // 128},4,2,128]"
+    words = n // plan.tile * plan.entries_block(k)
+    assert pairs in line and f"bf16[{n},{d}]" in line and f"s32[{words}]" in line
+    assert re.search(re.escape(pairs) + r"\{4,3,2,1,0:T\(2,128\)\(2,1\)(S\(\d\))?\} bitcast\(", text)
+    assert not re.search(r"(u32|bf16)\[[0-9,]+\]\S* (copy|transpose|reshape)\(", text)
+    assert not re.search(r"u32\[[0-9]", text)
+    (limit,) = re.findall(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"', line)
+    assert int(limit) == plan.vmem_bytes(k) + mr.VMEM_ROOM
+    name = short_name(line.strip().removeprefix("ROOT "))
+    assert _pattern("moe_rows_sum_ms").search(name), name
+    for other in OTHER_KERNEL_METRICS + ("rope_ms",):
+        assert not _pattern(other).search(name), (other, name)
+
+
 @pytest.mark.parametrize("leg", ["lm_swa", "lm_pre"])
 def test_a_step_under_remat_holds_the_rotations_its_plans_say(topo, as_on_a_tpu, leg):
     """The small presets of chip_smoke.py's `lm_swa` and `lm_pre` legs as
@@ -570,8 +624,8 @@ def test_the_update_stands_apart_of_the_wide_products_and_rides_the_narrow_ones(
 
 def _moe_calls(text):
     """{kernel: [(the call's line, the computation that holds it)]} for the
-    grouped products' Mosaic calls in a compiled program's text, and the
-    name of every `while`'s body."""
+    grouped products' Mosaic calls and the way back's (`ps_moe_rows_sum`) in
+    a compiled program's text, and the name of every `while`'s body."""
     import io
     import re
 
@@ -580,13 +634,21 @@ def _moe_calls(text):
     comps, _ = hlo._computations(io.StringIO(text))
     line_of = {m.group(1): line for line in text.splitlines()
                if hlo.MOSAIC_TARGET in line and (m := hlo._INSTR.match(line))}
-    calls = {"ps_moe_gmm": [], "ps_moe_tgmm": []}
+    calls = {"ps_moe_gmm": [], "ps_moe_tgmm": [], "ps_moe_rows_sum": []}
     for comp, body in comps.items():
         for ins in body:
             kernel = (hlo._KERNEL.findall(ins.op_name) or [""])[-1]
             if ins.mosaic and kernel in calls:
                 calls[kernel].append((line_of[ins.name], comp))
     return calls, set(re.findall(r"\bbody=%?([\w.\-]+)", text))
+
+
+def _assignment_gathers(text, n, k, d):
+    """The arrays of a compiled program shaped like the plain way back's
+    gather, a row of d for each of the N x k assignments ([k, N, D])."""
+    import re
+
+    return re.findall(rf"(?:bf16|f32)\[{k},{n},{d}\]", text)
 
 
 def test_an_expert_step_under_remat_holds_the_layer_once_inside_its_loops(topo, as_on_a_tpu):
@@ -599,19 +661,28 @@ def test_an_expert_step_under_remat_holds_the_layer_once_inside_its_loops(topo, 
     forward's three products in the forward's `while`, the re-run, the
     transposes and the weight gradients in the backward's, and the
     recomputed half-block runs no loop of its own. A second size of the
-    layer behind a `cond` would hold every one of them twice."""
+    layer behind a `cond` would hold every one of them twice. Beside them
+    the way back to the tokens, `ps_moe_rows_sum`: the forward's combine in
+    the forward's loop, the tokens' gradient in the backward's (the step's
+    jaxpr holds a third site, the re-run's combine, whose result nothing
+    reads: XLA drops it; tests/test_moe_rows_sum.py counts the jaxpr's), and
+    no gather over the N x k assignments is left in the step."""
     import chip_smoke
     from ps_pytorch_tpu.models.lm import load_lm_config
 
     cfg = load_lm_config(dict(chip_smoke.LM_CONFIG), attention_impl="flash", remat=True,
                          compute_dtype=jnp.bfloat16)
-    calls, bodies = _moe_calls(_lm_step_compiled(topo, cfg, 2, 256).as_text())
-    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (9, 3)
+    text = _lm_step_compiled(topo, cfg, 2, 256).as_text()
+    calls, bodies = _moe_calls(text)
+    assert {kernel: len(found) for kernel, found in calls.items()} == {
+        "ps_moe_gmm": 9, "ps_moe_tgmm": 3, "ps_moe_rows_sum": 2}
     held_in = {comp for found in calls.values() for _, comp in found}
     assert held_in <= bodies and len(held_in) == 2, held_in      # forward's loop, backward's
     per_loop = sorted(sum(comp == b for found in calls.values() for _, comp in found)
                       for b in held_in)
-    assert per_loop == [3, 9]
+    assert per_loop == [3 + 1, 9 + 1]
+    assert sorted(comp for _, comp in calls["ps_moe_rows_sum"]) == sorted(held_in)
+    assert not _assignment_gathers(text, 2 * 256, cfg.routing.top_k, cfg.hidden_size)
 
 
 def test_the_layer_in_passes_compiles_at_the_laguna_cells_shapes_with_its_vmem_statement(
@@ -621,8 +692,9 @@ def test_the_layer_in_passes_compiles_at_the_laguna_cells_shapes_with_its_vmem_s
     experts: a pass of 7,168 rows where the worst case is 83,968. The
     gradient's program holds what the layer before the passes held (6 and 3
     calls: the forward's own loop is dead once only gradients are asked
-    for), all inside ONE `while` body, and every `ps_moe_gmm` still states
-    its own VMEM limit (ops/grouped_matmul.GMM_VMEM_DEFAULT)."""
+    for), all inside ONE `while` body with the tokens' gradient, one
+    `ps_moe_rows_sum`, and every `ps_moe_gmm` still states its own VMEM limit
+    (ops/grouped_matmul.GMM_VMEM_DEFAULT)."""
     from ps_pytorch_tpu.parallel import moe
 
     spec = moe.DroplessSpec(num_experts=256, top_k=10, experts_held=8, routed_scale=2.5)
@@ -638,9 +710,11 @@ def test_the_layer_in_passes_compiles_at_the_laguna_cells_shapes_with_its_vmem_s
 
     text = jax.jit(jax.grad(loss, (0, 1))).lower(f32((1, n, d)), blk).compile().as_text()
     calls, bodies = _moe_calls(text)
-    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (6, 3)
+    assert {kernel: len(found) for kernel, found in calls.items()} == {
+        "ps_moe_gmm": 6, "ps_moe_tgmm": 3, "ps_moe_rows_sum": 1}
     held_in = {comp for found in calls.values() for _, comp in found}
     assert len(held_in) == 1 and held_in <= bodies
+    assert not _assignment_gathers(text, n, 10, d)
     for line, _ in calls["ps_moe_gmm"]:
         assert '"scoped_memory_configs":[{' in line
         assert "7168" in line and "83968" not in line
@@ -679,7 +753,8 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
     a layer each way at `[28, 16384, 128]`, three walking the band's 252
     live tiles and one the causal 528; the grouped products 9 + 3 a layer at
     2560 x 768 over 16 experts and a pass of `pass_rows(16384, spec)` rows,
-    never the worst case's; no rotation pass in the global layer, and in
+    never the worst case's; `ps_moe_rows_sum` twice a layer over those rows;
+    no rotation pass in the global layer, and in
     the others `ps_rope` on q and k each way (the operands are kept, so
     `remat` runs no rotation again)."""
     import re
@@ -697,6 +772,7 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
     text = compiled.as_text()
     assert kernel_census(text) == {"jnp": {}, "mosaic": {
         "ps_flash_fwd": 4, "ps_flash_dqkv": 4, "ps_moe_gmm": 36, "ps_moe_tgmm": 12,
+        "ps_moe_rows_sum": 4 * 2,   # the forward's combine and the tokens' gradient a layer
         "ps_rope": 3 * 2 * 2}}      # q and k, forward and backward, in the three rotary layers
     spec = cfg.routing
     rows, worst = moe.pass_rows(16384, spec), gm.buffer_rows(16384 * 6, 16)
@@ -709,6 +785,10 @@ def test_the_prerouted_cells_step_fits_the_chip_with_its_kernels_at_the_cells_sh
             walks[kernel].append(int(re.search(r"s32\[(\d+)\]\{0\}", line).group(1)))
         elif kernel == "ps_rope":
             assert re.search(r"bf16\[1,16384,(3584|512)\]", line), line
+        elif kernel == "ps_moe_rows_sum":
+            # a pass's rows in the chip's own tile order, pairs of rows a word; the words; [N, D] out
+            assert f"bf16[{rows // 8},20,4,2,128]" in line and "bf16[16384,2560]" in line, line
+            assert "s32[131072]" in line and str(worst) not in line      # 128 tiles' 768 words in blocks of 1,024
         else:
             assert f"[{rows}," in line and str(worst) not in line, line
             assert re.search(r"\[16,(2560,768|768,2560)\]", line), line
@@ -749,7 +829,9 @@ def test_the_accepted_expert_cells_still_hold_the_layer_once_inside_its_loops(
     to one expert layer) the step holds `ps_moe_gmm` 9 times and
     `ps_moe_tgmm` 3, in the forward's loop and the backward's, on operands
     shaped as before this family came: a pass's rows, the held experts'
-    matrices, and nothing the ReLU gate's count would add."""
+    matrices, and nothing the ReLU gate's count would add. The way back to
+    the tokens is `ps_moe_rows_sum` once in either loop over the pass's rows
+    in the chip's tile order, and no [k, N, D] gather is left."""
     import re
 
     from ps_pytorch_tpu.models.lm import load_lm_config
@@ -759,10 +841,15 @@ def test_the_accepted_expert_cells_still_hold_the_layer_once_inside_its_loops(
                          compute_dtype=jnp.bfloat16)
     assert (cfg.routing.scores, cfg.routing.router_input, cfg.routing.activation) == (
         "sigmoid", "ffn_norm", "silu")
-    calls, bodies = _moe_calls(_lm_step_compiled(topo, cfg, batch, 8192).as_text())
-    assert (len(calls["ps_moe_gmm"]), len(calls["ps_moe_tgmm"])) == (9, 3)
+    text = _lm_step_compiled(topo, cfg, batch, 8192).as_text()
+    calls, bodies = _moe_calls(text)
+    assert {kernel: len(found) for kernel, found in calls.items()} == {
+        "ps_moe_gmm": 9, "ps_moe_tgmm": 3, "ps_moe_rows_sum": 2}
     held_in = {comp for found in calls.values() for _, comp in found}
     assert held_in <= bodies and len(held_in) == 2, held_in
+    assert sorted(comp for _, comp in calls["ps_moe_rows_sum"]) == sorted(held_in)
+    n, k = batch * 8192, cfg.routing.top_k
+    assert not _assignment_gathers(text, n, k, d)
     shapes = {kernel: {tuple(re.findall(r"(?:bf16|f32|s32)\[[0-9,]+\]", line))
                        for line, _ in found} for kernel, found in calls.items()}
     walk = (f"s32[{rows // gm.TILE_M}]", "s32[1]")
@@ -773,3 +860,10 @@ def test_the_accepted_expert_cells_still_hold_the_layer_once_inside_its_loops(
         (wide, *walk, narrow, up), (narrow, *walk, wide, down)}      # their transposes
     assert shapes["ps_moe_tgmm"] == {
         (f"f32[{held},{d},{f}]", *walk, wide, narrow), (f"f32[{held},{f},{d}]", *walk, narrow, wide)}
+    # [N, D] out of the tiles' words and the pass's rows, pairs of rows a word
+    from ps_pytorch_tpu.ops import moe_rows_sum as mr
+
+    plan = mr.plan_rows(n, k, d, jnp.bfloat16)
+    words = n // plan.tile * plan.entries_block(k)
+    assert shapes["ps_moe_rows_sum"] == {
+        (f"bf16[{n},{d}]", f"s32[{words}]", f"bf16[{rows // 8},{d // 128},4,2,128]")}
